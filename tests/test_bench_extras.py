@@ -1,7 +1,7 @@
 """bench.py driver-artifact shape: the LLM/Wan extras folded into the one
-JSON line (VERDICT r4 #2) must keep their schema and degrade — never
-crash — when a tool fails, since the headline SD15 measurement must
-survive any extras breakage."""
+JSON line must keep their schema; a tool that fails becomes an error
+record in the line — and a non-zero exit of the run, so a cell that went
+missing cannot pass for a measurement."""
 
 import importlib.util
 import json
@@ -90,7 +90,7 @@ def test_llm_extras_schema(monkeypatch):
                                            stderr="")
 
     monkeypatch.setattr(subprocess, "run", fake_run)
-    out = bench._llm_extras(lambda *a: None)
+    out = bench._llm_extras(8)
     assert set(out) == {"continuous_e2e", "prefill_8k", "shared_prefix",
                         "paged", "speculative", "host_tier",
                         "chunked_prefill", "tp", "replay"}
@@ -163,7 +163,7 @@ def test_wan_extras_schema(monkeypatch):
                                            stderr="")
 
     monkeypatch.setattr(subprocess, "run", fake_run)
-    out = bench._wan_extras(lambda *a: None)
+    out = bench._wan_extras()
     assert out["mfu"] == 0.65 and out["seconds_per_video"] == 6.0
     assert check_meta(out["meta"]) == []
     assert "extra" not in out
@@ -171,23 +171,24 @@ def test_wan_extras_schema(monkeypatch):
 
 def test_extras_degrade_on_tool_failure(monkeypatch):
     """A crashing tool yields {'error': ...}, never an exception — the
-    SD15 headline must not die because an extra did."""
+    rest of the line stays readable (main() turns the record into a
+    non-zero exit: test_failed_child_fails_the_run)."""
     bench = load_bench()
 
     def fake_run(cmd, capture_output, text, timeout):
         raise subprocess.TimeoutExpired(cmd, timeout)
 
     monkeypatch.setattr(subprocess, "run", fake_run)
-    out = bench._llm_extras(lambda *a: None)
+    out = bench._llm_extras(8)
     assert "error" in out["continuous_e2e"] and "error" in out["prefill_8k"]
     assert "error" in out["shared_prefix"] and "error" in out["paged"]
     assert "error" in out["speculative"] and "error" in out["replay"]
-    wan = bench._wan_extras(lambda *a: None)
+    wan = bench._wan_extras()
     assert "error" in wan
 
 
 def test_run_tool_nonzero_exit_is_error_record(monkeypatch):
-    """ADVICE r5: a tool that exits nonzero after printing a stale JSON-
+    """A tool that exits nonzero after printing a stale JSON-
     looking line must be recorded as an error (with the stderr tail), not
     trusted as a measurement."""
     bench = load_bench()
@@ -198,10 +199,83 @@ def test_run_tool_nonzero_exit_is_error_record(monkeypatch):
             stderr="Traceback ...\nRuntimeError: device fell over")
 
     monkeypatch.setattr(subprocess, "run", fake_run)
-    out = bench._run_tool(lambda *a: None, "t", ["tools/bench_llm.py"])
+    out = bench._run_tool("t", ["tools/bench_llm.py"])
     assert out["error"] == "exit code 3"
     assert "device fell over" in out["stderr_tail"]
     assert "metric" not in out and "value" not in out
+
+
+def test_tp_cell_is_skipped_below_eight_devices(monkeypatch):
+    """The tp=8 sweep needs eight chips: on a smaller host the cell says so
+    instead of launching a child that can only fail."""
+    bench = load_bench()
+    calls = []
+
+    def fake_run(cmd, capture_output, text, timeout):
+        calls.append(cmd)
+        return subprocess.CompletedProcess(
+            cmd, 0, stdout=json.dumps({"value": 1.0}) + "\n", stderr="")
+
+    monkeypatch.setattr(subprocess, "run", fake_run)
+    out = bench._llm_extras(1)
+    assert "skipped" in out["tp"] and "error" not in out["tp"]
+    assert not any("--tp" in c for c in calls)
+
+
+def _fake_children(fail_substr):
+    """subprocess.run stand-in: every child prints a valid artifact, except
+    the one whose command contains ``fail_substr``, which exits 3."""
+    def fake_run(cmd, capture_output, text, timeout):
+        if fail_substr and any(fail_substr in c for c in cmd):
+            return subprocess.CompletedProcess(cmd, 3, stdout="",
+                                               stderr="boom")
+        payload = {"metric": "m", "value": 1.0, "unit": "u",
+                   "device": {"platform": "tpu", "kind": "TPU v5 lite",
+                              "count": 1},
+                   "content_check": "pass", "families": {}}
+        return subprocess.CompletedProcess(
+            cmd, 0, stdout=json.dumps(payload) + "\n", stderr="")
+    return fake_run
+
+
+def test_failed_child_fails_the_run(monkeypatch, capsys):
+    """ISSUE 21 item 4: an extra that fails, a content check that errors,
+    or a failed SD measurement each make bench.py exit non-zero (the line
+    is still printed, with the error record, unless SD itself failed)."""
+    bench = load_bench()
+    monkeypatch.setattr(subprocess, "run", _fake_children(None))
+    assert bench.main([]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["content_check"] == "pass"
+    assert "skipped" in line["llm"]["tp"]          # one device reported
+
+    monkeypatch.setattr(subprocess, "run", _fake_children("--speculative"))
+    assert bench.main([]) == 1
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["llm"]["speculative"]["error"] == "exit code 3"
+
+    monkeypatch.setattr(subprocess, "run", _fake_children("verify_hw.py"))
+    assert bench.main([]) == 1
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["content_check"].startswith("error:")
+
+    monkeypatch.setattr(subprocess, "run", _fake_children("--phase"))
+    assert bench.main([]) == 1
+    assert capsys.readouterr().out.strip() == ""   # no result printed
+
+
+def test_bench_small_exits_nonzero_when_its_child_fails():
+    """The real CLI, no fakes: ``bench.py --small`` runs the SD measurement
+    as a child; a dp mesh wider than the host makes that child fail, and
+    the parent must exit non-zero without printing a result."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py"), "--small",
+         "--dp", "64"], env=env, capture_output=True, text=True,
+        timeout=300, cwd=REPO)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "exited" in proc.stderr
 
 
 def test_meta_contract_matches_producer():

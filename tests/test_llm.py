@@ -116,7 +116,10 @@ def test_llm_server_endpoints(gen):
 
             r = await client.get("/props")
             j = await r.json()
-            assert j["n_ctx"] == 64 and j["backend"] == "jax/tpu"
+            assert j["n_ctx"] == 64
+            # the device as JAX reports it, not a literal
+            assert j["backend"] == {"platform": "cpu", "kind": "cpu",
+                                    "count": len(jax.devices())}
 
             r = await client.post("/tokenize", json={"content": "hi"})
             toks = (await r.json())["tokens"]

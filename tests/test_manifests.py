@@ -410,8 +410,8 @@ def test_flux_monitoring_kustomization_wired():
 
 def test_persistent_compile_cache_wired_into_serving_pods():
     """Every TPU serving Deployment (llm, wan, sd15) must set
-    TPUSTACK_COMPILE_CACHE (the stack's persistent-XLA-cache env contract,
-    read by ``tpustack.utils.enable_compile_cache``) to a path under a
+    JAX_COMPILATION_CACHE_DIR (JAX's own variable — the one spelling
+    ``tpustack.utils.enable_compile_cache`` honours) to a path under a
     mounted volume, so pod restarts reuse compiled programs instead of
     paying the multi-minute cold jit again."""
     serving = [CLUSTER / "apps" / "llm" / "deployment.yaml",
@@ -424,17 +424,18 @@ def test_persistent_compile_cache_wired_into_serving_pods():
             containers = d["spec"]["template"]["spec"]["containers"]
             server = containers[0]
             env = {e["name"]: e.get("value") for e in server.get("env", [])}
-            cache = env.get("TPUSTACK_COMPILE_CACHE")
-            assert cache, f"{p}: server container missing TPUSTACK_COMPILE_CACHE"
+            cache = env.get("JAX_COMPILATION_CACHE_DIR")
+            assert cache, (f"{p}: server container missing "
+                           "JAX_COMPILATION_CACHE_DIR")
             mounts = [m["mountPath"] for m in server.get("volumeMounts", [])]
             assert any(cache == m or cache.startswith(m.rstrip("/") + "/")
                        for m in mounts), (
-                f"{p}: TPUSTACK_COMPILE_CACHE={cache} is not under any "
+                f"{p}: JAX_COMPILATION_CACHE_DIR={cache} is not under any "
                 f"volumeMount {mounts} — the cache would die with the pod")
     # the HelmRelease variant carries the same contract through values
     hr = _load_all(CLUSTER / "apps" / "sd15-api" / "helmrelease.yaml")
     text = yaml.safe_dump(hr)
-    assert "TPUSTACK_COMPILE_CACHE" in text
+    assert "JAX_COMPILATION_CACHE_DIR" in text
 
 
 # ------------------------------------------------------------ resilience

@@ -1,0 +1,170 @@
+"""Cross-lower every Pallas entry point for TPU from the CPU host, at the
+shapes the servers run.
+
+Interpret mode (what the rest of tier-1 runs the kernels in) skips the
+Pallas→Mosaic lowering, so a BlockSpec the TPU compiler refuses — a block
+whose last two dims are neither whole axes nor tile multiples — used to
+surface only on a chip.  ``.trace(...).lower(lowering_platforms=("tpu",))``
+runs that lowering on any host in well under a second per case.  It is the
+pre-check before a chip run, not a replacement for one: Mosaic's own
+passes (layout inference, VMEM limits) only run under libtpu — the
+hardware tier (``tests/test_tpu_hw.py``) covers those.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from tpustack.ops.pallas.flash_attention import (flash_attention,
+                                                 paged_attention_partial)
+
+
+def _lower_for_tpu(fn, *avals):
+    return jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# (b, q heads, kv heads, head_dim, block, blocks/seq, pool blocks, q dtype):
+# the Deployment's Qwen2.5-7B (8 slots, ctx 4096 / 64-token blocks, 512+1
+# pool blocks) and the tiny preset the CPU servers boot
+PAGED_MODELS = {
+    "qwen25_7b": (8, 28, 4, 128, 64, 64, 513, jnp.bfloat16),
+    "tiny": (2, 4, 2, 16, 8, 16, 33, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify_k4"])
+@pytest.mark.parametrize("int8_pool", [False, True], ids=["pool", "int8pool"])
+@pytest.mark.parametrize("model", sorted(PAGED_MODELS))
+def test_paged_attention_lowers_for_tpu(model, int8_pool, s):
+    b, h, hkv, d, blk, nb, n_pool, qdt = PAGED_MODELS[model]
+    pool = _sds((n_pool, blk, hkv, d), jnp.int8 if int8_pool else qdt)
+    args = [_sds((b, s, h, d), qdt), pool, pool,
+            _sds((b, nb), jnp.int32), _sds((b,), jnp.int32)]
+    if int8_pool:
+        scales = _sds((n_pool, blk, hkv), jnp.float32)
+        args += [scales, scales]
+
+    def fn(q, pk, pv, bt, lens, ks=None, vs=None):
+        return paged_attention_partial(q, pk, pv, bt, lens, k_scale=ks,
+                                       v_scale=vs, interpret=False)
+
+    _lower_for_tpu(fn, *args)
+
+
+# panel kernel: (q tokens, k tokens, heads, head_dim)
+PANEL_SHAPES = {
+    "sd15_self_4096_d40": (4096, 4096, 8, 40),
+    "wan_self_2560": (2560, 2560, 12, 128),
+    "wan_self_8320": (8320, 8320, 12, 128),
+    "wan_cross_2560x512": (2560, 512, 12, 128),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(PANEL_SHAPES))
+def test_panel_attention_lowers_for_tpu(shape):
+    sq, sk, h, d = PANEL_SHAPES[shape]
+    q = _sds((1, sq, h, d), jnp.bfloat16)
+    kv = _sds((1, sk, h, d), jnp.bfloat16)
+    _lower_for_tpu(lambda q, k, v: flash_attention(q, k, v, interpret=False),
+                   q, kv, kv)
+
+
+def test_streaming_attention_lowers_for_tpu():
+    """LLM chunked prefill: an 8k chunk over a 32k cache, GQA 28/4, traced
+    offset and length (one compiled program serves every chunk)."""
+    q = _sds((1, 8192, 28, 128), jnp.bfloat16)
+    kv = _sds((1, 32768, 4, 128), jnp.bfloat16)
+    scalar = _sds((), jnp.int32)
+
+    def fn(q, k, v, off, n):
+        return flash_attention(q, k, v, causal=True, q_offset=off, kv_len=n,
+                               interpret=False)
+
+    _lower_for_tpu(fn, q, kv, kv, scalar, scalar)
+
+
+def test_refused_block_shape_fails_on_cpu():
+    """The check has teeth: a block of ONE kv head out of four (the shape
+    the pre-repair paged kernel asked for) is refused by the lowering on
+    this host, no chip needed."""
+    from jax.experimental import pallas as pl
+
+    def fn(x):
+        return pl.pallas_call(
+            lambda x_ref, o_ref: o_ref.__setitem__(..., x_ref[...]),
+            grid=(4,),
+            in_specs=[pl.BlockSpec((1, 64, 1, 128),
+                                   lambda i: (0, 0, i, 0))],
+            out_specs=pl.BlockSpec((1, 64, 1, 128), lambda i: (0, 0, i, 0)),
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype))(x)
+
+    with pytest.raises(ValueError, match="last two dimensions"):
+        _lower_for_tpu(fn, _sds((2, 64, 4, 128), jnp.bfloat16))
+
+
+# ------------------------------------------- kernels under the serving mesh
+def test_serving_programs_lower_for_tpu_under_a_tp_mesh(monkeypatch):
+    """A Mosaic kernel cannot be GSPMD-partitioned: traced as a TPU would
+    trace them (``auto`` picks the flash kernels, interpret off), the tp
+    server's admission prefill at a ≥1k bucket and its chunked long-prompt
+    prefill must still lower — the kernels run per head shard.  On four
+    v5e chips the un-wrapped program died with "Mosaic kernels cannot be
+    automatically partitioned" (PR 21); this reproduces that on the CPU."""
+    import dataclasses
+
+    from tpustack.models.llama import LlamaConfig, init_kv_caches
+    from tpustack.models.llm_generate import Generator
+    from tpustack.parallel import build_mesh
+
+    mesh = build_mesh((1, 1, 2, 1), devices=jax.devices()[:2])
+    cfg = dataclasses.replace(LlamaConfig.tiny(max_seq=4096),
+                              kv_quant="int8")
+    gen = Generator(cfg, dtype=jnp.bfloat16, mesh=mesh)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    caches = jax.eval_shape(
+        lambda: init_kv_caches(cfg, 1, dtype=jnp.bfloat16))
+    tokens = _sds((1, 1024), jnp.int32)
+    one = _sds((1,), jnp.int32)
+    # prefill from 0 at a 1k bucket: in-bucket causal, auto → panel kernel
+    lowered = Generator._prefill.trace(gen, gen.params, tokens, one,
+                                       caches).lower(
+        lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+    # a later chunk of a long prompt: the k-streaming kernel, traced offset
+    lowered = Generator._prefill_chunk.trace(
+        gen, gen.params, tokens, _sds((), jnp.int32), one,
+        caches).lower(lowering_platforms=("tpu",))
+    assert "tpu_custom_call" in lowered.as_text()
+
+
+def test_per_head_shard_matches_unsharded_attention():
+    """The wrapper's numerics: the flash kernel run per tp head shard
+    (interpret mode, GQA 4/2 heads over tp=2) equals plain XLA attention."""
+    import numpy as np
+
+    from tpustack.models.llama import LlamaConfig, _per_head_shard
+    from tpustack.ops.attention import dot_product_attention
+    from tpustack.parallel import build_mesh
+
+    mesh = build_mesh((1, 1, 2, 1), devices=jax.devices()[:2])
+    cfg = LlamaConfig.tiny()
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (1, 64, cfg.n_heads, 16), jnp.float32)
+    k = jax.random.normal(ks[1], (1, 64, cfg.n_kv_heads, 16), jnp.float32)
+    v = jax.random.normal(ks[2], (1, 64, cfg.n_kv_heads, 16), jnp.float32)
+    fn, ok = _per_head_shard(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=32,
+                                        interpret=True), mesh, cfg)
+    assert ok
+    ref = dot_product_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(np.asarray(jax.jit(fn)(q, k, v)),
+                               np.asarray(ref), atol=2e-5)
+    # heads that tp does not divide: handed back unwrapped, and says so
+    import dataclasses
+
+    odd = dataclasses.replace(cfg, n_kv_heads=1)
+    assert _per_head_shard(lambda *a: None, mesh, odd)[1] is False

@@ -1,7 +1,7 @@
-"""Perf-trajectory sentinel: deterministic signatures, the noise-aware
-bench regression gate, and trajectory rendering.
+"""Perf sentinel: deterministic signatures and the noise-aware bench
+regression gate.
 
-Three layers under test, mirroring the subsystem:
+Two layers under test, mirroring the subsystem:
 
 - ``tpustack.obs.perfsig``: signature assembly (dotted int counters),
   the shared ``meta`` provenance block, exact-diff semantics, the forced
@@ -13,11 +13,7 @@ Three layers under test, mirroring the subsystem:
   round-trip, and the REAL gate: ``--tiny`` scenario subsets shelled as
   subprocesses, clean on the unmodified tree and nonzero (naming the
   regressed metric) when the prefix cache is deliberately disabled via
-  ``TPUSTACK_PREFIX_CACHE=0``;
-- ``tools/perf_trajectory.py``: rendering over the five committed
-  BENCH_r*.json rounds (r01→r05 SD movement visible), best-ever/
-  regression markers on synthetic series, and the committed
-  docs/PERF_TRAJECTORY.md staleness check.
+  ``TPUSTACK_PREFIX_CACHE=0``.
 """
 
 import json
@@ -30,7 +26,7 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from tools import perf_gate, perf_trajectory  # noqa: E402
+from tools import perf_gate  # noqa: E402
 from tools.bench_schema import (LLM_EXTRA_KEEP, META_KEYS,  # noqa: E402
                                 WAN_KEEP, check_meta)
 from tpustack.obs import perfsig  # noqa: E402
@@ -306,54 +302,6 @@ def test_gate_tiny_full_clean():
     clean on an unmodified tree."""
     proc = _shell_gate([], timeout=900)
     assert proc.returncode == 0, proc.stdout + proc.stderr
-
-
-# ------------------------------------------------------------ trajectory
-def test_trajectory_renders_committed_history():
-    rounds = perf_trajectory.load_rounds(REPO)
-    assert [label for label, _ in rounds][:5] == [
-        "r01", "r02", "r03", "r04", "r05"]
-    doc = perf_trajectory.render(rounds)
-    # the r01→r05 SD improvement is visible as a headline movement
-    assert "1.591" in doc and "2.2225" in doc
-    assert "+39.7%" in doc
-    # the LLM/Wan rounds-5 numbers made it into the table
-    assert "624.8" in doc and "656.42" in doc
-    # column per committed round
-    assert "| r01 | r02 | r03 | r04 | r05 |" in doc
-
-
-def test_trajectory_committed_doc_is_current():
-    """docs/PERF_TRAJECTORY.md regenerates byte-identically from the
-    committed BENCH_r*.json series (the --check staleness gate)."""
-    assert perf_trajectory.main(["--check"]) == 0
-
-
-def test_trajectory_markers_on_synthetic_series(tmp_path):
-    for i, v in enumerate([10.0, 20.0, 15.0], start=1):
-        (tmp_path / f"BENCH_r{i:02d}.json").write_text(json.dumps(
-            {"parsed": {"metric": "m", "value": v,
-                        "unit": "samples/s/chip"}}))
-    rounds = perf_trajectory.load_rounds(str(tmp_path))
-    doc = perf_trajectory.render(rounds)
-    assert "20 ★" in doc            # best-ever marker on r02
-    assert "15 ⚠" in doc            # worse than previous round → flagged
-    assert "-25.0% vs r02" in doc   # ...and named in the flag section
-    assert "+50.0% r01→r03" in doc  # first→last headline movement
-
-
-def test_trajectory_check_detects_stale(tmp_path):
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(
-        {"parsed": {"metric": "m", "value": 1.0,
-                    "unit": "samples/s/chip"}}))
-    out = tmp_path / "PERF_TRAJECTORY.md"
-    assert perf_trajectory.main(["--root", str(tmp_path),
-                                 "--out", str(out)]) == 0
-    assert perf_trajectory.main(["--root", str(tmp_path), "--out",
-                                 str(out), "--check"]) == 0
-    out.write_text(out.read_text() + "drift\n")
-    assert perf_trajectory.main(["--root", str(tmp_path), "--out",
-                                 str(out), "--check"]) == 1
 
 
 # ----------------------------------------------- bench artifact schema

@@ -107,6 +107,26 @@ def test_quantize_params_consumes_and_skips_non_target():
     assert ttree["embed_tokens"]["embedding"] is emb
 
 
+def test_random_int8_init_in_slices_equals_whole_tree_init(monkeypatch):
+    """Random-weight int8 boot never holds the whole bf16 twin (15.2 GB for
+    a 7B model — RESOURCE_EXHAUSTED on a 16 GB v5e): it initialises and
+    quantises a slice of the tree at a time.  Every value must be the one
+    the whole-tree init produces, whatever the slice size."""
+    from tpustack.models.llm_generate import Generator
+
+    cfg = dataclasses.replace(LlamaConfig.tiny(), quant="int8")
+    twin = LlamaModel(dataclasses.replace(cfg, quant=None), dtype=jnp.float32)
+    whole = quantize_params(jax.jit(twin.init)(
+        jax.random.PRNGKey(3), jnp.zeros((1, 8), jnp.int32))["params"])
+    leaves = lambda t: jax.tree_util.tree_leaves_with_path(t)
+    for chunk_bytes in (Generator.RANDOM_INIT_CHUNK_BYTES, 40_000, 1):
+        monkeypatch.setattr(Generator, "RANDOM_INIT_CHUNK_BYTES", chunk_bytes)
+        got = Generator._random_quantized_params(cfg, jnp.float32, 3)
+        assert [p for p, _ in leaves(got)] == [p for p, _ in leaves(whole)]
+        for (_, a), (_, b) in zip(leaves(got), leaves(whole)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.slow
 def test_generator_end_to_end_int8():
     from tpustack.models.llm_generate import Generator, SampleConfig

@@ -47,3 +47,35 @@ def test_png_sizes_reasonable(lib_ok):
     img = np.zeros((256, 256, 3), np.uint8)
     png = runtime.png_encode(img)
     assert len(png) < 5000
+
+
+def test_leftover_so_is_rebuilt_not_trusted(lib_ok, monkeypatch):
+    """A ``.so`` whose stamp does not name this source on this host (a
+    stale build, or an ignored file that came along with a copy of the
+    tree) is rebuilt; mtimes are not consulted."""
+    with open(runtime._STAMP_PATH, "w") as f:
+        f.write("built-somewhere-else")
+    assert not runtime._built_here()
+    monkeypatch.setattr(runtime, "_lib", None)
+    assert runtime.available()
+    with open(runtime._STAMP_PATH) as f:
+        assert f.read() == runtime._build_id()
+    assert runtime.encoder() == "native"
+
+
+def test_failed_build_serves_pil_and_says_so(monkeypatch):
+    """No compiler → the leftover binary is NOT used; the process reports
+    the PIL encoder instead of quietly keeping whatever was on disk."""
+    import subprocess
+
+    def no_make(*a, **k):
+        raise subprocess.CalledProcessError(2, "make", stderr=b"no g++")
+
+    monkeypatch.setattr(runtime, "_lib", None)
+    monkeypatch.setattr(runtime, "_load_failed", False)
+    monkeypatch.setattr(runtime, "_built_here", lambda: False)
+    monkeypatch.setattr(subprocess, "run", no_make)
+    assert runtime.encoder() == "pil"
+    from tpustack.utils.image import array_to_png
+
+    assert array_to_png(np.zeros((8, 8, 3), np.uint8))[:4] == b"\x89PNG"

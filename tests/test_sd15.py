@@ -100,12 +100,8 @@ def test_host_key_data_matches_prngkey():
         theirs = np.asarray(jax.random.key_data(jax.random.PRNGKey(seed)))
         np.testing.assert_array_equal(ours, theirs, err_msg=f"seed {seed}")
 
-    # the x64 branch too (a deployment may enable it); the context manager
-    # moved between jax versions (top-level <-> experimental)
-    enable_x64 = getattr(jax, "enable_x64", None)
-    if enable_x64 is None:
-        from jax.experimental import enable_x64
-    with enable_x64(True):
+    # the x64 branch too (a deployment may enable it)
+    with jax.enable_x64(True):
         for seed in seeds:
             ours = _host_key_data([seed])[0]
             theirs = np.asarray(jax.random.key_data(jax.random.PRNGKey(seed)))

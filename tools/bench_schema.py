@@ -45,7 +45,7 @@ LLM_EXTRA_KEEP = (
     "acceptance_rate", "tokens_per_weight_pass_on",
     "tokens_per_weight_pass_off", "speedup_batch1",
     "tp_ways", "weights_per_chip_bytes", "kv_per_chip_bytes",
-    "flight", "error",
+    "flight", "error", "skipped",
     # replay artifact keys: offered vs achieved goodput + the per-tenant
     # AND per-priority-class percentile/outcome tables + the schedule
     # digest (same seed = same offered load across driver rounds) + the
@@ -85,8 +85,8 @@ def prune(record: Mapping, keep: Sequence[str]) -> Dict:
 
 def get_path(record, path):
     """Walk a nested artifact by dotted string (``"cache_on.ttft_p50_ms"``)
-    or key sequence; None when any hop is absent/non-dict.  The one lookup
-    the gate's wall-clock paths and the trajectory's metric paths share."""
+    or key sequence; None when any hop is absent/non-dict (the perf gate's
+    wall-clock paths)."""
     if isinstance(path, str):
         path = path.split(".")
     cur = record

@@ -12,6 +12,10 @@ Default: the FULL umt5-xxl-shape text tower, weight-only int8
 beside the DiT on one 16 GB chip; the serving configuration).  ``--toy-text``
 swaps in a miniature tower to isolate the DiT+VAE number.
 
+One process per chip: the content check (``tools/verify_hw.py``, whose hw
+phase is a child that needs the chip) runs BEFORE this process initialises
+a backend; a check that does not pass makes the run exit non-zero.
+
 Prints ONE JSON line: {"metric", "value", "unit", "seconds_per_video"}.
 The repo headline (driver-run) stays bench.py's SD15 number.
 """
@@ -49,16 +53,32 @@ def main() -> int:
                         "shape (isolates the DiT+VAE number)")
     args = p.parse_args()
     t_bench = time.time()
+    log = lambda *a: print(*a, file=sys.stderr, flush=True)
 
+    content_check = None
+    if not args.small and not args.no_content_check:
+        # bench.py-style gating: the Wan number only counts if the chip
+        # provably computes the right frames (wan family: 3-file export→
+        # reload→denoise+mapped-VAE parity; flash family incl. the S=8320
+        # d=128 case this very workload's DiT runs).  FIRST, while this
+        # process is still off jax: afterwards it holds the chip.
+        import bench
+
+        content_check = bench._content_check(families="wan,flash",
+                                             workdir="verify_hw_wan")
+        if content_check != "pass":
+            log(f"[bench_wan] content check: {content_check}")
+            return 1
+
+    from tpustack.utils import enable_compile_cache, require_accelerator
+
+    require_accelerator()
     import jax
 
     from tpustack.models.wan.config import UMT5Config, WanConfig
     from tpustack.models.wan.pipeline import WanPipeline
 
-    log = lambda *a: print(*a, file=sys.stderr, flush=True)
-    from tpustack.utils import enable_compile_cache
-
-    log(f"[bench_wan] compile cache: {enable_compile_cache() or 'unavailable'}")
+    log(f"[bench_wan] compile cache: {enable_compile_cache()}")
     log(f"[bench_wan] backend={jax.default_backend()}")
 
     if args.small:
@@ -108,9 +128,9 @@ def main() -> int:
     sec = statistics.median(times)
 
     mfu = None
-    from tpustack.utils.peaks import device_peaks
+    from tpustack.utils.peaks import measurement_peaks
 
-    peaks = device_peaks(jax.devices()[0])
+    peaks = measurement_peaks(jax.devices()[0])
     peak = peaks[0] if peaks else None
     if peak:
         try:
@@ -134,17 +154,8 @@ def main() -> int:
         "mfu": round(mfu, 4) if mfu is not None else None,
         "meta": perfsig.artifact_meta(t_bench),
     }
-    if not args.small and not args.no_content_check:
-        # bench.py-style gating: the Wan number only counts if the chip
-        # provably computes the right frames (wan family: 3-file export→
-        # reload→denoise+mapped-VAE parity; flash family incl. the S=8320
-        # d=128 case this very workload's DiT runs)
-        import bench
-
-        result["content_check"] = bench._content_check(
-            log, families="wan,flash", workdir="verify_hw_wan",
-            out=os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "HWVERIFY_wan_r05.json"))
+    if content_check is not None:
+        result["content_check"] = content_check
     print(json.dumps(result))
     return 0
 

@@ -74,6 +74,8 @@ def run_drill(args) -> int:
     with open(registry_file, "w"):
         pass
 
+    # the executor starts several replicas on this host and pins none to
+    # a chip (one process per chip: ROADMAP D7/R10) — CPU replicas only
     base_env = dict(os.environ,
                     JAX_PLATFORMS="cpu",
                     TPUSTACK_SANITIZE="1",

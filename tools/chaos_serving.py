@@ -173,6 +173,8 @@ def run_drill(args) -> int:
     router_url = f"http://127.0.0.1:{router_port}"
     watch_url = f"http://127.0.0.1:{watch_port}"
 
+    # the drill starts several replicas on this host and pins none to a
+    # chip (one process per chip: ROADMAP D7/R10) — CPU replicas only
     base_env = dict(os.environ,
                     JAX_PLATFORMS="cpu",
                     TPUSTACK_SANITIZE="1",

@@ -42,6 +42,8 @@ TASK_ARGV = ["resnet50", "--tiny", "--batch", "2", "--classes", "4",
 
 
 def run_task(ckpt_dir: str, steps: int, kill_step: int = 0):
+    # a CPU drill by construction: kill/resume parity is asserted bitwise
+    # against a CPU reference run, and no child is pinned to a chip
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("TPUSTACK_FAULT_TRAIN_KILL_STEP", None)
     env.pop("TPUSTACK_FAULT_TRAIN_CORRUPT_CKPT", None)

@@ -351,6 +351,9 @@ class _SelfHosted:
         import asyncio
         import logging
 
+        from tpustack.utils import require_accelerator
+
+        require_accelerator()  # self-hosting computes: same rule as main()
         import jax.numpy as jnp
         from aiohttp import web
 

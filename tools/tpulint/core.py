@@ -40,7 +40,7 @@ REPO = Path(__file__).resolve().parents[2]
 
 #: the python trees a full-repo lint walks (tests are excluded: fixture
 #: snippets deliberately violate rules, and tests may poke raw env vars)
-DEFAULT_SCAN = ("tpustack", "tools", "scripts", "bench.py")
+DEFAULT_SCAN = ("tpustack", "tools", "scripts", "bench.py", "chip_smoke.py")
 
 #: never linted: the registry itself (it IS the env boundary) and caches
 EXCLUDE_PARTS = ("__pycache__",)

@@ -30,6 +30,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as PS
 
 from tpustack.ops.attention import dot_product_attention
 
@@ -109,10 +110,34 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 KVCache = Dict[str, jax.Array]
 
 
+def _per_head_shard(fn, mesh, cfg: LlamaConfig, n_scalars: int = 0):
+    """``fn(q, k, v, *scalars) -> out`` (BSHD) made safe to trace under the
+    serving tp mesh.  A Mosaic kernel cannot be GSPMD-partitioned ("wrap
+    the call in a shard_map" — a tp=4 server on four v5e chips died on its
+    first 1k-token prefill, PR 21), and attention is independent per head:
+    so under a tp mesh ``fn`` runs per head shard, each chip seeing its own
+    q heads and the kv heads they read (both counts must divide tp — they
+    do wherever the KV substrate is head-sharded).  ``fn`` then judges
+    ``impl="auto"`` on the per-chip shapes.  Returns ``(callable, ok)``:
+    ``ok`` False means tp does not divide the heads and ``fn`` came back
+    unwrapped — the caller must keep Pallas kernels out of it."""
+    tp = (int(mesh.shape["tp"])
+          if mesh is not None and "tp" in mesh.axis_names else 1)
+    if tp == 1:
+        return fn, True
+    if cfg.n_heads % tp or cfg.n_kv_heads % tp:
+        return fn, False
+    heads = PS(None, None, "tp", None)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(heads,) * 3 + (PS(),) * n_scalars,
+                         out_specs=heads, check_vma=False), True
+
+
 class LlamaAttention(nn.Module):
     cfg: LlamaConfig
     dtype: Any = jnp.bfloat16
     ring_mesh: Any = None  # Mesh → train-path attention rings K/V over "sp"
+    tp_mesh: Any = None    # Mesh → serving kernels run per tp head shard
 
     def _ring_shapes_ok(self, b: int, s: int) -> bool:
         """Ring shard_map needs batch/seq/heads divisible by their mesh axes
@@ -278,7 +303,11 @@ class LlamaAttention(nn.Module):
                 # Chunked prefill (cache_index > 0 / traced, or an explicit
                 # mask) must see the earlier cache, so it takes a full-cache
                 # path below.
-                out = dot_product_attention(q, k, v, causal=True, impl="auto")
+                attend, sharded = _per_head_shard(
+                    lambda q, k, v: dot_product_attention(
+                        q, k, v, causal=True, impl="auto"), self.tp_mesh, c)
+                out = (attend(q, k, v) if sharded else
+                       dot_product_attention(q, k, v, causal=True))
             elif s > 1 and attn_mask is None:
                 # Chunked long-context prefill: this chunk's rows sit at
                 # global positions cache_index + i and attend the whole
@@ -298,9 +327,14 @@ class LlamaAttention(nn.Module):
                             vs_all[..., None].astype(self.dtype))
                 else:
                     k_in, v_in = k_all, v_all
-                out = flash_attention(q, k_in, v_in, causal=True,
-                                      q_offset=cache_index,
-                                      kv_len=cache_index + s)
+                # (a tp that does not divide the heads leaves the kernel
+                # unwrapped: Mosaic then refuses the partitioned program)
+                attend, _ = _per_head_shard(
+                    lambda q, k, v, off: flash_attention(
+                        q, k, v, causal=True, q_offset=off, kv_len=off + s),
+                    self.tp_mesh, c, n_scalars=1)
+                out = attend(q, k_in, v_in,
+                             jnp.asarray(cache_index, jnp.int32))
             else:
                 out = dot_product_attention(q, k_all, v_all, mask=attn_mask,
                                             k_scale=ks_all, v_scale=vs_all)
@@ -354,12 +388,13 @@ class LlamaBlock(nn.Module):
     cfg: LlamaConfig
     dtype: Any = jnp.bfloat16
     ring_mesh: Any = None
+    tp_mesh: Any = None
 
     @nn.compact
     def __call__(self, x, positions, kv_cache, cache_index, attn_mask):
         c = self.cfg
         h, new_cache = LlamaAttention(c, self.dtype, self.ring_mesh,
-                                      name="self_attn")(
+                                      self.tp_mesh, name="self_attn")(
             RMSNorm(c.rms_eps, self.dtype, name="input_layernorm")(x),
             positions, kv_cache, cache_index, attn_mask)
         x = x + h
@@ -374,11 +409,14 @@ class LlamaModel(nn.Module):
     ``ring_mesh``: a ``jax.sharding.Mesh`` with an ``sp`` axis > 1 switches
     the (cache-less) training attention to ring sequence parallelism —
     params are unchanged, so the same checkpoint serves/rings freely.
+    ``tp_mesh``: the serving tp mesh — the Pallas attention kernels then run
+    per head shard (``_per_head_shard``).
     """
 
     cfg: LlamaConfig
     dtype: Any = jnp.bfloat16
     ring_mesh: Any = None
+    tp_mesh: Any = None
 
     @nn.compact
     def __call__(self, tokens, positions=None, kv_caches=None, cache_index=0,
@@ -406,7 +444,8 @@ class LlamaModel(nn.Module):
         new_caches = [] if kv_caches is not None else None
         for i in range(c.n_layers):
             cache_i = kv_caches[i] if kv_caches is not None else None
-            x, nc = LlamaBlock(c, self.dtype, self.ring_mesh, name=f"layers_{i}")(
+            x, nc = LlamaBlock(c, self.dtype, self.ring_mesh, self.tp_mesh,
+                               name=f"layers_{i}")(
                 x, positions, cache_i, cache_index, attn_mask)
             if new_caches is not None:
                 new_caches.append(nc)

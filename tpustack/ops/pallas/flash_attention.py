@@ -219,7 +219,7 @@ def _default_block_q(streaming: bool, kv_tokens: int, d: int) -> int:
     — [block_q, S] f32 scores + the K/V panels — scales with BOTH S and D:
     256 at S=8704 fails to compile (measured r4), and every 256 compile
     check ran at D=128, so a larger head_dim must not inherit the
-    unverified config (ADVICE r5).  256 therefore requires S ≤ 6144 AND
+    unverified config.  256 therefore requires S ≤ 6144 AND
     d ≤ 128 (compile-verified on-chip across 4608/5120/6144 at D=128,
     matching block_q=128 exactly); anything else stays at 128."""
     if streaming:
@@ -385,9 +385,13 @@ def _flash_attention(q, k, v, *, causal, scale, block_q, block_k, interpret,
 # PagedAttention, Kwon et al. SOSP'23): the per-slot block table is a
 # scalar-prefetch operand (pltpu.PrefetchScalarGridSpec), so each grid
 # step's BlockSpec index map looks its pool block up BEFORE the kernel
-# body runs and the pipeline DMAs that block [block, head_dim] straight
-# from the pool tensor [n_blocks, block, kvh, hd] into VMEM — no dense
-# [B, max_seq] gather copy ever materialises in HBM.  Softmax is the
+# body runs and the pipeline DMAs that block — all kv heads of it, one
+# contiguous [block, kvh*hd] slab of the pool tensor [n_blocks, block,
+# kvh, hd] — straight into VMEM; no dense [B, max_seq] gather copy ever
+# materialises in HBM.  Every block's last two dims are whole axes of
+# the (reshaped) operand, which is what Mosaic's (8|16|32, 128) tiling
+# accepts for any head count; a block of ONE kv head out of kvh < 8 is
+# refused at lowering.  Softmax is the
 # online (m, l, acc) carry across the block grid dim, exactly like
 # _attn_kernel_stream; the result is returned as the UNNORMALISED partial
 # (acc, m, l) in dot_product_attention_partial's layout so the continuous
@@ -406,21 +410,30 @@ def _flash_attention(q, k, v, *, causal, scale, block_q, block_k, interpret,
 # grid steps whose contribution is exactly zero.
 
 
+def _sublane_tile(dtype) -> int:
+    """Rows of one packed TPU tile for ``dtype``: (8,128) f32, (16,128)
+    bf16, (32,128) int8 — what a matmul operand's row count must divide."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
 def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                        acc_out, m_out, l_out, m_s, l_s, acc_s, *,
-                       scale: float, blk: int, n_b: int, quant: bool):
-    """One (batch, kv-head, pool-block) grid step of in-place paged decode
-    attention.  ``q_ref`` holds this (b, kv-head)'s query rows [R, D]
-    (R = S·group, the multi-query verify rows x GQA group, padded to >= 8
-    sublanes); ``k_ref``/``v_ref`` the table-mapped pool block.  Numerics
-    mirror ``dot_product_attention_partial`` per element: f32 logits,
-    int8 dequant via cast-to-compute + per-vector scales OUTSIDE the
+                       scale: float, blk: int, n_b: int, hkv: int, d: int,
+                       quant: bool):
+    """One (batch, pool-block) grid step of in-place paged decode
+    attention, walking the kv heads inside the body.  ``q_ref`` holds this
+    batch row's query rows per kv head ``[Hkv, R, D]`` (R = S·group, the
+    multi-query verify rows x GQA group, padded to the q dtype's sublane
+    tile); ``k_ref``/``v_ref`` the table-mapped pool block with heads
+    folded into lanes ``[blk, Hkv·D]``.  Numerics mirror
+    ``dot_product_attention_partial`` per element: f32 logits, int8
+    dequant via cast-to-compute + per-vector scales OUTSIDE the
     d-contraction (``k_scale`` on the scores, ``v_scale`` on the probs
     after the denominator), plain ``exp`` — only the summation ORDER
     differs (per-block online carry vs one-pass), the same split the
     chunk-boundary merge already makes."""
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     kv_len = len_ref[b]
 
     @pl.when(j == 0)
@@ -433,37 +446,56 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     @pl.when(col0 < kv_len)
     def _compute():
-        q = q_ref[0, 0]                                 # [R, D]
-        k = k_ref[0, :, 0, :]                           # [blk, D]
-        v = v_ref[0, :, 0, :]
+        r_pad = q_ref.shape[2]
+        col = col0 + jax.lax.broadcasted_iota(jnp.int32, (r_pad, blk), 1)
+        valid = col < kv_len
         if quant:
-            # int8 pool blocks: HALF the bytes cross HBM; the cast to the
-            # compute dtype happens here in VMEM (int8 values are exact in
-            # bf16 — 8 mantissa bits cover +-127)
-            k = k.astype(q.dtype)
-            v = v.astype(q.dtype)
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [R, blk]
-        if quant:
-            logits = logits * ks_ref[0, :, 0][None, :]
-        logits = logits * scale
-        col = col0 + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        logits = jnp.where(col < kv_len, logits, NEG_INF)
-        m_prev = m_s[:, :1]                             # [R, 1]
-        l_prev = l_s[:, :1]
-        m_cur = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_cur)
-        p = jnp.exp(logits - m_cur)
-        p = jnp.where(logits <= NEG_INF, 0.0, p)        # masked cols: l += 0
-        l_s[...] = jnp.broadcast_to(
-            l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True), l_s.shape)
-        if quant:
-            p = p * vs_ref[0, :, 0][None, :]
-        acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_s[...] = jnp.broadcast_to(m_cur, m_s.shape)
+            # The pool stores scales [blk, Hkv]: a head's scales are a
+            # COLUMN (blk along sublanes) where the [R, blk] scores want
+            # them along lanes.  Mosaic has no general sublane→lane
+            # relayout, so spread the column on the diagonal of a
+            # [blk, blk] tile and reduce over sublanes — exact, each sum
+            # has one non-zero term.
+            diag = (jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
+                    == jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1))
+
+            def scale_row(s_ref, h):
+                return jnp.sum(jnp.where(diag, s_ref[0, :, h:h + 1], 0.0),
+                               axis=0, keepdims=True)        # [1, blk]
+
+        for h in range(hkv):                    # static: Hkv is 1..8
+            q = q_ref[0, h]                                 # [R, D]
+            k = k_ref[0, :, h * d:(h + 1) * d]              # [blk, D]
+            v = v_ref[0, :, h * d:(h + 1) * d]
+            if quant:
+                # int8 pool blocks: HALF the bytes cross HBM; the cast to
+                # the compute dtype happens here in VMEM (int8 values are
+                # exact in bf16 — 8 mantissa bits cover +-127)
+                k = k.astype(q.dtype)
+                v = v.astype(q.dtype)
+            logits = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)         # [R, blk]
+            if quant:
+                logits = logits * scale_row(ks_ref, h)
+            logits = logits * scale
+            logits = jnp.where(valid, logits, NEG_INF)
+            m_prev = m_s[h, :, :1]                          # [R, 1]
+            l_prev = l_s[h, :, :1]
+            m_cur = jnp.maximum(m_prev,
+                                jnp.max(logits, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_cur)
+            p = jnp.exp(logits - m_cur)
+            p = jnp.where(valid, p, 0.0)                    # masked: l += 0
+            l_s[h] = jnp.broadcast_to(
+                l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                l_s.shape[1:])
+            if quant:
+                p = p * scale_row(vs_ref, h)
+            acc_s[h] = acc_s[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_s[h] = jnp.broadcast_to(m_cur, m_s.shape[1:])
 
     @pl.when(j == n_b - 1)
     def _finish():
@@ -471,9 +503,9 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         # parked slot) leaves the init carry: m = NEG_INF, l = 0, acc = 0
         # — merge_attention_partials weights it out against the buffer
         # partial, which always holds the freshly-written position
-        acc_out[0, 0] = acc_s[...]
-        m_out[0, 0] = m_s[:, 0]
-        l_out[0, 0] = l_s[:, 0]
+        acc_out[0] = acc_s[...]
+        m_out[0] = m_s[...]
+        l_out[0] = l_s[...]
 
 
 def paged_attention_partial(
@@ -506,12 +538,12 @@ def paged_attention_partial(
     so the pipeline elides their DMA.  ``k_scale``/``v_scale``
     ``[N, block, Hkv]``: the int8 pool's per-vector dequant scales —
     dequant happens IN the kernel, so int8 halves the HBM bytes decode
-    actually moves.  GQA (Hkv < H) walks kv heads as a grid dim with the
-    whole q group as rows of one matmul.
+    actually moves.  GQA (Hkv < H) walks kv heads inside the kernel body
+    with the whole q group as rows of one matmul per head.
 
-    VMEM per grid step: 2 pool block panels (block x D) + the q rows +
-    f32 (R x D) carry — a few hundred KB at serving shapes (docs/PERF.md
-    round 15 has the table); sequence length is bounded by HBM only.
+    VMEM per grid step: 2 pool block slabs (block x Hkv·D) + the q rows +
+    the f32 (Hkv x R x D) carry — well under 2 MB at serving shapes;
+    sequence length is bounded by HBM only.
     """
     b, s, h, d = q.shape
     n_blocks, blk, hkv, dk = pool_k.shape
@@ -529,11 +561,11 @@ def paged_attention_partial(
     if quant != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be passed together")
 
-    # rows of the per-(b, kv-head) matmul: the S query positions x the GQA
-    # group, padded to the 8-sublane minimum (padded rows compute garbage
-    # the slice below drops)
+    # rows of the per-kv-head matmul: the S query positions x the GQA
+    # group, padded to the q dtype's sublane tile so the MXU operand is
+    # whole tiles (padded rows compute garbage the slice below drops)
     rows = s * g
-    r_pad = max(8, rows)
+    r_pad = -(-rows // _sublane_tile(q.dtype)) * _sublane_tile(q.dtype)
     qr = q.reshape(b, s, hkv, g, d).transpose(0, 2, 1, 3, 4)
     qr = qr.reshape(b, hkv, rows, d)
     if r_pad != rows:
@@ -542,23 +574,20 @@ def paged_attention_partial(
     bt = block_tables.astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
 
-    def kv_map(bi, hi, j, bt_ref, len_ref):
+    def block_map(bi, j, bt_ref, len_ref):
         # clamp past-the-frontier grid steps to the row's LAST valid block:
         # consecutive identical indices → the pipeline skips the re-DMA
         last = jnp.maximum((len_ref[bi] + blk - 1) // blk - 1, 0)
-        return (bt_ref[bi, jnp.minimum(j, last)], 0, hi, 0)
+        return (bt_ref[bi, jnp.minimum(j, last)], 0, 0)
 
-    def scale_map(bi, hi, j, bt_ref, len_ref):
-        last = jnp.maximum((len_ref[bi] + blk - 1) // blk - 1, 0)
-        return (bt_ref[bi, jnp.minimum(j, last)], 0, hi)
-
-    q_spec = pl.BlockSpec((1, 1, r_pad, d),
-                          lambda bi, hi, j, bt_ref, len_ref: (bi, hi, 0, 0))
-    kv_spec = pl.BlockSpec((1, blk, 1, d), kv_map)
-    in_specs = [q_spec, kv_spec, kv_spec]
-    operands = [qr, pool_k, pool_v]
+    row_map = lambda bi, j, bt_ref, len_ref: (bi, 0, 0, 0)
+    # heads folded into lanes: a free reshape of the contiguous pool
+    kv_spec = pl.BlockSpec((1, blk, hkv * d), block_map)
+    in_specs = [pl.BlockSpec((1, hkv, r_pad, d), row_map), kv_spec, kv_spec]
+    operands = [qr, pool_k.reshape(n_blocks, blk, hkv * d),
+                pool_v.reshape(n_blocks, blk, hkv * d)]
     if quant:
-        ks_spec = pl.BlockSpec((1, blk, 1), scale_map)
+        ks_spec = pl.BlockSpec((1, blk, hkv), block_map)
         in_specs += [ks_spec, ks_spec]
         operands += [k_scale, v_scale]
     else:
@@ -568,36 +597,35 @@ def paged_attention_partial(
         zero = jnp.zeros((1,), jnp.float32)
         operands += [zero, zero]
 
-    out_specs = [
-        pl.BlockSpec((1, 1, r_pad, d),
-                     lambda bi, hi, j, bt_ref, len_ref: (bi, hi, 0, 0)),
-        pl.BlockSpec((1, 1, r_pad),
-                     lambda bi, hi, j, bt_ref, len_ref: (bi, hi, 0)),
-        pl.BlockSpec((1, 1, r_pad),
-                     lambda bi, hi, j, bt_ref, len_ref: (bi, hi, 0)),
-    ]
+    # m/l leave lane-broadcast ([R, 128], like the scratch carry): a
+    # [R]-vector output block would need a sublane→lane relayout
+    out_specs = [pl.BlockSpec((1, hkv, r_pad, d), row_map),
+                 pl.BlockSpec((1, hkv, r_pad, 128), row_map),
+                 pl.BlockSpec((1, hkv, r_pad, 128), row_map)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, nb),          # block dim innermost: carry per (b, h)
+        grid=(b, nb),               # block dim innermost: carry per batch row
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((r_pad, 128), jnp.float32),   # running max m
-            pltpu.VMEM((r_pad, 128), jnp.float32),   # running denom l
-            pltpu.VMEM((r_pad, d), jnp.float32),     # unnormalised acc
+            pltpu.VMEM((hkv, r_pad, 128), jnp.float32),   # running max m
+            pltpu.VMEM((hkv, r_pad, 128), jnp.float32),   # running denom l
+            pltpu.VMEM((hkv, r_pad, d), jnp.float32),     # unnormalised acc
         ],
     )
     acc, m, l = pl.pallas_call(
         functools.partial(_paged_attn_kernel, scale=scale, blk=blk, n_b=nb,
-                          quant=quant),
+                          hkv=hkv, d=d, quant=quant),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, r_pad, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, r_pad), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, r_pad), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, r_pad, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, r_pad, 128), jnp.float32),
         ],
+        name="paged_attention",
         interpret=interpret,
     )(bt, lens, *operands)
+    m, l = m[..., 0], l[..., 0]
 
     # [B, Hkv, R(, D)] → [B, S, H(, D)] (drop row padding first)
     acc = acc[:, :, :rows].reshape(b, hkv, s, g, d)

@@ -44,8 +44,12 @@ def vectoradd_selftest(n: int = NUM_ELEMENTS, seed: int = 0) -> bool:
 
 
 def main() -> int:
-    devs = jax.devices()
-    print(f"[jax-vectoradd] backend={jax.default_backend()} devices={devs}")
+    from tpustack.utils import require_accelerator
+
+    # the device-plugin smoke must not pass on a node whose TPU is absent:
+    # JAX would fall back to the CPU and the add would still verify
+    backend = require_accelerator()
+    print(f"[jax-vectoradd] backend={backend} devices={jax.devices()}")
     print(f"[jax-vectoradd] Vector addition of {NUM_ELEMENTS} elements")
     ok = vectoradd_selftest()
     if ok:

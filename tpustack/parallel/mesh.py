@@ -85,10 +85,14 @@ def build_mesh(
 ) -> Mesh:
     """Build a Mesh over all (or given) devices.
 
-    Uses ``mesh_utils.create_device_mesh`` on real TPU backends so the logical
-    axes map onto the physical torus; falls back to a plain reshape on CPU
-    (virtual-device tests) where there is no topology to exploit.
+    ``mesh_utils.create_device_mesh`` maps the logical axes onto the
+    physical torus on TPU backends and is a plain reshape elsewhere (the
+    virtual-device CPU tests, where there is no topology to exploit).  A
+    device set it cannot lay out raises: a mesh in arbitrary order would
+    run, with tp collectives off nearest-neighbour ICI and nobody told.
     """
+    from jax.experimental import mesh_utils
+
     devices = list(devices if devices is not None else jax.devices())
     n = len(devices)
     if shape is None:
@@ -96,22 +100,5 @@ def build_mesh(
     shape = tuple(int(s) for s in shape)
     if math.prod(shape) != n:
         raise ValueError(f"mesh shape {shape} != {n} devices")
-
-    if devices[0].platform == "tpu":
-        from jax.experimental import mesh_utils
-
-        try:
-            dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-            return Mesh(dev_array, tuple(axis_names))
-        except (ValueError, NotImplementedError) as e:
-            # Odd topologies (e.g. a single chip) have no torus to map onto;
-            # anything else falling through here would cost real ICI locality,
-            # so make the fallback loud.
-            from tpustack.utils import get_logger
-
-            get_logger("parallel.mesh").warning(
-                "create_device_mesh failed (%s); falling back to reshape order "
-                "— tp collectives may not ride nearest-neighbor ICI", e
-            )
-    dev_array = np.asarray(devices).reshape(shape)
+    dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     return Mesh(dev_array, tuple(axis_names))

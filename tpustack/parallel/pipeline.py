@@ -30,19 +30,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as PS
 
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map as _shard_map
-    _REP_KW = "check_vma"
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KW = "check_rep"
-
 
 def shard_map(fn, *, mesh, in_specs, out_specs):
     # the replication checker can't see through the masked-psum broadcast at
-    # the end of the schedule; disabled under its per-version keyword
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_REP_KW: False})
+    # the end of the schedule
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def pipeline_apply(

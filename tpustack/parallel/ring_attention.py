@@ -19,15 +19,11 @@ from each shard's ring index.
 from __future__ import annotations
 
 import functools
-import inspect
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as PS
 
 NEG_INF = -1e30
@@ -121,14 +117,11 @@ def ring_attention(
         denom = jnp.maximum(s_run, 1e-30).transpose(0, 2, 1)[..., None]
         return (acc / denom).astype(q_loc.dtype)
 
-    # replication checking is off either way (the accumulator maths is not
-    # expressible to the checker); the kwarg renamed check_rep -> check_vma
-    # across jax versions
-    check_kw = ("check_vma" if "check_vma" in
-                inspect.signature(shard_map).parameters else "check_rep")
+    # replication checking is off (the accumulator maths is not
+    # expressible to the checker)
     fn = shard_map(local_fn, mesh=mesh,
                    in_specs=(seq_spec, seq_spec, seq_spec),
-                   out_specs=seq_spec, **{check_kw: False})
+                   out_specs=seq_spec, check_vma=False)
     return fn(q, k, v)
 
 

@@ -8,8 +8,14 @@ surface:
   path (``tpustack.utils.image`` falls back to PIL when the library isn't
   built).
 
-The shared object is built on first use when a compiler is available
-(``make -C native``); set ``TPUSTACK_NO_NATIVE=1`` to skip entirely.  Servers
+The shared object is built on first use (``make -C native``) by the machine
+that runs it: a stamp beside it records the source it was built from and
+the host that built it, and a ``.so`` whose stamp does not match — stale
+source, or a leftover copied in from another machine (``native/*.so`` is
+gitignored, so a copy of the working tree can carry one) — is rebuilt, never
+trusted.  If the build fails the process serves with PIL and says so
+(``encoder()``; the servers log it at start-up and report it on
+``/healthz``).  Set ``TPUSTACK_NO_NATIVE=1`` to skip entirely.  Servers
 should call ``available()`` once at startup so the (up to 120 s) build never
 lands inside a request; ``_load`` is locked so concurrent first calls cannot
 race two ``make`` processes against ``dlopen``.
@@ -18,6 +24,7 @@ race two ``make`` processes against ``dlopen``.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -25,22 +32,44 @@ from typing import Optional
 
 import numpy as np
 
+from tpustack.utils.logging import get_logger
+
+log = get_logger("runtime")
+
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "native")
 _SO_PATH = os.path.join(_NATIVE_DIR, "libtpustack_runtime.so")
-_SRC_PATH = os.path.join(_NATIVE_DIR, "png_encoder.cc")
+_STAMP_PATH = _SO_PATH + ".stamp"
+_SOURCES = ("png_encoder.cc", "Makefile")
 
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
 _load_lock = threading.Lock()
 
 
-def _stale() -> bool:
-    """True when the source is newer than the built .so (dev edits)."""
+def _build_id() -> str:
+    """What a trustworthy ``.so`` was built from and where: sha256 over the
+    sources plus this host's identity (mtimes do not survive a copy)."""
+    h = hashlib.sha256("|".join(os.uname()).encode())
+    for name in _SOURCES:
+        with open(os.path.join(_NATIVE_DIR, name), "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _built_here() -> bool:
     try:
-        return os.path.getmtime(_SRC_PATH) > os.path.getmtime(_SO_PATH)
+        with open(_STAMP_PATH) as f:
+            return os.path.exists(_SO_PATH) and f.read().strip() == _build_id()
     except OSError:
         return False
+
+
+def _build() -> None:
+    subprocess.run(["make", "-C", _NATIVE_DIR, "-B"], check=True,
+                   capture_output=True, timeout=120)
+    with open(_STAMP_PATH, "w") as f:
+        f.write(_build_id())
 
 
 def _load() -> Optional[ctypes.CDLL]:
@@ -54,23 +83,19 @@ def _load() -> Optional[ctypes.CDLL]:
     with _load_lock:
         if _lib is not None or _load_failed:
             return _lib
-        if not os.path.exists(_SO_PATH) or _stale():
-            try:
+        try:
+            if not _built_here():
                 # blocking build under the lock is the point: exactly one
                 # thread pays the compile, every other caller waits for
                 # the finished .so instead of racing a second make
-                subprocess.run(["make", "-C", _NATIVE_DIR, "-B"],  # tpulint: disable=TPL202
-                               check=True, capture_output=True, timeout=120)
-            except Exception:
-                if not os.path.exists(_SO_PATH):
-                    _load_failed = True  # don't re-pay the failing build per call
-                    return None
-                # rebuild of a stale .so failed (e.g. no compiler in the
-                # image) — keep using the existing binary
-        try:
+                _build()
             lib = ctypes.CDLL(_SO_PATH)
-        except OSError:
-            _load_failed = True
+        except (OSError, subprocess.SubprocessError) as e:
+            _load_failed = True  # don't re-pay the failing build per call
+            detail = (getattr(e, "stderr", b"") or b"").decode(
+                "utf-8", "replace").strip()[-300:]
+            log.warning("native runtime unavailable (%r %s) — PNG encoding "
+                        "falls back to PIL", e, detail)
             return None
         lib.tpustack_png_encode.restype = ctypes.c_long
         lib.tpustack_png_encode.argtypes = [
@@ -83,6 +108,11 @@ def _load() -> Optional[ctypes.CDLL]:
 
 def available() -> bool:
     return _load() is not None
+
+
+def encoder() -> str:
+    """Which PNG encoder this process serves with: ``native`` or ``pil``."""
+    return "native" if available() else "pil"
 
 
 def png_encode(img: np.ndarray, compression: int = 6) -> bytes:

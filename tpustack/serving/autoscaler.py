@@ -232,6 +232,11 @@ class LocalSubprocessExecutor(ScaleExecutor):
             stdout = open(os.path.join(self.log_dir,
                                        f"replica-{port}.log"), "wb")
         t0 = time.monotonic()
+        # Several replicas on one host, none pinned to a chip: a TPU
+        # belongs to the first process that initialises a backend on it, so
+        # this executor is for CPU replicas (its callers' env sets
+        # JAX_PLATFORMS=cpu).  One-chip replicas on a multi-chip host need a
+        # per-replica device assignment — out of scope until ROADMAP D7/R10.
         proc = subprocess.Popen(argv, env=self.spawn_env, cwd=self.cwd,
                                 stdout=stdout,
                                 stderr=subprocess.STDOUT if stdout else None)

@@ -54,7 +54,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 from aiohttp import web
 
-from tpustack import sanitize
+from tpustack import runtime, sanitize
 from tpustack.obs import Trace
 from tpustack.obs import accounting as obs_accounting
 from tpustack.obs import catalog as obs_catalog
@@ -1204,6 +1204,7 @@ class GraphServer:
             "worker_alive": self._worker.is_alive(),
             "running": running,
             "pending": pending,
+            "png_encoder": runtime.encoder(),
         })
         return web.json_response(payload, status=status,
                                  headers=self.resilience.health_headers(status))
@@ -1310,14 +1311,16 @@ class GraphServer:
 
 
 def main() -> None:
-    from tpustack import runtime
-    from tpustack.utils import enable_compile_cache
+    from tpustack.utils import enable_compile_cache, require_accelerator
 
+    require_accelerator()
     # honours JAX_COMPILATION_CACHE_DIR (the Deployment contract); dev-box
     # fallback to <repo>/.cache/xla — without it every server start pays
     # the full multi-minute Wan compile
     enable_compile_cache()
-    runtime.available()  # build/load the native PNG encoder before serving
+    # build/load the native PNG encoder before serving (never inside a
+    # request) and say which encoder this process ended up with
+    log.info("PNG encoder: %s", runtime.encoder())
     _text_quant(os.environ.get("WAN_PRESET", "wan_1_3b"))  # fail fast on typo
     port = int(os.environ.get("PORT", "8181"))
     server = GraphServer()

@@ -56,10 +56,11 @@ def run(argv=None) -> int:
     import jax
 
     from tpustack.parallel.distributed import initialize_from_env
-    from tpustack.utils import enable_compile_cache
+    from tpustack.utils import enable_compile_cache, require_accelerator
 
+    multi = initialize_from_env()  # before the guard touches the backend
+    require_accelerator()
     enable_compile_cache()
-    multi = initialize_from_env()
     rank = jax.process_index() if multi else 0
     log.info("llm_multihost: %d process(es), rank %d, %d global device(s)",
              jax.process_count() if multi else 1, rank, jax.device_count())

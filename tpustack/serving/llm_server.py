@@ -1787,11 +1787,14 @@ class LLMServer:
         verify the serving substrate (paged pool size/block/utilization,
         prefix-cache hit rate, dense-fallback flag) without scraping
         ``/metrics``."""
+        from tpustack.utils import device_info
+
         pc = self.prefix_cache
         payload = {
             "model": self.model_name,
             "n_ctx": self.gen.cfg.max_seq,
-            "backend": "jax/tpu",
+            # what jax.devices()[0] reports — never a literal
+            "backend": device_info(),
             "prefix_cache": pc.stats() if pc is not None
             else {"enabled": False},
         }
@@ -2030,8 +2033,9 @@ class LLMServer:
 
 
 def main() -> None:
-    from tpustack.utils import enable_compile_cache
+    from tpustack.utils import enable_compile_cache, require_accelerator
 
+    require_accelerator()
     enable_compile_cache()  # JAX_COMPILATION_CACHE_DIR or <repo>/.cache/xla
     port = int(os.environ.get("PORT", "8080"))
     server = LLMServer()

@@ -52,7 +52,7 @@ import numpy as np
 from aiohttp import web
 from pydantic import BaseModel, ValidationError
 
-from tpustack import sanitize
+from tpustack import runtime, sanitize
 from tpustack.obs import accounting as obs_accounting
 from tpustack.obs import catalog as obs_catalog
 from tpustack.obs import device as obs_device
@@ -286,6 +286,7 @@ class SDServer:
             "max_batch": self.max_batch,
             "batch_window_ms": self.batch_window_s * 1e3,
             "dp": self._mesh_data_size() or 1,
+            "png_encoder": runtime.encoder(),
         })
         return web.json_response(payload, status=status,
                                  headers=self.resilience.health_headers(status))
@@ -649,11 +650,13 @@ class SDServer:
 
 
 def main() -> None:
-    from tpustack import runtime
-    from tpustack.utils import enable_compile_cache
+    from tpustack.utils import enable_compile_cache, require_accelerator
 
+    require_accelerator()
     enable_compile_cache()  # JAX_COMPILATION_CACHE_DIR or <repo>/.cache/xla
-    runtime.available()  # build/load the native PNG encoder before serving
+    # build/load the native PNG encoder before serving (never inside a
+    # request) and say which encoder this process ended up with
+    log.info("PNG encoder: %s", runtime.encoder())
     port = int(os.environ.get("PORT", "8000"))
     server = SDServer()
     if os.environ.get("SD15_WARMUP", "1") not in ("0", "false"):

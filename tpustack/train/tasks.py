@@ -288,12 +288,9 @@ def _generic_lm_task(args, kind: str) -> None:
     from jax.sharding import PartitionSpec as PS
 
     from tpustack.parallel import build_mesh
-    from tpustack.parallel.distributed import initialize_from_env
     from tpustack.parallel.sharding import BATCH_SPEC, LLAMA_RULES
     from tpustack.train.trainer import (TrainerConfig, make_sharded_train_step,
                                         make_train_state)
-
-    initialize_from_env()  # no-op single-process; JobSet env multi-host
 
     n_dev = len(jax.devices())
     if kind == "bert":
@@ -417,9 +414,6 @@ def _generic_lm_task(args, kind: str) -> None:
 
 
 def main(argv=None) -> int:
-    from tpustack.utils import enable_compile_cache
-
-    enable_compile_cache()  # restarted/rescheduled trainers skip cold jit
     p = argparse.ArgumentParser(description="tpustack training ladder")
     p.add_argument("task", choices=["resnet50", "bert", "llama2", "sd15"])
     p.add_argument("--steps", type=int, default=100)
@@ -452,6 +446,15 @@ def main(argv=None) -> int:
                    help="sd15: write the fine-tuned model as a diffusers "
                         "snapshot servable via MODEL_DIR")
     args = p.parse_args(argv)
+
+    from tpustack.parallel.distributed import initialize_from_env
+    from tpustack.utils import enable_compile_cache, require_accelerator
+
+    # no-op single-process; JobSet env multi-host — and it must run before
+    # the guard (or anything else) initialises the backend
+    initialize_from_env()
+    require_accelerator()
+    enable_compile_cache()  # restarted/rescheduled trainers skip cold jit
 
     # TPUSTACK_METRICS_PORT (the train-job manifests set 9100): stdlib
     # /metrics sidecar thread so Prometheus sees trainer device gauges —

@@ -1,5 +1,7 @@
 from tpustack.utils import knobs
-from tpustack.utils.config import enable_compile_cache
+from tpustack.utils.config import (device_info, enable_compile_cache,
+                                   require_accelerator)
 from tpustack.utils.logging import get_logger
 
-__all__ = ["enable_compile_cache", "get_logger", "knobs"]
+__all__ = ["device_info", "enable_compile_cache", "get_logger", "knobs",
+           "require_accelerator"]

@@ -414,10 +414,6 @@ _declare("TPUSTACK_SANITIZE_MODE", str, "report",
          "tpustack_sanitizer_violations_total and log, never crash).")
 
 # ------------------------------------------------------------------ runtime
-_declare("TPUSTACK_COMPILE_CACHE", str, "",
-         "Persistent XLA compilation cache dir (the manifests' PVC-backed "
-         "volume); empty falls back to JAX_COMPILATION_CACHE_DIR, then "
-         "<repo>/.cache/xla.")
 _declare("TPUSTACK_NO_NATIVE", bool, False,
          "Skip building/loading the native (C) helpers; pure-python "
          "fallbacks serve instead.")
