@@ -386,20 +386,23 @@ def test_llm_profile_endpoint(gen, monkeypatch, tmp_path):
         client = TestClient(TestServer(server.build_app()))
         await client.start_server()
         try:
-            r = await client.post("/profile", json={"n_predict": 3})
+            # the new body: seconds of whatever the engine is serving —
+            # no device lock, no request of its own
+            r = await client.post("/profile", json={"seconds": 0.2})
             assert r.status == 200, await r.text()
             prof = await r.json()
+            assert prof["seconds"] == 0.2
             assert prof["trace_dir"].startswith(
                 os.path.join(str(tmp_path), "llm"))
             assert prof["files"] and all(
                 f.endswith(".xplane.pb") for f in prof["files"])
             # a second capture lists only its own files
-            r2 = await client.post("/profile", json={"n_predict": 3})
+            r2 = await client.post("/profile", json={"seconds": 0.2})
             prof2 = await r2.json()
             assert prof2["trace_dir"] != prof["trace_dir"]
             assert not set(prof2["files"]) & set(prof["files"])
             # validation: bad bodies → 4xx, never a 500
-            for bad in ([1, 2], {"n_predict": "abc"}):
+            for bad in ([1, 2], {"seconds": "abc"}):
                 r = await client.post("/profile", json=bad)
                 assert r.status == 422, f"{bad} → {r.status}"
         finally:

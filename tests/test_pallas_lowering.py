@@ -11,6 +11,8 @@ passes (layout inference, VMEM limits) only run under libtpu — the
 hardware tier (``tests/test_tpu_hw.py``) covers those.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -85,6 +87,38 @@ def test_streaming_attention_lowers_for_tpu():
                                interpret=False)
 
     _lower_for_tpu(fn, q, kv, kv, scalar, scalar)
+
+
+def _kernel_programs():
+    """One small call of each Pallas kernel, by the name it has to carry."""
+    q = _sds((1, 256, 4, 128), jnp.bfloat16)
+    kv = _sds((1, 256, 2, 128), jnp.bfloat16)
+    scalar = _sds((), jnp.int32)
+    pool = _sds((33, 8, 2, 128), jnp.bfloat16)
+    return {
+        "flash_panel": (lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=False), (q, kv, kv)),
+        "flash_kstream": (lambda q, k, v, off, n: flash_attention(
+            q, k, v, causal=True, q_offset=off, kv_len=n, interpret=False),
+            (q, kv, kv, scalar, scalar)),
+        "paged_attention": (lambda q, pk, pv, bt, lens:
+                            paged_attention_partial(q, pk, pv, bt, lens,
+                                                    interpret=False),
+                            (_sds((2, 1, 4, 128), jnp.bfloat16), pool, pool,
+                             _sds((2, 16), jnp.int32), _sds((2,), jnp.int32))),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["flash_panel", "flash_kstream",
+                                    "paged_attention"])
+def test_kernel_names_are_pinned(kernel):
+    """A kernel's name is what a device trace shows and what the benchmark's
+    rooflines sum by (``flash_prefill_roofline``, ``paged_attention_
+    roofline``): a rename of the enclosing function must not move it."""
+    fn, avals = _kernel_programs()[kernel]
+    text = _lower_for_tpu(fn, *avals).as_text()
+    assert "tpu_custom_call" in text
+    assert re.findall(r'kernel_name = "([^"]*)"', text) == [kernel]
 
 
 def test_refused_block_shape_fails_on_cpu():

@@ -90,9 +90,11 @@ class RMSNorm(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
-        xf = x.astype(jnp.float32)
-        xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + self.eps)
-        return (xf * scale).astype(self.dtype)
+        with jax.named_scope("norm"):
+            xf = x.astype(jnp.float32)
+            xf = xf * jax.lax.rsqrt(
+                jnp.mean(xf * xf, axis=-1, keepdims=True) + self.eps)
+            return (xf * scale).astype(self.dtype)
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -160,11 +162,19 @@ class LlamaAttention(nn.Module):
         dense = lambda feats, name, bias: make_dense(
             c.quant, feats, use_bias=bias, dtype=self.dtype, name=name)
         b, s, _ = x.shape
-        q = dense(c.n_heads * hd, "q_proj", c.qkv_bias)(x).reshape(b, s, c.n_heads, hd)
-        k = dense(c.n_kv_heads * hd, "k_proj", c.qkv_bias)(x).reshape(b, s, c.n_kv_heads, hd)
-        v = dense(c.n_kv_heads * hd, "v_proj", c.qkv_bias)(x).reshape(b, s, c.n_kv_heads, hd)
-        q = rope(q, positions, c.rope_theta)
-        k = rope(k, positions, c.rope_theta)
+        # the jax.named_scope names below (attn_qkv, kv_write, kv_read,
+        # attn_core, attn_out; norm, mlp, embed, lm_head in the modules
+        # further down; sample in llm_generate) are what a device trace's
+        # operations are summed by: a promise, like a kernel's name
+        with jax.named_scope("attn_qkv"):
+            q = dense(c.n_heads * hd, "q_proj", c.qkv_bias)(x).reshape(
+                b, s, c.n_heads, hd)
+            k = dense(c.n_kv_heads * hd, "k_proj", c.qkv_bias)(x).reshape(
+                b, s, c.n_kv_heads, hd)
+            v = dense(c.n_kv_heads * hd, "v_proj", c.qkv_bias)(x).reshape(
+                b, s, c.n_kv_heads, hd)
+            q = rope(q, positions, c.rope_theta)
+            k = rope(k, positions, c.rope_theta)
 
         if kv_cache is not None and "ck" in kv_cache:
             # CONTINUOUS-slot decode chunk (s == 1): every slot sits at its
@@ -197,100 +207,106 @@ class LlamaAttention(nn.Module):
             quantized = ("k_scale" in kv_cache
                          or "pk_scale" in kv_cache)
             cbuf_len = kv_cache["ck"].shape[1]
-            if quantized:
-                # quantise at write — the buffer holds the SAME int8 values
-                # the main cache will, so flushing is a copy, not a requant
-                k_q, k_s = _quantize_kv(k)
-                v_q, v_s = _quantize_kv(v)
-                new_cache = dict(
-                    kv_cache,
-                    ck=jax.lax.dynamic_update_slice(
-                        kv_cache["ck"], k_q, (0, t, 0, 0)),
-                    cv=jax.lax.dynamic_update_slice(
-                        kv_cache["cv"], v_q, (0, t, 0, 0)),
-                    ck_scale=jax.lax.dynamic_update_slice(
-                        kv_cache["ck_scale"], k_s, (0, t, 0)),
-                    cv_scale=jax.lax.dynamic_update_slice(
-                        kv_cache["cv_scale"], v_s, (0, t, 0)))
-            else:
-                new_cache = dict(
-                    kv_cache,
-                    ck=jax.lax.dynamic_update_slice(
-                        kv_cache["ck"], k.astype(kv_cache["ck"].dtype),
-                        (0, t, 0, 0)),
-                    cv=jax.lax.dynamic_update_slice(
-                        kv_cache["cv"], v.astype(kv_cache["cv"].dtype),
-                        (0, t, 0, 0)))
+            with jax.named_scope("kv_write"):
+                if quantized:
+                    # quantise at write — the buffer holds the SAME int8
+                    # values the main cache will, so flushing is a copy, not
+                    # a requant
+                    k_q, k_s = _quantize_kv(k)
+                    v_q, v_s = _quantize_kv(v)
+                    new_cache = dict(
+                        kv_cache,
+                        ck=jax.lax.dynamic_update_slice(
+                            kv_cache["ck"], k_q, (0, t, 0, 0)),
+                        cv=jax.lax.dynamic_update_slice(
+                            kv_cache["cv"], v_q, (0, t, 0, 0)),
+                        ck_scale=jax.lax.dynamic_update_slice(
+                            kv_cache["ck_scale"], k_s, (0, t, 0)),
+                        cv_scale=jax.lax.dynamic_update_slice(
+                            kv_cache["cv_scale"], v_s, (0, t, 0)))
+                else:
+                    new_cache = dict(
+                        kv_cache,
+                        ck=jax.lax.dynamic_update_slice(
+                            kv_cache["ck"], k.astype(kv_cache["ck"].dtype),
+                            (0, t, 0, 0)),
+                        cv=jax.lax.dynamic_update_slice(
+                            kv_cache["cv"], v.astype(kv_cache["cv"].dtype),
+                            (0, t, 0, 0)))
             from tpustack.ops.attention import (dot_product_attention_partial,
                                                 merge_attention_partials)
 
-            if s == 1:
-                buf_mask = jnp.broadcast_to(
-                    jnp.arange(cbuf_len)[None, None, :] <= t,
-                    (b, 1, cbuf_len))
-            else:
-                # verify segment: per-query in-segment causal (see above)
-                buf_mask = jnp.broadcast_to(
-                    jnp.arange(cbuf_len)[None, None, :]
-                    <= (t + jnp.arange(s))[None, :, None], (b, s, cbuf_len))
-            if paged_flash:
-                # read the KV pool blocks IN PLACE through the slot block
-                # tables (scalar-prefetch Pallas kernel, per-row `cur0`
-                # masking + int8 dequant in-kernel) — no dense [B, max_seq]
-                # gather copy; every query row of a multi-query verify
-                # attends the same [0, cur0) pool prefix, so ONE kernel
-                # pass covers the whole segment and the in-segment causal
-                # half stays in the buffer partial below
-                from tpustack.ops.pallas.flash_attention import (
-                    paged_attention_partial)
+            with jax.named_scope("attn_core"):
+                if s == 1:
+                    buf_mask = jnp.broadcast_to(
+                        jnp.arange(cbuf_len)[None, None, :] <= t,
+                        (b, 1, cbuf_len))
+                else:
+                    # verify segment: per-query in-segment causal (see above)
+                    buf_mask = jnp.broadcast_to(
+                        jnp.arange(cbuf_len)[None, None, :]
+                        <= (t + jnp.arange(s))[None, :, None],
+                        (b, s, cbuf_len))
+                if paged_flash:
+                    # read the KV pool blocks IN PLACE through the slot block
+                    # tables (scalar-prefetch Pallas kernel, per-row `cur0`
+                    # masking + int8 dequant in-kernel) — no dense
+                    # [B, max_seq] gather copy; every query row of a multi-
+                    # query verify attends the same [0, cur0) pool prefix, so
+                    # ONE kernel pass covers the whole segment and the in-
+                    # segment causal half stays in the buffer partial below
+                    from tpustack.ops.pallas.flash_attention import (
+                        paged_attention_partial)
 
-                part_main = paged_attention_partial(
-                    q, kv_cache["pk"], kv_cache["pv"], kv_cache["bt"],
-                    cur0, k_scale=kv_cache.get("pk_scale"),
-                    v_scale=kv_cache.get("pv_scale"))
-            else:
-                main_mask = (jnp.arange(kv_cache["k"].shape[1])
-                             [None, None, :]
-                             < cur0[:, None, None])      # [B, 1, S]
-                part_main = dot_product_attention_partial(
-                    q, kv_cache["k"], kv_cache["v"], mask=main_mask,
-                    k_scale=kv_cache.get("k_scale"),
-                    v_scale=kv_cache.get("v_scale"))
-            part_buf = dot_product_attention_partial(
-                q, new_cache["ck"], new_cache["cv"], mask=buf_mask,
-                k_scale=new_cache.get("ck_scale"),
-                v_scale=new_cache.get("cv_scale"))
-            out = merge_attention_partials(part_main, part_buf, self.dtype)
-            out = out.reshape(b, s, c.n_heads * hd)
-            return dense(c.dim, "o_proj", False)(out), new_cache
+                    part_main = paged_attention_partial(
+                        q, kv_cache["pk"], kv_cache["pv"], kv_cache["bt"],
+                        cur0, k_scale=kv_cache.get("pk_scale"),
+                        v_scale=kv_cache.get("pv_scale"))
+                else:
+                    main_mask = (jnp.arange(kv_cache["k"].shape[1])
+                                 [None, None, :]
+                                 < cur0[:, None, None])      # [B, 1, S]
+                    part_main = dot_product_attention_partial(
+                        q, kv_cache["k"], kv_cache["v"], mask=main_mask,
+                        k_scale=kv_cache.get("k_scale"),
+                        v_scale=kv_cache.get("v_scale"))
+                part_buf = dot_product_attention_partial(
+                    q, new_cache["ck"], new_cache["cv"], mask=buf_mask,
+                    k_scale=new_cache.get("ck_scale"),
+                    v_scale=new_cache.get("cv_scale"))
+                out = merge_attention_partials(part_main, part_buf, self.dtype)
+                out = out.reshape(b, s, c.n_heads * hd)
+            with jax.named_scope("attn_out"):
+                return dense(c.dim, "o_proj", False)(out), new_cache
         if kv_cache is not None:
             quantized = "k_scale" in kv_cache
-            if quantized:
-                # int8 cache: quantise this call's K/V vectors as they are
-                # written; reads below keep int8 as the attention matmul
-                # operand and apply the scales outside the d-contraction
-                k_q, k_s = _quantize_kv(k)
-                v_q, v_s = _quantize_kv(v)
-                k_all = jax.lax.dynamic_update_slice(
-                    kv_cache["k"], k_q, (0, cache_index, 0, 0))
-                v_all = jax.lax.dynamic_update_slice(
-                    kv_cache["v"], v_q, (0, cache_index, 0, 0))
-                ks_all = jax.lax.dynamic_update_slice(
-                    kv_cache["k_scale"], k_s, (0, cache_index, 0))
-                vs_all = jax.lax.dynamic_update_slice(
-                    kv_cache["v_scale"], v_s, (0, cache_index, 0))
-                new_cache = {"k": k_all, "k_scale": ks_all,
-                             "v": v_all, "v_scale": vs_all}
-            else:
-                # static-shape cache update at cache_index (decode: s==1)
-                k_all = jax.lax.dynamic_update_slice(
-                    kv_cache["k"], k.astype(kv_cache["k"].dtype),
-                    (0, cache_index, 0, 0))
-                v_all = jax.lax.dynamic_update_slice(
-                    kv_cache["v"], v.astype(kv_cache["v"].dtype),
-                    (0, cache_index, 0, 0))
-                ks_all = vs_all = None
-                new_cache = {"k": k_all, "v": v_all}
+            with jax.named_scope("kv_write"):
+                if quantized:
+                    # int8 cache: quantise this call's K/V vectors as they are
+                    # written; reads below keep int8 as the attention matmul
+                    # operand and apply the scales outside the d-contraction
+                    k_q, k_s = _quantize_kv(k)
+                    v_q, v_s = _quantize_kv(v)
+                    k_all = jax.lax.dynamic_update_slice(
+                        kv_cache["k"], k_q, (0, cache_index, 0, 0))
+                    v_all = jax.lax.dynamic_update_slice(
+                        kv_cache["v"], v_q, (0, cache_index, 0, 0))
+                    ks_all = jax.lax.dynamic_update_slice(
+                        kv_cache["k_scale"], k_s, (0, cache_index, 0))
+                    vs_all = jax.lax.dynamic_update_slice(
+                        kv_cache["v_scale"], v_s, (0, cache_index, 0))
+                    new_cache = {"k": k_all, "k_scale": ks_all,
+                                 "v": v_all, "v_scale": vs_all}
+                else:
+                    # static-shape cache update at cache_index (decode: s==1)
+                    k_all = jax.lax.dynamic_update_slice(
+                        kv_cache["k"], k.astype(kv_cache["k"].dtype),
+                        (0, cache_index, 0, 0))
+                    v_all = jax.lax.dynamic_update_slice(
+                        kv_cache["v"], v.astype(kv_cache["v"].dtype),
+                        (0, cache_index, 0, 0))
+                    ks_all = vs_all = None
+                    new_cache = {"k": k_all, "v": v_all}
             from_zero = isinstance(cache_index, int) and cache_index == 0
             if s > 1 and from_zero and attn_mask is None:
                 # Prefill from position 0: attend IN-BUCKET, not over the
@@ -303,11 +319,13 @@ class LlamaAttention(nn.Module):
                 # Chunked prefill (cache_index > 0 / traced, or an explicit
                 # mask) must see the earlier cache, so it takes a full-cache
                 # path below.
-                attend, sharded = _per_head_shard(
-                    lambda q, k, v: dot_product_attention(
-                        q, k, v, causal=True, impl="auto"), self.tp_mesh, c)
-                out = (attend(q, k, v) if sharded else
-                       dot_product_attention(q, k, v, causal=True))
+                with jax.named_scope("attn_core"):
+                    attend, sharded = _per_head_shard(
+                        lambda q, k, v: dot_product_attention(
+                            q, k, v, causal=True, impl="auto"),
+                        self.tp_mesh, c)
+                    out = (attend(q, k, v) if sharded else
+                           dot_product_attention(q, k, v, causal=True))
             elif s > 1 and attn_mask is None:
                 # Chunked long-context prefill: this chunk's rows sit at
                 # global positions cache_index + i and attend the whole
@@ -317,27 +335,32 @@ class LlamaAttention(nn.Module):
                 # would need [s, max_seq] scores per head here.
                 from tpustack.ops.pallas.flash_attention import flash_attention
 
-                if quantized:
-                    # the kernel has no scale inputs: dequantise for this
-                    # (per-chunk, compile-once) path — the decode step below
-                    # is where the int8 bandwidth saving matters
-                    k_in = (k_all.astype(self.dtype) *
-                            ks_all[..., None].astype(self.dtype))
-                    v_in = (v_all.astype(self.dtype) *
-                            vs_all[..., None].astype(self.dtype))
-                else:
-                    k_in, v_in = k_all, v_all
+                with jax.named_scope("kv_read"):
+                    if quantized:
+                        # the kernel has no scale inputs: dequantise for this
+                        # (per-chunk, compile-once) path — the decode step
+                        # below is where the int8 bandwidth saving matters
+                        k_in = (k_all.astype(self.dtype) *
+                                ks_all[..., None].astype(self.dtype))
+                        v_in = (v_all.astype(self.dtype) *
+                                vs_all[..., None].astype(self.dtype))
+                    else:
+                        k_in, v_in = k_all, v_all
                 # (a tp that does not divide the heads leaves the kernel
                 # unwrapped: Mosaic then refuses the partitioned program)
-                attend, _ = _per_head_shard(
-                    lambda q, k, v, off: flash_attention(
-                        q, k, v, causal=True, q_offset=off, kv_len=off + s),
-                    self.tp_mesh, c, n_scalars=1)
-                out = attend(q, k_in, v_in,
-                             jnp.asarray(cache_index, jnp.int32))
+                with jax.named_scope("attn_core"):
+                    attend, _ = _per_head_shard(
+                        lambda q, k, v, off: flash_attention(
+                            q, k, v, causal=True, q_offset=off,
+                            kv_len=off + s),
+                        self.tp_mesh, c, n_scalars=1)
+                    out = attend(q, k_in, v_in,
+                                 jnp.asarray(cache_index, jnp.int32))
             else:
-                out = dot_product_attention(q, k_all, v_all, mask=attn_mask,
-                                            k_scale=ks_all, v_scale=vs_all)
+                with jax.named_scope("attn_core"):
+                    out = dot_product_attention(
+                        q, k_all, v_all, mask=attn_mask, k_scale=ks_all,
+                        v_scale=vs_all)
         elif (self.ring_mesh is not None and attn_mask is None
                 and "sp" in self.ring_mesh.axis_names
                 and self.ring_mesh.shape["sp"] > 1
@@ -350,12 +373,13 @@ class LlamaAttention(nn.Module):
             from tpustack.parallel.ring_attention import ring_attention
 
             new_cache = None
-            if c.n_kv_heads != c.n_heads:  # ring expects matched heads
-                rep = c.n_heads // c.n_kv_heads
-                k = jnp.repeat(k, rep, axis=2)
-                v = jnp.repeat(v, rep, axis=2)
-            out = ring_attention(q, k, v, mesh=self.ring_mesh, axis="sp",
-                                 causal=True)
+            with jax.named_scope("attn_core"):
+                if c.n_kv_heads != c.n_heads:  # ring expects matched heads
+                    rep = c.n_heads // c.n_kv_heads
+                    k = jnp.repeat(k, rep, axis=2)
+                    v = jnp.repeat(v, rep, axis=2)
+                out = ring_attention(q, k, v, mesh=self.ring_mesh, axis="sp",
+                                     causal=True)
         else:
             new_cache = None
             # Deliberately impl="xla": this no-cache path is also the training
@@ -363,9 +387,12 @@ class LlamaAttention(nn.Module):
             # above covers sp-sharded training).  Serving prefill goes through
             # the masked KV-cache branch, so flash cannot apply there either
             # (kernel supports causal, not arbitrary masks).
-            out = dot_product_attention(q, k, v, causal=True, mask=attn_mask)
+            with jax.named_scope("attn_core"):
+                out = dot_product_attention(q, k, v, causal=True,
+                                            mask=attn_mask)
         out = out.reshape(b, s, c.n_heads * hd)
-        return dense(c.dim, "o_proj", False)(out), new_cache
+        with jax.named_scope("attn_out"):
+            return dense(c.dim, "o_proj", False)(out), new_cache
 
 
 class LlamaMLP(nn.Module):
@@ -379,9 +406,10 @@ class LlamaMLP(nn.Module):
         c = self.cfg
         dense = lambda feats, name: make_dense(
             c.quant, feats, use_bias=False, dtype=self.dtype, name=name)
-        gate = dense(c.ffn_dim, "gate_proj")(x)
-        up = dense(c.ffn_dim, "up_proj")(x)
-        return dense(c.dim, "down_proj")(nn.silu(gate) * up)
+        with jax.named_scope("mlp"):
+            gate = dense(c.ffn_dim, "gate_proj")(x)
+            up = dense(c.ffn_dim, "up_proj")(x)
+            return dense(c.dim, "down_proj")(nn.silu(gate) * up)
 
 
 class LlamaBlock(nn.Module):
@@ -440,7 +468,8 @@ class LlamaModel(nn.Module):
         else:
             embed = nn.Embed(c.vocab_size, c.dim, dtype=self.dtype,
                              name="embed_tokens")
-        x = embed(tokens)
+        with jax.named_scope("embed"):
+            x = embed(tokens)
         new_caches = [] if kv_caches is not None else None
         for i in range(c.n_layers):
             cache_i = kv_caches[i] if kv_caches is not None else None
@@ -453,17 +482,19 @@ class LlamaModel(nn.Module):
         if logits_at is not None:
             x = jnp.take_along_axis(
                 x, logits_at[:, None, None].astype(jnp.int32), axis=1)  # [B,1,D]
-        if c.tie_embeddings:
-            logits = embed.attend(x.astype(jnp.float32))
-        else:
-            from tpustack.ops.quant import make_dense
+        from tpustack.ops.quant import make_dense
 
-            # int8 lm_head still matmuls in bf16 (x is bf16) but scales/
-            # accumulates logits in f32, matching the bf16 path's out dtype
-            logits = make_dense(c.quant, c.vocab_size, use_bias=False,
-                                dtype=self.dtype, name="lm_head",
-                                out_dtype=jnp.float32)(
-                x if c.quant else x.astype(jnp.float32))
+        with jax.named_scope("lm_head"):
+            if c.tie_embeddings:
+                logits = embed.attend(x.astype(jnp.float32))
+            else:
+                # int8 lm_head still matmuls in bf16 (x is bf16) but scales/
+                # accumulates logits in f32, matching the bf16 path's out
+                # dtype
+                logits = make_dense(c.quant, c.vocab_size, use_bias=False,
+                                    dtype=self.dtype, name="lm_head",
+                                    out_dtype=jnp.float32)(
+                    x if c.quant else x.astype(jnp.float32))
         return logits, new_caches
 
 

@@ -76,6 +76,7 @@ the served path):
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -89,9 +90,13 @@ import numpy as np
 from tpustack import sanitize
 from tpustack.models.llama import init_kv_caches
 from tpustack.models.llm_generate import Generator, SampleConfig
+from tpustack.obs.flight import PhaseClock
 from tpustack.utils import get_logger
 
 log = get_logger("models.llm_continuous")
+
+#: what ``with self._phase(...)`` enters on an engine with no flight recorder
+_NO_PHASE = contextlib.nullcontext()
 
 
 @dataclasses.dataclass
@@ -191,6 +196,25 @@ class SlotRequest:
     # prompt/cached split, not the resume's history-as-prefix view) and
     # how many chunk dispatches ran so far.  None = not a continuation.
     chunk_cont: Optional[Tuple[int, int]] = None
+    # the admission's timeline (flight ``prefill`` record: ``queue_s``,
+    # ``admit_s``; the row's ``queue_s`` stat): ``t_enqueue`` is the
+    # wall-clock the server queued the request at (carried like ``tenant``;
+    # None on bench/CLI paths), ``t_handed`` the one its ``feed()`` popped
+    # it at — THE measurement of the queue wait, which the server's ledger,
+    # QoS and phase histogram read too; the engine stamps it itself on what
+    # a ``feed()`` hands out unstamped.  A preempted row's resume carries
+    # neither (its first token is long out); a chunked-prefill continuation
+    # carries both, so the chunk hops count into ``admit_s``.
+    t_enqueue: Optional[float] = None
+    t_handed: Optional[float] = None
+
+    @property
+    def queue_s(self) -> Optional[float]:
+        """Queued at the server -> handed out by ``feed()``; None where
+        either end was never stamped — never a made-up 0."""
+        if self.t_enqueue is None or self.t_handed is None:
+            return None
+        return self.t_handed - self.t_enqueue
 
 
 class _Slot:
@@ -234,12 +258,15 @@ class _PendingWave:
     resolution (when prefill has provably landed) and handed to each
     request's ``on_prefill_kv``."""
 
-    __slots__ = ("rows", "firsts_dev", "t0", "extracts", "block_inserts")
+    __slots__ = ("rows", "firsts_dev", "t0", "extracts", "block_inserts",
+                 "bucket")
 
-    def __init__(self, rows, firsts_dev, t0, extracts=(), block_inserts=()):
+    def __init__(self, rows, firsts_dev, t0, extracts=(), block_inserts=(),
+                 bucket=None):
         self.rows = rows            # [(slot_idx, req, budget)]
         self.firsts_dev = firsts_dev
         self.t0 = t0
+        self.bucket = bucket        # padded tokens a row (a hit: its suffix)
         self.extracts = list(extracts)  # [(req, device kv slices)]
         # paged: [(req, prompt block ids)] — handed to on_prefill_blocks at
         # resolution (zero-copy cache insert; no device work at all)
@@ -393,6 +420,15 @@ class ContinuousEngine:
                               if paged is not None else 0)
         self._prefill_chunks = 0  # per-run chunk dispatches (stats)
         self._last_wave_t: Optional[float] = None
+        # host phase timers (tpustack.obs.flight.PhaseClock): where the
+        # engine thread's wall time goes between two wave/verify records
+        # (their ``host_s``), and the same phases as ``engine/<phase>``
+        # events on the profiler's host plane.  On wherever there is a
+        # flight recorder to read them (a server always has one): a
+        # perf_counter pair and an annotation object per phase, ~ten a wave.
+        self._clock = PhaseClock() if flight is not None else None
+        self._phase = (self._clock.phase if flight is not None
+                       else lambda name: _NO_PHASE)
         self._to_park: List[int] = []  # retirements awaiting a fused park
         self._pending: List[_PendingWave] = []
         self._retired_tokens = 0
@@ -644,7 +680,8 @@ class ContinuousEngine:
             on_prefill_blocks=req.on_prefill_blocks,
             speculative=req.speculative, tenant=req.tenant,
             t_kv_alloc=req.t_kv_alloc, priority=req.priority,
-            chunk_cont=(orig_cached, n_chunks + 1)))
+            chunk_cont=(orig_cached, n_chunks + 1),
+            t_enqueue=req.t_enqueue, t_handed=req.t_handed))
 
     def _admit_dispatch(self, state, slots: List[_Slot],
                         waves: List[Tuple[int, SlotRequest]], gen_ctr: int):
@@ -859,7 +896,8 @@ class ContinuousEngine:
                 self.paged.arrays = state["pool"]
                 slots[i].pending = True
                 self._pending.append(_PendingWave(
-                    rows, firsts, t0, block_inserts=block_inserts(rows)))
+                    rows, firsts, t0, block_inserts=block_inserts(rows),
+                    bucket=sbucket))
                 continue
             prefix_dev = g._prefix_to_device(
                 pkv, req.prefix[2] if len(req.prefix) > 2 else None)
@@ -888,7 +926,8 @@ class ContinuousEngine:
                 topk_r, greedy_r, row_keys)
             slots[i].pending = True
             self._pending.append(_PendingWave(rows, firsts, t0,
-                                              dispatch_extracts(rows)))
+                                              dispatch_extracts(rows),
+                                              bucket=sbucket))
 
         for bucket, rows in sorted(groups.items()):
             n = len(rows)
@@ -931,7 +970,8 @@ class ContinuousEngine:
                 for i, _, _ in rows:
                     slots[i].pending = True
                 self._pending.append(_PendingWave(
-                    rows, firsts, t0, block_inserts=block_inserts(rows)))
+                    rows, firsts, t0, block_inserts=block_inserts(rows),
+                    bucket=bucket))
                 continue
             if bucket > g.PREFILL_CHUNK:
                 # chunked long-prompt admission: one fused scan dispatch
@@ -966,7 +1006,8 @@ class ContinuousEngine:
             for i, _, _ in rows:
                 slots[i].pending = True
             self._pending.append(_PendingWave(rows, firsts, t0,
-                                              dispatch_extracts(rows)))
+                                              dispatch_extracts(rows),
+                                              bucket=bucket))
         return gen_ctr
 
     def _resolve(self, state, slots: List[_Slot], wave: _PendingWave):
@@ -975,7 +1016,8 @@ class ContinuousEngine:
         and retire rows that already ended (stop-token first, budget 1).
         ``prefill_s`` is wall time from dispatch to resolution — with
         overlap this is the request's true time-to-first-token."""
-        firsts = [int(t) for t in np.asarray(wave.firsts_dev)]
+        with self._phase("resolve_wait"):
+            firsts = [int(t) for t in np.asarray(wave.firsts_dev)]
         t_first = time.time() - wave.t0
         if self.paged is not None and self.paged.cache is not None:
             tier = getattr(self.paged.cache, "host_tier", None)
@@ -992,7 +1034,18 @@ class ContinuousEngine:
                 prompt_tokens=sum(len(r.ids) for _, r, _ in wave.rows),
                 cached_tokens=sum(slots[i].cached
                                   for i, _, _ in wave.rows),
-                prefill_s=round(t_first, 6))
+                prefill_s=round(t_first, 6),
+                # the admission's timeline, a value per row: queued at
+                # the server -> handed out by feed() -> this group's
+                # dispatch (where prefill_s starts, so the three add up
+                # to enqueue -> first token on the host)
+                queue_s=[None if r.queue_s is None else round(r.queue_s, 6)
+                         for _, r, _ in wave.rows],
+                admit_s=[None if r.t_handed is None
+                         else round(wave.t0 - r.t_handed, 6)
+                         for _, r, _ in wave.rows],
+                bucket=wave.bucket,
+                prompt_lens=[len(r.ids) for _, r, _ in wave.rows])
         for req, ids in wave.block_inserts:
             # prefill has landed (the firsts fetch above synced on it): the
             # prompt's full blocks are valid, so the zero-copy cache insert
@@ -1062,19 +1115,20 @@ class ContinuousEngine:
         if not self._pending:
             return
         remaining = []
-        for wave in self._pending:
-            urgent = any(budget <= 1 for _, _, budget in wave.rows)
-            if needed_slots is not None:
-                must = urgent or any(i in needed_slots
-                                     for i, _, _ in wave.rows)
-            elif only_ready:
-                must = urgent or wave.firsts_dev.is_ready()
-            else:
-                must = True
-            if must:
-                self._resolve(state, slots, wave)
-            else:
-                remaining.append(wave)
+        with self._phase("resolve"):
+            for wave in self._pending:
+                urgent = any(budget <= 1 for _, _, budget in wave.rows)
+                if needed_slots is not None:
+                    must = urgent or any(i in needed_slots
+                                         for i, _, _ in wave.rows)
+                elif only_ready:
+                    must = urgent or wave.firsts_dev.is_ready()
+                else:
+                    must = True
+                if must:
+                    self._resolve(state, slots, wave)
+                else:
+                    remaining.append(wave)
         self._pending = remaining
 
     def _retire(self, state, slots: List[_Slot], i: int, batch_size: int,
@@ -1125,6 +1179,8 @@ class ContinuousEngine:
                 "tokens_per_s": (len(out) / max(dt - s.prefill_s, 1e-9)
                                  if out else 0.0),
             }
+            if req.queue_s is not None:
+                st["queue_s"] = req.queue_s
             if req.chunk_cont is not None:
                 # a chunked-prefill continuation: report the ORIGINAL
                 # request's cache-hit split, not the resume's history-as-
@@ -1222,6 +1278,8 @@ class ContinuousEngine:
             st["cached_tokens"] = orig_cached
             st["prefill_tokens"] = len(req.ids) - orig_cached
             st["preempted"] = st.get("preempted", 0) + 1
+            if req.queue_s is not None:  # the wait before its FIRST slot
+                st["queue_s"] = req.queue_s
             orig_done(prior + tokens, st)
 
         parked = SlotRequest(
@@ -1316,6 +1374,8 @@ class ContinuousEngine:
         self._spec_dispatches = self._plain_steps = 0
         self._wave_ctr = 0
         self._last_wave_t = None  # per-run: wave_s must not span idle gaps
+        if self._clock is not None:
+            self._clock.reset()  # likewise host_s
         # (wall time, tokens consumed so far, waves fetched so far) at each
         # block fetch: the steady-state decode rate is the slope between
         # the first and last marks — what the bench reports alongside
@@ -1327,23 +1387,28 @@ class ContinuousEngine:
         def admit_free() -> None:
             nonlocal gen_ctr, admitted
             wave = []
-            for i in range(self.B):
-                if slots[i].req is not None:
-                    continue
-                req = feed()
-                if req is None:
-                    # no fresh work for this slot: resume preempted batch
-                    # entries (their retained blocks warm-start through
-                    # the prefix path — counted as resumes, not requests)
-                    req = self._pop_parked()
+            with self._phase("admit"):
+                for i in range(self.B):
+                    if slots[i].req is not None:
+                        continue
+                    req = feed()
                     if req is None:
-                        break
-                    self._resumed += 1
-                else:
-                    admitted += 1
-                wave.append((i, req))
-            if wave:
-                gen_ctr = self._admit_dispatch(state, slots, wave, gen_ctr)
+                        # no fresh work for this slot: resume preempted
+                        # batch entries (their retained blocks warm-start
+                        # through the prefix path — counted as resumes,
+                        # not requests)
+                        req = self._pop_parked()
+                        if req is None:
+                            break
+                        self._resumed += 1
+                    else:
+                        admitted += 1
+                        if req.t_handed is None:  # a feed() that stamps none
+                            req.t_handed = time.time()
+                    wave.append((i, req))
+                if wave:
+                    gen_ctr = self._admit_dispatch(state, slots, wave,
+                                                   gen_ctr)
 
         def dispatch_ok(s: _Slot) -> bool:
             # this row still wants tokens the chain hasn't covered (budget
@@ -1454,40 +1519,41 @@ class ContinuousEngine:
         pipelined dispatch half of the wave loop, shared by the plain and
         speculative run loops)."""
         g = self.gen
-        while len(chain) < self.depth and any(
-                dispatch_ok(s) for s in slots):
-            snapshot = [(i, s.gen_id, s.dispatched)
-                        for i, s in enumerate(slots) if dispatch_ok(s)]
-            if self.paged is not None:
-                (toks, last, state["cur"], state["pool"],
-                 state["keys"]) = g._decode_scan_paged(
-                    g.params, state["first"], state["cur"],
-                    state["active"], state["pool"],
-                    jnp.asarray(self._bt), state["keys"],
-                    state["temp"], state["topk"], state["greedy"],
-                    self.chunk, flash=self.paged_flash)
-                # keep the runtime's arrays reference CURRENT (donation
-                # rotated the buffers): the host-tier spill path reads
-                # blocks through it between dispatches, and cached prefix
-                # blocks are immutable post-prefill — so the freshest
-                # buffer generation always holds their right bytes
-                self.paged.arrays = state["pool"]
-                if self.paged_flash:
-                    self._flash_dispatches += 1
+        with self._phase("dispatch"):
+            while len(chain) < self.depth and any(
+                    dispatch_ok(s) for s in slots):
+                snapshot = [(i, s.gen_id, s.dispatched)
+                            for i, s in enumerate(slots) if dispatch_ok(s)]
+                if self.paged is not None:
+                    (toks, last, state["cur"], state["pool"],
+                     state["keys"]) = g._decode_scan_paged(
+                        g.params, state["first"], state["cur"],
+                        state["active"], state["pool"],
+                        jnp.asarray(self._bt), state["keys"],
+                        state["temp"], state["topk"], state["greedy"],
+                        self.chunk, flash=self.paged_flash)
+                    # keep the runtime's arrays reference CURRENT (donation
+                    # rotated the buffers): the host-tier spill path reads
+                    # blocks through it between dispatches, and cached prefix
+                    # blocks are immutable post-prefill — so the freshest
+                    # buffer generation always holds their right bytes
+                    self.paged.arrays = state["pool"]
+                    if self.paged_flash:
+                        self._flash_dispatches += 1
+                    else:
+                        self._gather_dispatches += 1
                 else:
-                    self._gather_dispatches += 1
-            else:
-                (toks, last, state["cur"], state["caches"],
-                 state["keys"]) = g._decode_scan_cont(
-                    g.params, state["first"], state["cur"],
-                    state["active"], state["caches"], state["keys"],
-                    state["temp"], state["topk"], state["greedy"],
-                    self.chunk)
-            state["first"] = last
-            self._plain_steps += self.chunk
-            for i, _, _ in snapshot:
-                slots[i].dispatched += self.chunk
-            chain.append((toks, snapshot))
+                    (toks, last, state["cur"], state["caches"],
+                     state["keys"]) = g._decode_scan_cont(
+                        g.params, state["first"], state["cur"],
+                        state["active"], state["caches"], state["keys"],
+                        state["temp"], state["topk"], state["greedy"],
+                        self.chunk)
+                state["first"] = last
+                self._plain_steps += self.chunk
+                for i, _, _ in snapshot:
+                    slots[i].dispatched += self.chunk
+                chain.append((toks, snapshot))
 
     def _sanitize_wave(self) -> None:
         """Wave-boundary sanitizer checks (no-op unless TPUSTACK_SANITIZE):
@@ -1529,17 +1595,24 @@ class ContinuousEngine:
                      drafted: int = 0, accepted: int = 0,
                      occupancy: Optional[int] = None,
                      tenants: Optional[Dict[str, int]] = None,
-                     priorities: Optional[Dict[str, int]] = None) -> None:
+                     priorities: Optional[Dict[str, int]] = None,
+                     ctx_tokens: int = 0) -> None:
         """Append one flight record for a fetched wave (plain chunk or
         speculative verify).  Host-side values only — the fetch that
         produced ``tokens`` already synced, so this is a dict build and a
         deque append, nothing more.  ``occupancy`` and ``tenants`` are
         the live count / tenant split AT FETCH (callers snapshot both
         before retiring finished rows, so a request's last wave still
-        carries — and bills — its tenant)."""
+        carries — and bills — its tenant).  ``ctx_tokens``: prompt +
+        generated so far, summed over the rows this wave advanced, as
+        they stood when it was fetched (what its attention had to read).
+        ``host_s``: the engine thread's seconds by phase since the
+        previous wave/verify record, ``other`` being what no phase
+        covered — they add up to ``wave_s``."""
         if self.flight is None:
             return
         now = time.time()
+        host_s = self._clock.take()
         rec = {
             "wave": self._wave_ctr,
             "occupancy": (occupancy if occupancy is not None else
@@ -1552,6 +1625,8 @@ class ContinuousEngine:
             "accepted": int(accepted),
             "wave_s": (round(now - self._last_wave_t, 6)
                        if self._last_wave_t is not None else None),
+            "host_s": host_s,
+            "ctx_tokens": int(ctx_tokens),
         }
         self._last_wave_t = now
         if self._queue_depth_fn is not None:
@@ -1610,7 +1685,7 @@ class ContinuousEngine:
         live = self._live(slots)
         tenants = self._tenant_occupancy(slots)  # pre-retire, like live
         priorities = self._priority_occupancy(slots)
-        wave_tokens = 0
+        wave_tokens = ctx_tokens = 0
         for i, gid, offset in snapshot:
             s = slots[i]
             if s.req is None or s.gen_id != gid or s.done:
@@ -1619,6 +1694,7 @@ class ContinuousEngine:
                 s.done = True
                 self._retire(state, slots, i, live)
                 continue
+            ctx_tokens += len(s.req.ids) + len(s.out)
             # chunks are consumed in dispatch order and never overlap:
             # this block carries exactly decode steps [offset, offset+chunk)
             assert len(s.out) - 1 == offset, (len(s.out), offset)
@@ -1640,7 +1716,25 @@ class ContinuousEngine:
                 self._retire(state, slots, i, live)
         self._flight_wave(slots, "wave", wave_tokens, self.chunk,
                           stride=self.chunk, occupancy=live,
-                          tenants=tenants, priorities=priorities)
+                          tenants=tenants, priorities=priorities,
+                          ctx_tokens=ctx_tokens)
+
+    def _fetch_consume(self, state, slots, block, snapshot):
+        """THE wave-boundary fetch: one sync per consumed chunk, with
+        `depth` more chunks already dispatched behind it — the wait timed
+        apart from the bookkeeping that follows it."""
+        with self._phase("fetch_wait"):
+            block = np.asarray(block)  # tpulint: disable=TPL101
+        with self._phase("consume"):
+            self._consume_block(state, slots, block, snapshot)
+
+    def _retire_exhausted(self, state, slots, dispatch_ok):
+        """Retire every row that is done or has nothing left to dispatch
+        (the chain-empty branch of both run loops)."""
+        with self._phase("consume"):
+            for i, s in enumerate(slots):
+                if s.req is not None and (s.done or not dispatch_ok(s)):
+                    self._retire(state, slots, i, self._live(slots))
 
     def _run_loop(self, state, slots, chain, admit_free, dispatch_ok):
         while True:
@@ -1648,8 +1742,9 @@ class ContinuousEngine:
             # request is waiting (no-op without a QoS preempt hint), then
             # flush parks BEFORE admissions — a freshly admitted slot's
             # state would otherwise be zeroed by its predecessor's park
-            self._maybe_preempt(slots)
-            self._flush_park(state)
+            with self._phase("park"):
+                self._maybe_preempt(slots)
+                self._flush_park(state)
             admit_free()
             if self._live(slots) == 0 and not self._parked:
                 # NOT while anything is parked: a chunked-prefill
@@ -1665,9 +1760,7 @@ class ContinuousEngine:
                 # or out of budget: resolve (blocking — their retires need
                 # first tokens), then re-enter retire bookkeeping
                 self._resolve_pending(state, slots)
-                for i, s in enumerate(slots):
-                    if s.req is not None and (s.done or not dispatch_ok(s)):
-                        self._retire(state, slots, i, self._live(slots))
+                self._retire_exhausted(state, slots, dispatch_ok)
                 continue
             block, snapshot = chain.popleft()
             pending_here = {i for i, _, _ in snapshot if slots[i].pending}
@@ -1681,9 +1774,7 @@ class ContinuousEngine:
                 # already-computed tokens are never stalled behind them
                 self._resolve_pending(state, slots,
                                       needed_slots=pending_here)
-            # THE wave-boundary fetch: one sync per consumed chunk, with
-            # `depth` more chunks already dispatched behind it
-            self._consume_block(state, slots, np.asarray(block), snapshot)  # tpulint: disable=TPL101
+            self._fetch_consume(state, slots, block, snapshot)
 
     # ------------------------------------------------- speculative decoding
     def _slot_draft_budget(self, s: _Slot) -> int:
@@ -1752,9 +1843,20 @@ class ContinuousEngine:
         device wrote KV for ACCEPTED positions only (the verify programs
         clip the flush/scatter at the accepted frontier), so a rejected
         draft costs compute, never cache or pool state."""
+        with self._phase("verify"):
+            toks_dev, n_acc, dlen, rows = self._spec_issue(state, slots,
+                                                           plan)
+        with self._phase("verify_wait"):
+            block = np.asarray(toks_dev)
+            accs = np.asarray(n_acc).tolist()
+        with self._phase("consume"):
+            self._spec_consume(state, slots, block, accs, dlen, rows)
+
+    def _spec_issue(self, state, slots, plan):
+        """Ship the plan's drafts and dispatch the verify program; returns
+        its device outputs and the rows it carried."""
         g = self.gen
-        spec = self.spec
-        K = spec.tokens
+        K = self.spec.tokens
         # structural invariant (the spec loop plans only after a blocking
         # resolve): a pending slot is device-active but host-unaccounted —
         # a verify advancing it would desync its token stream
@@ -1788,8 +1890,13 @@ class ContinuousEngine:
                 state["topk"], state["greedy"], K)
         state["first"] = last
         self._spec_dispatches += 1
-        block = np.asarray(toks_dev)
-        accs = np.asarray(n_acc)
+        return toks_dev, n_acc, dlen.tolist(), rows
+
+    def _spec_consume(self, state, slots, block, accs, dlen, rows):
+        """Host bookkeeping for one fetched verify wave: deliver each
+        row's accepted run + bonus token, retire, record.  ``block`` is
+        the fetched tokens; ``accs`` and ``dlen`` are plain per-slot ints."""
+        spec = self.spec
         if self._on_progress is not None:
             self._on_progress("wave")
         self._sanitize_wave()
@@ -1803,7 +1910,7 @@ class ContinuousEngine:
         live = self._live(slots)
         tenants = self._tenant_occupancy(slots)  # pre-retire, like live
         priorities = self._priority_occupancy(slots)
-        wave_tokens = wave_drafted = wave_accepted = 0
+        wave_tokens = wave_drafted = wave_accepted = ctx_tokens = 0
         for i, gid in rows:
             s = slots[i]
             if s.req is None or s.gen_id != gid or s.done:
@@ -1812,8 +1919,9 @@ class ContinuousEngine:
                 s.done = True
                 self._retire(state, slots, i, live)
                 continue
-            k_i = int(dlen[i])
-            m = min(int(accs[i]), k_i)
+            ctx_tokens += len(s.req.ids) + len(s.out)
+            k_i = dlen[i]
+            m = min(accs[i], k_i)
             if k_i > 0:
                 s.spec_ema = (1 - alpha) * s.spec_ema + alpha * (m / k_i)
                 s.spec_idle = 0
@@ -1855,7 +1963,7 @@ class ContinuousEngine:
                           stride=wave_tokens / max(1, len(rows)),
                           drafted=wave_drafted, accepted=wave_accepted,
                           occupancy=live, tenants=tenants,
-                          priorities=priorities)
+                          priorities=priorities, ctx_tokens=ctx_tokens)
 
     def _run_loop_spec(self, state, slots, chain, admit_free, dispatch_ok):
         """Variable-stride wave loop (``spec`` configured): whenever the
@@ -1869,8 +1977,9 @@ class ContinuousEngine:
         and traffic that never drafts runs the plain loop at full depth —
         degrade-to-plain, never below it."""
         while True:
-            self._maybe_preempt(slots)
-            self._flush_park(state)
+            with self._phase("park"):
+                self._maybe_preempt(slots)
+                self._flush_park(state)
             admit_free()
             if self._live(slots) == 0 and not self._parked:
                 break  # see _run_loop: parked continuations still queue
@@ -1884,32 +1993,34 @@ class ContinuousEngine:
                 # flush the parks — a verify must never advance a retired
                 # slot whose blocks were already released
                 self._resolve_pending(state, slots)
-                for i, s in enumerate(slots):
-                    if s.req is not None and (s.done or not dispatch_ok(s)):
-                        self._retire(state, slots, i, self._live(slots))
+                self._retire_exhausted(state, slots, dispatch_ok)
                 if self._live(slots) == 0:
                     continue
-                self._flush_park(state)
+                with self._phase("park"):
+                    self._flush_park(state)
                 # NOTE: no admission here — a freshly dispatched admission
                 # would be pending (unresolved firsts) and a verify must
                 # never advance a slot the host can't account for; the
                 # loop top admits and the blocking resolve above completes
                 # those before any verify dispatch
-                plan = self._spec_plan(slots, dispatch_ok)
+                with self._phase("draft"):
+                    plan = self._spec_plan(slots, dispatch_ok)
             if plan is not None:
                 self._spec_dispatch(state, slots, plan)
                 continue
             # plain decode: refill the pipeline only while NO slot would
             # draft on its current history; otherwise drain what's in
             # flight so the next iteration can speculate
-            if not chain or not self._spec_plan(slots, dispatch_ok,
-                                                probe_only=True):
+            refill = not chain
+            if not refill:
+                with self._phase("draft"):
+                    refill = not self._spec_plan(slots, dispatch_ok,
+                                                 probe_only=True)
+            if refill:
                 self._fill_chain(state, slots, chain, dispatch_ok)
             if not chain:
                 self._resolve_pending(state, slots)
-                for i, s in enumerate(slots):
-                    if s.req is not None and (s.done or not dispatch_ok(s)):
-                        self._retire(state, slots, i, self._live(slots))
+                self._retire_exhausted(state, slots, dispatch_ok)
                 continue
             block, snapshot = chain.popleft()
             pending_here = {i for i, _, _ in snapshot if slots[i].pending}
@@ -1918,4 +2029,4 @@ class ContinuousEngine:
                                       needed_slots=pending_here)
             # the spec loop's plain-chunk fallback shares the one-sync-
             # per-wave contract of _run_loop above
-            self._consume_block(state, slots, np.asarray(block), snapshot)  # tpulint: disable=TPL101
+            self._fetch_consume(state, slots, block, snapshot)

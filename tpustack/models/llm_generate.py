@@ -68,6 +68,7 @@ def resolve_paged_flash(env=None, mesh=None) -> bool:
                      "boolean (want auto, 1/true/yes/on or 0/false/no/off)")
 
 
+@jax.named_scope("sample")
 def _advance_keys(keys):
     """Advance per-row PRNG chains ``[B, 2]`` one step: returns
     ``(step_keys [B, 2], next_keys [B, 2])``.  Row i's chain is seeded at
@@ -427,6 +428,7 @@ class Generator:
         return [{k: sl(v) for k, v in layer.items()} for layer in caches]
 
     @staticmethod
+    @jax.named_scope("kv_write")
     def _restore_body(row_caches, prefix):
         """Traced body of the prefix restore — see _restore_kv_rows."""
 
@@ -524,7 +526,8 @@ class Generator:
             return jnp.where(gr, jnp.argmax(logits, axis=-1),
                              sampled).astype(jnp.int32)
 
-        return self._greedy_gated(logits, gr, mixed)
+        with jax.named_scope("sample"):
+            return self._greedy_gated(logits, gr, mixed)
 
     def _sample_from_logits_perrow(self, logits, keys, temperature, top_k,
                                    greedy):
@@ -544,7 +547,8 @@ class Generator:
             return jnp.where(gr, jnp.argmax(logits, axis=-1),
                              sampled).astype(jnp.int32)
 
-        return self._greedy_gated(logits, gr, mixed)
+        with jax.named_scope("sample"):
+            return self._greedy_gated(logits, gr, mixed)
 
     def _decode_logits(self, params, token, index, caches):
         """One cached decode step: ``[B,1]`` token → (``[B,V]`` f32, caches)."""
@@ -780,6 +784,7 @@ class Generator:
         caches = self._flush_chunk_bufs(caches, bufs, cur0, cur_end, n_steps)
         return toks, last, cur_end, caches, keys
 
+    @jax.named_scope("kv_write")
     def _flush_chunk_bufs(self, caches, bufs, cur0, cur_end, n_steps: int):
         """Traced flush of chunk-local K/V buffers into per-row cache lines
         at ``[cur0, cur_end)``: one linear pass per cache tensor — gather
@@ -831,6 +836,7 @@ class Generator:
     # decode before any mask can admit it, the same ordering argument the
     # dense engine makes for reassigned slot lines.
 
+    @jax.named_scope("kv_read")
     def _pool_gather_body(self, pool, bt):
         """Traced: pool tensors ``[N, blk, *tail]`` → dense per-row view
         ``[B, max_seq, *tail]`` via block tables ``bt [B, nb]``."""
@@ -862,6 +868,7 @@ class Generator:
         return [view(layer) for layer in pool]
 
     @staticmethod
+    @jax.named_scope("kv_write")
     def _pool_scatter_body(pool, bt_rows, src_layers, keymap, positions,
                            valid):
         """Traced: scatter per-row values at global cache ``positions
@@ -891,6 +898,7 @@ class Generator:
         return [{k: sc(layer[k], srcl[keymap.get(k, k)]) for k in layer}
                 for layer, srcl in zip(pool, src_layers)]
 
+    @jax.named_scope("kv_write")
     def _insert_span_body(self, pool, bt_rows, caches, start, bucket: int,
                           limits):
         """Traced: write cache positions ``[start, start + bucket)`` of R
@@ -1030,7 +1038,8 @@ class Generator:
             step_keys.append(sk)
 
         gr = jnp.broadcast_to(jnp.atleast_1d(jnp.asarray(greedy)), (B,))
-        outs_greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # [B, S]
+        with jax.named_scope("sample"):
+            outs_greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         valid = jnp.arange(K)[None, :] < draft_len[:, None]          # [B, K]
 
         def greedy_path(_):
@@ -1081,8 +1090,9 @@ class Generator:
 
         # all-greedy runtime gate, like _greedy_gated: the common serving
         # mix (and every parked slot) skips the softmax/draw machinery
-        n_acc, bonus = jax.lax.cond(jnp.all(gr), greedy_path, mixed_path,
-                                    None)
+        with jax.named_scope("sample"):  # both paths are traced in here
+            n_acc, bonus = jax.lax.cond(jnp.all(gr), greedy_path,
+                                        mixed_path, None)
         ar = jnp.arange(S)[None, :]
         draft_pad = jnp.pad(draft, ((0, 0), (0, 1)))
         toks = jnp.where(ar < n_acc[:, None], draft_pad,
@@ -1224,6 +1234,7 @@ class Generator:
                                       tokens.shape[1], limits)
 
     @staticmethod
+    @jax.named_scope("kv_write")
     def _splice_rows(slot_caches, row_caches, slot_ids, n: int, bucket: int):
         """Traced body: copy positions ``[0, bucket)`` of an n-row prefill
         cache into the slot rows ``slot_ids[j]`` (all layers, K/V and int8
@@ -1243,6 +1254,7 @@ class Generator:
 
         return jax.tree.map(ins, slot_caches, row_caches)
 
+    @jax.named_scope("sample")
     def _first_sample(self, logits, seeds, temperature, top_k, greedy):
         """Traced body: per-request key-chain init from seeds + first-token
         sample.  Shared by ``_admit_sample_jit`` and ``_admit_fused``."""

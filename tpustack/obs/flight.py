@@ -53,7 +53,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 from tpustack.utils import knobs
 
 __all__ = [
-    "FlightRecorder", "register", "recorders", "dump_all", "snapshot_all",
+    "FlightRecorder", "PhaseClock", "register", "recorders", "dump_all",
+    "snapshot_all",
     "device_peaks_info", "llm_wave_arith", "llm_utilization",
     "sd_utilization",
 ]
@@ -269,6 +270,87 @@ class FlightRecorder:
             _log().warning("flight dump failed (reason=%s)", reason,
                            exc_info=True)
             return None
+
+
+class PhaseClock:
+    """Where one thread's wall time goes between two flight records.
+
+    ``with clock.phase("fetch_wait"):`` charges the enclosed wall time to
+    that phase and puts an ``engine/fetch_wait`` event on the profiler's
+    host plane (``jax.profiler.TraceAnnotation``), so a capture shows the
+    same phases on the device trace's clock.  Phases nest: an inner phase
+    pauses its parent, so the seconds are exclusive and add up.
+    ``take()`` hands out ``{phase: seconds}`` since the previous ``take()``
+    — an open phase is charged up to now — with whatever no phase covered
+    as ``other``, and starts the next interval.  One ``perf_counter`` pair
+    per phase; no lock (one thread owns a clock).
+    """
+
+    __slots__ = ("_acc", "_stack", "_mark", "_annotate")
+
+    #: the host plane's events are ``engine/<phase>``
+    PREFIX = "engine/"
+
+    def __init__(self):
+        from jax.profiler import TraceAnnotation
+
+        self._annotate = TraceAnnotation
+        self.reset()
+
+    def reset(self) -> None:
+        self._acc: Dict[str, float] = {}
+        self._stack: List[List] = []  # [name, running since] innermost last
+        self._mark = time.perf_counter()
+
+    def phase(self, name: str) -> "_Phase":
+        return _Phase(self, name)
+
+    def _charge(self, now: float) -> None:
+        """Charge the innermost open phase up to ``now`` and restart it."""
+        if self._stack:
+            top = self._stack[-1]
+            self._acc[top[0]] = self._acc.get(top[0], 0.0) + now - top[1]
+            top[1] = now
+
+    def _push(self, name: str) -> None:
+        now = time.perf_counter()
+        self._charge(now)  # the parent pauses
+        self._stack.append([name, now])
+
+    def _pop(self) -> None:
+        now = time.perf_counter()
+        self._charge(now)
+        self._stack.pop()
+        if self._stack:
+            self._stack[-1][1] = now  # the parent resumes
+
+    def take(self) -> Dict[str, float]:
+        now = time.perf_counter()
+        self._charge(now)
+        out = {k: round(v, 6) for k, v in self._acc.items()}
+        other = (now - self._mark) - sum(self._acc.values())
+        if other > 0:
+            out["other"] = round(other, 6)
+        self._acc = {}
+        self._mark = now
+        return out
+
+
+class _Phase:
+    __slots__ = ("_clock", "_name", "_ann")
+
+    def __init__(self, clock: PhaseClock, name: str):
+        self._clock, self._name = clock, name
+
+    def __enter__(self):
+        self._ann = self._clock._annotate(PhaseClock.PREFIX + self._name)
+        self._ann.__enter__()
+        self._clock._push(self._name)
+
+    def __exit__(self, *exc):
+        self._clock._pop()
+        self._ann.__exit__(*exc)
+        return False
 
 
 def _log():

@@ -71,8 +71,10 @@ def capture(base: str, run: Callable[[], object],
             prefix: str = "capture-") -> Dict[str, object]:
     """Run blocking ``run()`` under ``jax.profiler.trace`` into a fresh
     subdir of ``base``; returns the endpoint payload.  Callers invoke
-    this from an executor thread while holding their device-serialising
-    lock — the capture must contain only the profiled run."""
+    this from an executor thread.  The SD server holds its
+    device-serialising lock around it, so its capture contains only the
+    profiled run; the LLM server holds none and sleeps in ``run``, so its
+    capture is whatever the engine serves meanwhile."""
     import jax
 
     os.makedirs(base, exist_ok=True)

@@ -342,6 +342,7 @@ def _flash_attention(q, k, v, *, causal, scale, block_q, block_k, interpret,
             out_specs=pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0)),
             out_shape=jax.ShapeDtypeStruct((b * h, sq_pad, d), q.dtype),
             interpret=interpret,
+            name="flash_panel",  # the device trace's name for it: a promise
         )(qf, kf, vf)
     else:
         bk = min(block_k, panel_max_kv)
@@ -373,6 +374,7 @@ def _flash_attention(q, k, v, *, causal, scale, block_q, block_k, interpret,
                 pltpu.VMEM((bq, d), jnp.float32),     # unnormalised acc
             ],
             interpret=interpret,
+            name="flash_kstream",
         )(qf, kf, vf, off, klen)
 
     out = out[:, :sq]                                  # drop q padding

@@ -235,7 +235,7 @@ class _PendingCompletion:
                  "stream_put", "seed", "prefix", "kv_extract", "on_prefill_kv",
                  "phase", "span_ctx", "queue_span", "kv_blocks",
                  "on_prefill_blocks", "speculative", "tenant", "t_enqueue",
-                 "t_kv_alloc", "priority", "host_restore")
+                 "t_handed", "t_kv_alloc", "priority", "host_restore")
 
     def __init__(self, ids, n_predict, sample, future, stream_put=None,
                  seed=None, prefix=None, kv_extract=None, on_prefill_kv=None,
@@ -282,10 +282,12 @@ class _PendingCompletion:
         # tenant cost accounting: the tenant id (resolved by the obs
         # middleware, captured at enqueue like span_ctx — engine threads
         # don't see the contextvar), enqueue wall clock (queue-seconds
-        # charge when feed() pops the request), and the paged-admission
-        # allocation wall clock (KV-block-seconds run from here)
+        # charge when feed() pops the request, which stamps ``t_handed``),
+        # and the paged-admission allocation wall clock (KV-block-seconds
+        # run from here)
         self.tenant = None
         self.t_enqueue = 0.0
+        self.t_handed = None
         self.t_kv_alloc = t_kv_alloc
         # QoS priority class (resolved by the resilience middleware,
         # captured at enqueue like tenant/span_ctx); None with QoS off
@@ -475,6 +477,7 @@ class LLMServer:
         # solo requests queued on the device lock; the engine stops
         # admitting while > 0 so the FIFO-fair lock can hand over
         self._solo_waiting = 0
+        self._profiling = False  # one POST /profile capture at a time
         # shared resilience layer: drain on SIGTERM, per-request deadlines,
         # 429 backpressure, hung-dispatch watchdog, TPUSTACK_FAULT_* hooks
         self.resilience = ResilienceManager(
@@ -1109,7 +1112,9 @@ class LLMServer:
                            on_prefill_blocks=r.on_prefill_blocks,
                            speculative=r.speculative, tenant=r.tenant,
                            t_kv_alloc=r.t_kv_alloc, priority=r.priority,
-                           host_restore=r.host_restore)
+                           host_restore=r.host_restore,
+                           t_enqueue=r.t_enqueue or None,
+                           t_handed=r.t_handed)
 
     # -------------------------------------------------- QoS queue helpers
     def _pop_queued(self) -> "_PendingCompletion":
@@ -1207,9 +1212,12 @@ class LLMServer:
                         r = self._pop_queued()
                         self.metrics["tpustack_llm_queue_depth"].set(
                             len(self._queue))
+                        r.t_handed = time.time()  # THE queue wait's end:
+                        # the flight record's queue_s, the row's stat and
+                        # the queue_wait phase histogram read this stamp too
                         if r.t_enqueue:  # queue-seconds to the tenant,
                             # cancelled and admitted alike — both waited
-                            wait_s = time.time() - r.t_enqueue
+                            wait_s = r.t_handed - r.t_enqueue
                             self.ledger.charge_queue_seconds(
                                 "llm", r.tenant, wait_s)
                             if self.qos is not None:
@@ -1356,8 +1364,10 @@ class LLMServer:
     def _observe_done(self, n_prompt: int, stats: dict, total_s: float) -> None:
         """Fold one finished completion into the metric families: token
         counters, prompt-length histogram, and the phase breakdown
-        (queue_wait is the wall time the device phases don't account for —
-        admission queueing, lock waits, event-loop overhead)."""
+        (queue_wait is the engine's own ``queue_s``, enqueue -> handed out
+        by feed(); a solo completion has no queue, so there it is the wall
+        time its device phases don't account for — the wait for the device
+        lock, event-loop overhead)."""
         from tpustack.obs import Trace
 
         m = self.metrics
@@ -1376,8 +1386,11 @@ class LLMServer:
         prefill = stats.get("prefill_s", 0.0)
         decode = stats.get("decode_s", 0.0)
         detok = stats.get("detokenize_s", 0.0)
+        queue = stats.get("queue_s")
+        if queue is None:
+            queue = max(0.0, total_s - prefill - decode - detok)
         tr = Trace()
-        tr.add("queue_wait", max(0.0, total_s - prefill - decode - detok))
+        tr.add("queue_wait", queue)
         tr.add("prefill", prefill)
         tr.add("decode", decode)
         tr.add("detokenize", detok)
@@ -1830,46 +1843,36 @@ class LLMServer:
             reason=reason).inc()
 
     async def profile(self, request: web.Request) -> web.Response:
-        """Capture an XLA/TPU profile (xplane) around one small greedy
-        completion — the SD server's ``POST /profile`` contract on the
-        LLM surface (``tpustack.obs.profile``).  Body: ``{n_predict?,
-        prompt?}``; runs under the generation lock, so the capture never
-        interleaves with the continuous engine's dispatches.  View with
-        ``tools/xprof_summary.py``."""
+        """Capture an XLA/TPU profile (xplane) of whatever the engine is
+        serving for the next ``seconds`` (body ``{"seconds": n}``, default
+        3, at most 60).  No device lock and no request of its own: the
+        capture is the live traffic — the engine thread's ``engine/<phase>``
+        host events beside the device's programs and kernels, on one
+        clock.  One capture at a time (409 while another runs)."""
         try:
             body = await request.json() if request.can_read_body else {}
         except ValueError:
             body = {}
-        try:
-            fields = obs_profile.parse_int_fields(body, {"n_predict": 8})
-        except ValueError as e:
-            return web.json_response({"detail": str(e)}, status=422)
-        prompt = "profile capture"
-        if isinstance(body, dict) and isinstance(body.get("prompt"), str) \
-                and body["prompt"].strip():
-            prompt = body["prompt"]
-        ids = self.tok.encode(prompt)
-        n = max(1, min(fields["n_predict"], self.gen.cfg.max_seq - len(ids)))
-        if len(ids) >= self.gen.cfg.max_seq:
+        if body is None:
+            body = {}
+        seconds = body.get("seconds", 3) if isinstance(body, dict) else None
+        if isinstance(seconds, bool) or not isinstance(seconds, (int, float)) \
+                or not 0 < seconds <= 60:
             return web.json_response(
-                {"detail": f"prompt ({len(ids)}) exceeds ctx "
-                           f"{self.gen.cfg.max_seq}"}, status=400)
-        from tpustack.models.llm_generate import SampleConfig
-
-        def run():
-            self.resilience.beat()  # a long cold compile must not trip
-            # the watchdog mid-capture
-            self.gen.generate_fused(
-                ids, max_new_tokens=n, sample=SampleConfig(greedy=True),
-                stop_tokens=(self.tok.eos_id,), chunk=min(self.chunk, n))
-
-        base = obs_profile.base_dir("llm")
+                {"detail": "body must be a JSON object; seconds a number "
+                           "in (0, 60]"}, status=422)
+        if self._profiling:
+            return web.json_response(
+                {"detail": "a capture is already running"}, status=409)
+        self._profiling = True
         try:
-            out = await self._run_on_device(
-                lambda: obs_profile.capture(base, run))
-        except ValueError as e:
-            return web.json_response({"detail": str(e)}, status=400)
-        return web.json_response(out)
+            out = await asyncio.get_running_loop().run_in_executor(
+                None, lambda: obs_profile.capture(
+                    obs_profile.base_dir("llm"),
+                    lambda: time.sleep(seconds)))
+        finally:
+            self._profiling = False
+        return web.json_response(dict(out, seconds=seconds))
 
     async def completion(self, request: web.Request) -> web.Response:
         try:
