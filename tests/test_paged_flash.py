@@ -1,5 +1,6 @@
-"""Paged-flash decode attention: the scalar-prefetch Pallas kernel that
-reads KV pool blocks IN PLACE (no dense gather copy) and its fused
+"""Paged-flash decode attention: the Pallas kernel that copies the KV pool
+blocks a row has straight out of the pool, through scalar-prefetched block
+tables (no dense gather copy), and its fused
 multi-query speculative verify, knob-gated as ``TPUSTACK_PAGED_FLASH``.
 
 The acceptance bars this file carries:
@@ -17,6 +18,7 @@ The acceptance bars this file carries:
   (subprocess-proven) with identical outputs to ``=1``.
 """
 
+import functools
 import json
 import os
 import subprocess
@@ -35,9 +37,11 @@ from tpustack.models.llm_generate import (Generator, SampleConfig,
 from tpustack.ops.attention import (dot_product_attention,
                                     dot_product_attention_partial,
                                     merge_attention_partials)
-from tpustack.ops.pallas.flash_attention import (paged_attention_partial,
+from tpustack.ops.pallas.flash_attention import (PAGED_COMPUTE_TOKENS,
+                                                 paged_attention_partial,
                                                  paged_bytes_accounting,
-                                                 paged_flash_attention)
+                                                 paged_flash_attention,
+                                                 paged_pages_per_step)
 from tpustack.serving.kv_pool import KVBlockPool, PagedKVRuntime
 from tpustack.serving.speculative import SpecConfig
 
@@ -72,7 +76,7 @@ def _pool_setup(rng, *, b=3, hkv=2, d=16, blk=8, nb=6, n_pool=14,
         pool_v = rng.randn(n_pool, blk, hkv, d).astype(np.float32)
     if poison_block0:
         # the reserved block: idle table entries point here — huge values
-        # must never reach any output through the masked/clamped reads
+        # must never reach any output: idle entries are not read at all
         pool_k[0] = 127 if int8 else 1e4
         pool_v[0] = 127 if int8 else 1e4
     lens = np.zeros(b, np.int32)
@@ -106,7 +110,7 @@ def _len_mask(lens, max_seq, s):
 def test_kernel_block_table_indirection_and_block0():
     """The kernel's table-mapped reads equal the dense gather reference,
     with the reserved block 0 poisoned: idle-tail garbage never leaks
-    through the clamped index map + length mask."""
+    through the table walk + length mask."""
     rng = np.random.RandomState(0)
     pk, pv, bt, lens, max_seq = _pool_setup(rng, poison_block0=True)
     b = lens.shape[0]
@@ -226,10 +230,176 @@ def test_kernel_multi_query_verify_causal(k):
                                rtol=1e-5, atol=1e-5)
 
 
+# ----------------------------------------- compute blocks of several pages
+#
+# The kernel walks a row in compute blocks of `paged_pages_per_step` pool
+# blocks and copies only the pool blocks a row has.  These shapes make the
+# derived compute block smaller than the table and not a divisor of it
+# (two compute blocks of 16 pages, the second 12 short), so lengths can sit
+# on either kind of edge; every table entry past a row's frontier points
+# at the reserved block 0 or at a poisoned block, alternately.
+
+def _walk_shape(int8):
+    """(blk, nb, pages): 512-token compute blocks over a 640-token table."""
+    blk, nb = 32, 20
+    pages = paged_pages_per_step(blk, nb, 2, 16,
+                                 jnp.int8 if int8 else jnp.float32)
+    assert pages * blk == PAGED_COMPUTE_TOKENS and 1 < pages < nb
+    assert nb % pages
+    return blk, nb, pages
+
+
+def _walk_case(seed, lens, *, int8, hkv=2, g=2, s=1, d=16, poison="nan"):
+    """A call's inputs, with the pool as the kernel gets it (poisoned) and
+    as the reference gets it (the poison zeroed: masked columns multiply
+    by it there)."""
+    rng = np.random.RandomState(seed)
+    blk, nb, _ = _walk_shape(int8)
+    b = len(lens)
+    lens = np.asarray(lens, np.int32)
+    n_valid = [-(-int(x) // blk) for x in lens]
+    n_pool = sum(n_valid) + 2
+    bad = n_pool - 1                            # block 0 and this one
+    shape = (n_pool, blk, hkv, d)
+    if int8:
+        pool = {"k": rng.randint(-127, 128, shape).astype(np.int8),
+                "v": rng.randint(-127, 128, shape).astype(np.int8)}
+        scales = {"k_scale": (rng.rand(*shape[:3]) * 0.02 + 1e-3
+                              ).astype(np.float32),
+                  "v_scale": (rng.rand(*shape[:3]) * 0.02 + 1e-3
+                              ).astype(np.float32)}
+    else:
+        pool = {"k": rng.randn(*shape).astype(np.float32),
+                "v": rng.randn(*shape).astype(np.float32)}
+        scales = {}
+    clean = {k: v.copy() for k, v in {**pool, **scales}.items()}
+    for idx in (0, bad):
+        for k in clean:
+            clean[k][idx] = 0
+        for k in pool:
+            pool[k][idx] = 127 if int8 else (np.nan if poison == "nan"
+                                             else 1e30)
+        for k in scales:
+            scales[k][idx] = np.nan if poison == "nan" else 1e30
+    # shuffled, non-contiguous tables; the idle tail alternates 0 / bad
+    perm, pos = rng.permutation(np.arange(1, bad)), 0
+    bt = np.where(np.arange(nb)[None, :] % 2, bad, 0).astype(np.int32)
+    bt = np.broadcast_to(bt, (b, nb)).copy()
+    for i, n in enumerate(n_valid):
+        bt[i, :n] = perm[pos:pos + n]
+        pos += n
+    q = rng.randn(b, s, hkv * g, d).astype(np.float32)
+    to = lambda t: {k: jnp.asarray(v) for k, v in t.items()}
+    return (jnp.asarray(q), to({**pool, **scales}), to(clean),
+            jnp.asarray(bt), jnp.asarray(lens))
+
+
+@functools.partial(jax.jit, static_argnames=("kernel",))
+def _walk_partial(q, pool, bt, lens, kernel):
+    scales = {k: pool[k] for k in ("k_scale", "v_scale") if k in pool}
+    if kernel:
+        return paged_attention_partial(q, pool["k"], pool["v"], bt, lens,
+                                       **scales)
+    max_seq = bt.shape[1] * pool["k"].shape[1]
+    return dot_product_attention_partial(
+        q, _gather_view(pool["k"], bt), _gather_view(pool["v"], bt),
+        mask=_len_mask(lens, max_seq, q.shape[1]),
+        **{k: _gather_view(v, bt) for k, v in scales.items()})
+
+
+def _assert_walk_parity(q, pool, clean, bt, lens):
+    got = _walk_partial(q, pool, bt, lens, kernel=True)
+    ref = _walk_partial(q, clean, bt, lens, kernel=False)
+    for g, r in zip(got, ref):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=1e-4, atol=1e-5)
+    return got
+
+
+#: a row's length, from (pool block, compute block, table) sizes
+WALK_EDGES = {
+    "zero": lambda blk, T, full: 0,
+    "one": lambda blk, T, full: 1,
+    "block_edge_minus_1": lambda blk, T, full: 3 * blk - 1,
+    "block_edge": lambda blk, T, full: 3 * blk,
+    "block_edge_plus_1": lambda blk, T, full: 3 * blk + 1,
+    "compute_block_edge_minus_1": lambda blk, T, full: T - 1,
+    "compute_block_edge": lambda blk, T, full: T,
+    "compute_block_edge_plus_1": lambda blk, T, full: T + 1,
+    "whole_table": lambda blk, T, full: full,
+}
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["pool", "int8pool"])
+@pytest.mark.parametrize("edge", sorted(WALK_EDGES))
+def test_kernel_walk_length_edges(edge, int8):
+    """Parity with the gathered view at every edge a length can sit on:
+    none, one, either side of a pool block's end and of a compute block's
+    end, the whole table — beside a second row that the first hands its
+    scratch half to."""
+    blk, nb, pages = _walk_shape(int8)
+    n = WALK_EDGES[edge](blk, pages * blk, nb * blk)
+    acc, m, l = _assert_walk_parity(
+        *_walk_case(10, [n, blk + 3], int8=int8))
+    if n == 0:
+        assert float(jnp.max(jnp.abs(acc[0]))) == 0.0
+        assert float(jnp.max(l[0])) == 0.0
+        assert float(jnp.max(m[0])) <= -1e29
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["pool", "int8pool"])
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify_k4"])
+def test_kernel_walk_mixed_rows_dead_between_live(s, int8):
+    """One call, eight rows: dead rows first, between and last; live rows
+    of one, two and a partial second compute block, so a row's first
+    copies are started by the row before it, or by itself after a dead
+    one, into either half of the scratch."""
+    blk, nb, pages = _walk_shape(int8)
+    T = pages * blk
+    lens = [0, T + 1, 0, 5, nb * blk, 0, 0, blk]
+    _assert_walk_parity(*_walk_case(20 + s, lens, int8=int8, s=s))
+    _assert_walk_parity(*_walk_case(30 + s, lens[::-1], int8=int8, s=s))
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["pool", "int8pool"])
+@pytest.mark.parametrize("hkv", [1, 2, 4])
+def test_kernel_walk_kv_heads(hkv, int8):
+    """Hkv 1 (a tp shard's slab), 2 and 4 heads folded into the lanes."""
+    blk, nb, pages = _walk_shape(int8)
+    lens = [pages * blk + blk + 1, 0, 2 * blk]
+    _assert_walk_parity(*_walk_case(40 + hkv, lens, int8=int8, hkv=hkv))
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["pool", "int8pool"])
+@pytest.mark.parametrize("poison", ["nan", "huge"])
+def test_kernel_walk_never_reads_past_the_frontier(poison, int8):
+    """Entries past the frontier point at block 0 and at a poisoned block
+    (NaN, or 1e30 / ±127 with 1e30 scales): neither is fetched, and what a
+    partial compute block leaves stale in the scratch reaches nothing."""
+    blk, nb, pages = _walk_shape(int8)
+    lens = [nb * blk, 1, pages * blk - blk - 1, blk + 1]
+    _assert_walk_parity(
+        *_walk_case(50, lens, int8=int8, s=5, poison=poison))
+
+
+def test_pages_per_step_follows_the_shapes():
+    """The compute block is derived, not set: 512 tokens at the
+    Deployment's int8 and bf16 pools, the whole table when that is
+    shorter, one pool block when a block is under the pool dtype's sublane
+    tile, fewer pages when K/V would not fit the scratch budget."""
+    assert paged_pages_per_step(64, 64, 4, 128, jnp.int8) == 8
+    assert paged_pages_per_step(64, 64, 2, 128, jnp.bfloat16) == 8
+    assert paged_pages_per_step(64, 4, 4, 128, jnp.int8) == 4
+    assert paged_pages_per_step(8, 16, 2, 16, jnp.float32) == 16
+    assert paged_pages_per_step(8, 16, 2, 16, jnp.int8) == 1
+    assert paged_pages_per_step(64, 64, 64, 128, jnp.float32) == 1
+
+
 def test_bytes_accounting_inplace_strictly_fewer():
     """The shared gather-vs-in-place bytes model: in place must move
     strictly fewer bytes per step at every occupancy, and the idle tail
-    costs ONE clamped block, not the whole table span."""
+    costs nothing: only the blocks a row has are copied."""
     for valid in (1, 4, 8):
         acct = paged_bytes_accounting(
             n_valid_blocks=valid, blocks_per_seq=8, block=16, kvh=2,
@@ -242,8 +412,7 @@ def test_bytes_accounting_inplace_strictly_fewer():
     one = paged_bytes_accounting(n_valid_blocks=1, blocks_per_seq=8,
                                  block=16, kvh=2, hd=16, esize=2,
                                  scale_bytes=0, n_steps=8)
-    # 1 valid + 1 clamped tail block = 2 blocks/step vs the full 8
-    assert one["paged_flash_step_bytes"] * 4 == full["paged_flash_step_bytes"]
+    assert one["paged_flash_step_bytes"] * 8 == full["paged_flash_step_bytes"]
 
 
 # -------------------------------------------------------- engine parity

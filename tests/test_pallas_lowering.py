@@ -32,29 +32,81 @@ def _sds(shape, dtype):
 # (b, q heads, kv heads, head_dim, block, blocks/seq, pool blocks, q dtype):
 # the Deployment's Qwen2.5-7B (8 slots, ctx 4096 / 64-token blocks, 512+1
 # pool blocks) and the tiny preset the CPU servers boot
+# ... and one head shard of the 7B pool under LLM_TP=4
+# (``llama._per_head_shard``): a pool block is a [64, 1·128] slab
 PAGED_MODELS = {
     "qwen25_7b": (8, 28, 4, 128, 64, 64, 513, jnp.bfloat16),
+    "qwen25_7b_tp4_shard": (8, 7, 1, 128, 64, 64, 513, jnp.bfloat16),
     "tiny": (2, 4, 2, 16, 8, 16, 33, jnp.float32),
 }
 
 
-@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify_k4"])
-@pytest.mark.parametrize("int8_pool", [False, True], ids=["pool", "int8pool"])
-@pytest.mark.parametrize("model", sorted(PAGED_MODELS))
-def test_paged_attention_lowers_for_tpu(model, int8_pool, s):
+def _paged_call(model, int8_pool, s, sharding=None):
+    """``(fn, avals)`` of one paged kernel call at a served shape."""
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
     b, h, hkv, d, blk, nb, n_pool, qdt = PAGED_MODELS[model]
-    pool = _sds((n_pool, blk, hkv, d), jnp.int8 if int8_pool else qdt)
-    args = [_sds((b, s, h, d), qdt), pool, pool,
-            _sds((b, nb), jnp.int32), _sds((b,), jnp.int32)]
+    pool = sds((n_pool, blk, hkv, d), jnp.int8 if int8_pool else qdt)
+    args = [sds((b, s, h, d), qdt), pool, pool,
+            sds((b, nb), jnp.int32), sds((b,), jnp.int32)]
     if int8_pool:
-        scales = _sds((n_pool, blk, hkv), jnp.float32)
+        scales = sds((n_pool, blk, hkv), jnp.float32)
         args += [scales, scales]
 
     def fn(q, pk, pv, bt, lens, ks=None, vs=None):
         return paged_attention_partial(q, pk, pv, bt, lens, k_scale=ks,
                                        v_scale=vs, interpret=False)
 
+    return fn, args
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify_k4"])
+@pytest.mark.parametrize("int8_pool", [False, True], ids=["pool", "int8pool"])
+@pytest.mark.parametrize("model", sorted(PAGED_MODELS))
+def test_paged_attention_lowers_for_tpu(model, int8_pool, s):
+    fn, args = _paged_call(model, int8_pool, s)
     _lower_for_tpu(fn, *args)
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A described, not attached, v5e chip: the TPU compiler is installed
+    on this host, so Mosaic's own passes (layout inference, the alignment
+    of every copy's slice, VMEM) can refuse a kernel here instead of on
+    the chip.  Made inside a test, never at import (one process per
+    libtpu)."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else logs in /tmp
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify_k4"])
+@pytest.mark.parametrize("int8_pool", [False, True], ids=["pool", "int8pool"])
+@pytest.mark.parametrize("model", sorted(PAGED_MODELS))
+def test_paged_attention_compiles_for_v5e(one_v5e_chip, model, int8_pool, s):
+    """The whole compile, Mosaic included: the kernel copies pool blocks
+    itself, and Mosaic refuses a copy whose slice is not whole tiles (a
+    ``[64, 4]`` scale page out of a ``pl.ANY`` operand was) only here."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    fn, args = _paged_call(model, int8_pool, s, sharding=one_v5e_chip)
+    # an entry written for a described chip cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        jax.jit(fn).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
 
 
 # panel kernel: (q tokens, k tokens, heads, head_dim)

@@ -24,19 +24,109 @@ import statistics
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+#: the Deployment's decode shape (cluster-config/apps/llm/deployment.yaml):
+#: 8 slots, Qwen2.5-7B GQA 28q/4kv x 128, int8 pool of 512+1 64-token
+#: blocks, ctx 4096
+PAGED_DEPLOYMENT = dict(b=8, s=1, h=28, hkv=4, d=128, blk=64, nb=64)
+PAGED_SWEEP_CTX = (256, 512, 1024, 2048, 4096)
+PAGED_SWEEP_LIVE = (1, 4, 8)
+#: ms per layer-call of the (b, nb)-grid kernel this sweep replaced, from
+#: the records (PERF.md §6, PR 27): PR 21's probe and PR 26's two traced
+#: cells — (about ctx, live rows) → ms
+PAGED_OLD_MS = {(4096, 8): 0.48, (2900, 4): 0.19, (450, 7.3): 0.122}
+
+
+def _device_events(trace_dir: str):
+    """``(name, start_ns, duration_ns)`` of every device operation in the
+    newest trace under ``trace_dir`` — read as the benchmark's rooflines
+    read it (``benchmark/readers/trace.py``)."""
+    from benchmark.readers import trace
+
+    devices = trace.extract(trace.find_xplane(trace_dir))
+    return [e for events in devices.values() for e in events]
+
+
+def paged_sweep(partial_fn, log, *, calls: int = 64, trace_dir=None):
+    """Device time per ``paged_attention`` call over context x live rows at
+    the Deployment's shape, int8 pool — the table ROADMAP S4 asked for.
+    ``partial_fn`` is ``paged_attention_partial`` (a parameter so one sweep
+    can time two trees' kernels).  Each cell runs ``calls`` chained calls
+    in one program, the pool and tables loop-invariant as in a decode
+    chunk, under the profiler; the time is the median of the kernel's own
+    device events, the roofline share the cell's K/V + scale bytes over
+    the chip's HBM bandwidth (``peaks.py``) over that."""
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.readers import trace
+    from tpustack.utils.peaks import measurement_peaks
+
+    hbm_bytes_per_s = measurement_peaks(jax.devices()[0])[1]
+    sh = PAGED_DEPLOYMENT
+    b, h, hkv, d, blk, nb = (sh[k] for k in ("b", "h", "hkv", "d", "blk",
+                                             "nb"))
+    n_pool = b * nb + 1
+    rng = np.random.RandomState(0)
+    q = jnp.asarray(rng.randn(b, 1, h, d), jnp.bfloat16)
+    pk = jnp.asarray(rng.randint(-127, 128, (n_pool, blk, hkv, d)), jnp.int8)
+    pv = jnp.asarray(rng.randint(-127, 128, (n_pool, blk, hkv, d)), jnp.int8)
+    ks = jnp.asarray(rng.rand(n_pool, blk, hkv) * 0.02 + 1e-3, jnp.float32)
+    vs = jnp.asarray(rng.rand(n_pool, blk, hkv) * 0.02 + 1e-3, jnp.float32)
+    bt = jnp.asarray(rng.permutation(np.arange(1, n_pool)).reshape(b, nb),
+                     jnp.int32)
+
+    @jax.jit
+    def chain(q, lens):
+        def step(qq, _):
+            acc, m, l = partial_fn(qq, pk, pv, bt, lens, k_scale=ks,
+                                   v_scale=vs)
+            # the next call waits for this one; the values do not move
+            return qq + (0 * acc[:, :, :, :1]).astype(qq.dtype), None
+        return jax.lax.scan(step, q, None, length=calls)[0]
+
+    rows = []
+    trace_dir = trace_dir or tempfile.mkdtemp(prefix="paged_sweep_")
+    for ctx in PAGED_SWEEP_CTX:
+        for live in PAGED_SWEEP_LIVE:
+            lens = np.zeros(b, np.int32)
+            lens[np.arange(live) * (b // live)] = ctx   # dead rows between
+            lens = jnp.asarray(lens)
+            chain(q, lens).block_until_ready()          # compile, warm
+            cell_dir = os.path.join(trace_dir, f"ctx{ctx}_live{live}")
+            with jax.profiler.trace(cell_dir):
+                chain(q, lens).block_until_ready()
+            secs = [dur / 1e9 for name, _, dur in _device_events(cell_dir)
+                    if "paged_attention" in trace.short_name(name)]
+            kv_bytes = ctx * live * hkv * (2 * d + 8)   # int8 K+V, f32 scales
+            us = statistics.median(secs) * 1e6
+            rows.append({
+                "ctx": ctx, "live_rows": live, "calls": len(secs),
+                "us_per_call": round(us, 2),
+                "roofline_pct": round(
+                    100 * kv_bytes / hbm_bytes_per_s / (us * 1e-6), 2)})
+            log(f"[bench_flash] paged ctx {ctx:5d} live {live}: "
+                f"{us:8.2f} us/call ({len(secs)} events), "
+                f"{rows[-1]['roofline_pct']:.2f}% of the HBM roofline")
+    return rows
+
+
 def _paged_mode(args) -> int:
     """``--paged``: gather-vs-in-place paged decode attention.
 
     Two implementations of the same math — gather every table-mapped pool
     block into a dense ``[B, max_seq]`` view then run the masked XLA
     partial (what ``_pool_gather_body`` + ``dot_product_attention_partial``
-    do per chunk), vs the scalar-prefetch Pallas kernel reading the pool
-    blocks IN PLACE (``paged_attention_partial``).  Asserts the outputs
-    agree and that the in-place path moves STRICTLY fewer HBM bytes per
-    decode step (``paged_bytes_accounting`` — the same arithmetic
-    ``bench_llm --paged`` embeds in its roofline block); on CPU this runs
-    the kernel in interpret mode, so timing is only reported on real TPU
-    backends (interpret wall clock proves nothing)."""
+    do per chunk), vs the Pallas kernel reading the pool blocks IN PLACE
+    (``paged_attention_partial``).  Asserts the outputs agree and that the
+    in-place path moves STRICTLY fewer HBM bytes per decode step
+    (``paged_bytes_accounting`` — the same arithmetic ``bench_llm --paged``
+    embeds in its roofline block).  On CPU the kernel runs in interpret
+    mode and no time is reported (interpret wall clock proves nothing); on
+    a TPU it adds the wall time of both at one ragged shape and
+    ``paged_sweep``'s table of the kernel's device time per call."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -53,9 +143,8 @@ def _paged_mode(args) -> int:
         b, s, h, hkv, d, blk, nb = 4, 1, 4, 2, 16, 8, 8
         n_steps = 8
     else:
-        # Qwen-7B serving decode: 8 slots, GQA 28q/4kv, 64-token blocks
-        # over a 2048-token table span
-        b, s, h, hkv, d, blk, nb = 8, 1, 28, 4, 128, 64, 32
+        b, s, h, hkv, d, blk, nb = (PAGED_DEPLOYMENT[k] for k in
+                                    ("b", "s", "h", "hkv", "d", "blk", "nb"))
         n_steps = 16
     max_seq = blk * nb
     n_pool = b * nb + 1  # every slot fully backed + reserved block 0
@@ -77,20 +166,28 @@ def _paged_mode(args) -> int:
         pos += valid
     bt, lens = jnp.asarray(bt), jnp.asarray(lens)
 
-    def gather_partial(qq):
-        def ga(x):
-            g = jnp.take(x, bt.reshape(-1), axis=0)
-            return g.reshape((b, nb * x.shape[1]) + x.shape[2:])
+    def dense_view(x):
+        g = jnp.take(x, bt.reshape(-1), axis=0)
+        return g.reshape((b, nb * x.shape[1]) + x.shape[2:])
+
+    def dense_partial(qq, k, v):
         mask = jnp.arange(max_seq)[None, None, :] < lens[:, None, None]
         return dot_product_attention_partial(
-            qq, ga(pool_k), ga(pool_v),
-            mask=jnp.broadcast_to(mask, (b, s, max_seq)))
+            qq, k, v, mask=jnp.broadcast_to(mask, (b, s, max_seq)))
+
+    gather_partial = lambda qq: dense_partial(qq, dense_view(pool_k),
+                                              dense_view(pool_v))
 
     inplace_partial = lambda qq: paged_attention_partial(
         qq, pool_k, pool_v, bt, lens)
 
-    ref = jax.jit(gather_partial)(q)
-    got = jax.jit(inplace_partial)(q)
+    # (acc, m, l) compared as what they are for: the normalised output and
+    # the two merge statistics (an unnormalised acc over 4k bf16 tokens
+    # carries rounding of the order of an absolute tolerance)
+    norm = lambda part: (part[0] / jnp.maximum(part[2][..., None], 1e-30),
+                         part[1], part[2])
+    ref = norm(jax.jit(gather_partial)(q))
+    got = norm(jax.jit(inplace_partial)(q))
     ok = all(np.allclose(np.asarray(x), np.asarray(y), rtol=2e-2, atol=2e-2)
              for x, y in zip(got, ref))
     log(f"[bench_flash] paged in-place vs gather allclose: {ok}")
@@ -106,20 +203,40 @@ def _paged_mode(args) -> int:
         f"{bytes_acct['gather_step_bytes']:.0f} vs in-place "
         f"{bytes_acct['paged_flash_step_bytes']:.0f} (fewer={fewer})")
 
-    timing = None
-    if on_tpu:
-        from tpustack.utils.benchmark import pipelined_intervals
+    timing = sweep = None
+    if on_tpu and not args.tiny:
+        import tempfile
 
-        for name, fn in (("gather", jax.jit(gather_partial)),
-                         ("inplace", jax.jit(inplace_partial))):
-            np.asarray(fn(q)[0])  # compile
-            times = pipelined_intervals(lambda seed: fn(q)[0],
-                                        repeats=args.repeats,
-                                        warmup_min=1, warmup_max=4,
-                                        unit="call")
-            med = statistics.median(times)
-            timing = dict(timing or {}, **{f"{name}_ms": round(med * 1e3, 3)})
-            log(f"[bench_flash] paged {name}: {med * 1e3:.3f} ms")
+        from benchmark.readers import trace
+
+        # device time per step of one n_steps decode chunk, all of it: the
+        # gather path copies the dense view once a chunk and reads all of
+        # it every step, the in-place path gathers its scale rows once
+        def chunk(attend_of):
+            def run(qq):
+                attend = attend_of()
+                def step(c, _):
+                    acc = attend(c)[0]
+                    return c + (0 * acc[:, :, :, :1]).astype(c.dtype), None
+                return jax.lax.scan(step, qq, None, length=n_steps)[0]
+            return jax.jit(run)
+
+        def gather_chunk():
+            k, v = dense_view(pool_k), dense_view(pool_v)
+            return lambda qq: dense_partial(qq, k, v)
+
+        timing = {}
+        for name, fn in (("gather", chunk(gather_chunk)),
+                         ("inplace", chunk(lambda: inplace_partial))):
+            fn(q).block_until_ready()
+            tdir = tempfile.mkdtemp(prefix=f"paged_{name}_")
+            with jax.profiler.trace(tdir):
+                fn(q).block_until_ready()
+            us = trace.busy_ns(_device_events(tdir)) / 1e3 / n_steps
+            timing[f"{name}_us_per_step"] = round(us, 2)
+            log(f"[bench_flash] paged {name}: {us:.2f} us of device time a "
+                f"step of a {n_steps}-step chunk")
+        sweep = paged_sweep(paged_attention_partial, log)
 
     print(json.dumps({
         "shape": "paged", "batch": b, "heads": h, "kv_heads": hkv,
@@ -127,6 +244,9 @@ def _paged_mode(args) -> int:
         "interpret": not on_tpu, "outputs_allclose": bool(ok),
         "bytes_per_step": {k: round(v, 1) for k, v in bytes_acct.items()},
         "inplace_moves_fewer_bytes": bool(fewer), "timing": timing,
+        "sweep": sweep,
+        "old_ms_per_call": {f"ctx~{c},live~{n}": ms
+                            for (c, n), ms in PAGED_OLD_MS.items()},
     }))
     # both properties gate: a wrong kernel or a bytes model that stopped
     # favoring in-place fails the smoke (tier-1 shells this)

@@ -100,9 +100,12 @@ PAGED_CASES = [
     ("paged_verify_k4_bf16", 5, False),
     ("paged_verify_k4_int8", 5, True),
 ]
-PAGED_SHAPE = dict(b=8, h=28, hkv=4, d=128, blk=64, nb=16, n_pool=129)
-#: per-slot valid prefix: full, mid-block, empty (parked), block-aligned …
-PAGED_LENS = (1024, 67, 0, 512, 513, 64, 1, 700)
+# The Deployment's table and pool: ctx 4096 = 64 blocks a slot, 512+1 blocks.
+PAGED_SHAPE = dict(b=8, h=28, hkv=4, d=128, blk=64, nb=64, n_pool=513)
+#: per-slot valid prefix, ragged: full context, mid-block, empty (parked)
+#: between live rows, exactly one 512-token compute block and one past it,
+#: a long-prompt row, one token, a chat row
+PAGED_LENS = (4096, 67, 0, 512, 513, 2900, 1, 700)
 
 # Pass thresholds.  The f32 rows run under jax.default_matmul_precision
 # "highest" (without it the MXU's default bf16-input passes make "f32"

@@ -384,32 +384,51 @@ def _flash_attention(q, k, v, *, causal, scale, block_q, block_k, interpret,
 # --------------------------------------------------------- paged attention
 #
 # Decode attention that reads the paged KV pool IN PLACE (vLLM-style
-# PagedAttention, Kwon et al. SOSP'23): the per-slot block table is a
-# scalar-prefetch operand (pltpu.PrefetchScalarGridSpec), so each grid
-# step's BlockSpec index map looks its pool block up BEFORE the kernel
-# body runs and the pipeline DMAs that block — all kv heads of it, one
-# contiguous [block, kvh*hd] slab of the pool tensor [n_blocks, block,
-# kvh, hd] — straight into VMEM; no dense [B, max_seq] gather copy ever
-# materialises in HBM.  Every block's last two dims are whole axes of
-# the (reshaped) operand, which is what Mosaic's (8|16|32, 128) tiling
-# accepts for any head count; a block of ONE kv head out of kvh < 8 is
-# refused at lowering.  Softmax is the
-# online (m, l, acc) carry across the block grid dim, exactly like
-# _attn_kernel_stream; the result is returned as the UNNORMALISED partial
-# (acc, m, l) in dot_product_attention_partial's layout so the continuous
-# decode/verify step can merge it with the chunk-buffer partial
-# (merge_attention_partials) — the buffer carries the in-segment causal
-# half of a multi-query speculative verify, the pool partial the shared
-# [0, cur) prefix every query row attends.
+# PagedAttention, Kwon et al. SOSP'23): no dense [B, max_seq] gather copy
+# ever materialises in HBM.  The pool tensors [n_blocks, block, kvh, hd]
+# stay in HBM (memory_space=pl.ANY); the per-slot block table and the
+# lengths are scalar-prefetch operands, and the kernel fetches what it
+# reads itself.
 #
-# Traffic discipline for blocks past a row's `cur` frontier: their index
-# map CLAMPS to the row's last valid block, so consecutive grid steps
-# present the SAME block index and the Pallas pipeline elides the re-DMA
-# (a revisited block is not refetched) — the idle tail of a short row
-# costs one extra block fetch, not (nb - valid) fetches.  Their compute
-# is skipped outright (pl.when), and the reserved block 0 (which idle
-# table entries point at) is therefore only ever read by fully-masked
-# grid steps whose contribution is exactly zero.
+# The work a call does follows the bytes it has to read.  The grid is one
+# step per batch row; inside it a loop bounded by lengths[b] walks the
+# row's COMPUTE BLOCKS of `pages` pool blocks each (about
+# PAGED_COMPUTE_TOKENS tokens).  For each compute block the kernel issues
+# one async copy per VALID pool block of K and of V — all kv heads of it,
+# one contiguous [block, kvh*hd] slab — into one half of a double-buffered
+# VMEM scratch, and the next compute block's copies (the next row's
+# first, at a row's end) are in flight while this one is computed.  A row
+# with lengths[b] == 0 fetches nothing and loops zero times; pool blocks
+# at or past a row's frontier are never read, wherever their table
+# entries point (the reserved block 0 included).  What they leave in the
+# scratch is stale, so every use of it is masked by a select, never by a
+# multiply.
+#
+# An int8 pool's scales [n_blocks, block, kvh] take another road: 3% of
+# the bytes, in a shape no copy can take whole (a [block, kvh] page is a
+# column per head where the [rows, tokens] scores want a lane row, and
+# Mosaic slices no operand whose minor dim is under 128 lanes; padding it
+# to 128 is a 17 MB write that XLA leaves inside the decode scan).  The
+# wrapper hands the kernel each row's scales through its block table as
+# per-head lane rows [kvh, n_cb, tokens] — a gather of 32 bytes a token
+# that XLA hoists out of the decode scan (the pool and the tables do not
+# change inside a chunk).
+#
+# Softmax is the online (m, l, acc) carry across the compute blocks,
+# exactly like _attn_kernel_stream; the result is returned as the
+# UNNORMALISED partial (acc, m, l) in dot_product_attention_partial's
+# layout so the continuous decode/verify step can merge it with the
+# chunk-buffer partial (merge_attention_partials) — the buffer carries the
+# in-segment causal half of a multi-query speculative verify, the pool
+# partial the shared [0, cur) prefix every query row attends.
+
+#: tokens of one compute block the paged kernel aims for: enough for the
+#: fixed cost of a loop trip (DMA issue and wait, the online-softmax
+#: update) to spread over 0.5 MB of int8 K/V at the 7B shape
+PAGED_COMPUTE_TOKENS = 512
+#: VMEM the double-buffered K/V scratch may take (1 MB at the int8 7B
+#: shape; a quarter of what a v5e kernel may use without asking for more)
+PAGED_VMEM_BUDGET = 4 * 1024 * 1024
 
 
 def _sublane_tile(dtype) -> int:
@@ -418,57 +437,121 @@ def _sublane_tile(dtype) -> int:
     return 8 * (4 // jnp.dtype(dtype).itemsize)
 
 
-def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                       acc_out, m_out, l_out, m_s, l_s, acc_s, *,
-                       scale: float, blk: int, n_b: int, hkv: int, d: int,
-                       quant: bool):
-    """One (batch, pool-block) grid step of in-place paged decode
-    attention, walking the kv heads inside the body.  ``q_ref`` holds this
-    batch row's query rows per kv head ``[Hkv, R, D]`` (R = S·group, the
-    multi-query verify rows x GQA group, padded to the q dtype's sublane
-    tile); ``k_ref``/``v_ref`` the table-mapped pool block with heads
-    folded into lanes ``[blk, Hkv·D]``.  Numerics mirror
-    ``dot_product_attention_partial`` per element: f32 logits, int8
-    dequant via cast-to-compute + per-vector scales OUTSIDE the
-    d-contraction (``k_scale`` on the scores, ``v_scale`` on the probs
+def paged_pages_per_step(blk: int, nb: int, hkv: int, d: int,
+                         pool_dtype) -> int:
+    """Pool blocks of one compute block, from what the call can see: as
+    many as reach ``PAGED_COMPUTE_TOKENS``, at most the table's ``nb``,
+    halved until both halves of the K/V scratch fit ``PAGED_VMEM_BUDGET``.
+    A block shorter than the pool dtype's sublane tile cannot be stacked
+    into one ``[tokens, hkv*d]`` operand without a relayout, so it goes
+    alone."""
+    if blk % _sublane_tile(pool_dtype):
+        return 1
+    lanes = -(-hkv * d // 128) * 128
+    page_bytes = 2 * blk * lanes * jnp.dtype(pool_dtype).itemsize
+    pages = max(1, min(nb, PAGED_COMPUTE_TOKENS // blk))
+    while pages > 1 and 2 * pages * page_bytes > PAGED_VMEM_BUDGET:
+        pages //= 2
+    return pages
+
+
+def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref,
+                       acc_out, m_out, l_out, k_buf, v_buf, sem, st_ref, *,
+                       scale: float, blk: int, pages: int, n_b: int,
+                       hkv: int, d: int, quant: bool):
+    """One batch row of in-place paged decode attention: a loop over the
+    row's compute blocks, walking the kv heads inside each.  ``q_ref``
+    holds this row's query rows per kv head ``[Hkv, R, D]`` (R = S·group,
+    the multi-query verify rows x GQA group, padded to the q dtype's
+    sublane tile); ``k_buf``/``v_buf`` ``[2, pages, blk, Hkv·D]`` the two
+    halves of the scratch the table-mapped pool blocks are copied into,
+    heads folded into lanes; ``ks_ref``/``vs_ref`` ``[Hkv, n_cb, tokens]``
+    this row's int8 scales, a lane row per head per compute block.
+    Numerics mirror ``dot_product_attention_partial`` per element: f32
+    logits, int8 dequant via cast-to-compute + per-vector scales OUTSIDE
+    the d-contraction (``k_scale`` on the scores, ``v_scale`` on the probs
     after the denominator), plain ``exp`` — only the summation ORDER
-    differs (per-block online carry vs one-pass), the same split the
-    chunk-boundary merge already makes."""
+    differs (per-compute-block online carry vs one-pass), the same split
+    the chunk-boundary merge already makes.
+
+    ``st_ref`` (SMEM, lives across the grid): [0] the scratch half the
+    row's first compute block is in, [1] whether the previous row already
+    started that block's copies."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    n_rows = pl.num_programs(0)
+    tokens = pages * blk
     kv_len = len_ref[b]
+    n_cb = (kv_len + tokens - 1) // tokens
+    nxt_row = jnp.minimum(b + 1, n_rows - 1)
+    nxt_live = (b + 1 < n_rows) & (len_ref[nxt_row] > 0)
 
-    @pl.when(j == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+    def copies(row, cb, slot, go):
+        """Start (``go`` a traced bool) or wait for (``go`` None) the
+        copies of compute block ``cb`` of ``row`` into half ``slot``: one
+        per pool block that holds a valid position, so a wait meets the
+        same set its start issued."""
+        for p in range(pages):                  # static
+            j = cb * pages + p
+            live = j * blk < len_ref[row]
 
-    col0 = j * blk
+            @pl.when(live if go is None else live & go)
+            def _():
+                page = bt_ref[row, jnp.minimum(j, n_b - 1)]
+                for src, dst in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                    cp = pltpu.make_async_copy(src.at[page], dst.at[slot, p],
+                                               sem.at[slot])
+                    if go is None:
+                        cp.wait()
+                    else:
+                        cp.start()
 
-    @pl.when(col0 < kv_len)
-    def _compute():
+    @pl.when(b == 0)
+    def _first_row():
+        st_ref[0] = 0
+        st_ref[1] = 0
+
+    # a row whose EVERY pool column is masked (cur == 0: fresh slot,
+    # parked slot) leaves this: m = NEG_INF, l = 0, acc = 0 —
+    # merge_attention_partials weights it out against the buffer partial,
+    # which always holds the freshly-written position
+    m_out[...] = jnp.full_like(m_out, NEG_INF)
+    l_out[...] = jnp.zeros_like(l_out)
+    acc_out[...] = jnp.zeros_like(acc_out)
+
+    slot0 = st_ref[0]
+    copies(b, 0, slot0, st_ref[1] == 0)
+
+    def compute_block(i, carry):
+        slot = (slot0 + i) % 2
+        more = i + 1 < n_cb
+        copies(jnp.where(more, b, nxt_row), jnp.where(more, i + 1, 0),
+               1 - slot, more | nxt_live)
+        copies(b, i, slot, None)
+
         r_pad = q_ref.shape[2]
-        col = col0 + jax.lax.broadcasted_iota(jnp.int32, (r_pad, blk), 1)
+        col0 = i * tokens
+        col = col0 + jax.lax.broadcasted_iota(jnp.int32, (r_pad, tokens), 1)
         valid = col < kv_len
         if quant:
-            # The pool stores scales [blk, Hkv]: a head's scales are a
-            # COLUMN (blk along sublanes) where the [R, blk] scores want
-            # them along lanes.  Mosaic has no general sublane→lane
-            # relayout, so spread the column on the diagonal of a
-            # [blk, blk] tile and reduce over sublanes — exact, each sum
-            # has one non-zero term.
-            diag = (jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 0)
-                    == jax.lax.broadcasted_iota(jnp.int32, (blk, blk), 1))
-
-            def scale_row(s_ref, h):
-                return jnp.sum(jnp.where(diag, s_ref[0, :, h:h + 1], 0.0),
-                               axis=0, keepdims=True)        # [1, blk]
+            # a stale block's scales are whatever its table entry points
+            # at: NaN·0 would reach acc, so select them away here; on the
+            # scores `valid` does it below
+            ks_row = lambda h: ks_ref[0, h, pl.ds(i, 1), :]  # [1, tokens]
+            vs_row = lambda h: jnp.where(
+                valid[:1], vs_ref[0, h, pl.ds(i, 1), :], 0.0)
+        else:
+            # int8 garbage is finite and meets p == 0; a float pool's
+            # stale V may hold NaN, and 0·NaN would reach acc: zero the
+            # pool blocks of this compute block that were not fetched
+            for p in range(pages):
+                @pl.when(col0 + p * blk >= kv_len)
+                def _():
+                    v_buf[slot, p] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
 
         for h in range(hkv):                    # static: Hkv is 1..8
             q = q_ref[0, h]                                 # [R, D]
-            k = k_ref[0, :, h * d:(h + 1) * d]              # [blk, D]
-            v = v_ref[0, :, h * d:(h + 1) * d]
+            k = k_buf[slot, :, :, h * d:(h + 1) * d].reshape(tokens, d)
+            v = v_buf[slot, :, :, h * d:(h + 1) * d].reshape(tokens, d)
             if quant:
                 # int8 pool blocks: HALF the bytes cross HBM; the cast to
                 # the compute dtype happens here in VMEM (int8 values are
@@ -477,37 +560,35 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                 v = v.astype(q.dtype)
             logits = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [R, blk]
+                preferred_element_type=jnp.float32)         # [R, tokens]
             if quant:
-                logits = logits * scale_row(ks_ref, h)
+                logits = logits * ks_row(h)
             logits = logits * scale
             logits = jnp.where(valid, logits, NEG_INF)
-            m_prev = m_s[h, :, :1]                          # [R, 1]
-            l_prev = l_s[h, :, :1]
+            m_prev = m_out[0, h, :, :1]                     # [R, 1]
+            l_prev = l_out[0, h, :, :1]
             m_cur = jnp.maximum(m_prev,
                                 jnp.max(logits, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_cur)
             p = jnp.exp(logits - m_cur)
             p = jnp.where(valid, p, 0.0)                    # masked: l += 0
-            l_s[h] = jnp.broadcast_to(
+            l_out[0, h] = jnp.broadcast_to(
                 l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True),
-                l_s.shape[1:])
+                l_out.shape[2:])
             if quant:
-                p = p * scale_row(vs_ref, h)
-            acc_s[h] = acc_s[h] * alpha + jax.lax.dot_general(
+                p = p * vs_row(h)
+            acc_out[0, h] = acc_out[0, h] * alpha + jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            m_s[h] = jnp.broadcast_to(m_cur, m_s.shape[1:])
+            m_out[0, h] = jnp.broadcast_to(m_cur, m_out.shape[2:])
+        return carry
 
-    @pl.when(j == n_b - 1)
-    def _finish():
-        # a row whose EVERY pool column is masked (cur == 0: fresh slot,
-        # parked slot) leaves the init carry: m = NEG_INF, l = 0, acc = 0
-        # — merge_attention_partials weights it out against the buffer
-        # partial, which always holds the freshly-written position
-        acc_out[0] = acc_s[...]
-        m_out[0] = m_s[...]
-        l_out[0] = l_s[...]
+    jax.lax.fori_loop(0, n_cb, compute_block, 0)
+
+    @pl.when(n_cb > 0)
+    def _hand_over():
+        st_ref[0] = (slot0 + n_cb) % 2
+        st_ref[1] = nxt_live.astype(jnp.int32)
 
 
 def paged_attention_partial(
@@ -536,16 +617,15 @@ def paged_attention_partial(
     dense per-row view.  ``lengths [B]``: each row's valid prefix (the
     slot's ``cur`` frontier); idle table entries may point anywhere
     (the reserved block 0 included) — blocks at or past ``lengths`` are
-    compute-skipped and their index map clamps to the last valid block
-    so the pipeline elides their DMA.  ``k_scale``/``v_scale``
+    neither fetched nor computed.  ``k_scale``/``v_scale``
     ``[N, block, Hkv]``: the int8 pool's per-vector dequant scales —
     dequant happens IN the kernel, so int8 halves the HBM bytes decode
     actually moves.  GQA (Hkv < H) walks kv heads inside the kernel body
     with the whole q group as rows of one matmul per head.
 
-    VMEM per grid step: 2 pool block slabs (block x Hkv·D) + the q rows +
-    the f32 (Hkv x R x D) carry — well under 2 MB at serving shapes;
-    sequence length is bounded by HBM only.
+    VMEM: two halves of ``paged_pages_per_step`` pool blocks of K and V
+    (1 MB at the int8 7B shape) + the q rows, a row's scale rows and the
+    f32 (Hkv x R x D) carry; sequence length is bounded by HBM only.
     """
     b, s, h, d = q.shape
     n_blocks, blk, hkv, dk = pool_k.shape
@@ -553,15 +633,14 @@ def paged_attention_partial(
         raise ValueError(f"q head_dim {d} != pool head_dim {dk}")
     if h % hkv:
         raise ValueError(f"q heads {h} not a multiple of kv heads {hkv}")
+    if (k_scale is None) != (v_scale is None):
+        raise ValueError("k_scale and v_scale must be passed together")
     nb = block_tables.shape[1]
     g = h // hkv
     if scale is None:
         scale = d ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    quant = k_scale is not None
-    if quant != (v_scale is not None):
-        raise ValueError("k_scale and v_scale must be passed together")
 
     # rows of the per-kv-head matmul: the S query positions x the GQA
     # group, padded to the q dtype's sublane tile so the MXU operand is
@@ -574,59 +653,28 @@ def paged_attention_partial(
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, r_pad - rows), (0, 0)))
 
     bt = block_tables.astype(jnp.int32)
-    lens = lengths.astype(jnp.int32)
+    lens = jnp.minimum(lengths.astype(jnp.int32), nb * blk)
+    pages = paged_pages_per_step(blk, nb, hkv, d, pool_k.dtype)
+    n_cb = -(-nb // pages)
 
-    def block_map(bi, j, bt_ref, len_ref):
-        # clamp past-the-frontier grid steps to the row's LAST valid block:
-        # consecutive identical indices → the pipeline skips the re-DMA
-        last = jnp.maximum((len_ref[bi] + blk - 1) // blk - 1, 0)
-        return (bt_ref[bi, jnp.minimum(j, last)], 0, 0)
+    def slabs(x):
+        # one pool block = one contiguous [block, lanes] slab the kernel
+        # copies whole: heads folded into lanes (a free reshape of the
+        # contiguous pool), lanes padded to whole 128-lane tiles (a no-op
+        # at served widths)
+        x = x.reshape(n_blocks, blk, hkv * d)
+        return jnp.pad(x, ((0, 0), (0, 0), (0, -(hkv * d) % 128)))
 
-    row_map = lambda bi, j, bt_ref, len_ref: (bi, 0, 0, 0)
-    # heads folded into lanes: a free reshape of the contiguous pool
-    kv_spec = pl.BlockSpec((1, blk, hkv * d), block_map)
-    in_specs = [pl.BlockSpec((1, hkv, r_pad, d), row_map), kv_spec, kv_spec]
-    operands = [qr, pool_k.reshape(n_blocks, blk, hkv * d),
-                pool_v.reshape(n_blocks, blk, hkv * d)]
-    if quant:
-        ks_spec = pl.BlockSpec((1, blk, hkv), block_map)
-        in_specs += [ks_spec, ks_spec]
-        operands += [k_scale, v_scale]
-    else:
-        # dummy scalar operands keep ONE kernel arity (the kernel ignores
-        # them when quant=False; SMEM spec so no tile constraints apply)
-        in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
-        zero = jnp.zeros((1,), jnp.float32)
-        operands += [zero, zero]
+    def scale_rows(x):
+        # [N, blk, Hkv] → [B, Hkv, n_cb, tokens] through the tables
+        x = jnp.take(x, jnp.pad(bt, ((0, 0), (0, n_cb * pages - nb))), axis=0)
+        return x.reshape(b, n_cb, pages * blk, hkv).transpose(0, 3, 1, 2)
 
-    # m/l leave lane-broadcast ([R, 128], like the scratch carry): a
-    # [R]-vector output block would need a sublane→lane relayout
-    out_specs = [pl.BlockSpec((1, hkv, r_pad, d), row_map),
-                 pl.BlockSpec((1, hkv, r_pad, 128), row_map),
-                 pl.BlockSpec((1, hkv, r_pad, 128), row_map)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, nb),               # block dim innermost: carry per batch row
-        in_specs=in_specs,
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((hkv, r_pad, 128), jnp.float32),   # running max m
-            pltpu.VMEM((hkv, r_pad, 128), jnp.float32),   # running denom l
-            pltpu.VMEM((hkv, r_pad, d), jnp.float32),     # unnormalised acc
-        ],
-    )
-    acc, m, l = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, scale=scale, blk=blk, n_b=nb,
-                          hkv=hkv, d=d, quant=quant),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, r_pad, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, r_pad, 128), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, r_pad, 128), jnp.float32),
-        ],
-        name="paged_attention",
-        interpret=interpret,
-    )(bt, lens, *operands)
+    scales = (() if k_scale is None
+              else (scale_rows(k_scale), scale_rows(v_scale)))
+    acc, m, l = _paged_call(bt, lens, qr, slabs(pool_k), slabs(pool_v),
+                            *scales, scale=scale, d=d, pages=pages,
+                            interpret=interpret)
     m, l = m[..., 0], l[..., 0]
 
     # [B, Hkv, R(, D)] → [B, S, H(, D)] (drop row padding first)
@@ -635,6 +683,61 @@ def paged_attention_partial(
     to_bsh = lambda x: (x[:, :, :rows].reshape(b, hkv, s, g)
                         .transpose(0, 2, 1, 3).reshape(b, s, h))
     return acc, to_bsh(m), to_bsh(l)
+
+
+# The kernel call alone is jitted: a program's 28 layers then trace and
+# lower the kernel body once (traced per layer it cost the decode program
+# 40 s of set-up on the chip's host), and what surrounds the call still
+# fuses with each layer's own operations.
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "d", "pages", "interpret"))
+def _paged_call(bt, lens, qr, k_slabs, v_slabs, *scales, scale, d, pages,
+                interpret):
+    b, hkv, r_pad, _ = qr.shape
+    blk = k_slabs.shape[1]
+    nb = bt.shape[1]
+    quant = bool(scales)
+    row_map = lambda bi, bt_ref, len_ref: (bi, 0, 0, 0)
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, hkv, r_pad, d), row_map), in_hbm, in_hbm]
+    if quant:
+        in_specs += [pl.BlockSpec((1,) + scales[0].shape[1:], row_map)] * 2
+    else:
+        # dummy scalar operands keep ONE kernel arity (the kernel ignores
+        # them when quant=False; SMEM spec so no tile constraints apply)
+        in_specs += [pl.BlockSpec(memory_space=pltpu.SMEM)] * 2
+        scales = (jnp.zeros((1,), jnp.float32),) * 2
+    kv_buf = pltpu.VMEM((2, pages) + k_slabs.shape[1:], k_slabs.dtype)
+
+    # m/l leave lane-broadcast ([R, 128]): a [R]-vector output block
+    # would need a sublane→lane relayout
+    out_specs = [pl.BlockSpec((1, hkv, r_pad, d), row_map),
+                 pl.BlockSpec((1, hkv, r_pad, 128), row_map),
+                 pl.BlockSpec((1, hkv, r_pad, 128), row_map)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=[kv_buf, kv_buf, pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((2,), jnp.int32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_paged_attn_kernel, scale=scale, blk=blk,
+                          pages=pages, n_b=nb, hkv=hkv, d=d, quant=quant),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hkv, r_pad, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, r_pad, 128), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, r_pad, 128), jnp.float32),
+        ],
+        # rows in order on one core: a row's last compute block starts
+        # the next row's first copies, and the scratch half carries over
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="paged_attention",
+        interpret=interpret,
+    )(bt, lens, qr, k_slabs, v_slabs, *scales)
 
 
 def paged_flash_attention(
@@ -670,17 +773,15 @@ def paged_bytes_accounting(*, n_valid_blocks: int, blocks_per_seq: int,
     Gather (the ``_pool_gather_body`` path) pays, per chunk of
     ``n_steps``: read EVERY table-mapped block + write the dense
     ``[max_seq]`` copy once, then read the full dense copy per step.
-    In place pays: read the valid blocks per step, plus ONE clamped
-    re-fetch block for the idle tail (the pipeline elides the rest —
-    consecutive identical block indices are not re-DMA'd).  Bytes are
-    K + V per position (``esize`` each) plus the int8 layout's per-vector
-    scales (``scale_bytes``: 2 x 4 f32, or 0)."""
+    In place pays: read the valid blocks per step and nothing else — the
+    kernel copies only the pool blocks a row has.  Bytes are K + V per
+    position (``esize`` each) plus the int8 layout's per-vector scales
+    (``scale_bytes``: 2 x 4 f32, or 0)."""
     pos_bytes = kvh * (2 * hd * esize + scale_bytes)
     full = blocks_per_seq * block * pos_bytes          # whole table span
     valid = n_valid_blocks * block * pos_bytes
-    tail = (block * pos_bytes) if n_valid_blocks < blocks_per_seq else 0
     gather_chunk = 2 * full + n_steps * full           # copy (r+w) + reads
-    inplace_chunk = n_steps * (valid + tail)
+    inplace_chunk = n_steps * valid
     return {
         "gather_step_bytes": gather_chunk / max(1, n_steps),
         "paged_flash_step_bytes": inplace_chunk / max(1, n_steps),
