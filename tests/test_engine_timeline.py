@@ -408,3 +408,114 @@ def test_a_capture_holds_the_engine_phases_on_a_host_line(gen, tmp_path):
     assert names.get("engine/fetch_wait", 0) >= 3, names
     assert names.get("engine/admit", 0) >= 1, names
     assert {n.split("/", 1)[1] for n in names} <= PHASES
+
+
+# ------------------------------- (d) layer kinds: routed experts and windows
+MOE_SCOPES = ("moe_router", "moe_experts", "moe_shared", "moe_combine")
+MOE_FIELDS = ("moe_layer_calls", "moe_pairs", "moe_experts_touched",
+              "moe_max_expert_tokens")
+
+
+@pytest.fixture(scope="module")
+def moe_gen():
+    from tpustack.models.llama import LlamaConfig
+
+    return Generator(LlamaConfig.tiny_moe(max_seq=64), dtype=jnp.float32,
+                     seed=5)
+
+
+@pytest.mark.parametrize("engine", ["plain_paged", "spec_paged"])
+def test_engine_serves_layer_kinds_with_rows_joining_and_leaving(
+        moe_gen, engine):
+    """Five requests of different lengths through two slots: rows join and
+    leave a running batch of a model with window and full attention and
+    routed experts; in-place pool reads and the gather say the same tokens,
+    and every record carries the routed-expert counters (``wave``/
+    ``verify`` also the window-cut context) within what they can be."""
+    prompts = [REPETITIVE, REPETITIVE[:7], [5, 6, 7], [9] * 12, [3, 4]]
+    outs = {}
+    for flash in (True, False):
+        res = {}
+        rec = FlightRecorder("eng", capacity=1024)
+        eng = ContinuousEngine(moe_gen, slots=2, chunk=4, flight=rec,
+                               paged_flash=flash,
+                               **ENGINES[engine](moe_gen))
+        q = [SlotRequest(ids=list(p), max_new=6 + 3 * i, sample=GREEDY,
+                         on_done=lambda t, s, i=i: res.__setitem__(i, t))
+             for i, p in enumerate(prompts)]
+        eng.run(lambda: q.pop(0) if q else None)
+        outs[flash] = res
+        recs = rec.recent()
+    assert outs[True] == outs[False]
+    assert [len(outs[True][i]) for i in range(5)] == [6, 9, 12, 15, 18]
+    sparse, held, window = 3, 4, 8
+    for r in recs:
+        if r["kind"] not in ("wave", "verify", "prefill"):
+            continue
+        assert all(isinstance(r.get(f), int) for f in MOE_FIELDS), r
+        passes = {"wave": 4, "verify": 1, "prefill": 1}[r["kind"]]
+        calls = r["moe_layer_calls"]
+        assert calls == sparse * passes
+        assert 0 < r["moe_experts_touched"] <= held * calls
+        assert r["moe_experts_touched"] <= r["moe_pairs"]
+        # the fullest expert of a call holds at least the call's mean
+        assert (r["moe_pairs"] / held <= r["moe_max_expert_tokens"]
+                <= r["moe_pairs"])
+        if r["kind"] != "prefill":
+            assert 0 < r["ctx_tokens_window"] <= min(
+                r["ctx_tokens"], 2 * window)
+    kinds = {r["kind"] for r in recs}
+    assert {"wave", "prefill"} <= kinds
+
+
+def test_a_dense_model_records_no_layer_kind_fields(gen):
+    recs, _ = _run(gen, [[5, 6, 7]], max_new=6, paged=_paged(gen))
+    for r in recs:
+        assert not any(k.startswith("moe_") for k in r), r
+        assert "ctx_tokens_window" not in r
+
+
+@pytest.fixture(scope="module")
+def moe_programs(moe_gen):
+    """``paged_programs`` for the model with every layer kind."""
+    from tpustack.models.llama import init_kv_pool
+
+    cfg = dataclasses.replace(moe_gen.cfg, kv_quant="int8")
+    g = Generator(cfg, dtype=jnp.float32, seed=3)
+    sds = jax.ShapeDtypeStruct
+    B, blk, n_blocks, n, bucket = 2, 8, 17, 2, 16
+    nb = cfg.max_seq // blk
+    pool = jax.eval_shape(
+        lambda: init_kv_pool(cfg, n_blocks, blk, dtype=jnp.float32))
+    i32 = lambda *s: sds(s, jnp.int32)
+    f32 = lambda *s: sds(s, jnp.float32)
+    keys = sds((B, 2), jnp.uint32)
+    flags = sds((B,), jnp.bool_)
+    slot_state = (i32(B), i32(B), i32(B, 1), f32(B), i32(B), flags, keys)
+    row = (f32(n), i32(n), sds((n,), jnp.bool_))
+    traced = {
+        "_decode_scan_paged": Generator._decode_scan_paged.trace(
+            g, g.params, i32(B, 1), i32(B), i32(B), pool, i32(B, nb), keys,
+            f32(B), i32(B), flags, 4, flash=True),
+        "_admit_fused_paged": Generator._admit_fused_paged.trace(
+            g, g.params, i32(n, bucket), pool, i32(n, nb), i32(n), i32(n),
+            i32(n), sds((n,), jnp.uint32), *slot_state, *row),
+    }
+    return {name: _lowered_text(t) for name, t in traced.items()}
+
+
+@pytest.mark.parametrize("program", ["_decode_scan_paged",
+                                     "_admit_fused_paged"])
+@pytest.mark.parametrize("scope", MOE_SCOPES + ("mlp", "attn_core"))
+def test_layer_kind_programs_name_the_expert_scopes(moe_programs, program,
+                                                    scope):
+    assert _has_scope(moe_programs[program], scope)
+
+
+@pytest.mark.parametrize("program,kernels", [
+    ("_decode_scan_paged", ("moe_gmm", "paged_attention")),
+    ("_admit_fused_paged", ("moe_gmm",))])
+def test_layer_kind_programs_name_their_kernels(moe_programs, program,
+                                                kernels):
+    for kernel in kernels:
+        assert kernel in moe_programs[program]

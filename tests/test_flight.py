@@ -745,3 +745,40 @@ def test_xprof_summary_missing_package_is_one_line(tmp_path, monkeypatch,
     rc = _xprof_main([str(tmp_path / "fake.xplane.pb"), "--json"])
     assert rc == 3
     assert "error" in json.loads(capsys.readouterr().out)
+
+
+def test_wave_arith_counts_by_layer_kind():
+    """A model with routed-expert and window layers (ISSUE 28): of an
+    expert stack the arithmetic charges the share a token's choices make
+    of it and the share a step's rows are expected to touch — not a dense
+    stream — and of a window layer's cache line its window."""
+    import jax
+    import jax.numpy as jnp
+
+    from tpustack.models.llama import LlamaConfig
+    from tpustack.models.llm_generate import Generator
+
+    cfg = LlamaConfig.tiny_moe(max_seq=64)
+    g = Generator(cfg, dtype=jnp.float32, seed=0)
+    moe = cfg.moe
+    flat = jax.tree_util.tree_leaves_with_path(g.params)
+    name = lambda p: [str(getattr(k, "key", k)) for k in p]
+    stack = lambda p, x: x.ndim == 3 and name(p)[-1] == "kernel"
+    stacks = sum(x.size for p, x in flat if stack(p, x))
+    others = sum(x.size for p, x in flat
+                 if name(p)[-1] == "kernel" and not stack(p, x))
+    assert stacks > 0
+    for rows in (1, 2, 16):
+        arith = obs_flight.llm_wave_arith(cfg, g.params, g.cache_dtype,
+                                          rows=rows)
+        chosen = moe.top_k / moe.n_experts
+        assert arith["flops_per_token"] == pytest.approx(
+            2 * (others + stacks * chosen))
+        touched = 1 - (1 - chosen) ** rows
+        dense_bytes = sum(x.nbytes for p, x in flat
+                          if not stack(p, x) and "embed_tokens" not in name(p))
+        assert arith["weight_stream_bytes"] == pytest.approx(
+            dense_bytes + 4 * stacks * touched)
+    # 3 window layers of 8 positions, one full layer of max_seq
+    per_pos = 2 * cfg.n_kv_heads * cfg.head_dim * 4
+    assert arith["kv_step_bytes_per_slot"] == (3 * 8 + 64) * per_pos

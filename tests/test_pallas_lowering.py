@@ -19,6 +19,7 @@ import pytest
 
 from tpustack.ops.pallas.flash_attention import (flash_attention,
                                                  paged_attention_partial)
+from tpustack.ops.pallas.moe_gmm import moe_gmm
 
 
 def _lower_for_tpu(fn, *avals):
@@ -34,27 +35,31 @@ def _sds(shape, dtype):
 # pool blocks) and the tiny preset the CPU servers boot
 # ... and one head shard of the 7B pool under LLM_TP=4
 # (``llama._per_head_shard``): a pool block is a [64, 1·128] slab
+# ... and K-EXAONE's share (16 slots, 64 q / 8 kv heads), whose window
+# layers run the same kernel with a first position a row
 PAGED_MODELS = {
+    "k_exaone_ep8": (16, 64, 8, 128, 64, 64, 1025, jnp.bfloat16),
     "qwen25_7b": (8, 28, 4, 128, 64, 64, 513, jnp.bfloat16),
     "qwen25_7b_tp4_shard": (8, 7, 1, 128, 64, 64, 513, jnp.bfloat16),
     "tiny": (2, 4, 2, 16, 8, 16, 33, jnp.float32),
 }
 
 
-def _paged_call(model, int8_pool, s, sharding=None):
-    """``(fn, avals)`` of one paged kernel call at a served shape."""
+def _paged_call(model, int8_pool, s, sharding=None, window=None):
+    """``(fn, avals)`` of one paged kernel call at a served shape; with
+    ``window`` a window layer's call (the last operand: its positions)."""
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
     b, h, hkv, d, blk, nb, n_pool, qdt = PAGED_MODELS[model]
     pool = sds((n_pool, blk, hkv, d), jnp.int8 if int8_pool else qdt)
+    scales = sds((n_pool, blk, hkv), jnp.float32) if int8_pool else None
     args = [sds((b, s, h, d), qdt), pool, pool,
-            sds((b, nb), jnp.int32), sds((b,), jnp.int32)]
-    if int8_pool:
-        scales = sds((n_pool, blk, hkv), jnp.float32)
-        args += [scales, scales]
+            sds((b, nb), jnp.int32), sds((b,), jnp.int32), scales, scales,
+            sds((b,), jnp.int32) if window else None]
 
-    def fn(q, pk, pv, bt, lens, ks=None, vs=None):
+    def fn(q, pk, pv, bt, lens, ks, vs, q_pos):
         return paged_attention_partial(q, pk, pv, bt, lens, k_scale=ks,
-                                       v_scale=vs, interpret=False)
+                                       v_scale=vs, interpret=False,
+                                       window=window, q_pos=q_pos)
 
     return fn, args
 
@@ -98,15 +103,64 @@ def test_paged_attention_compiles_for_v5e(one_v5e_chip, model, int8_pool, s):
     from jax.experimental.compilation_cache import compilation_cache
 
     fn, args = _paged_call(model, int8_pool, s, sharding=one_v5e_chip)
+    _compile_for_described_chip(fn, args)
+
+
+def _compile_for_described_chip(fn, args):
+    from jax.experimental.compilation_cache import compilation_cache
+
     # an entry written for a described chip cannot be read back without one
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        jax.jit(fn).lower(*args).compile()
+        return jax.jit(fn).lower(*args).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify_k4"])
+def test_paged_window_layer_compiles_for_v5e(one_v5e_chip, s):
+    """A window layer's call at K-EXAONE's share: a third scalar-prefetch
+    operand (each row's first position), a walk that starts mid-table."""
+    fn, args = _paged_call("k_exaone_ep8", True, s, sharding=one_v5e_chip,
+                           window=128)
+    _compile_for_described_chip(fn, args)
+
+
+# moe_gmm at K-EXAONE's share (16 held experts of [6144, 2048]): (tokens a
+# call routes, K, N) — a decode step's 16 rows, admissions of 1 and 16 rows
+# of the 512 bucket; gate/up and down
+GMM_SHAPES = {
+    "decode_gate_up": (16, 6144, 2048), "decode_down": (16, 2048, 6144),
+    "admit1_gate_up": (512, 6144, 2048), "admit16_gate_up": (8192, 6144, 2048),
+    "admit16_down": (8192, 2048, 6144),
+}
+
+
+def _gmm_call(shape, sharding=None):
+    from tpustack.models.moe import row_tile
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
+    tokens, k, n = GMM_SHAPES[shape]
+    held, top_k, n_experts = 16, 8, 128
+    tm = row_tile(tokens, top_k, n_experts)
+    m = -(-(tokens * min(top_k, held) + held * (tm - 1)) // tm) * tm
+    args = [sds((m, k), jnp.bfloat16), sds((held, k, n), jnp.int8),
+            sds((held, n), jnp.float32), sds((m // tm,), jnp.int32),
+            sds((), jnp.int32)]
+    return (lambda x, w, s, te, na: moe_gmm(x, w, s, te, na, tm=tm,
+                                            interpret=False)), args
+
+
+@pytest.mark.parametrize("shape", sorted(GMM_SHAPES))
+def test_moe_gmm_compiles_for_v5e(one_v5e_chip, shape):
+    """The grouped product, Mosaic included, at the served shapes: the
+    panel of an expert, the row tile and the f32 sum have to fit the VMEM
+    the call states."""
+    fn, args = _gmm_call(shape, sharding=one_v5e_chip)
+    _compile_for_described_chip(fn, args)
 
 
 # panel kernel: (q tokens, k tokens, heads, head_dim)
@@ -158,11 +212,28 @@ def _kernel_programs():
                                                     interpret=False),
                             (_sds((2, 1, 4, 128), jnp.bfloat16), pool, pool,
                              _sds((2, 16), jnp.int32), _sds((2,), jnp.int32))),
+        "moe_gmm": _gmm_call("decode_gate_up"),
     }
 
 
+@pytest.mark.parametrize("window", [None, 128], ids=["causal", "window"])
+@pytest.mark.parametrize("s", [512, 4096])
+def test_prefill_kernels_lower_for_tpu_at_k_exaone_heads(s, window):
+    """64 q / 8 kv heads x 128: a bucket's panel call and, from a traced
+    offset, the streaming one; with the window the panel kernel slices its
+    K/V panel at a 128-row boundary it computes from the grid index."""
+    q = _sds((1, s, 64, 128), jnp.bfloat16)
+    kv = _sds((1, s, 8, 128), jnp.bfloat16)
+    scalar = _sds((), jnp.int32)
+    _lower_for_tpu(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window, interpret=False), q, kv, kv)
+    _lower_for_tpu(lambda q, k, v, off, n: flash_attention(
+        q, k, v, causal=True, window=window, q_offset=off, kv_len=n,
+        interpret=False), q, kv, kv, scalar, scalar)
+
+
 @pytest.mark.parametrize("kernel", ["flash_panel", "flash_kstream",
-                                    "paged_attention"])
+                                    "paged_attention", "moe_gmm"])
 def test_kernel_names_are_pinned(kernel):
     """A kernel's name is what a device trace shows and what the benchmark's
     rooflines sum by (``flash_prefill_roofline``, ``paged_attention_

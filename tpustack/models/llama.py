@@ -36,6 +36,39 @@ from tpustack.ops.attention import dot_product_attention
 
 
 @dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One decoder layer's kind.  ``window``: None attends the whole causal
+    prefix, ``w`` only keys ``j`` with ``0 <= i - j < w``.  ``rope``: whether
+    q/k carry the rotary position (some families leave their full layers
+    without one).  ``ffn``: ``"dense"`` (SwiGLU of ``ffn_dim``) or
+    ``"experts"`` (the routed-expert layer of ``LlamaConfig.moe``)."""
+    window: Optional[int] = None
+    rope: bool = True
+    ffn: str = "dense"
+
+
+@dataclasses.dataclass(frozen=True)
+class MoESpec:
+    """The routed-expert layer (``tpustack.models.moe``): a router over all
+    ``n_experts``, ``top_k`` a token, SwiGLU experts of ``expert_dim``, a
+    shared expert of ``shared_dim`` every token passes.  ``held`` is
+    ``(first, count)``: the experts this chip holds under expert
+    parallelism — the layer routes over all of them and computes its own
+    experts' part of the result."""
+    n_experts: int
+    top_k: int
+    expert_dim: int
+    shared_dim: int
+    routed_scale: float = 1.0
+    held: Tuple[int, int] = (0, 0)   # (0, 0): all of them
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        first, count = self.held
+        return (first, count) if count else (0, self.n_experts)
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
     dim: int = 4096
@@ -52,10 +85,33 @@ class LlamaConfig:
     kv_quant: Optional[str] = None  # None (bf16 cache) | "int8": per-vector-
     # scaled int8 KV cache — halves decode KV traffic and cache HBM (the
     # dominant bytes term at long context: 1.9 GB/step at 32k on Qwen-7B)
+    head_size: Optional[int] = None  # None: dim // n_heads
+    # the model's spec beyond its widths: each layer's kind (None: n_layers
+    # x full attention with rope, dense SwiGLU), where the norms sit
+    # ("pre": x + f(norm(x)); "post": x + norm(f(x))), and whether q and k
+    # are RMS-normed per head before the rotary embedding
+    layers: Optional[Tuple[LayerSpec, ...]] = None
+    norm_placement: str = "pre"
+    qk_norm: bool = False
+    moe: Optional[MoESpec] = None
+
+    def __post_init__(self):
+        if self.layers is not None and len(self.layers) != self.n_layers:
+            raise ValueError(f"{len(self.layers)} layer specs for "
+                             f"n_layers={self.n_layers}")
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(f"norm_placement {self.norm_placement!r}")
+        if self.moe is None and any(
+                sp.ffn == "experts" for sp in self.layers or ()):
+            raise ValueError("an 'experts' layer needs LlamaConfig.moe")
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        return self.head_size or self.dim // self.n_heads
+
+    @property
+    def layer_specs(self) -> Tuple[LayerSpec, ...]:
+        return self.layers or (LayerSpec(),) * self.n_layers
 
     @classmethod
     def llama2_7b(cls) -> "LlamaConfig":
@@ -75,6 +131,42 @@ class LlamaConfig:
         (HBM math rehearsed in tests/test_llm_tp.py)."""
         return cls(dim=8192, n_layers=80, n_heads=64, n_kv_heads=8,
                    ffn_dim=28672)
+
+    @classmethod
+    def k_exaone_236b_ep8(cls, share: int = 0) -> "LlamaConfig":
+        """K-EXAONE-236B-A23B (LGAI-EXAONE, ``exaone_moe``), one chip's
+        share of an 8-chip deployment, layers 0-7 (two whole LLLG periods):
+        routed experts 16 of 128 a chip (``share`` picks which), an eighth
+        of the 153,600-row vocabulary, attention and the shared expert
+        whole.  Window layers carry rope, full layers none; q/k RMS-normed
+        per head; norms after each sublayer (the EXAONE 4.0 conventions).
+        The multi-token-prediction layer is left out."""
+        kinds = [LayerSpec(window=128), LayerSpec(window=128),
+                 LayerSpec(window=128), LayerSpec(rope=False)] * 2
+        layers = tuple(dataclasses.replace(k, ffn="experts" if i else "dense")
+                       for i, k in enumerate(kinds))
+        return cls(vocab_size=19200, dim=6144, n_layers=8, n_heads=64,
+                   n_kv_heads=8, head_size=128, ffn_dim=18432,
+                   rope_theta=1_000_000.0, rms_eps=1e-5, layers=layers,
+                   norm_placement="post", qk_norm=True,
+                   moe=MoESpec(n_experts=128, top_k=8, expert_dim=2048,
+                               shared_dim=2048, routed_scale=2.5,
+                               held=(16 * share, 16)))
+
+    @classmethod
+    def tiny_moe(cls, max_seq: int = 128, share: int = 0) -> "LlamaConfig":
+        """Every layer kind at test size: window and full attention, rope
+        and none, one dense then routed-expert layers (8 experts, 4 held),
+        q/k norm, norms after the sublayers, head_dim != dim / n_heads."""
+        w = LayerSpec(window=8, ffn="experts")
+        layers = (LayerSpec(window=8), w, w,
+                  LayerSpec(rope=False, ffn="experts"))
+        return cls(vocab_size=512, dim=64, n_layers=4, n_heads=4,
+                   n_kv_heads=2, head_size=32, ffn_dim=128, max_seq=max_seq,
+                   layers=layers, norm_placement="post", qk_norm=True,
+                   moe=MoESpec(n_experts=8, top_k=2, expert_dim=32,
+                               shared_dim=32, routed_scale=2.5,
+                               held=(4 * share, 4)))
 
     @classmethod
     def tiny(cls, max_seq: int = 128) -> "LlamaConfig":
@@ -140,6 +232,7 @@ class LlamaAttention(nn.Module):
     dtype: Any = jnp.bfloat16
     ring_mesh: Any = None  # Mesh → train-path attention rings K/V over "sp"
     tp_mesh: Any = None    # Mesh → serving kernels run per tp head shard
+    spec: LayerSpec = LayerSpec()  # this layer's kind: window, rope
 
     def _ring_shapes_ok(self, b: int, s: int) -> bool:
         """Ring shard_map needs batch/seq/heads divisible by their mesh axes
@@ -159,6 +252,10 @@ class LlamaAttention(nn.Module):
 
         c = self.cfg
         hd = c.head_dim
+        # window layers see keys j with 0 <= i - j < window (i the query's
+        # position); every branch below masks the same band, and the
+        # kernels skip the blocks that lie wholly behind it
+        window = self.spec.window
         dense = lambda feats, name, bias: make_dense(
             c.quant, feats, use_bias=bias, dtype=self.dtype, name=name)
         b, s, _ = x.shape
@@ -173,8 +270,12 @@ class LlamaAttention(nn.Module):
                 b, s, c.n_kv_heads, hd)
             v = dense(c.n_kv_heads * hd, "v_proj", c.qkv_bias)(x).reshape(
                 b, s, c.n_kv_heads, hd)
-            q = rope(q, positions, c.rope_theta)
-            k = rope(k, positions, c.rope_theta)
+            if c.qk_norm:
+                q = RMSNorm(c.rms_eps, self.dtype, name="q_norm")(q)
+                k = RMSNorm(c.rms_eps, self.dtype, name="k_norm")(k)
+            if self.spec.rope:
+                q = rope(q, positions, c.rope_theta)
+                k = rope(k, positions, c.rope_theta)
 
         if kv_cache is not None and "ck" in kv_cache:
             # CONTINUOUS-slot decode chunk (s == 1): every slot sits at its
@@ -247,6 +348,11 @@ class LlamaAttention(nn.Module):
                         jnp.arange(cbuf_len)[None, None, :]
                         <= (t + jnp.arange(s))[None, :, None],
                         (b, s, cbuf_len))
+                if window is not None:
+                    # buffer index u holds position cur0 + u
+                    buf_mask = buf_mask & (
+                        (cur0[:, None] + jnp.arange(cbuf_len)[None, :])
+                        [:, None, :] > positions[:, :, None] - window)
                 if paged_flash:
                     # read the KV pool blocks IN PLACE through the slot block
                     # tables (scalar-prefetch Pallas kernel, per-row `cur0`
@@ -261,11 +367,17 @@ class LlamaAttention(nn.Module):
                     part_main = paged_attention_partial(
                         q, kv_cache["pk"], kv_cache["pv"], kv_cache["bt"],
                         cur0, k_scale=kv_cache.get("pk_scale"),
-                        v_scale=kv_cache.get("pv_scale"))
+                        v_scale=kv_cache.get("pv_scale"),
+                        **({} if window is None else {
+                            "window": window, "q_pos": positions[:, 0]}))
                 else:
-                    main_mask = (jnp.arange(kv_cache["k"].shape[1])
-                                 [None, None, :]
+                    main_pos = jnp.arange(kv_cache["k"].shape[1])
+                    main_mask = (main_pos[None, None, :]
                                  < cur0[:, None, None])      # [B, 1, S]
+                    if window is not None:
+                        main_mask = main_mask & (
+                            main_pos[None, None, :]
+                            > positions[:, :, None] - window)
                     part_main = dot_product_attention_partial(
                         q, kv_cache["k"], kv_cache["v"], mask=main_mask,
                         k_scale=kv_cache.get("k_scale"),
@@ -322,10 +434,12 @@ class LlamaAttention(nn.Module):
                 with jax.named_scope("attn_core"):
                     attend, sharded = _per_head_shard(
                         lambda q, k, v: dot_product_attention(
-                            q, k, v, causal=True, impl="auto"),
+                            q, k, v, causal=True, impl="auto",
+                            window=window),
                         self.tp_mesh, c)
                     out = (attend(q, k, v) if sharded else
-                           dot_product_attention(q, k, v, causal=True))
+                           dot_product_attention(q, k, v, causal=True,
+                                                 window=window))
             elif s > 1 and attn_mask is None:
                 # Chunked long-context prefill: this chunk's rows sit at
                 # global positions cache_index + i and attend the whole
@@ -352,11 +466,16 @@ class LlamaAttention(nn.Module):
                     attend, _ = _per_head_shard(
                         lambda q, k, v, off: flash_attention(
                             q, k, v, causal=True, q_offset=off,
-                            kv_len=off + s),
+                            kv_len=off + s, window=window),
                         self.tp_mesh, c, n_scalars=1)
                     out = attend(q, k_in, v_in,
                                  jnp.asarray(cache_index, jnp.int32))
             else:
+                if window is not None:
+                    band = (jnp.arange(k_all.shape[1])[None, None, None, :]
+                            > positions[:, None, :, None] - window)
+                    attn_mask = (band if attn_mask is None
+                                 else attn_mask & band)
                 with jax.named_scope("attn_core"):
                     out = dot_product_attention(
                         q, k_all, v_all, mask=attn_mask, k_scale=ks_all,
@@ -372,6 +491,10 @@ class LlamaAttention(nn.Module):
             # streaming-softmax merge (differentiable — lax.scan + ppermute)
             from tpustack.parallel.ring_attention import ring_attention
 
+            if window is not None:
+                raise NotImplementedError(
+                    "ring attention has no window: train window layers "
+                    "without an sp axis")
             new_cache = None
             with jax.named_scope("attn_core"):
                 if c.n_kv_heads != c.n_heads:  # ring expects matched heads
@@ -389,7 +512,7 @@ class LlamaAttention(nn.Module):
             # (kernel supports causal, not arbitrary masks).
             with jax.named_scope("attn_core"):
                 out = dot_product_attention(q, k, v, causal=True,
-                                            mask=attn_mask)
+                                            mask=attn_mask, window=window)
         out = out.reshape(b, s, c.n_heads * hd)
         with jax.named_scope("attn_out"):
             return dense(c.dim, "o_proj", False)(out), new_cache
@@ -398,17 +521,20 @@ class LlamaAttention(nn.Module):
 class LlamaMLP(nn.Module):
     cfg: LlamaConfig
     dtype: Any = jnp.bfloat16
+    width: Optional[int] = None  # None: cfg.ffn_dim
+    trace_name: str = "mlp"      # the jax.named_scope it runs under
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         from tpustack.ops.quant import make_dense
 
         c = self.cfg
+        width = self.width or c.ffn_dim
         dense = lambda feats, name: make_dense(
             c.quant, feats, use_bias=False, dtype=self.dtype, name=name)
-        with jax.named_scope("mlp"):
-            gate = dense(c.ffn_dim, "gate_proj")(x)
-            up = dense(c.ffn_dim, "up_proj")(x)
+        with jax.named_scope(self.trace_name):
+            gate = dense(width, "gate_proj")(x)
+            up = dense(width, "up_proj")(x)
             return dense(c.dim, "down_proj")(nn.silu(gate) * up)
 
 
@@ -417,17 +543,30 @@ class LlamaBlock(nn.Module):
     dtype: Any = jnp.bfloat16
     ring_mesh: Any = None
     tp_mesh: Any = None
+    spec: LayerSpec = LayerSpec()
 
     @nn.compact
     def __call__(self, x, positions, kv_cache, cache_index, attn_mask):
         c = self.cfg
-        h, new_cache = LlamaAttention(c, self.dtype, self.ring_mesh,
-                                      self.tp_mesh, name="self_attn")(
-            RMSNorm(c.rms_eps, self.dtype, name="input_layernorm")(x),
-            positions, kv_cache, cache_index, attn_mask)
-        x = x + h
-        x = x + LlamaMLP(c, self.dtype, name="mlp")(
-            RMSNorm(c.rms_eps, self.dtype, name="post_attention_layernorm")(x))
+        attn = LlamaAttention(c, self.dtype, self.ring_mesh, self.tp_mesh,
+                              self.spec, name="self_attn")
+        if self.spec.ffn == "experts":
+            from tpustack.models.moe import MoEFeedForward
+
+            ffn = MoEFeedForward(c, self.dtype, name="mlp")
+        else:
+            ffn = LlamaMLP(c, self.dtype, name="mlp")
+        norm = lambda name: RMSNorm(c.rms_eps, self.dtype, name=name)
+        if c.norm_placement == "pre":
+            h, new_cache = attn(norm("input_layernorm")(x), positions,
+                                kv_cache, cache_index, attn_mask)
+            x = x + h
+            x = x + ffn(norm("post_attention_layernorm")(x))
+        else:
+            h, new_cache = attn(x, positions, kv_cache, cache_index,
+                                attn_mask)
+            x = x + norm("post_attention_layernorm")(h)
+            x = x + norm("post_feedforward_layernorm")(ffn(x))
         return x, new_cache
 
 
@@ -471,10 +610,10 @@ class LlamaModel(nn.Module):
         with jax.named_scope("embed"):
             x = embed(tokens)
         new_caches = [] if kv_caches is not None else None
-        for i in range(c.n_layers):
+        for i, spec in enumerate(c.layer_specs):
             cache_i = kv_caches[i] if kv_caches is not None else None
             x, nc = LlamaBlock(c, self.dtype, self.ring_mesh, self.tp_mesh,
-                               name=f"layers_{i}")(
+                               spec, name=f"layers_{i}")(
                 x, positions, cache_i, cache_index, attn_mask)
             if new_caches is not None:
                 new_caches.append(nc)

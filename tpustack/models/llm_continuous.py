@@ -259,12 +259,15 @@ class _PendingWave:
     request's ``on_prefill_kv``."""
 
     __slots__ = ("rows", "firsts_dev", "t0", "extracts", "block_inserts",
-                 "bucket")
+                 "bucket", "moe_dev")
 
     def __init__(self, rows, firsts_dev, t0, extracts=(), block_inserts=(),
-                 bucket=None):
+                 bucket=None, moe_dev=None):
         self.rows = rows            # [(slot_idx, req, budget)]
         self.firsts_dev = firsts_dev
+        # the admission's routed-expert counters (Generator._apply_counted;
+        # None for a model without such a layer): fetched with the firsts
+        self.moe_dev = moe_dev
         self.t0 = t0
         self.bucket = bucket        # padded tokens a row (a hit: its suffix)
         self.extracts = list(extracts)  # [(req, device kv slices)]
@@ -297,6 +300,13 @@ class ContinuousEngine:
         self.chunk = chunk
         self.stop_tokens = stop_tokens
         self.depth = depth
+        # what the flight records say of the model's layer kinds: its
+        # attention window (the shortest, where layers differ) and how
+        # many routed-expert layers one pass runs
+        specs = gen.cfg.layer_specs
+        self._window = min((sp.window for sp in specs if sp.window),
+                           default=None)
+        self._sparse_layers = sum(sp.ffn == "experts" for sp in specs)
         # speculative decoding (tpustack.serving.speculative.SpecConfig):
         # when set, the wave loop turns variable-stride — each dispatch is
         # either a verify step (host-drafted tokens scored in ONE forward
@@ -867,10 +877,12 @@ class ContinuousEngine:
                 if req.host_restore:
                     self._dispatch_restore(state, req)
                 bt_rows, limits = paged_rowmeta(rows)
+                moe = None
                 if sbucket * c.max_seq <= g.MASKED_PREFILL_MAX:
                     (state["pool"], firsts, state["cur"], state["active"],
                      state["first"], state["temp"], state["topk"],
-                     state["greedy"], state["keys"]) = g._admit_prefix_paged(
+                     state["greedy"], state["keys"],
+                     moe) = g._admit_prefix_paged(
                         g.params, jnp.asarray(tokens), state["pool"],
                         bt_rows, jnp.asarray(plen, jnp.int32), lengths,
                         limits, slot_ids, seeds, state["cur"],
@@ -897,7 +909,7 @@ class ContinuousEngine:
                 slots[i].pending = True
                 self._pending.append(_PendingWave(
                     rows, firsts, t0, block_inserts=block_inserts(rows),
-                    bucket=sbucket))
+                    bucket=sbucket, moe_dev=moe))
                 continue
             prefix_dev = g._prefix_to_device(
                 pkv, req.prefix[2] if len(req.prefix) > 2 else None)
@@ -938,6 +950,7 @@ class ContinuousEngine:
                 row_arrays(rows))
             if self.paged is not None:
                 bt_rows, limits = paged_rowmeta(rows)
+                moe = None
                 if bucket > g.PREFILL_CHUNK:
                     # chunked long-prompt admission: same prefill programs
                     # as dense, only the splice goes through block tables
@@ -960,7 +973,8 @@ class ContinuousEngine:
                 else:
                     (state["pool"], firsts, state["cur"], state["active"],
                      state["first"], state["temp"], state["topk"],
-                     state["greedy"], state["keys"]) = g._admit_fused_paged(
+                     state["greedy"], state["keys"],
+                     moe) = g._admit_fused_paged(
                         g.params, jnp.asarray(tokens), state["pool"],
                         bt_rows, lengths, limits, slot_ids, seeds,
                         state["cur"], state["active"], state["first"],
@@ -971,7 +985,7 @@ class ContinuousEngine:
                     slots[i].pending = True
                 self._pending.append(_PendingWave(
                     rows, firsts, t0, block_inserts=block_inserts(rows),
-                    bucket=bucket))
+                    bucket=bucket, moe_dev=moe))
                 continue
             if bucket > g.PREFILL_CHUNK:
                 # chunked long-prompt admission: one fused scan dispatch
@@ -1017,7 +1031,8 @@ class ContinuousEngine:
         ``prefill_s`` is wall time from dispatch to resolution — with
         overlap this is the request's true time-to-first-token."""
         with self._phase("resolve_wait"):
-            firsts = [int(t) for t in np.asarray(wave.firsts_dev)]
+            firsts, moe = jax.device_get((wave.firsts_dev, wave.moe_dev))
+            firsts = [int(t) for t in firsts]
         t_first = time.time() - wave.t0
         if self.paged is not None and self.paged.cache is not None:
             tier = getattr(self.paged.cache, "host_tier", None)
@@ -1045,7 +1060,8 @@ class ContinuousEngine:
                          else round(wave.t0 - r.t_handed, 6)
                          for _, r, _ in wave.rows],
                 bucket=wave.bucket,
-                prompt_lens=[len(r.ids) for _, r, _ in wave.rows])
+                prompt_lens=[len(r.ids) for _, r, _ in wave.rows],
+                **self._moe_fields(moe, passes=1))
         for req, ids in wave.block_inserts:
             # prefill has landed (the firsts fetch above synced on it): the
             # prompt's full blocks are valid, so the zero-copy cache insert
@@ -1524,9 +1540,10 @@ class ContinuousEngine:
                     dispatch_ok(s) for s in slots):
                 snapshot = [(i, s.gen_id, s.dispatched)
                             for i, s in enumerate(slots) if dispatch_ok(s)]
+                moe = None
                 if self.paged is not None:
                     (toks, last, state["cur"], state["pool"],
-                     state["keys"]) = g._decode_scan_paged(
+                     state["keys"], moe) = g._decode_scan_paged(
                         g.params, state["first"], state["cur"],
                         state["active"], state["pool"],
                         jnp.asarray(self._bt), state["keys"],
@@ -1553,7 +1570,7 @@ class ContinuousEngine:
                 self._plain_steps += self.chunk
                 for i, _, _ in snapshot:
                     slots[i].dispatched += self.chunk
-                chain.append((toks, snapshot))
+                chain.append(((toks, moe), snapshot))
 
     def _sanitize_wave(self) -> None:
         """Wave-boundary sanitizer checks (no-op unless TPUSTACK_SANITIZE):
@@ -1596,7 +1613,8 @@ class ContinuousEngine:
                      occupancy: Optional[int] = None,
                      tenants: Optional[Dict[str, int]] = None,
                      priorities: Optional[Dict[str, int]] = None,
-                     ctx_tokens: int = 0) -> None:
+                     ctx_tokens: int = 0, ctx_window: int = 0,
+                     moe: Optional[Dict[str, int]] = None) -> None:
         """Append one flight record for a fetched wave (plain chunk or
         speculative verify).  Host-side values only — the fetch that
         produced ``tokens`` already synced, so this is a dict build and a
@@ -1605,7 +1623,11 @@ class ContinuousEngine:
         before retiring finished rows, so a request's last wave still
         carries — and bills — its tenant).  ``ctx_tokens``: prompt +
         generated so far, summed over the rows this wave advanced, as
-        they stood when it was fetched (what its attention had to read).
+        they stood when it was fetched (what its attention had to read);
+        ``ctx_window``: the same with each row's context cut at the
+        model's attention window (what a window layer had to read; only a
+        model with such layers gets the field).  ``moe``: the wave's
+        routed-expert counters (``_moe_fields``).
         ``host_s``: the engine thread's seconds by phase since the
         previous wave/verify record, ``other`` being what no phase
         covered — they add up to ``wave_s``."""
@@ -1628,6 +1650,9 @@ class ContinuousEngine:
             "host_s": host_s,
             "ctx_tokens": int(ctx_tokens),
         }
+        if self._window is not None:
+            rec["ctx_tokens_window"] = int(ctx_window)
+        rec.update(moe or {})
         self._last_wave_t = now
         if self._queue_depth_fn is not None:
             try:
@@ -1670,9 +1695,10 @@ class ContinuousEngine:
         if self.ledger is not None:
             self.ledger.charge_flight_wave("llm", rec)
 
-    def _consume_block(self, state, slots, block, snapshot):
+    def _consume_block(self, state, slots, block, snapshot, moe=None):
         """Host bookkeeping for one fetched plain chunk block (the consume
-        half of the wave loop, shared by both run loops)."""
+        half of the wave loop, shared by both run loops).  ``moe``: the
+        chunk's fetched routed-expert counters, if the model has any."""
         if self._on_progress is not None:
             self._on_progress("wave")
         self._sanitize_wave()
@@ -1685,7 +1711,7 @@ class ContinuousEngine:
         live = self._live(slots)
         tenants = self._tenant_occupancy(slots)  # pre-retire, like live
         priorities = self._priority_occupancy(slots)
-        wave_tokens = ctx_tokens = 0
+        wave_tokens = ctx_tokens = ctx_window = 0
         for i, gid, offset in snapshot:
             s = slots[i]
             if s.req is None or s.gen_id != gid or s.done:
@@ -1694,7 +1720,9 @@ class ContinuousEngine:
                 s.done = True
                 self._retire(state, slots, i, live)
                 continue
-            ctx_tokens += len(s.req.ids) + len(s.out)
+            ctx = len(s.req.ids) + len(s.out)
+            ctx_tokens += ctx
+            ctx_window += min(ctx, self._window or 0)
             # chunks are consumed in dispatch order and never overlap:
             # this block carries exactly decode steps [offset, offset+chunk)
             assert len(s.out) - 1 == offset, (len(s.out), offset)
@@ -1717,16 +1745,30 @@ class ContinuousEngine:
         self._flight_wave(slots, "wave", wave_tokens, self.chunk,
                           stride=self.chunk, occupancy=live,
                           tenants=tenants, priorities=priorities,
-                          ctx_tokens=ctx_tokens)
+                          ctx_tokens=ctx_tokens, ctx_window=ctx_window,
+                          moe=self._moe_fields(moe, passes=self.chunk))
+
+    def _moe_fields(self, moe, passes: int) -> Dict[str, int]:
+        """Flight-record fields of one dispatch's routed-expert work:
+        ``moe`` its fetched counters (``Generator._apply_counted``), summed
+        over the ``moe_layer_calls`` sparse layer-calls its ``passes`` made.
+        Empty for a model without such a layer."""
+        if moe is None:
+            return {}
+        pairs, touched, fullest = (int(x) for x in moe)
+        return {"moe_layer_calls": self._sparse_layers * passes,
+                "moe_pairs": pairs, "moe_experts_touched": touched,
+                "moe_max_expert_tokens": fullest}
 
     def _fetch_consume(self, state, slots, block, snapshot):
         """THE wave-boundary fetch: one sync per consumed chunk, with
         `depth` more chunks already dispatched behind it — the wait timed
         apart from the bookkeeping that follows it."""
         with self._phase("fetch_wait"):
-            block = np.asarray(block)  # tpulint: disable=TPL101
+            # the chunk's tokens and, with them, its routed-expert counters
+            block, moe = jax.device_get(block)  # tpulint: disable=TPL101
         with self._phase("consume"):
-            self._consume_block(state, slots, block, snapshot)
+            self._consume_block(state, slots, block, snapshot, moe)
 
     def _retire_exhausted(self, state, slots, dispatch_ok):
         """Retire every row that is done or has nothing left to dispatch
@@ -1844,13 +1886,13 @@ class ContinuousEngine:
         clip the flush/scatter at the accepted frontier), so a rejected
         draft costs compute, never cache or pool state."""
         with self._phase("verify"):
-            toks_dev, n_acc, dlen, rows = self._spec_issue(state, slots,
-                                                           plan)
+            toks_dev, n_acc, dlen, rows, moe = self._spec_issue(
+                state, slots, plan)
         with self._phase("verify_wait"):
-            block = np.asarray(toks_dev)
-            accs = np.asarray(n_acc).tolist()
+            block, accs, moe = jax.device_get((toks_dev, n_acc, moe))
+            accs = accs.tolist()
         with self._phase("consume"):
-            self._spec_consume(state, slots, block, accs, dlen, rows)
+            self._spec_consume(state, slots, block, accs, dlen, rows, moe)
 
     def _spec_issue(self, state, slots, plan):
         """Ship the plan's drafts and dispatch the verify program; returns
@@ -1868,9 +1910,10 @@ class ContinuousEngine:
             draft[i, :len(toks)] = toks
             dlen[i] = len(toks)
             rows.append((i, slots[i].gen_id))
+        moe = None
         if self.paged is not None:
             (toks_dev, n_acc, last, state["cur"], state["pool"],
-             state["keys"]) = g._spec_verify_paged(
+             state["keys"], moe) = g._spec_verify_paged(
                 g.params, state["first"], jnp.asarray(draft),
                 jnp.asarray(dlen), state["cur"], state["active"],
                 state["pool"], jnp.asarray(self._bt), state["keys"],
@@ -1890,12 +1933,14 @@ class ContinuousEngine:
                 state["topk"], state["greedy"], K)
         state["first"] = last
         self._spec_dispatches += 1
-        return toks_dev, n_acc, dlen.tolist(), rows
+        return toks_dev, n_acc, dlen.tolist(), rows, moe
 
-    def _spec_consume(self, state, slots, block, accs, dlen, rows):
+    def _spec_consume(self, state, slots, block, accs, dlen, rows,
+                      moe=None):
         """Host bookkeeping for one fetched verify wave: deliver each
         row's accepted run + bonus token, retire, record.  ``block`` is
-        the fetched tokens; ``accs`` and ``dlen`` are plain per-slot ints."""
+        the fetched tokens; ``accs`` and ``dlen`` are plain per-slot ints;
+        ``moe`` the wave's fetched routed-expert counters, if any."""
         spec = self.spec
         if self._on_progress is not None:
             self._on_progress("wave")
@@ -1910,7 +1955,8 @@ class ContinuousEngine:
         live = self._live(slots)
         tenants = self._tenant_occupancy(slots)  # pre-retire, like live
         priorities = self._priority_occupancy(slots)
-        wave_tokens = wave_drafted = wave_accepted = ctx_tokens = 0
+        wave_tokens = wave_drafted = wave_accepted = 0
+        ctx_tokens = ctx_window = 0
         for i, gid in rows:
             s = slots[i]
             if s.req is None or s.gen_id != gid or s.done:
@@ -1919,7 +1965,9 @@ class ContinuousEngine:
                 s.done = True
                 self._retire(state, slots, i, live)
                 continue
-            ctx_tokens += len(s.req.ids) + len(s.out)
+            ctx = len(s.req.ids) + len(s.out)
+            ctx_tokens += ctx
+            ctx_window += min(ctx, self._window or 0)
             k_i = dlen[i]
             m = min(accs[i], k_i)
             if k_i > 0:
@@ -1963,7 +2011,9 @@ class ContinuousEngine:
                           stride=wave_tokens / max(1, len(rows)),
                           drafted=wave_drafted, accepted=wave_accepted,
                           occupancy=live, tenants=tenants,
-                          priorities=priorities, ctx_tokens=ctx_tokens)
+                          priorities=priorities, ctx_tokens=ctx_tokens,
+                          ctx_window=ctx_window,
+                          moe=self._moe_fields(moe, passes=1))
 
     def _run_loop_spec(self, state, slots, chain, admit_free, dispatch_ok):
         """Variable-stride wave loop (``spec`` configured): whenever the

@@ -102,6 +102,10 @@ class Generator:
         path: mesh-partitioned compute, compiler-placed caches — the
         pre-tp-serving behavior."""
         self.cfg = config
+        if mesh is not None and config.moe is not None:
+            raise NotImplementedError(
+                "a routed-expert model under a tp mesh: the expert stacks "
+                "have no partition rule and moe_gmm no shard_map yet")
         self.model = LlamaModel(config, dtype=dtype, tp_mesh=mesh)
         self.cache_dtype = dtype
         self.mesh = mesh
@@ -238,6 +242,17 @@ class Generator:
         return cls(config, params=params, dtype=dtype, mesh=mesh, rules=rules,
                    shard_kv=shard_kv)
 
+    def _apply_counted(self, params, *args):
+        """``model.apply`` for the paged serving programs: ``(logits,
+        caches, moe)`` with ``moe`` the routed-expert layers' counters
+        summed over the sparse layer-calls this pass made — int32
+        ``[pairs, experts_touched, max_expert_tokens]`` — or None for a
+        model without such a layer (its programs then trace as they did)."""
+        (logits, caches), extra = self.model.apply(
+            {"params": params}, *args, mutable=["moe_stats"])
+        counts = jax.tree.leaves(extra)
+        return logits, caches, (sum(counts) if counts else None)
+
     # -------------------------------------------------------------- compiled
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(4,))
     def _prefill(self, params, tokens, length, caches):
@@ -342,21 +357,21 @@ class Generator:
         positions ``base + i`` attend ``[0, base + i]`` via an explicit
         mask (the full-cache XLA attention path) — semantics identical to
         ``_prefill_chunk``.  Shared by ``_prefill_masked`` and the fused
-        restore+prefill program."""
+        restore+prefill program.  Returns ``(logits, caches, moe)``."""
         b, s = tokens.shape
         positions = base + jnp.broadcast_to(jnp.arange(s), (b, s))
         mask = (jnp.arange(self.cfg.max_seq)[None, None, None, :]
                 <= positions[:, None, :, None])
         local_last = jnp.clip(length - 1 - base, 0, s - 1)
-        logits, caches = self.model.apply(
-            {"params": params}, tokens, positions, caches, base, mask,
-            local_last)
-        return logits[:, 0], caches
+        logits, caches, moe = self._apply_counted(
+            params, tokens, positions, caches, base, mask, local_last)
+        return logits[:, 0], caches, moe
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(5,))
     def _prefill_masked(self, params, tokens, base, length, caches):
         """One-dispatch small-suffix prefill — see _prefill_masked_body."""
-        return self._prefill_masked_body(params, tokens, base, length, caches)
+        return self._prefill_masked_body(params, tokens, base, length,
+                                         caches)[:2]
 
     @functools.partial(jax.jit, static_argnums=(0,))
     def _prefill_prefix_fused(self, params, tokens, base, length, prefix):
@@ -368,7 +383,8 @@ class Generator:
         b = tokens.shape[0]
         caches = init_kv_caches(self.cfg, b, dtype=self.cache_dtype)
         caches = self._restore_body(caches, prefix)
-        return self._prefill_masked_body(params, tokens, base, length, caches)
+        return self._prefill_masked_body(params, tokens, base, length,
+                                         caches)[:2]
 
     def _prefill_from(self, tokens: np.ndarray, base: int, length, caches):
         """Prefill ``tokens [B, bucket]`` starting at cache position
@@ -730,7 +746,8 @@ class Generator:
         pool and scatters the buffers back through the block tables) — one
         source of truth is what makes paged-vs-dense greedy outputs
         byte-identical.  Returns ``(toks [B, T], last, cur_end, bufs,
-        keys)``."""
+        keys, moe)`` (``moe``: ``_apply_counted``'s counters summed over
+        the steps, None for a model without routed experts)."""
         from tpustack.models.llama import init_chunk_bufs
 
         S = self.cfg.max_seq
@@ -738,24 +755,28 @@ class Generator:
         cur0 = cur
         bufs0 = init_chunk_bufs(self.cfg, B, n_steps, dtype=self.cache_dtype)
 
+        moe0 = (None if self.cfg.moe is None
+                else jnp.zeros((3,), jnp.int32))
+
         def step(carry, t):
-            tok, bufs, keys = carry
+            tok, bufs, keys, moe = carry
             cur_t = jnp.minimum(cur0 + t * active, S - 1)
             merged = [dict(c, **bf) for c, bf in zip(caches, bufs)]
-            logits, merged = self.model.apply(
-                {"params": params}, tok, cur_t[:, None], merged, (cur0, t),
-                None)
+            logits, merged, counts = self._apply_counted(
+                params, tok, cur_t[:, None], merged, (cur0, t), None)
+            if counts is not None:
+                moe = moe + counts
             bufs = [{k: d[k] for k in bf} for d, bf in zip(merged, bufs)]
             step_keys, keys = _advance_keys(keys)
             nxt = self._sample_from_logits_perrow(
                 logits[:, -1].astype(jnp.float32), step_keys, temperature,
                 top_k, greedy)
-            return (nxt[:, None], bufs, keys), nxt
+            return (nxt[:, None], bufs, keys, moe), nxt
 
-        (last, bufs, keys), toks = jax.lax.scan(
-            step, (first_tok, bufs0, keys), jnp.arange(n_steps))
+        (last, bufs, keys, moe), toks = jax.lax.scan(
+            step, (first_tok, bufs0, keys, moe0), jnp.arange(n_steps))
         cur_end = jnp.minimum(cur0 + n_steps * active, S - 1)
-        return toks.T, last, cur_end, bufs, keys
+        return toks.T, last, cur_end, bufs, keys, moe
 
     @functools.partial(jax.jit, static_argnums=(0, 10), donate_argnums=(5,))
     def _decode_scan_cont(self, params, first_tok, cur, active, caches, keys,
@@ -778,7 +799,7 @@ class Generator:
         clipped out of the flush window entirely, so a retiring row's
         speculative garbage is never written to the cache at all."""
         cur0 = cur
-        toks, last, cur_end, bufs, keys = self._decode_cont_body(
+        toks, last, cur_end, bufs, keys, _ = self._decode_cont_body(
             params, first_tok, cur, active, caches, keys, temperature,
             top_k, greedy, n_steps)
         caches = self._flush_chunk_bufs(caches, bufs, cur0, cur_end, n_steps)
@@ -957,10 +978,13 @@ class Generator:
         (``paged_attention_partial``) — no gather copy, no dense
         intermediate, per-row ``cur`` masking and int8 dequant inside the
         kernel.  Same traced scan body either way, so greedy outputs are
-        token-identical across the flag."""
+        token-identical across the flag.
+
+        Returns ``(toks, last, cur_end, pool, keys, moe)``: ``moe`` rides
+        to the host in the fetch that takes ``toks``."""
         view = (self._pool_views(pool, bt) if flash
                 else self._pool_gather_body(pool, bt))
-        toks, last, cur_end, bufs, keys = self._decode_cont_body(
+        toks, last, cur_end, bufs, keys, moe = self._decode_cont_body(
             params, first_tok, cur, active, view,
             keys, temperature, top_k, greedy, n_steps)
         B = bt.shape[0]
@@ -970,7 +994,7 @@ class Generator:
             pool, bt, bufs,
             {"k": "ck", "v": "cv", "k_scale": "ck_scale",
              "v_scale": "cv_scale"}, positions, valid)
-        return toks, last, cur_end, pool, keys
+        return toks, last, cur_end, pool, keys, moe
 
     # --------------------------------------------------- speculative verify
     #
@@ -1010,7 +1034,7 @@ class Generator:
         valid counts ``draft_len [B]`` (zero-draft rows run exactly one
         plain decode step's worth of work inside the same dispatch).
         Returns ``(toks [B,K+1], n_acc [B], last [B,1], cur_end [B], bufs,
-        keys)`` — the host takes ``toks[i, :n_acc[i]+1]``."""
+        keys, moe)`` — the host takes ``toks[i, :n_acc[i]+1]``."""
         from tpustack.models.llama import init_chunk_bufs
 
         S_max = self.cfg.max_seq
@@ -1024,8 +1048,8 @@ class Generator:
         merged = [dict(c, **bf) for c, bf in zip(caches, bufs0)]
         offs = jnp.arange(S)[None, :] * active[:, None]
         positions = jnp.minimum(cur0[:, None] + offs, S_max - 1)
-        logits, merged = self.model.apply(
-            {"params": params}, seg, positions, merged, (cur0, 0), None)
+        logits, merged, moe = self._apply_counted(
+            params, seg, positions, merged, (cur0, 0), None)
         bufs = [{k: d[k] for k in bf} for d, bf in zip(merged, bufs0)]
         logits = logits.astype(jnp.float32)                      # [B, S, V]
 
@@ -1099,7 +1123,7 @@ class Generator:
                          jnp.where(ar == n_acc[:, None], bonus[:, None],
                                    0)).astype(jnp.int32)
         cur_end = jnp.minimum(cur0 + (n_acc + 1) * active, S_max - 1)
-        return toks, n_acc, bonus[:, None], cur_end, bufs, keys
+        return toks, n_acc, bonus[:, None], cur_end, bufs, keys, moe
 
     @functools.partial(jax.jit, static_argnums=(0, 12), donate_argnums=(7,))
     def _spec_verify_cont(self, params, first_tok, draft, draft_len, cur,
@@ -1109,7 +1133,7 @@ class Generator:
         frozen slot caches, then the shared chunk flush clipped at each
         row's ACCEPTED frontier — rejected draft K/V is never written."""
         cur0 = cur
-        toks, n_acc, last, cur_end, bufs, keys = self._spec_verify_parts(
+        toks, n_acc, last, cur_end, bufs, keys, _ = self._spec_verify_parts(
             params, first_tok, draft, draft_len, cur, active, caches, keys,
             temperature, top_k, greedy, n_draft)
         caches = self._flush_chunk_bufs(caches, bufs, cur0, cur_end,
@@ -1136,7 +1160,7 @@ class Generator:
         ``_decode_scan_paged`` for the flag's contract."""
         view = (self._pool_views(pool, bt) if flash
                 else self._pool_gather_body(pool, bt))
-        toks, n_acc, last, cur_end, bufs, keys = self._spec_verify_parts(
+        toks, n_acc, last, cur_end, bufs, keys, moe = self._spec_verify_parts(
             params, first_tok, draft, draft_len, cur, active,
             view, keys, temperature, top_k,
             greedy, n_draft)
@@ -1147,7 +1171,7 @@ class Generator:
             pool, bt, bufs,
             {"k": "ck", "v": "cv", "k_scale": "ck_scale",
              "v_scale": "cv_scale"}, positions, valid)
-        return toks, n_acc, last, cur_end, pool, keys
+        return toks, n_acc, last, cur_end, pool, keys, moe
 
     @functools.partial(jax.jit, static_argnums=(0,),
                        donate_argnums=(3, 9, 10, 11, 12, 13, 14, 15))
@@ -1157,13 +1181,13 @@ class Generator:
         """Paged twin of ``_admit_fused``: ONE dispatch covering fresh
         in-graph row caches → batched prefill (identical trace, identical
         logits) → paged splice through the rows' block tables →
-        first-token sample → slot activation."""
+        first-token sample → slot activation.  Last of what it returns is
+        ``_apply_counted``'s ``moe``: it leaves with the first tokens."""
         n, bucket = tokens.shape
         row_caches = init_kv_caches(self.cfg, n, dtype=self.cache_dtype)
         positions = jnp.broadcast_to(jnp.arange(bucket), (n, bucket))
-        logits, row_caches = self.model.apply(
-            {"params": params}, tokens, positions, row_caches, 0, None,
-            lengths - 1)
+        logits, row_caches, moe = self._apply_counted(
+            params, tokens, positions, row_caches, 0, None, lengths - 1)
         pool = self._insert_span_body(pool, bt_rows, row_caches,
                                       jnp.zeros((), jnp.int32), bucket,
                                       limits)
@@ -1171,7 +1195,7 @@ class Generator:
                                                topk_r, greedy_r)
         return (pool, firsts) + self._activate_rows(
             cur, active, first, temp, topk, greedy, keys, slot_ids,
-            lengths, firsts, temp_r, topk_r, greedy_r, next_keys)
+            lengths, firsts, temp_r, topk_r, greedy_r, next_keys) + (moe,)
 
     @functools.partial(jax.jit, static_argnums=(0,),
                        donate_argnums=(3, 10, 11, 12, 13, 14, 15, 16))
@@ -1183,17 +1207,18 @@ class Generator:
         shared prefix blocks hold exactly what prefill wrote — zero-copy
         restore) → masked suffix prefill (same traced body as the dense
         fused warm start) → scatter the suffix span back through the block
-        table → sample + activate."""
+        table → sample + activate.  ``moe`` last, as in
+        ``_admit_fused_paged``."""
         caches = self._pool_gather_body(pool, bt_rows)
-        logits, caches = self._prefill_masked_body(params, tokens, base,
-                                                   length, caches)
+        logits, caches, moe = self._prefill_masked_body(
+            params, tokens, base, length, caches)
         pool = self._insert_span_body(pool, bt_rows, caches, base,
                                       tokens.shape[1], limits)
         firsts, next_keys = self._first_sample(logits, seeds, temp_r, topk_r,
                                                greedy_r)
         return (pool, firsts) + self._activate_rows(
             cur, active, first, temp, topk, greedy, keys, slot_ids,
-            length, firsts, temp_r, topk_r, greedy_r, next_keys)
+            length, firsts, temp_r, topk_r, greedy_r, next_keys) + (moe,)
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(1,))
     def _restore_blocks_paged(self, pool, ids, payloads):
@@ -1228,8 +1253,8 @@ class Generator:
         # ``limits`` ([B]) is exactly the post-chunk length ``base + step``
         # — reuse it as the masked body's per-row true length (the sampled
         # logits are discarded, but ``logits_at`` still gathers per row)
-        _, caches = self._prefill_masked_body(params, tokens, base, limits,
-                                              caches)
+        _, caches, _ = self._prefill_masked_body(params, tokens, base,
+                                                 limits, caches)
         return self._insert_span_body(pool, bt_rows, caches, base,
                                       tokens.shape[1], limits)
 

@@ -411,18 +411,26 @@ def device_peaks_info() -> Tuple[str, Optional[Tuple[float, float]]]:
     return getattr(dev, "device_kind", ""), device_peaks(dev)
 
 
-def llm_wave_arith(cfg, params, cache_dtype) -> Dict[str, float]:
+def llm_wave_arith(cfg, params, cache_dtype, rows: int = 1
+                   ) -> Dict[str, float]:
     """Per-dispatch decode arithmetic from the llama config + param tree —
     the SAME accounting ``tools/bench_llm.py`` prints offline, shared so
-    the live gauges and the bench can never disagree:
+    the live gauges and the bench can never disagree.  Counted by layer
+    kind (``cfg.layer_specs``):
 
-    - ``flops_per_token``: 2 FLOPs per matmul weight element (decode
-      touches every kernel once per token);
+    - ``flops_per_token``: 2 FLOPs per matmul weight element a token
+      multiplies — every dense kernel once; of a routed-expert stack
+      ``[experts, in, out]`` the ``top_k / n_experts`` share a token's
+      choices make of it (its expected experts among those held here);
     - ``weight_stream_bytes``: bytes one decode weight pass streams (the
-      full param tree minus embedding tables — decode gathers one row);
+      param tree minus embedding tables — decode gathers one row); of an
+      expert stack the share a step of ``rows`` tokens is expected to
+      touch, ``1 - (1 - top_k / n_experts) ** rows`` — held experts no
+      token chose are not read;
     - ``kv_step_bytes_per_slot``: KV bytes one slot's attention reads per
-      step (the full static-shape cache line; int8 cache = 1 B/element +
-      one f32 scale per vector).
+      step (a full layer the whole static-shape cache line, a window
+      layer its window; int8 cache = 1 B/element + one f32 scale per
+      vector).
     """
     import jax
     import jax.numpy as jnp
@@ -432,14 +440,28 @@ def llm_wave_arith(cfg, params, cache_dtype) -> Dict[str, float]:
     def key_str(k):
         return str(getattr(k, "key", k))
 
+    moe = getattr(cfg, "moe", None)
+    chosen = moe.top_k / moe.n_experts if moe is not None else 1.0
+    touched = 1.0 - (1.0 - chosen) ** max(1, rows)
+
+    def is_stack(p, x):
+        # an expert stack's leaves: [experts, in, out] and [experts, out]
+        return moe is not None and any(
+            key_str(k) in ("gate_proj", "up_proj", "down_proj")
+            for k in p) and x.ndim == (3 if key_str(p[-1]) == "kernel"
+                                       else 2)
+
     weight_stream_bytes = sum(
-        x.nbytes for p, x in flat
+        x.nbytes * (touched if is_stack(p, x) else 1.0) for p, x in flat
         if not any("embed" in key_str(k) for k in p))
     flops_per_token = 2 * sum(
-        x.size for p, x in flat if key_str(p[-1]) == "kernel")
+        x.size * (chosen if is_stack(p, x) else 1.0) for p, x in flat
+        if key_str(p[-1]) == "kernel")
     kv_elt = 1 if cfg.kv_quant == "int8" else jnp.dtype(cache_dtype).itemsize
+    kv_positions = sum(min(cfg.max_seq, sp.window or cfg.max_seq)
+                       for sp in cfg.layer_specs)
     kv_step_bytes_per_slot = (
-        cfg.n_layers * 2 * cfg.max_seq * cfg.n_kv_heads
+        2 * kv_positions * cfg.n_kv_heads
         * (cfg.head_dim * kv_elt + (4 if cfg.kv_quant == "int8" else 0)))
     return {
         "flops_per_token": float(flops_per_token),

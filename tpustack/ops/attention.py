@@ -75,6 +75,7 @@ def dot_product_attention(
     data_shards: int = 1,
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Scaled dot-product attention over BSHD tensors.
 
@@ -86,6 +87,8 @@ def dot_product_attention(
         (3D is rejected as ambiguous between batch and head axes); True
         means *attend*.
       causal: apply a causal mask (decoder LMs).
+      window: with ``causal``, a query at (bottom-right aligned) position
+        ``i`` sees only keys ``j`` with ``i - j < window``.
       scale: defaults to ``1/sqrt(D)``.
       impl: ``"xla"`` (default), ``"flash"`` (Pallas kernel, TPU), or
         ``"auto"`` — flash on TPU for long sequences at small batch·heads
@@ -103,6 +106,8 @@ def dot_product_attention(
     hkv = k.shape[2]
     if h % hkv:
         raise ValueError(f"q heads {h} not a multiple of kv heads {hkv}")
+    if window is not None and not causal:
+        raise ValueError("window= needs causal=True")
     if mask is not None:
         mask = jnp.asarray(mask)
         # 3D masks are ambiguous ([B, Sq, Sk] vs [H, Sq, Sk]): broadcasting
@@ -150,7 +155,7 @@ def dot_product_attention(
                 "not supported; use impl='xla'")
         q_off = k.shape[1] - sq if causal and k.shape[1] != sq else None
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               q_offset=q_off)
+                               q_offset=q_off, window=window)
     if impl != "xla":
         raise ValueError(f"unknown attention impl {impl!r}")
 
@@ -159,6 +164,9 @@ def dot_product_attention(
     sk = k.shape[1]
     if causal:
         causal_mask = jnp.tril(jnp.ones((sq, sk), dtype=bool), k=sk - sq)
+        if window is not None:
+            causal_mask &= ~jnp.tril(jnp.ones((sq, sk), dtype=bool),
+                                     k=sk - sq - window)
         mask = causal_mask if mask is None else jnp.logical_and(mask, causal_mask)
 
     # [B, Sk, Hkv] scales → broadcastable over the score/prob layouts

@@ -36,7 +36,7 @@ LOG2E = 1.4426950408889634
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
-                 kv_len: int, block_q: int):
+                 kv_len: int, block_q: int, window: Optional[int] = None):
     """One (batch*head, q-block) grid step: softmax(q·kᵀ)·v, fp32 accumulate.
 
     Inputs stay in their storage dtype (bf16 on TPU) through the two
@@ -53,6 +53,12 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
     through exp2 with log2(e) folded into the static scale (same math:
     exp(l·s - m) == exp2(l·s·log2e - m') with the max taken in the scaled
     domain; one fewer VPU multiply per element if exp lowers to scale+exp2).
+
+    ``window`` (static, with ``causal``): row ``i`` sees keys ``j`` with
+    ``i - j < window``.  The keys a q block can see span ``block_q +
+    window - 1`` positions, so the step reads that slice of the panel
+    (rounded out to 128-row tiles) and not the whole of it: what lies
+    wholly behind the band costs no product and no softmax pass.
     """
     qi = pl.program_id(1)
     # fold the softmax scale (with log2e) into the q TILE, not the scores:
@@ -61,20 +67,33 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
     # that one full score pass is measurable.  bf16 q x scalar rounds at
     # bf16 grain, the same order as the input rounding itself.
     q = q_ref[0] * jnp.asarray(scale * LOG2E, q_ref.dtype)  # [block_q, D]
-    k = k_ref[0]                                # [S_pad, D]
-    v = v_ref[0]
+    s_pad, col0 = k_ref.shape[1], 0
+    if window is not None and block_q % 128 == 0:
+        back = -(-(window - 1) // 128) * 128
+        if block_q + back < s_pad:              # static
+            col0 = pl.multiple_of(jnp.clip(qi * block_q - back, 0,
+                                           s_pad - block_q - back), 128)
+            s_pad = block_q + back
+    if s_pad < k_ref.shape[1]:
+        k = k_ref[0, pl.ds(col0, s_pad), :]     # [S_pad, D]
+        v = v_ref[0, pl.ds(col0, s_pad), :]
+    else:
+        k = k_ref[0]
+        v = v_ref[0]
 
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-    s_pad = logits.shape[-1]
-    if causal or kv_len < s_pad:                # static: skip 3 VPU passes
-        col = jax.lax.broadcasted_iota(jnp.int32, (block_q, s_pad), 1)
+    if causal or kv_len < k_ref.shape[1]:       # static: skip 3 VPU passes
+        col = col0 + jax.lax.broadcasted_iota(jnp.int32, (block_q, s_pad), 1)
         valid = col < kv_len                    # mask K padding
         if causal:
-            row = jax.lax.broadcasted_iota(jnp.int32, (block_q, s_pad), 0)
-            valid = valid & (col <= row + qi * block_q)
+            row = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, s_pad), 0)
+            valid = valid & (col <= row)
+            if window is not None:
+                valid = valid & (col > row - window)
         logits = jnp.where(valid, logits, NEG_INF)
 
     m = jnp.max(logits, axis=-1, keepdims=True)
@@ -87,7 +106,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
 
 def _attn_kernel_stream(q_ref, k_ref, v_ref, off_ref, len_ref, o_ref,
                         m_ref, l_ref, acc_ref, *, scale: float, causal: bool,
-                        block_q: int, block_k: int, n_k: int):
+                        block_q: int, block_k: int, n_k: int,
+                        window: Optional[int] = None):
     """One (batch*head, q-block, k-block) grid step with a running-softmax
     carry — the long-context kernel.  Unlike ``_attn_kernel`` the K/V panel
     never sits whole in VMEM: blocks of ``block_k`` stream through while
@@ -102,7 +122,9 @@ def _attn_kernel_stream(q_ref, k_ref, v_ref, off_ref, len_ref, o_ref,
     offset (chunked prefill: a chunk at cache offset ``off`` attends the
     whole cache prefix) and the number of valid K tokens.  K-blocks past
     ``len`` or fully above the (offset) diagonal skip their compute (their
-    DMA is still scheduled — see the wrapper docstring).
+    DMA is still scheduled — see the wrapper docstring); with ``window``
+    (static) so do the blocks wholly behind the band of the block's first
+    row, and a block the band's edge crosses takes the masked branch.
     """
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -119,8 +141,11 @@ def _attn_kernel_stream(q_ref, k_ref, v_ref, off_ref, len_ref, o_ref,
     # skip k-blocks past the valid length; causal: also those fully above
     # this q-block's diagonal
     needed = col0 < kv_len
+    row0 = off + qi * block_q                   # first row's position
     if causal:
-        needed = needed & (col0 <= off + qi * block_q + block_q - 1)
+        needed = needed & (col0 <= row0 + block_q - 1)
+    if window is not None:
+        needed = needed & (col0 + block_k - 1 > row0 - window)
 
     def _accumulate(logits):
         """Online-softmax update of the (m, l, acc) carry from one block of
@@ -159,7 +184,10 @@ def _attn_kernel_stream(q_ref, k_ref, v_ref, off_ref, len_ref, o_ref,
     if causal:
         # fully-below-diagonal test against the STRICTEST row (row 0 of the
         # q block): every column valid for row 0 is valid for all rows
-        boundary = boundary | (col0 + block_k - 1 > off + qi * block_q)
+        boundary = boundary | (col0 + block_k - 1 > row0)
+    if window is not None:
+        # wholly inside the band only if the LAST row still sees col0
+        boundary = boundary | (col0 <= row0 + block_q - 1 - window)
 
     @pl.when(needed & boundary)
     def _compute_masked():
@@ -168,7 +196,9 @@ def _attn_kernel_stream(q_ref, k_ref, v_ref, off_ref, len_ref, o_ref,
         valid = col < kv_len
         if causal:
             row = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-            valid = valid & (col <= row + off + qi * block_q)
+            valid = valid & (col <= row + row0)
+            if window is not None:
+                valid = valid & (col > row + row0 - window)
         _accumulate(jnp.where(valid, logits, NEG_INF))
 
     @pl.when(needed & jnp.logical_not(boundary))
@@ -240,6 +270,7 @@ def flash_attention(
     q_offset=None,
     kv_len=None,
     panel_max_kv: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """``[B, S, H, D]`` flash attention; K/V may carry fewer (GQA) heads.
 
@@ -274,7 +305,13 @@ def flash_attention(
     while the K/V BlockSpec index maps ``bh → bh // (H/Hkv)``, so shared
     K/V panels are DMA'd per kv-head without ever materialising the
     repeated tensor (at 32k ctx the repeat would be ~0.5 GB per layer).
+
+    ``window`` (static, needs ``causal``): a q row at position ``i`` sees
+    keys ``j`` with ``0 <= i - j < window``; both kernels skip what lies
+    wholly behind the band, as they skip what lies above the diagonal.
     """
+    if window is not None and not causal:
+        raise ValueError("window= needs causal=True")
     # Resolve the trace-time choices OUTSIDE the jit boundary so they join
     # the jit cache key: the module global PANEL_MAX_KV is read here at every
     # call, not baked into a previously compiled signature (tests monkeypatch
@@ -298,14 +335,15 @@ def flash_attention(
                             block_q=block_q, block_k=block_k,
                             interpret=interpret, q_offset=q_offset,
                             kv_len=kv_len, streaming=streaming,
-                            panel_max_kv=panel_max_kv)
+                            panel_max_kv=panel_max_kv, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "interpret",
-                                             "streaming", "panel_max_kv"))
+                                             "streaming", "panel_max_kv",
+                                             "window"))
 def _flash_attention(q, k, v, *, causal, scale, block_q, block_k, interpret,
-                     q_offset, kv_len, streaming, panel_max_kv):
+                     q_offset, kv_len, streaming, panel_max_kv, window):
     b, sq, h, d = q.shape
     sk = k.shape[1]
     hkv = k.shape[2]
@@ -332,7 +370,7 @@ def _flash_attention(q, k, v, *, causal, scale, block_q, block_k, interpret,
         grid = (b * h, sq_pad // bq)
         out = pl.pallas_call(
             functools.partial(_attn_kernel, scale=scale, causal=causal,
-                              kv_len=sk, block_q=bq),
+                              kv_len=sk, block_q=bq, window=window),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, bq, d), lambda bh, i: (bh, i, 0)),
@@ -357,7 +395,7 @@ def _flash_attention(q, k, v, *, causal, scale, block_q, block_k, interpret,
         grid = (b * h, sq_pad // bq, n_k)  # k innermost: carry is per (bh, qi)
         out = pl.pallas_call(
             functools.partial(_attn_kernel_stream, scale=scale, causal=causal,
-                              block_q=bq, block_k=bk, n_k=n_k),
+                              block_q=bq, block_k=bk, n_k=n_k, window=window),
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, bq, d), lambda bh, i, j: (bh, i, 0)),
@@ -455,10 +493,11 @@ def paged_pages_per_step(blk: int, nb: int, hkv: int, d: int,
     return pages
 
 
-def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref,
-                       acc_out, m_out, l_out, k_buf, v_buf, sem, st_ref, *,
-                       scale: float, blk: int, pages: int, n_b: int,
-                       hkv: int, d: int, quant: bool):
+def _paged_attn_kernel(bt_ref, len_ref, lo_ref, q_ref, k_hbm, v_hbm, ks_ref,
+                       vs_ref, acc_out, m_out, l_out, k_buf, v_buf, sem,
+                       st_ref, *, scale: float, blk: int, pages: int,
+                       n_b: int, hkv: int, d: int, quant: bool,
+                       group: Optional[int] = None):
     """One batch row of in-place paged decode attention: a loop over the
     row's compute blocks, walking the kv heads inside each.  ``q_ref``
     holds this row's query rows per kv head ``[Hkv, R, D]`` (R = S·group,
@@ -476,7 +515,16 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref,
 
     ``st_ref`` (SMEM, lives across the grid): [0] the scratch half the
     row's first compute block is in, [1] whether the previous row already
-    started that block's copies."""
+    started that block's copies.
+
+    A WINDOW layer (``group`` set, static: the GQA group of a multi-query
+    segment, 0 for a single query position) reads the key set
+    ``[lo_ref[b] + j, lengths[b])`` for its query position ``j``, with
+    ``lo_ref[b]`` the oldest position the row's first query sees (below 0
+    while the window still reaches past the sequence's start): the walk
+    starts at the compute block that holds it, pool blocks wholly behind
+    it are not copied, and the head of the first one is masked.  A full
+    layer (``group`` None) never reads ``lo_ref``: its walk starts at 0."""
     b = pl.program_id(0)
     n_rows = pl.num_programs(0)
     tokens = pages * blk
@@ -484,6 +532,12 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref,
     n_cb = (kv_len + tokens - 1) // tokens
     nxt_row = jnp.minimum(b + 1, n_rows - 1)
     nxt_live = (b + 1 < n_rows) & (len_ref[nxt_row] > 0)
+    windowed = group is not None
+    # first compute block / first position of a row's walk
+    lo_of = lambda row: lo_ref[row] if windowed else 0
+    cb0_of = lambda row: (jnp.maximum(lo_ref[row], 0) // tokens
+                          if windowed else 0)
+    cb0 = cb0_of(b)
 
     def copies(row, cb, slot, go):
         """Start (``go`` a traced bool) or wait for (``go`` None) the
@@ -493,6 +547,8 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref,
         for p in range(pages):                  # static
             j = cb * pages + p
             live = j * blk < len_ref[row]
+            if windowed:
+                live = live & ((j + 1) * blk > lo_of(row))
 
             @pl.when(live if go is None else live & go)
             def _():
@@ -519,12 +575,13 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref,
     acc_out[...] = jnp.zeros_like(acc_out)
 
     slot0 = st_ref[0]
-    copies(b, 0, slot0, st_ref[1] == 0)
+    copies(b, cb0, slot0, st_ref[1] == 0)
 
     def compute_block(i, carry):
-        slot = (slot0 + i) % 2
+        slot = (slot0 + i - cb0) % 2
         more = i + 1 < n_cb
-        copies(jnp.where(more, b, nxt_row), jnp.where(more, i + 1, 0),
+        copies(jnp.where(more, b, nxt_row),
+               jnp.where(more, i + 1, cb0_of(nxt_row)),
                1 - slot, more | nxt_live)
         copies(b, i, slot, None)
 
@@ -532,6 +589,12 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref,
         col0 = i * tokens
         col = col0 + jax.lax.broadcasted_iota(jnp.int32, (r_pad, tokens), 1)
         valid = col < kv_len
+        if windowed:
+            lo = lo_ref[b]
+            if group:  # query row r is position r // group of the segment
+                lo = lo + jax.lax.broadcasted_iota(
+                    jnp.int32, (r_pad, tokens), 0) // group
+            valid = valid & (col >= lo)
         if quant:
             # a stale block's scales are whatever its table entry points
             # at: NaN·0 would reach acc, so select them away here; on the
@@ -544,7 +607,11 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref,
             # stale V may hold NaN, and 0·NaN would reach acc: zero the
             # pool blocks of this compute block that were not fetched
             for p in range(pages):
-                @pl.when(col0 + p * blk >= kv_len)
+                dead = col0 + p * blk >= kv_len
+                if windowed:
+                    dead = dead | (col0 + (p + 1) * blk <= lo_ref[b])
+
+                @pl.when(dead)
                 def _():
                     v_buf[slot, p] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
 
@@ -583,11 +650,11 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, ks_ref, vs_ref,
             m_out[0, h] = jnp.broadcast_to(m_cur, m_out.shape[2:])
         return carry
 
-    jax.lax.fori_loop(0, n_cb, compute_block, 0)
+    jax.lax.fori_loop(cb0, n_cb, compute_block, 0)
 
     @pl.when(n_cb > 0)
     def _hand_over():
-        st_ref[0] = (slot0 + n_cb) % 2
+        st_ref[0] = (slot0 + n_cb - cb0) % 2
         st_ref[1] = nxt_live.astype(jnp.int32)
 
 
@@ -602,6 +669,8 @@ def paged_attention_partial(
     k_scale: Optional[jax.Array] = None,
     v_scale: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
+    q_pos: Optional[jax.Array] = None,
 ):
     """In-place paged decode attention over key set ``[0, lengths[b])``,
     returned as the online-softmax partial ``(acc [B,S,H,D] f32
@@ -622,6 +691,13 @@ def paged_attention_partial(
     dequant happens IN the kernel, so int8 halves the HBM bytes decode
     actually moves.  GQA (Hkv < H) walks kv heads inside the kernel body
     with the whole q group as rows of one matmul per head.
+
+    ``window`` (static) with ``q_pos [B]`` (the position of each row's
+    first query; query ``j`` of a multi-query segment sits at ``q_pos +
+    j``): a window layer's key set is ``[max(0, q_pos + j - window + 1),
+    lengths[b])`` — the kernel starts at the pool block that holds the
+    oldest visible key and reads at most ``(window - 2) // block + 2``
+    pool blocks a row, whatever the context.
 
     VMEM: two halves of ``paged_pages_per_step`` pool blocks of K and V
     (1 MB at the int8 7B shape) + the q rows, a row's scale rows and the
@@ -654,6 +730,12 @@ def paged_attention_partial(
 
     bt = block_tables.astype(jnp.int32)
     lens = jnp.minimum(lengths.astype(jnp.int32), nb * blk)
+    first = ()
+    if window is not None:
+        # a row whose window no longer reaches the pool has no key here
+        lo = q_pos.astype(jnp.int32) - (window - 1)
+        lens = jnp.where(lo < lens, lens, 0)
+        first = (jnp.where(lo < lens, lo, 0),)
     pages = paged_pages_per_step(blk, nb, hkv, d, pool_k.dtype)
     n_cb = -(-nb // pages)
 
@@ -672,9 +754,11 @@ def paged_attention_partial(
 
     scales = (() if k_scale is None
               else (scale_rows(k_scale), scale_rows(v_scale)))
-    acc, m, l = _paged_call(bt, lens, qr, slabs(pool_k), slabs(pool_v),
-                            *scales, scale=scale, d=d, pages=pages,
-                            interpret=interpret)
+    acc, m, l = _paged_call((bt, lens) + first, qr, slabs(pool_k),
+                            slabs(pool_v), *scales, scale=scale, d=d,
+                            pages=pages, interpret=interpret,
+                            group=None if window is None else (
+                                g if s > 1 else 0))
     m, l = m[..., 0], l[..., 0]
 
     # [B, Hkv, R(, D)] → [B, S, H(, D)] (drop row padding first)
@@ -689,15 +773,17 @@ def paged_attention_partial(
 # lower the kernel body once (traced per layer it cost the decode program
 # 40 s of set-up on the chip's host), and what surrounds the call still
 # fuses with each layer's own operations.
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "d", "pages", "interpret"))
-def _paged_call(bt, lens, qr, k_slabs, v_slabs, *scales, scale, d, pages,
-                interpret):
+@functools.partial(jax.jit, static_argnames=("scale", "d", "pages",
+                                             "interpret", "group"))
+def _paged_call(prefetch, qr, k_slabs, v_slabs, *scales, scale, d, pages,
+                interpret, group=None):
+    """``prefetch``: the scalar-prefetch operands — tables and lengths,
+    and for a window layer (``group`` set) each row's first position."""
     b, hkv, r_pad, _ = qr.shape
     blk = k_slabs.shape[1]
-    nb = bt.shape[1]
+    nb = prefetch[0].shape[1]
     quant = bool(scales)
-    row_map = lambda bi, bt_ref, len_ref: (bi, 0, 0, 0)
+    row_map = lambda bi, *prefetch_refs: (bi, 0, 0, 0)
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [pl.BlockSpec((1, hkv, r_pad, d), row_map), in_hbm, in_hbm]
     if quant:
@@ -714,8 +800,14 @@ def _paged_call(bt, lens, qr, k_slabs, v_slabs, *scales, scale, d, pages,
     out_specs = [pl.BlockSpec((1, hkv, r_pad, d), row_map),
                  pl.BlockSpec((1, hkv, r_pad, 128), row_map),
                  pl.BlockSpec((1, hkv, r_pad, 128), row_map)]
+    kernel = functools.partial(_paged_attn_kernel, scale=scale, blk=blk,
+                               pages=pages, n_b=nb, hkv=hkv, d=d,
+                               quant=quant, group=group)
+    if group is None:
+        full, kernel = kernel, lambda bt_ref, len_ref, *refs: full(
+            bt_ref, len_ref, None, *refs)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(prefetch),
         grid=(b,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -723,8 +815,7 @@ def _paged_call(bt, lens, qr, k_slabs, v_slabs, *scales, scale, d, pages,
                         pltpu.SMEM((2,), jnp.int32)],
     )
     return pl.pallas_call(
-        functools.partial(_paged_attn_kernel, scale=scale, blk=blk,
-                          pages=pages, n_b=nb, hkv=hkv, d=d, quant=quant),
+        kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, r_pad, d), jnp.float32),
@@ -737,7 +828,7 @@ def _paged_call(bt, lens, qr, k_slabs, v_slabs, *scales, scale, d, pages,
             dimension_semantics=("arbitrary",)),
         name="paged_attention",
         interpret=interpret,
-    )(bt, lens, qr, k_slabs, v_slabs, *scales)
+    )(*prefetch, qr, k_slabs, v_slabs, *scales)
 
 
 def paged_flash_attention(

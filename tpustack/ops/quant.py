@@ -131,13 +131,15 @@ def make_dense(quant: Optional[str], features: int, *, use_bias: bool,
 @jax.jit
 def quantize_kernel(kernel: jax.Array) -> Dict[str, jax.Array]:
     """``[in, out]`` float kernel → {kernel: int8, scale: f32[out]}
-    (symmetric absmax per output channel).  Jitted so the fp32 intermediate
-    never materialises in HBM — XLA fuses the convert into the absmax
-    reduction and the rounding."""
+    (symmetric absmax per output channel); a stack ``[experts, in, out]``
+    → scale ``[experts, out]``, a channel of each expert its own.  Jitted
+    so the fp32 intermediate never materialises in HBM — XLA fuses the
+    convert into the absmax reduction and the rounding."""
     w = kernel.astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(w), axis=0)
+    absmax = jnp.max(jnp.abs(w), axis=-2)
     scale = jnp.where(absmax > 0, absmax / 127.0, 1.0)
-    q = jnp.clip(jnp.round(w / scale), -127, 127).astype(jnp.int8)
+    q = jnp.clip(jnp.round(w / scale[..., None, :]), -127, 127).astype(
+        jnp.int8)
     return {"kernel": q, "scale": scale.astype(jnp.float32)}
 
 
@@ -172,7 +174,7 @@ def quantize_params(params: Dict, names: frozenset = QUANTIZABLE,
         for k in list(tree.keys()):
             v = tree.pop(k)
             if (isinstance(v, dict) and k in names
-                    and getattr(v.get("kernel"), "ndim", 0) == 2):
+                    and getattr(v.get("kernel"), "ndim", 0) in (2, 3)):
                 kern = v.pop("kernel")
                 q = dict(quantize_kernel(kern))
                 del kern  # refcount → bf16 kernel freed before the next one

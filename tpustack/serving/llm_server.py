@@ -18,7 +18,8 @@ but the engine is this package's JAX prefill+KV-cache generator on TPU: bf16
 whole-model on-chip (no GGUF quantisation, no ``--n-gpu-layers`` CPU split —
 v5e HBM holds 7B), ctx 4096 parity via ``LLM_CTX`` env.
 
-Env: ``LLM_PRESET`` (``qwen25_7b``|``llama2_7b``|``tiny``), ``LLM_CTX``,
+Env: ``LLM_PRESET`` (``qwen25_7b``|``llama2_7b``|``k_exaone_236b_ep8``|
+``tiny``|``tiny_moe``), ``LLM_CTX``,
 ``LLM_TP`` (tensor-parallel ways: GSPMD-shards the model over N chips,
 lifting the per-chip HBM ceiling),
 ``LLM_KV_QUANT`` (``int8`` → per-vector int8 KV cache: halves long-context
@@ -164,6 +165,15 @@ def _build_generator():
     if preset == "tiny":
         cfg = LlamaConfig.tiny(max_seq=min(ctx, 128))
         dtype = jnp.float32
+    elif preset == "tiny_moe":
+        cfg = LlamaConfig.tiny_moe(max_seq=min(ctx, 128))
+        dtype = jnp.float32
+    elif preset == "k_exaone_236b_ep8":
+        # one chip's share of the 8-chip deployment (share 0): layer kinds,
+        # routed experts and window attention on the same served path
+        cfg = dataclasses.replace(LlamaConfig.k_exaone_236b_ep8(),
+                                  max_seq=ctx)
+        dtype = jnp.bfloat16
     elif preset == "llama2_7b":
         cfg = dataclasses.replace(LlamaConfig.llama2_7b(), max_seq=ctx)
         dtype = jnp.bfloat16
@@ -502,7 +512,8 @@ class LLMServer:
         # the same arithmetic bench_llm reports offline, so the live
         # gauges and the bench can never disagree
         self._flight_arith = obs_flight.llm_wave_arith(
-            self.gen.cfg, self.gen.params, self.gen.cache_dtype)
+            self.gen.cfg, self.gen.params, self.gen.cache_dtype,
+            rows=self.max_batch)
         self._flight_chips = self._mesh_props()["devices"]
         from tpustack.obs.metrics import REGISTRY
 

@@ -84,7 +84,8 @@ def _declare(name: str, type_: type, default, doc: str) -> None:
 
 # --------------------------------------------------------------------- model
 _declare("LLM_PRESET", str, "qwen25_7b",
-         "Model preset served by llm_server (qwen25_7b | llama2_7b | tiny).")
+         "Model preset served by llm_server (qwen25_7b | llama2_7b | "
+         "llama2_70b | k_exaone_236b_ep8 | tiny | tiny_moe).")
 _declare("LLM_CTX", int, 4096,
          "Context window in tokens (llama.cpp --ctx-size parity).")
 _declare("LLM_QUANT", str, "",
