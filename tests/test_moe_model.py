@@ -95,7 +95,7 @@ def served_logits(cfg, weights, ids, n_prompt, chunk=4):
             bufs = [{k: d[k] for k in bf} for d, bf in zip(merged, bufs)]
             out.append(np.asarray(logits[0, 0]))
         pos = cur0[:, None] + jnp.arange(chunk)[None, :]
-        pool = gen._pool_scatter_body(pool, bt, bufs, keymap, pos,
+        pool = gen._pool_scatter_body(pool, bt, bufs, keymap, cur0,
                                       pos < cur + steps)
         cur += steps
     return np.stack(out)
@@ -298,8 +298,10 @@ def test_paged_window_starts_at_the_first_visible_block(ctx, seg):
         first = max(0, int(q_pos[row]) - WINDOW + 1) // blk
         pool_k[bt[row, :first]] = np.nan
         pool_v[bt[row, :first]] = np.nan
+    # (the kernel takes the pool as it rests: heads folded into lanes)
     got = paged_attention_partial(
-        q, jnp.asarray(pool_k), jnp.asarray(pool_v), jnp.asarray(bt),
+        q, jnp.asarray(pool_k).reshape(-1, blk, hkv * d),
+        jnp.asarray(pool_v).reshape(-1, blk, hkv * d), jnp.asarray(bt),
         jnp.asarray(lens), window=WINDOW, q_pos=jnp.asarray(q_pos))
     for g, w in zip(got, want):
         # rows whose window has left the pool: m = NEG_INF, l = acc = 0

@@ -30,7 +30,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tpustack.models.llama import LlamaConfig, init_kv_pool
+from tpustack.models.llama import LlamaConfig, init_kv_pool, pool_pages
 from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
 from tpustack.models.llm_generate import (Generator, SampleConfig,
                                           resolve_paged_flash)
@@ -41,7 +41,8 @@ from tpustack.ops.pallas.flash_attention import (PAGED_COMPUTE_TOKENS,
                                                  paged_attention_partial,
                                                  paged_bytes_accounting,
                                                  paged_flash_attention,
-                                                 paged_pages_per_step)
+                                                 paged_pages_per_step,
+                                                 paged_scale_rows)
 from tpustack.serving.kv_pool import KVBlockPool, PagedKVRuntime
 from tpustack.serving.speculative import SpecConfig
 
@@ -101,6 +102,18 @@ def _gather_view(x, bt):
     return g.reshape((b, nb * x.shape[1]) + x.shape[2:])
 
 
+def _at_rest(x):
+    """A pool tensor in the dense order these tests build and their
+    reference reads (K/V ``[n, blk, hkv, d]``, scales ``[n, blk, hkv]``) →
+    the layout the pool rests in and the kernel takes."""
+    return pool_pages("k" if x.ndim == 4 else "k_scale", x)
+
+
+def _scale_rows(ks, vs, bt, pk):
+    """Scale planes at rest → the lane rows the kernel reads."""
+    return (paged_scale_rows(ks, bt, pk), paged_scale_rows(vs, bt, pk))
+
+
 def _len_mask(lens, max_seq, s):
     return jnp.broadcast_to(
         jnp.arange(max_seq)[None, None, :] < lens[:, None, None],
@@ -119,7 +132,7 @@ def test_kernel_block_table_indirection_and_block0():
     ref = dot_product_attention_partial(
         q, _gather_view(pk, bt), _gather_view(pv, bt),
         mask=_len_mask(lens, max_seq, 1))
-    got = paged_attention_partial(q, pk, pv, bt, lens)
+    got = paged_attention_partial(q, _at_rest(pk), _at_rest(pv), bt, lens)
     for g, r in zip(got, ref):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                    rtol=1e-5, atol=1e-5)
@@ -134,11 +147,12 @@ def test_kernel_ragged_cur_and_zero_length():
     b, h, d = lens.shape[0], 4, pk.shape[-1]
     assert int(lens[2]) == 0 and int(lens[1]) % int(pk.shape[1])
     q = jnp.asarray(rng.randn(b, 1, h, d).astype(np.float32))
-    acc, m, l = paged_attention_partial(q, pk, pv, bt, lens)
+    acc, m, l = paged_attention_partial(q, _at_rest(pk), _at_rest(pv), bt,
+                                        lens)
     assert float(jnp.max(jnp.abs(acc[2]))) == 0.0
     assert float(jnp.max(l[2])) == 0.0
     assert float(jnp.max(m[2])) <= -1e29
-    out = paged_flash_attention(q, pk, pv, bt, lens)
+    out = paged_flash_attention(q, _at_rest(pk), _at_rest(pv), bt, lens)
     assert float(jnp.max(jnp.abs(out[2]))) == 0.0
     ref = dot_product_attention_partial(
         q, _gather_view(pk, bt), _gather_view(pv, bt),
@@ -167,8 +181,9 @@ def test_kernel_int8_dequant_in_kernel():
         q, _gather_view(pk, bt), _gather_view(pv, bt),
         mask=_len_mask(lens, max_seq, 1),
         k_scale=_gather_view(ks, bt), v_scale=_gather_view(vs, bt))
-    got = paged_attention_partial(q, pk, pv, bt, lens, k_scale=ks,
-                                  v_scale=vs)
+    got = paged_attention_partial(
+        q, _at_rest(pk), _at_rest(pv), bt, lens,
+        scale_rows=_scale_rows(_at_rest(ks), _at_rest(vs), bt, _at_rest(pk)))
     for g, r in zip(got, ref):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                    rtol=1e-4, atol=1e-5)
@@ -189,7 +204,7 @@ def test_kernel_gqa_head_mapping(h, hkv):
     ref_exp = dot_product_attention_partial(
         q, jnp.repeat(kd, rep, axis=2), jnp.repeat(vd, rep, axis=2),
         mask=_len_mask(lens, max_seq, 1))
-    got = paged_attention_partial(q, pk, pv, bt, lens)
+    got = paged_attention_partial(q, _at_rest(pk), _at_rest(pv), bt, lens)
     for g, r, re in zip(got, ref, ref_exp):
         np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                    rtol=1e-5, atol=1e-5)
@@ -213,7 +228,8 @@ def test_kernel_multi_query_verify_causal(k):
     seg_k = jnp.asarray(rng.randn(b, s, hkv, d).astype(np.float32))
     seg_v = jnp.asarray(rng.randn(b, s, hkv, d).astype(np.float32))
 
-    part_pool = paged_attention_partial(q, pk, pv, bt, lens)
+    part_pool = paged_attention_partial(q, _at_rest(pk), _at_rest(pv), bt,
+                                        lens)
     seg_causal = jnp.broadcast_to(
         jnp.arange(s)[None, None, :] <= jnp.arange(s)[None, :, None],
         (b, s, s))
@@ -298,8 +314,11 @@ def _walk_case(seed, lens, *, int8, hkv=2, g=2, s=1, d=16, poison="nan"):
 def _walk_partial(q, pool, bt, lens, kernel):
     scales = {k: pool[k] for k in ("k_scale", "v_scale") if k in pool}
     if kernel:
-        return paged_attention_partial(q, pool["k"], pool["v"], bt, lens,
-                                       **scales)
+        rest = {k: _at_rest(v) for k, v in pool.items()}
+        return paged_attention_partial(
+            q, rest["k"], rest["v"], bt, lens,
+            scale_rows=_scale_rows(rest["k_scale"], rest["v_scale"], bt,
+                                   rest["k"]) if scales else None)
     max_seq = bt.shape[1] * pool["k"].shape[1]
     return dot_product_attention_partial(
         q, _gather_view(pool["k"], bt), _gather_view(pool["v"], bt),
@@ -394,6 +413,29 @@ def test_pages_per_step_follows_the_shapes():
     assert paged_pages_per_step(8, 16, 2, 16, jnp.float32) == 16
     assert paged_pages_per_step(8, 16, 2, 16, jnp.int8) == 1
     assert paged_pages_per_step(64, 64, 64, 128, jnp.float32) == 1
+
+
+MIB = 1 << 20
+
+
+@pytest.mark.parametrize("pool_bytes,vmem,claim", [
+    (513 * 64 * 512, 128 * MIB, 112 * MIB),        # 7B int8, as served
+    (513 * 64 * 512 * 2, 128 * MIB, 112 * MIB),    # 7B, float pool
+    (1061 * 64 * 1024, 128 * MIB, None),   # K-EXAONE: never staged by XLA
+    (257 * 64 * 512, 128 * MIB, None),     # fits the 16 MiB a claim leaves
+    (513 * 64 * 512, 16 * MIB, None),      # a v4 core: nothing to claim
+    (513 * 64 * 512, None, None),          # off the TPU
+], ids=["7b_int8", "7b_float", "k_exaone", "half_pool", "v4", "no_tpu"])
+def test_vmem_claim_only_where_it_pays(pool_bytes, vmem, claim):
+    """The paged call claims all of the core's VMEM but the default 16 MiB
+    exactly where XLA would stage its pool tensors there and the claim can
+    stop it: one pool tensor does not fit what the claim leaves, two fit
+    what it takes.  The core's size comes from the device."""
+    from tpustack.ops.pallas.flash_attention import (core_vmem_bytes,
+                                                     paged_vmem_claim)
+
+    assert paged_vmem_claim(pool_bytes, vmem) == claim
+    assert core_vmem_bytes() is None       # this process runs on the CPU
 
 
 def test_bytes_accounting_inplace_strictly_fewer():

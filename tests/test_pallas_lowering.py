@@ -18,7 +18,8 @@ import jax.numpy as jnp
 import pytest
 
 from tpustack.ops.pallas.flash_attention import (flash_attention,
-                                                 paged_attention_partial)
+                                                 paged_attention_partial,
+                                                 paged_scale_rows)
 from tpustack.ops.pallas.moe_gmm import moe_gmm
 
 
@@ -50,16 +51,19 @@ def _paged_call(model, int8_pool, s, sharding=None, window=None):
     ``window`` a window layer's call (the last operand: its positions)."""
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
     b, h, hkv, d, blk, nb, n_pool, qdt = PAGED_MODELS[model]
-    pool = sds((n_pool, blk, hkv, d), jnp.int8 if int8_pool else qdt)
-    scales = sds((n_pool, blk, hkv), jnp.float32) if int8_pool else None
+    # the pool as it rests (llama.init_kv_pool)
+    pool = sds((n_pool, blk, hkv * d), jnp.int8 if int8_pool else qdt)
+    scales = sds((n_pool, hkv * blk), jnp.float32) if int8_pool else None
     args = [sds((b, s, h, d), qdt), pool, pool,
             sds((b, nb), jnp.int32), sds((b,), jnp.int32), scales, scales,
             sds((b,), jnp.int32) if window else None]
 
     def fn(q, pk, pv, bt, lens, ks, vs, q_pos):
-        return paged_attention_partial(q, pk, pv, bt, lens, k_scale=ks,
-                                       v_scale=vs, interpret=False,
-                                       window=window, q_pos=q_pos)
+        rows = (None if ks is None else (paged_scale_rows(ks, bt, pk),
+                                         paged_scale_rows(vs, bt, pv)))
+        return paged_attention_partial(q, pk, pv, bt, lens, scale_rows=rows,
+                                       interpret=False, window=window,
+                                       q_pos=q_pos)
 
     return fn, args
 
@@ -100,24 +104,14 @@ def test_paged_attention_compiles_for_v5e(one_v5e_chip, model, int8_pool, s):
     """The whole compile, Mosaic included: the kernel copies pool blocks
     itself, and Mosaic refuses a copy whose slice is not whole tiles (a
     ``[64, 4]`` scale page out of a ``pl.ANY`` operand was) only here."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     fn, args = _paged_call(model, int8_pool, s, sharding=one_v5e_chip)
     _compile_for_described_chip(fn, args)
 
 
 def _compile_for_described_chip(fn, args):
-    from jax.experimental.compilation_cache import compilation_cache
+    from tpustack.utils.hlo_text import compile_program
 
-    # an entry written for a described chip cannot be read back without one
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        return jax.jit(fn).lower(*args).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
-        compilation_cache.reset_cache()
+    return compile_program(jax.jit(fn), args)
 
 
 @pytest.mark.parametrize("s", [1, 5], ids=["decode", "verify_k4"])
@@ -127,6 +121,76 @@ def test_paged_window_layer_compiles_for_v5e(one_v5e_chip, s):
     fn, args = _paged_call("k_exaone_ep8", True, s, sharding=one_v5e_chip,
                            window=128)
     _compile_for_described_chip(fn, args)
+
+
+# --------------------------------------------- the pool rests as it is read
+@pytest.mark.parametrize("program,pool_share", [
+    ("decode", 1), ("decode", 2), ("admit", 1)],
+    ids=["decode", "decode_half_pool", "admit"])
+@pytest.mark.parametrize("kv", ["int8", "float"])
+@pytest.mark.parametrize("preset", ["k_exaone_236b_ep8", "qwen25_7b"])
+def test_no_compiled_program_relays_the_pool(one_v5e_chip, preset, kv,
+                                             program, pool_share):
+    """The pool rests in the layout its consumers take (PR 29): compiled for
+    a described v5e, neither a 16-step decode chunk (``flash=True``) nor a
+    1-row 512-bucket admission holds, outside its scan and outside fusions,
+    a ``copy`` / ``reshape`` / ``slice`` / ``pad`` whose result is shaped
+    like the pool and reaches a quarter of a K/V pool tensor — at the pool
+    the benchmark's two configurations serve (their KV shapes, 4 x 128 and
+    8 x 128 heads, are what the layout has to fit; two layers of each at
+    its true widths compile in 5-10 s) and at half of it.  On the tree
+    before, every chunk re-tiled each K/V tensor whole for the kernel (a
+    ``reshape``) and padded each scale plane's 4 or 8 heads to 128 lanes
+    for the scatter (a ``copy`` 32 or 16 times the plane), and so did every
+    admission: 3.2 GB a chunk at 28 layers.
+
+    What XLA stages of the pool INSIDE the scan is the next test's."""
+    from tpustack.utils.hlo_text import (SERVED_SLOTS, compile_program,
+                                         pool_relayouts, serving_config,
+                                         serving_program)
+
+    cfg = serving_config(preset, 2, kv)
+    slots, block = SERVED_SLOTS[preset], 64
+    n_blocks = slots * (cfg.max_seq // block) // pool_share + 1
+    text = compile_program(*serving_program(
+        program, cfg, one_v5e_chip, rows=slots if program == "decode" else 1,
+        pool_blocks=n_blocks, block=block)).as_text()
+    assert "paged_attention" in text or program == "admit"
+    tensor = (n_blocks * block * cfg.n_kv_heads * cfg.head_dim
+              * (1 if kv == "int8" else 2))
+    found = pool_relayouts(text, n_blocks, block, tensor // 4)
+    assert not found, "\n".join(i.line[:200] for i in found[:6])
+
+
+@pytest.mark.parametrize("preset,kv", [
+    ("qwen25_7b", "int8"), ("qwen25_7b", "float"),
+    ("k_exaone_236b_ep8", "int8")])
+def test_no_decode_step_stages_the_pool(one_v5e_chip, preset, kv):
+    """The pool stays where it rests while a chunk's steps run: compiled
+    for a described v5e at 8 layers (XLA's memory-space assignment shows
+    its choice from 8; at 2 it stages nothing), no step of the decode scan
+    holds a copy of a whole pool tensor.  XLA takes the paged call to read
+    its operands whole and, with VMEM to spare, stages 16-32 MiB pool
+    tensors in VMEM ahead of every call (7 of 16 at this size without
+    ``paged_vmem_claim``: 0.4 GB a step at 28 layers); the 7B pools are in
+    that range, int8 and float, and the claim keeps them out; K-EXAONE's
+    64 MiB tensors were never staged, and its calls claim nothing.  A
+    pool tensor of 16 MiB or less (half the served pool) is out of the
+    claim's reach and IS staged: ``paged_vmem_claim``'s docstring."""
+    from tpustack.utils.hlo_text import (SERVED_SLOTS, compile_program,
+                                         pool_relayouts, serving_config,
+                                         serving_program)
+
+    cfg = serving_config(preset, 8, kv)
+    slots, block = SERVED_SLOTS[preset], 64
+    n_blocks = slots * (cfg.max_seq // block) + 1
+    text = compile_program(*serving_program(
+        "decode", cfg, one_v5e_chip, rows=slots, pool_blocks=n_blocks,
+        block=block)).as_text()
+    tensor = (n_blocks * block * cfg.n_kv_heads * cfg.head_dim
+              * (1 if kv == "int8" else 2))
+    found = pool_relayouts(text, n_blocks, block, tensor // 4, in_scan=True)
+    assert not found, "\n".join(i.line[:200] for i in found[:6])
 
 
 # moe_gmm at K-EXAONE's share (16 held experts of [6144, 2048]): (tokens a
@@ -200,7 +264,7 @@ def _kernel_programs():
     q = _sds((1, 256, 4, 128), jnp.bfloat16)
     kv = _sds((1, 256, 2, 128), jnp.bfloat16)
     scalar = _sds((), jnp.int32)
-    pool = _sds((33, 8, 2, 128), jnp.bfloat16)
+    pool = _sds((33, 8, 2 * 128), jnp.bfloat16)
     return {
         "flash_panel": (lambda q, k, v: flash_attention(
             q, k, v, causal=True, interpret=False), (q, kv, kv)),

@@ -121,6 +121,20 @@ def test_paged_kernel_tiny_preset_compiles_on_chip(tpu_backend):
         atol=THRESH["flash_vs_xla_on_chip_atol"])
 
 
+@pytest.mark.parametrize("kvh,blk,kv", [
+    (1, 8, "int8"), (4, 32, "int8"), (8, 32, "float")])
+def test_pool_writers_spell_the_dense_cache_on_chip(tpu_backend, kvh, blk,
+                                                    kv):
+    """The paged pool's page writes (an admission's splice, a decode
+    chunk's flush) then its gather, bit for bit against the dense cache on
+    the chip's own gather and scatter — ``tests/test_pool_layout.py``'s
+    cases, which the CPU suite runs on the CPU only."""
+    import test_pool_layout as layout
+
+    layout.test_admission_splice_spells_the_dense_cache(kvh, blk, kv)
+    layout.test_chunk_flush_matches_the_dense_flush(kvh, blk, kv)
+
+
 def test_sd15_tiny_unet_step_full_precision_vs_cpu(tpu_backend):
     """One UNet CFG forward at full precision: chip vs CPU within f32
     rounding — the per-op version of verify_hw's whole-pipeline proof."""

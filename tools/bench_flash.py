@@ -62,6 +62,7 @@ def paged_sweep(partial_fn, log, *, calls: int = 64, trace_dir=None):
     import numpy as np
 
     from benchmark.readers import trace
+    from tpustack.ops.pallas.flash_attention import paged_scale_rows
     from tpustack.utils.peaks import measurement_peaks
 
     hbm_bytes_per_s = measurement_peaks(jax.devices()[0])[1]
@@ -71,18 +72,20 @@ def paged_sweep(partial_fn, log, *, calls: int = 64, trace_dir=None):
     n_pool = b * nb + 1
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(b, 1, h, d), jnp.bfloat16)
-    pk = jnp.asarray(rng.randint(-127, 128, (n_pool, blk, hkv, d)), jnp.int8)
-    pv = jnp.asarray(rng.randint(-127, 128, (n_pool, blk, hkv, d)), jnp.int8)
-    ks = jnp.asarray(rng.rand(n_pool, blk, hkv) * 0.02 + 1e-3, jnp.float32)
-    vs = jnp.asarray(rng.rand(n_pool, blk, hkv) * 0.02 + 1e-3, jnp.float32)
+    # the pool as it rests (llama.init_kv_pool): heads folded into lanes,
+    # scales token-minor; their lane rows made once, as a decode chunk does
+    pk = jnp.asarray(rng.randint(-127, 128, (n_pool, blk, hkv * d)), jnp.int8)
+    pv = jnp.asarray(rng.randint(-127, 128, (n_pool, blk, hkv * d)), jnp.int8)
+    ks = jnp.asarray(rng.rand(n_pool, hkv * blk) * 0.02 + 1e-3, jnp.float32)
+    vs = jnp.asarray(rng.rand(n_pool, hkv * blk) * 0.02 + 1e-3, jnp.float32)
     bt = jnp.asarray(rng.permutation(np.arange(1, n_pool)).reshape(b, nb),
                      jnp.int32)
+    rows_kv = (paged_scale_rows(ks, bt, pk), paged_scale_rows(vs, bt, pv))
 
     @jax.jit
     def chain(q, lens):
         def step(qq, _):
-            acc, m, l = partial_fn(qq, pk, pv, bt, lens, k_scale=ks,
-                                   v_scale=vs)
+            acc, m, l = partial_fn(qq, pk, pv, bt, lens, scale_rows=rows_kv)
             # the next call waits for this one; the values do not move
             return qq + (0 * acc[:, :, :, :1]).astype(qq.dtype), None
         return jax.lax.scan(step, q, None, length=calls)[0]
@@ -131,6 +134,7 @@ def _paged_mode(args) -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from tpustack.models.llama import pool_pages
     from tpustack.ops.attention import dot_product_attention_partial
     from tpustack.ops.pallas.flash_attention import (paged_attention_partial,
                                                      paged_bytes_accounting)
@@ -178,8 +182,9 @@ def _paged_mode(args) -> int:
     gather_partial = lambda qq: dense_partial(qq, dense_view(pool_k),
                                               dense_view(pool_v))
 
+    rest_k, rest_v = pool_pages("k", pool_k), pool_pages("v", pool_v)
     inplace_partial = lambda qq: paged_attention_partial(
-        qq, pool_k, pool_v, bt, lens)
+        qq, rest_k, rest_v, bt, lens)
 
     # (acc, m, l) compared as what they are for: the normalised output and
     # the two merge statistics (an unnormalised acc over 4k bf16 tokens

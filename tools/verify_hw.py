@@ -292,6 +292,7 @@ def paged_outputs(vec: dict, dtype, kernel: bool) -> np.ndarray:
     math).  Float inputs are cast to ``dtype`` first."""
     import jax.numpy as jnp
 
+    from tpustack.models.llama import pool_pages
     from tpustack.ops.attention import dot_product_attention_partial
     from tpustack.ops.pallas.flash_attention import paged_flash_attention
 
@@ -302,7 +303,11 @@ def paged_outputs(vec: dict, dtype, kernel: bool) -> np.ndarray:
     scales = ({"k_scale": jnp.asarray(vec["ks"]),
                "v_scale": jnp.asarray(vec["vs"])} if "ks" in vec else {})
     if kernel:
-        out = paged_flash_attention(q, pk, pv, bt, lens, **scales)
+        # the vectors are in the dense order the reference reads; the
+        # kernel takes the pool as it rests
+        out = paged_flash_attention(
+            q, pool_pages("k", pk), pool_pages("v", pv), bt, lens,
+            **{k: pool_pages(k, v) for k, v in scales.items()})
         return np.asarray(out, np.float32)
     b, nb = bt.shape
     view = lambda x: jnp.take(x, bt.reshape(-1), axis=0).reshape(

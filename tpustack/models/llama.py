@@ -300,13 +300,13 @@ class LlamaAttention(nn.Module):
             # which it collapses to exactly at s == 1.
             cur0, t = cache_index      # [B] slot frontiers, scalar chunk step
             # the frozen main-cache view is either a dense per-slot line
-            # (k/v keys) or the paged-flash IN-PLACE pool view (pk/pv +
-            # block table bt, TPUSTACK_PAGED_FLASH): same key set, same
+            # (k/v keys) or the paged-flash IN-PLACE pool view (pk/pv, an
+            # int8 pool's scale rows pk_rows/pv_rows, + block table bt,
+            # TPUSTACK_PAGED_FLASH): same key set, same
             # masking semantics, different storage — see the partial
             # branch below
             paged_flash = "pk" in kv_cache
-            quantized = ("k_scale" in kv_cache
-                         or "pk_scale" in kv_cache)
+            quantized = "k_scale" in kv_cache or "pk_rows" in kv_cache
             cbuf_len = kv_cache["ck"].shape[1]
             with jax.named_scope("kv_write"):
                 if quantized:
@@ -366,8 +366,9 @@ class LlamaAttention(nn.Module):
 
                     part_main = paged_attention_partial(
                         q, kv_cache["pk"], kv_cache["pv"], kv_cache["bt"],
-                        cur0, k_scale=kv_cache.get("pk_scale"),
-                        v_scale=kv_cache.get("pv_scale"),
+                        cur0, scale_rows=(
+                            (kv_cache["pk_rows"], kv_cache["pv_rows"])
+                            if quantized else None),
                         **({} if window is None else {
                             "window": window, "q_pos": positions[:, 0]}))
                 else:
@@ -637,7 +638,7 @@ class LlamaModel(nn.Module):
         return logits, new_caches
 
 
-def _shard_kv(caches, cfg: "LlamaConfig", mesh):
+def _shard_kv(caches, cfg: "LlamaConfig", mesh, pool: bool = False):
     """Serving-KV head-axis sharding (``parallel.sharding.shard_kv_tree``):
     host call sites pass the tp mesh so every cache/pool/buffer tensor
     lands split over its kv-head axis — the per-chip KV HBM bill divides
@@ -648,7 +649,7 @@ def _shard_kv(caches, cfg: "LlamaConfig", mesh):
         return caches
     from tpustack.parallel.sharding import shard_kv_tree
 
-    return shard_kv_tree(caches, mesh, cfg.n_kv_heads)
+    return shard_kv_tree(caches, mesh, cfg.n_kv_heads, pool=pool)
 
 
 def init_kv_caches(cfg: LlamaConfig, batch: int, dtype=jnp.bfloat16,
@@ -669,18 +670,27 @@ def init_kv_caches(cfg: LlamaConfig, batch: int, dtype=jnp.bfloat16,
 
 def init_kv_pool(cfg: LlamaConfig, n_blocks: int, block: int,
                  dtype=jnp.bfloat16, mesh=None):
-    """Per-layer PAGED KV pool tensors: ``[n_blocks, block, kv_heads,
-    head_dim]`` (+ per-vector scales when the cache is int8).  The paged
-    serving substrate (``tpustack.serving.kv_pool``): a sequence's cache
-    line is a block table into these tensors instead of a private
-    ``[max_seq]`` row, so HBM holds exactly the tokens in flight plus the
-    refcounted prefix cache — not ``slots x max_seq`` regardless of use.
-    Block 0 is reserved (idle table entries point at it; nothing writes
-    it), mirroring the dense cache's same-keys layout so the gather view
-    is attention-compatible as-is."""
-    shape = (n_blocks, block, cfg.n_kv_heads, cfg.head_dim)
+    """Per-layer PAGED KV pool tensors, AT REST in the layout their hot
+    consumers take, so no compiled program re-lays them: K/V ``[n_blocks,
+    block, kv_heads * head_dim]`` — a pool block is the ``[block, lanes]``
+    slab ``paged_attention`` copies whole and a token is one lane row the
+    scatter writes — and, when the cache is int8, per-vector scales
+    TOKEN-MINOR and folded ``[n_blocks, kv_heads * block]`` — a block's
+    page is one lane row, a head's ``block`` scales side by side (no view
+    of a plane has a minor dimension of ``kv_heads``: under TPU tiling
+    that is 4 live lanes of 128, and a minor dimension of ``block`` alone
+    makes the chip lay the plane out blocks-minor).  ``pool_lines``,
+    ``pool_pages`` and ``pool_rows`` convert between this and the dense
+    cache's ``[..., tokens, kv_heads, head_dim]`` / ``[..., tokens,
+    kv_heads]``.  The paged serving substrate
+    (``tpustack.serving.kv_pool``): a sequence's cache line is a block
+    table into these tensors instead of a private ``[max_seq]`` row, so
+    HBM holds exactly the tokens in flight plus the refcounted prefix
+    cache — not ``slots x max_seq`` regardless of use.  Block 0 is
+    reserved (idle table entries point at it; nothing writes it)."""
+    shape = (n_blocks, block, cfg.n_kv_heads * cfg.head_dim)
     if cfg.kv_quant == "int8":
-        sshape = shape[:-1]
+        sshape = (n_blocks, cfg.n_kv_heads * block)
         pool = [{"k": jnp.zeros(shape, jnp.int8),
                  "k_scale": jnp.zeros(sshape, jnp.float32),
                  "v": jnp.zeros(shape, jnp.int8),
@@ -689,7 +699,43 @@ def init_kv_pool(cfg: LlamaConfig, n_blocks: int, block: int,
     else:
         pool = [{"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
                 for _ in range(cfg.n_layers)]
-    return _shard_kv(pool, cfg, mesh)
+    return _shard_kv(pool, cfg, mesh, pool=True)
+
+
+def is_scale_key(key: str) -> bool:
+    """A per-vector scale plane of an int8 cache, pool or buffer."""
+    return key.endswith("_scale")
+
+
+def pool_lines(key: str, blocks: jax.Array, n_kv_heads: int) -> jax.Array:
+    """Pool blocks ``[..., n, block, kvh * hd]`` (scales ``[..., n, kvh *
+    block]``) → the dense cache line they spell: ``[..., n * block, kvh,
+    hd]`` (scales ``[..., n * block, kvh]``)."""
+    if is_scale_key(key):
+        lead, n = blocks.shape[:-2], blocks.shape[-2]
+        pages = blocks.reshape(lead + (n, n_kv_heads, -1))
+        return jnp.swapaxes(pages, -1, -2).reshape(lead + (-1, n_kv_heads))
+    lead, (n, blk) = blocks.shape[:-3], blocks.shape[-3:-1]
+    return blocks.reshape(lead + (n * blk, n_kv_heads, -1))
+
+
+def pool_pages(key: str, blocks: jax.Array) -> jax.Array:
+    """Whole blocks in the dense order ``[n, block, kvh, hd]`` (scales
+    ``[n, block, kvh]``) → the pages they rest as: ``pool_lines``'s
+    inverse, for what builds a pool by hand (benches, tests, vectors)."""
+    n, blk = blocks.shape[:2]
+    if is_scale_key(key):
+        return jnp.swapaxes(blocks, 1, 2).reshape(n, -1)
+    return blocks.reshape(n, blk, -1)
+
+
+def pool_rows(key: str, lines: jax.Array) -> jax.Array:
+    """Dense cache values ``[..., tokens, kvh, hd]`` → the pool's token
+    rows ``[..., tokens, kvh * hd]``; a scale's ``[..., tokens, kvh]`` is
+    one already."""
+    if is_scale_key(key):
+        return lines
+    return lines.reshape(lines.shape[:-2] + (-1,))
 
 
 def init_chunk_bufs(cfg: LlamaConfig, batch: int, chunk: int,

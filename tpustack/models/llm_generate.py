@@ -27,7 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpustack.models.llama import LlamaConfig, LlamaModel, init_kv_caches
+from tpustack.models.llama import (LlamaConfig, LlamaModel, init_kv_caches,
+                                   is_scale_key, pool_lines, pool_rows)
 from tpustack.utils import get_logger
 
 log = get_logger("models.llm_generate")
@@ -77,6 +78,46 @@ def _advance_keys(keys):
     matter when the request was admitted or who its batch peers are."""
     split = jax.vmap(jax.random.split)(keys)          # [B, 2, 2]
     return split[:, 0], split[:, 1]
+
+
+def _page_align(x, shift, blk: int, nj: int, axis: int, fill):
+    """A run of tokens along ``axis`` of ``x [R, ...]`` laid where its
+    pages hold it: ``[R, .., L, ..] → [R, .., nj * blk, ..]``, token ``t``
+    of row ``r`` at ``t + shift[r]`` (``shift < blk``), ``fill`` around.
+    ``shift`` None: the runs start on a page's first slot."""
+    span, L = nj * blk, x.shape[axis]
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, span - L) if shift is None else (blk, span - L)
+    x = jnp.pad(x, pad, constant_values=fill)
+    if shift is None:
+        return x
+    return jax.vmap(lambda row, s: jax.lax.dynamic_slice_in_dim(
+        row, blk - s, span, axis - 1))(x, shift)
+
+
+# Jitted by itself, like the paged kernel's call: a program's 4 x layers
+# pool tensors then trace and lower this body once a shape, not once each.
+@jax.jit
+def _write_pages(dst, rows, page, live, shift):
+    """One pool tensor's page writes (``_pool_scatter_body``): ``dst`` K/V
+    ``[N, blk, kvh·hd]`` with token rows ``rows [R, L, kvh·hd]``, or a
+    scale plane ``[N, kvh·blk]`` with ``rows [R, kvh, L]``; ``page [R·nj]``
+    the pages' pool ids (out of range: dropped), ``live [R·nj, blk]`` the
+    tokens to lay over what a page holds, ``shift [R]`` each run's offset
+    in its first page (None: none)."""
+    R, blk = rows.shape[0], live.shape[1]
+    nj = live.shape[0] // R
+    old = jnp.take(dst, page, axis=0, mode="clip")            # [R·nj, page]
+    if dst.ndim == 2:                   # a page is [kvh, blk], folded
+        new = _page_align(rows, shift, blk, nj, 2, 0)         # [R, kvh, span]
+        new = new.reshape(R, -1, nj, blk).swapaxes(1, 2)
+        new = jnp.where(live[:, None, :], new.reshape(R * nj, -1, blk),
+                        old.reshape(R * nj, -1, blk))
+    else:
+        new = _page_align(rows, shift, blk, nj, 1, 0)
+        new = jnp.where(live[:, :, None], new.reshape(R * nj, blk, -1), old)
+    return dst.at[page].set(new.reshape(old.shape), mode="drop",
+                            unique_indices=True)
 
 
 class Generator:
@@ -837,17 +878,22 @@ class Generator:
     # --------------------------------------------------------- paged KV pool
     #
     # Device half of the paged KV substrate (tpustack.serving.kv_pool):
-    # every layer's K/V lives in pool tensors [n_blocks, block, ...] and a
+    # every layer's K/V lives in pool tensors [n_blocks, block, kvh*hd]
+    # (int8 scales [n_blocks, kvh*block]: llama.init_kv_pool — the layout
+    # the paged kernel and the page writes below take as it rests) and a
     # slot's logical cache line is a BLOCK TABLE (bt [B, max_seq // block],
     # int32 pool indices; the reserved block 0 backs idle entries).  The
     # compute view is a gather through the table — elementwise equal to
     # what the dense cache line would hold, so the attention bodies above
     # run unchanged and greedy outputs are byte-identical paged-vs-dense.
-    # Writes scatter ONLY the freshly produced K/V (an admission's prefill
-    # rows, a chunk's buffers) through the table, with positions outside a
-    # row's allocation dropped via out-of-range indices — shared prefix
-    # blocks (refcount > 1) are never written after their prefill, which
-    # is what makes cross-request sharing safe.
+    # Writes land ONLY the freshly produced K/V (an admission's prefill
+    # rows, a chunk's buffers) through the table, a whole page at a time
+    # (the pages a run touches are read, the run's valid tokens laid over
+    # them, and written back: _pool_scatter_body), with positions outside a
+    # row's allocation left as they were and pages without a valid token
+    # dropped via out-of-range ids — shared prefix blocks (refcount > 1)
+    # are never written after their prefill, which is what makes
+    # cross-request sharing safe.
     #
     # Reallocation hazard (freed blocks reassigned while chunks are in
     # flight): dispatches execute in order on the device stream, and the
@@ -859,64 +905,101 @@ class Generator:
 
     @jax.named_scope("kv_read")
     def _pool_gather_body(self, pool, bt):
-        """Traced: pool tensors ``[N, blk, *tail]`` → dense per-row view
-        ``[B, max_seq, *tail]`` via block tables ``bt [B, nb]``."""
-        B, nb = bt.shape
+        """Traced: pool tensors (``init_kv_pool``'s layout: K/V ``[N, blk,
+        kvh·hd]``, scales ``[N, kvh·blk]``) → dense per-row view ``[B,
+        max_seq, kvh, hd]`` / ``[B, max_seq, kvh]`` via block tables ``bt
+        [B, nb]`` — what a dense cache line would hold, bit for bit."""
+        kvh = self.cfg.n_kv_heads
 
-        def ga(x):
-            g = jnp.take(x, bt.reshape(-1), axis=0)     # [B*nb, blk, *tail]
-            return g.reshape((B, nb * x.shape[1]) + x.shape[2:])
+        def ga(key, x):
+            blocks = jnp.take(x, bt, axis=0, mode="clip")   # [B, nb, *page]
+            return pool_lines(key, blocks, kvh)
 
-        return [{k: ga(v) for k, v in layer.items()} for layer in pool]
+        return [{k: ga(k, v) for k, v in layer.items()} for layer in pool]
 
     @staticmethod
     def _pool_views(pool, bt):
         """Per-layer IN-PLACE pool views for the paged-flash attention
         branch (``TPUSTACK_PAGED_FLASH``): the pool tensors ride into the
-        attention dict unchanged under ``pk``/``pv`` (+ scales) keys next
-        to the block table, and ``LlamaAttention`` reads them in place
-        through the scalar-prefetch Pallas kernel — the zero-copy
-        replacement for ``_pool_gather_body``'s dense ``[B, max_seq]``
-        materialisation (and the whole point of the paged-flash path:
-        the gather's read+write copy never happens)."""
+        attention dict unchanged under ``pk``/``pv`` next to the block
+        table, and ``LlamaAttention`` reads them in place through the
+        scalar-prefetch Pallas kernel — the zero-copy replacement for
+        ``_pool_gather_body``'s dense ``[B, max_seq]`` materialisation
+        (and the whole point of the paged-flash path: the gather's
+        read+write copy never happens).  An int8 pool's scales ride as the
+        kernel's lane rows (``pk_rows``/``pv_rows``), gathered through the
+        tables HERE, once a chunk: the pool is frozen while the chunk's
+        steps run, and the compiler hoists only part of that gather out
+        of the scan when it is left inside the layer."""
+        from tpustack.ops.pallas.flash_attention import paged_scale_rows
+
         def view(layer):
             v = {"pk": layer["k"], "pv": layer["v"], "bt": bt}
             if "k_scale" in layer:
-                v["pk_scale"] = layer["k_scale"]
-                v["pv_scale"] = layer["v_scale"]
+                with jax.named_scope("kv_read"):
+                    v["pk_rows"] = paged_scale_rows(layer["k_scale"], bt,
+                                                    layer["k"])
+                    v["pv_rows"] = paged_scale_rows(layer["v_scale"], bt,
+                                                    layer["v"])
             return v
 
         return [view(layer) for layer in pool]
 
     @staticmethod
     @jax.named_scope("kv_write")
-    def _pool_scatter_body(pool, bt_rows, src_layers, keymap, positions,
-                           valid):
-        """Traced: scatter per-row values at global cache ``positions
-        [R, L]`` (``valid`` selects real entries) into the pool through
-        ``bt_rows [R, nb]``.  ``src_layers`` arrays are ``[R, L, *tail]``;
-        ``keymap`` maps pool key → source key.  Invalid entries get
-        UNIQUE out-of-range indices and ``mode='drop'``, so the scatter
-        stays unique-indices (vectorisable) and the reserved block 0 is
-        never written."""
-        blk = pool[0]["k"].shape[1]
-        R, L = positions.shape
+    def _pool_scatter_body(pool, bt_rows, src_layers, keymap, start, valid):
+        """Traced: write each row's run of ``L`` fresh positions ``[start[r],
+        start[r] + L)`` (``valid [R, L]`` selects the real ones; ``start``
+        ``[R]`` or one traced scalar, or one STATIC page-aligned int) into the
+        pool through ``bt_rows [R, nb]``.  ``src_layers`` arrays are dense
+        cache values ``[R, L, kvh, hd]`` / ``[R, L, kvh]``; ``keymap`` maps
+        pool key → source key.
+
+        The unit of a write is the unit the pool rests in: a WHOLE PAGE
+        (``[block, kvh·hd]`` of K/V, the ``[kvh·block]`` lane row of a scale
+        plane).  A run touches at most ``(L - 1) // block + 2`` pages a row:
+        they are read, the run's valid tokens selected over what they hold,
+        and written back — a dozen page updates for a 512-token admission
+        where a token-row scatter makes 512 (0.1-0.2 µs each on a v5e, one
+        after another: 88 → 7 µs a K/V tensor, 71 → 6 µs a scale plane), and
+        no view of a plane with ``kvh`` minor, which the compiler answers
+        with a 17 MB padded copy of it.  Pages without a valid token get
+        UNIQUE out-of-range ids and ``mode='drop'``: the scatter stays
+        unique-indices, the reserved block 0 and shared prefix blocks
+        (never valid here) are never written.  The tables and frontiers
+        have to be traced values, as the engine's are: from page ids it
+        can fold to constants XLA for the TPU drops the whole scatter
+        (seen on a v5e, PR 29; ``tests/test_pool_layout.py``)."""
+        n_blocks, blk = pool[0]["k"].shape[:2]
+        R, L = valid.shape
         nb = bt_rows.shape[1]
-        blk_idx = jnp.take_along_axis(
-            bt_rows, jnp.clip(positions // blk, 0, nb - 1), axis=1)
-        flat = blk_idx * blk + positions % blk            # [R, L]
-        oob_base = pool[0]["k"].shape[0] * blk
-        oob = oob_base + jnp.arange(R * L, dtype=flat.dtype).reshape(R, L)
-        idx = jnp.where(valid, flat, oob).reshape(-1)
+        if isinstance(start, int):
+            # a static start on a page's first slot (an admission's 0): no
+            # run needs shifting into place, none spills into a further page
+            assert start % blk == 0, (start, blk)
+            nj, shift = -(-L // blk), None
+            j = jnp.broadcast_to(start // blk + jnp.arange(nj), (R, nj))
+        else:
+            start = jnp.broadcast_to(start, (R,))
+            nj = (L - 1) // blk + 2 if L > 1 else 1
+            j = start[:, None] // blk + jnp.arange(nj)[None, :]       # [R, nj]
+            shift = start % blk
+        live = _page_align(valid, shift, blk, nj, 1, False)
+        live = live.reshape(R * nj, blk)
+        page = jnp.take_along_axis(bt_rows, jnp.clip(j, 0, nb - 1), axis=1)
+        oob = n_blocks + jnp.arange(R * nj, dtype=page.dtype)
+        page = jnp.where(live.any(axis=1) & (j < nb).reshape(-1),
+                         page.reshape(-1), oob)
 
-        def sc(dst, src):
-            fd = dst.reshape((dst.shape[0] * dst.shape[1],) + dst.shape[2:])
-            fd = fd.at[idx].set(
-                src.reshape((-1,) + src.shape[2:]).astype(dst.dtype),
-                mode="drop", unique_indices=True)
-            return fd.reshape(dst.shape)
+        def sc(key, dst, src):
+            rows = pool_rows(key, src).astype(dst.dtype)
+            if is_scale_key(key):
+                # tokens minor from the start: a [.., tokens, kvh] temporary
+                # pads kvh to 128 lanes
+                rows = rows.swapaxes(1, 2)
+            return _write_pages(dst, rows, page, live, shift)
 
-        return [{k: sc(layer[k], srcl[keymap.get(k, k)]) for k in layer}
+        return [{k: sc(k, layer[k], srcl[keymap.get(k, k)]) for k in layer}
                 for layer, srcl in zip(pool, src_layers)]
 
     @jax.named_scope("kv_write")
@@ -937,11 +1020,8 @@ class Generator:
                 x, idx, (x.shape[0], bucket) + x.shape[2:])
 
         src = [{k: sl(v) for k, v in layer.items()} for layer in caches]
-        R = bt_rows.shape[0]
-        positions = start + jnp.broadcast_to(jnp.arange(bucket), (R, bucket))
-        valid = positions < limits[:, None]
-        return self._pool_scatter_body(pool, bt_rows, src, {}, positions,
-                                       valid)
+        valid = start + jnp.arange(bucket)[None, :] < limits[:, None]
+        return self._pool_scatter_body(pool, bt_rows, src, {}, start, valid)
 
     @functools.partial(jax.jit, static_argnums=(0, 5), donate_argnums=(1,))
     def _insert_rows_paged(self, pool, bt_rows, row_caches, start,
@@ -987,13 +1067,12 @@ class Generator:
         toks, last, cur_end, bufs, keys, moe = self._decode_cont_body(
             params, first_tok, cur, active, view,
             keys, temperature, top_k, greedy, n_steps)
-        B = bt.shape[0]
-        positions = cur[:, None] + jnp.arange(n_steps)[None, :]
-        valid = positions < cur_end[:, None]
+        valid = (cur[:, None] + jnp.arange(n_steps)[None, :]
+                 < cur_end[:, None])
         pool = self._pool_scatter_body(
             pool, bt, bufs,
             {"k": "ck", "v": "cv", "k_scale": "ck_scale",
-             "v_scale": "cv_scale"}, positions, valid)
+             "v_scale": "cv_scale"}, cur, valid)
         return toks, last, cur_end, pool, keys, moe
 
     # --------------------------------------------------- speculative verify
@@ -1164,13 +1243,12 @@ class Generator:
             params, first_tok, draft, draft_len, cur, active,
             view, keys, temperature, top_k,
             greedy, n_draft)
-        S = n_draft + 1
-        positions = cur[:, None] + jnp.arange(S)[None, :]
-        valid = positions < cur_end[:, None]
+        valid = (cur[:, None] + jnp.arange(n_draft + 1)[None, :]
+                 < cur_end[:, None])
         pool = self._pool_scatter_body(
             pool, bt, bufs,
             {"k": "ck", "v": "cv", "k_scale": "ck_scale",
-             "v_scale": "cv_scale"}, positions, valid)
+             "v_scale": "cv_scale"}, cur, valid)
         return toks, n_acc, last, cur_end, pool, keys, moe
 
     @functools.partial(jax.jit, static_argnums=(0,),
@@ -1188,8 +1266,7 @@ class Generator:
         positions = jnp.broadcast_to(jnp.arange(bucket), (n, bucket))
         logits, row_caches, moe = self._apply_counted(
             params, tokens, positions, row_caches, 0, None, lengths - 1)
-        pool = self._insert_span_body(pool, bt_rows, row_caches,
-                                      jnp.zeros((), jnp.int32), bucket,
+        pool = self._insert_span_body(pool, bt_rows, row_caches, 0, bucket,
                                       limits)
         firsts, next_keys = self._first_sample(logits[:, 0], seeds, temp_r,
                                                topk_r, greedy_r)

@@ -22,7 +22,7 @@ and masks).
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -423,8 +423,9 @@ def _flash_attention(q, k, v, *, causal, scale, block_q, block_k, interpret,
 #
 # Decode attention that reads the paged KV pool IN PLACE (vLLM-style
 # PagedAttention, Kwon et al. SOSP'23): no dense [B, max_seq] gather copy
-# ever materialises in HBM.  The pool tensors [n_blocks, block, kvh, hd]
-# stay in HBM (memory_space=pl.ANY); the per-slot block table and the
+# ever materialises in HBM.  The pool tensors [n_blocks, block, kvh*hd]
+# (heads folded into lanes: how the pool rests, llama.init_kv_pool) stay
+# in HBM (memory_space=pl.ANY) as they are; the per-slot block table and the
 # lengths are scalar-prefetch operands, and the kernel fetches what it
 # reads itself.
 #
@@ -442,15 +443,14 @@ def _flash_attention(q, k, v, *, causal, scale, block_q, block_k, interpret,
 # scratch is stale, so every use of it is masked by a select, never by a
 # multiply.
 #
-# An int8 pool's scales [n_blocks, block, kvh] take another road: 3% of
-# the bytes, in a shape no copy can take whole (a [block, kvh] page is a
-# column per head where the [rows, tokens] scores want a lane row, and
-# Mosaic slices no operand whose minor dim is under 128 lanes; padding it
-# to 128 is a 17 MB write that XLA leaves inside the decode scan).  The
-# wrapper hands the kernel each row's scales through its block table as
-# per-head lane rows [kvh, n_cb, tokens] — a gather of 32 bytes a token
-# that XLA hoists out of the decode scan (the pool and the tables do not
-# change inside a chunk).
+# An int8 pool's scales [n_blocks, kvh*block] take another road: 3% of
+# the bytes, in pages of [kvh, block] — a lane row per head, as the
+# [rows, tokens] scores want it, but 64 lanes where Mosaic slices no
+# operand whose minor dim is under 128.  The kernel gets each row's scales
+# through its block table as per-head lane rows [kvh, n_cb, tokens]
+# (paged_scale_rows) — a gather of 32 bytes a token that a decode chunk
+# makes once, outside its scan (the pool and the tables do not change
+# inside a chunk).
 #
 # Softmax is the online (m, l, acc) carry across the compute blocks,
 # exactly like _attn_kernel_stream; the result is returned as the
@@ -467,6 +467,45 @@ PAGED_COMPUTE_TOKENS = 512
 #: VMEM the double-buffered K/V scratch may take (1 MB at the int8 7B
 #: shape; a quarter of what a v5e kernel may use without asking for more)
 PAGED_VMEM_BUDGET = 4 * 1024 * 1024
+#: what a TPU kernel may use of its core's VMEM without asking for more
+DEFAULT_SCOPED_VMEM = 16 * 1024 * 1024
+
+
+def core_vmem_bytes() -> Optional[int]:
+    """VMEM of the TensorCore this trace is for: the process's chip, or
+    the abstract device that a compile for a described chip names
+    (``jax.sharding.use_abstract_mesh``).  None off the TPU."""
+    if jax.default_backend() != "tpu":
+        return None
+    return pltpu.get_tpu_info().vmem_capacity_bytes
+
+
+def paged_vmem_claim(pool_bytes: int,
+                     vmem_bytes: Optional[int]) -> Optional[int]:
+    """Scoped VMEM a paged call CLAIMS (it uses a few MB) to keep the pool
+    where it rests: all of the core's but the default 16 MiB, where that
+    pays; None where it does not, and the call asks for nothing.
+
+    XLA takes a custom call to read its operands whole, and with VMEM to
+    spare its memory-space assignment stages pool tensors in VMEM ahead of
+    EVERY call (copies inside the decode scan: 0.4-0.9 GB a step at the 7B
+    shape) for a kernel that copies the 2-3% of them a row reads.  No
+    operand annotation stops it (a BlockSpec's HBM reaches Mosaic only; a
+    colour-0 ``with_memory_space_constraint`` and a ``cost_estimate`` are
+    read and overruled); VMEM the call claims for itself is not XLA's to
+    fill.  Compiled for a described v5e, XLA staged K/V tensors of 8, 16
+    and 32 MiB and none of 48 MiB or more: so the claim is made only
+    where the call's two pool tensors would fit in what the claim takes,
+    and only where it can do its work, that is where one pool tensor does
+    not fit in the 16 MiB it leaves (a smaller pool is staged as before).
+    Those 16 MiB still hold a projection weight prefetched for the next
+    matmul (13 MB at 7B)."""
+    if vmem_bytes is None:
+        return None
+    claim = vmem_bytes - DEFAULT_SCOPED_VMEM
+    if DEFAULT_SCOPED_VMEM < pool_bytes <= claim // 2:
+        return claim
+    return None
 
 
 def _sublane_tile(dtype) -> int:
@@ -658,6 +697,28 @@ def _paged_attn_kernel(bt_ref, len_ref, lo_ref, q_ref, k_hbm, v_hbm, ks_ref,
         st_ref[1] = nxt_live.astype(jnp.int32)
 
 
+def paged_scale_rows(scale: jax.Array, block_tables: jax.Array,
+                     pool: jax.Array) -> jax.Array:
+    """An int8 pool's scale plane ``[N, Hkv·block]`` → the lane rows the
+    kernel reads, ``[B, Hkv, n_cb, tokens]``, through ``block_tables [B,
+    nb]``: a gather of whole pages (a lane row each, 32 bytes a token),
+    then heads before blocks.  ``pool [N, block, Hkv·D]`` is the K or V
+    tensor the plane belongs to (its shape and type set the compute
+    block).  The pool and the tables do not change inside a decode chunk,
+    so the chunk makes these once, outside its scan."""
+    _, blk, folded = pool.shape
+    b, nb = block_tables.shape
+    hkv = scale.shape[1] // blk
+    pages = paged_pages_per_step(blk, nb, hkv, folded // hkv, pool.dtype)
+    n_cb = -(-nb // pages)
+    bt = jnp.pad(block_tables.astype(jnp.int32),
+                 ((0, 0), (0, n_cb * pages - nb)))
+    # (table entries are pool ids by construction: no fill for strays)
+    x = jnp.take(scale, bt, axis=0, mode="clip")  # [B, n_cb·pages, Hkv·blk]
+    x = x.reshape(b, n_cb, pages, hkv, blk).transpose(0, 3, 1, 2, 4)
+    return x.reshape(b, hkv, n_cb, pages * blk)
+
+
 def paged_attention_partial(
     q: jax.Array,
     pool_k: jax.Array,
@@ -666,8 +727,7 @@ def paged_attention_partial(
     lengths: jax.Array,
     *,
     scale: Optional[float] = None,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
+    scale_rows: Optional[Tuple[jax.Array, jax.Array]] = None,
     interpret: Optional[bool] = None,
     window: Optional[int] = None,
     q_pos: Optional[jax.Array] = None,
@@ -681,16 +741,21 @@ def paged_attention_partial(
     ``q [B, S, H, D]``: S = 1 for a plain decode step, K+1 for a
     speculative multi-query verify (every row attends the same pool
     prefix; the in-segment causal half lives in the buffer partial).
-    ``pool_k/pool_v [N, block, Hkv, D]`` are the POOL tensors — read
-    through ``block_tables [B, nb]`` in place, never gathered into a
-    dense per-row view.  ``lengths [B]``: each row's valid prefix (the
-    slot's ``cur`` frontier); idle table entries may point anywhere
-    (the reserved block 0 included) — blocks at or past ``lengths`` are
-    neither fetched nor computed.  ``k_scale``/``v_scale``
-    ``[N, block, Hkv]``: the int8 pool's per-vector dequant scales —
-    dequant happens IN the kernel, so int8 halves the HBM bytes decode
-    actually moves.  GQA (Hkv < H) walks kv heads inside the kernel body
-    with the whole q group as rows of one matmul per head.
+    ``pool_k/pool_v [N, block, Hkv·D]`` are the POOL tensors as they rest
+    (``llama.init_kv_pool``: heads folded into lanes) — read through
+    ``block_tables [B, nb]`` in place, never gathered into a dense
+    per-row view and never re-laid: the kernel's operand IS the pool.
+    ``lengths [B]``: each row's valid prefix (the slot's ``cur``
+    frontier); idle table entries may point anywhere (the reserved block
+    0 included) — blocks at or past ``lengths`` are neither fetched nor
+    computed.  ``scale_rows``: an int8 pool's per-vector dequant scales
+    as the kernel reads them, ``paged_scale_rows`` of the K and of the V
+    plane ``[N, Hkv·block]`` through the same tables (a decode chunk
+    makes them once, outside its scan: the pool is frozen while its
+    steps run) — dequant happens IN the kernel, so int8 halves the HBM
+    bytes decode actually moves.  GQA (Hkv < H) walks kv heads inside
+    the kernel body with the whole q group as rows of one matmul per
+    head.
 
     ``window`` (static) with ``q_pos [B]`` (the position of each row's
     first query; query ``j`` of a multi-query segment sits at ``q_pos +
@@ -704,13 +769,13 @@ def paged_attention_partial(
     f32 (Hkv x R x D) carry; sequence length is bounded by HBM only.
     """
     b, s, h, d = q.shape
-    n_blocks, blk, hkv, dk = pool_k.shape
-    if d != dk:
-        raise ValueError(f"q head_dim {d} != pool head_dim {dk}")
+    _, blk, folded = pool_k.shape
+    if folded % d:
+        raise ValueError(f"pool lanes {folded} are not whole heads of the "
+                         f"q head_dim {d}")
+    hkv = folded // d
     if h % hkv:
         raise ValueError(f"q heads {h} not a multiple of kv heads {hkv}")
-    if (k_scale is None) != (v_scale is None):
-        raise ValueError("k_scale and v_scale must be passed together")
     nb = block_tables.shape[1]
     g = h // hkv
     if scale is None:
@@ -737,28 +802,23 @@ def paged_attention_partial(
         lens = jnp.where(lo < lens, lens, 0)
         first = (jnp.where(lo < lens, lo, 0),)
     pages = paged_pages_per_step(blk, nb, hkv, d, pool_k.dtype)
-    n_cb = -(-nb // pages)
 
     def slabs(x):
-        # one pool block = one contiguous [block, lanes] slab the kernel
-        # copies whole: heads folded into lanes (a free reshape of the
-        # contiguous pool), lanes padded to whole 128-lane tiles (a no-op
-        # at served widths)
-        x = x.reshape(n_blocks, blk, hkv * d)
-        return jnp.pad(x, ((0, 0), (0, 0), (0, -(hkv * d) % 128)))
+        # one pool block = one [block, lanes] slab the kernel copies whole:
+        # the pool rests that way, so this is the identity at served widths
+        # (whole 128-lane tiles); a narrower test pool pads its lanes, which
+        # IS a copy of the pool
+        return jnp.pad(x, ((0, 0), (0, 0), (0, -folded % 128)))
 
-    def scale_rows(x):
-        # [N, blk, Hkv] → [B, Hkv, n_cb, tokens] through the tables
-        x = jnp.take(x, jnp.pad(bt, ((0, 0), (0, n_cb * pages - nb))), axis=0)
-        return x.reshape(b, n_cb, pages * blk, hkv).transpose(0, 3, 1, 2)
-
-    scales = (() if k_scale is None
-              else (scale_rows(k_scale), scale_rows(v_scale)))
+    scales = scale_rows or ()
+    vmem_limit = None if interpret else paged_vmem_claim(
+        pool_k.size * pool_k.dtype.itemsize, core_vmem_bytes())
     acc, m, l = _paged_call((bt, lens) + first, qr, slabs(pool_k),
                             slabs(pool_v), *scales, scale=scale, d=d,
                             pages=pages, interpret=interpret,
                             group=None if window is None else (
-                                g if s > 1 else 0))
+                                g if s > 1 else 0),
+                            vmem_limit=vmem_limit)
     m, l = m[..., 0], l[..., 0]
 
     # [B, Hkv, R(, D)] → [B, S, H(, D)] (drop row padding first)
@@ -774,11 +834,13 @@ def paged_attention_partial(
 # 40 s of set-up on the chip's host), and what surrounds the call still
 # fuses with each layer's own operations.
 @functools.partial(jax.jit, static_argnames=("scale", "d", "pages",
-                                             "interpret", "group"))
+                                             "interpret", "group",
+                                             "vmem_limit"))
 def _paged_call(prefetch, qr, k_slabs, v_slabs, *scales, scale, d, pages,
-                interpret, group=None):
+                interpret, group=None, vmem_limit=None):
     """``prefetch``: the scalar-prefetch operands — tables and lengths,
-    and for a window layer (``group`` set) each row's first position."""
+    and for a window layer (``group`` set) each row's first position.
+    ``vmem_limit``: ``paged_vmem_claim``'s verdict."""
     b, hkv, r_pad, _ = qr.shape
     blk = k_slabs.shape[1]
     nb = prefetch[0].shape[1]
@@ -825,7 +887,8 @@ def _paged_call(prefetch, qr, k_slabs, v_slabs, *scales, scale, d, pages,
         # rows in order on one core: a row's last compute block starts
         # the next row's first copies, and the scratch half carries over
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit),
         name="paged_attention",
         interpret=interpret,
     )(*prefetch, qr, k_slabs, v_slabs, *scales)
@@ -846,10 +909,16 @@ def paged_flash_attention(
     """Normalised in-place paged attention ``[B, S, H, D]`` (the
     standalone/microbench surface; the serving path merges the partial
     with its chunk-buffer half instead — see ``paged_attention_partial``).
-    Rows with ``lengths[b] == 0`` return zeros (no valid key)."""
+    ``k_scale``/``v_scale``: an int8 pool's scale planes ``[N, Hkv·block]``
+    as they rest.  Rows with ``lengths[b] == 0`` return zeros (no valid
+    key)."""
+    rows = None
+    if k_scale is not None:
+        rows = (paged_scale_rows(k_scale, block_tables, pool_k),
+                paged_scale_rows(v_scale, block_tables, pool_v))
     acc, _, l = paged_attention_partial(
         q, pool_k, pool_v, block_tables, lengths, scale=scale,
-        k_scale=k_scale, v_scale=v_scale, interpret=interpret)
+        scale_rows=rows, interpret=interpret)
     return (acc / jnp.maximum(l[..., None], 1e-30)).astype(q.dtype)
 
 

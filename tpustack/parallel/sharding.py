@@ -117,12 +117,20 @@ BATCH_SPEC = PS(("dp", "fsdp"), "sp")  # tokens [B, S]: batch over dp+fsdp, seq 
 # Serving-time KV tensors shard on the HEAD axis over ``tp`` (Pope et al.
 # 2022: attention is embarrassingly parallel per head, so each chip holds
 # only its heads' K/V and the decode step's cache read/write never crosses
-# ICI).  Every serving KV layout puts kv_heads at axis 2:
+# ICI).  Every DENSE serving KV layout puts kv_heads at axis 2:
 #
 #     dense slot caches  [B, max_seq, kvh, hd]
-#     paged pool tensors [n_blocks, block, kvh, hd]
 #     chunk-local bufs   [B, chunk, kvh, hd]
 #     int8 scale arrays  [..., kvh]           (axis 2 is the LAST axis)
+#
+# and the PAGED POOL rests in the layout its kernel and its scatter take
+# (``llama.init_kv_pool``), heads folded into lanes and scales token-minor:
+#
+#     pool K/V           [n_blocks, block, kvh * hd]   (axis 2)
+#     pool int8 scales   [n_blocks, kvh * block]       (axis 1)
+#
+# a folded axis shards in whole heads (kvh % tp == 0: each chip's slice is
+# its heads' lanes, contiguous).
 #
 # When ``n_kv_heads`` does not divide the tp ways (GQA at high tp — e.g.
 # 4 kv heads over tp=8), the K/V heads replicate per chip, matching what
@@ -130,9 +138,12 @@ BATCH_SPEC = PS(("dp", "fsdp"), "sp")  # tokens [B, S]: batch over dp+fsdp, seq 
 # partitioned programs stay correct either way, this only decides whether
 # the cache HBM bill divides by tp.
 
-def kv_head_axis_spec(ndim: int) -> PS:
-    """PartitionSpec sharding axis 2 (kv heads) on ``tp``; rank-3 scale
-    arrays have the head axis last, so the same spec serves both."""
+def kv_head_axis_spec(ndim: int, pool_scale: bool = False) -> PS:
+    """PartitionSpec sharding the kv-head axis on ``tp``: axis 2 of every
+    dense layout (rank-3 scale arrays have it last) and of the pool's
+    folded K/V; axis 1 of a POOL's scale plane (``pool_scale``)."""
+    if pool_scale:
+        return PS(None, "tp")
     return PS(*([None, None, "tp"] + [None] * (ndim - 3)))
 
 
@@ -145,17 +156,20 @@ def can_shard_kv_heads(mesh: Optional[Mesh], n_kv_heads: int) -> bool:
     return tp > 1 and n_kv_heads % tp == 0
 
 
-def shard_kv_tree(caches, mesh: Mesh, n_kv_heads: int):
+def shard_kv_tree(caches, mesh: Mesh, n_kv_heads: int, pool: bool = False):
     """device_put every serving-KV leaf (per-layer dicts of k/v [+ scales])
     with the head-axis NamedSharding; replicated when the heads don't
-    divide tp.  Idempotent on already-sharded trees."""
+    divide tp.  ``pool``: the tree is a paged pool (``init_kv_pool``'s
+    layout), whose ``*_scale`` planes fold the heads into axis 1.
+    Idempotent on already-sharded trees."""
     shard = can_shard_kv_heads(mesh, n_kv_heads)
 
-    def put(x):
-        spec = kv_head_axis_spec(x.ndim) if shard else PS()
+    def put(key, x):
+        spec = (kv_head_axis_spec(x.ndim, pool and key.endswith("_scale"))
+                if shard else PS())
         return jax.device_put(x, NamedSharding(mesh, spec))
 
-    return jax.tree.map(put, caches)
+    return [{k: put(k, x) for k, x in layer.items()} for layer in caches]
 
 
 def tree_bytes(tree) -> int:
