@@ -43,12 +43,9 @@ def gen():
 
 
 def _paged(gen):
-    from tpustack.models.llama import init_kv_pool
-    from tpustack.serving.kv_pool import KVBlockPool, PagedKVRuntime
+    from tpustack.serving.kv_pool import PagedKVRuntime
 
-    pool = KVBlockPool(33, 8)
-    return PagedKVRuntime(init_kv_pool(gen.cfg, 33, 8), pool,
-                          gen.cfg.max_seq)
+    return PagedKVRuntime.build(gen.cfg, 2, block=8, pool_blocks=32)
 
 
 def _spec():

@@ -199,12 +199,9 @@ def test_engine_spec_records_drafted_accepted(gen):
 
 
 def test_engine_paged_records_kv_state(gen):
-    from tpustack.models.llama import init_kv_pool
-    from tpustack.serving.kv_pool import KVBlockPool, PagedKVRuntime
+    from tpustack.serving.kv_pool import PagedKVRuntime
 
-    cfg = gen.cfg
-    pool = KVBlockPool(17, 8)
-    rt = PagedKVRuntime(init_kv_pool(cfg, 17, 8), pool, cfg.max_seq)
+    rt = PagedKVRuntime.build(gen.cfg, 2, block=8)  # 2 x 64 / 8 blocks
     rec = obs_flight.FlightRecorder("eng", capacity=256)
     _, stats = _engine_fleet(gen, n=2, flight=rec, paged=rt)
     waves = [r for r in rec.recent() if r["kind"] == "wave"]

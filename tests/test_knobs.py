@@ -38,10 +38,10 @@ def test_malformed_float_names_the_knob():
 
 def test_malformed_bool_names_the_knob_and_the_accepted_spellings():
     with pytest.raises(ValueError) as ei:
-        knobs.get_bool("TPUSTACK_PAGED_KV",
-                       env={"TPUSTACK_PAGED_KV": "enabled"})
+        knobs.get_bool("TPUSTACK_PREFIX_CACHE",
+                       env={"TPUSTACK_PREFIX_CACHE": "enabled"})
     msg = str(ei.value)
-    assert "TPUSTACK_PAGED_KV" in msg and "enabled" in msg
+    assert "TPUSTACK_PREFIX_CACHE" in msg and "enabled" in msg
     # the error teaches the accepted spellings — an operator fixing a
     # manifest at 3am must not have to read the source
     assert "1/true/yes/on" in msg and "0/false/no/off" in msg
@@ -59,15 +59,15 @@ def test_blank_and_whitespace_values_fall_back_to_defaults():
     assert knobs.get_int("LLM_CTX", env={"LLM_CTX": ""}) == 4096
     assert knobs.get_float("TPUSTACK_DRAIN_TIMEOUT_S",
                            env={"TPUSTACK_DRAIN_TIMEOUT_S": "  "}) == 30.0
-    assert knobs.get_bool("TPUSTACK_PAGED_KV",
-                          env={"TPUSTACK_PAGED_KV": ""}) is True
+    assert knobs.get_bool("TPUSTACK_PREFIX_CACHE",
+                          env={"TPUSTACK_PREFIX_CACHE": ""}) is True
 
 
 def test_bool_spellings_case_insensitive():
     for raw, want in (("TRUE", True), ("Yes", True), ("oN", True),
                       ("FALSE", False), ("No", False), ("0", False)):
-        assert knobs.get_bool("TPUSTACK_PAGED_KV",
-                              env={"TPUSTACK_PAGED_KV": raw}) is want
+        assert knobs.get_bool("TPUSTACK_PREFIX_CACHE",
+                              env={"TPUSTACK_PREFIX_CACHE": raw}) is want
 
 
 # ------------------------------------------------------------ undeclared reads

@@ -47,7 +47,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from tpustack import sanitize  # noqa: E402
-from tpustack.models.llama import LlamaConfig, init_kv_pool  # noqa: E402
+from tpustack.models.llama import LlamaConfig  # noqa: E402
 from tpustack.models.llm_continuous import (ContinuousEngine,  # noqa: E402
                                             SlotRequest)
 from tpustack.models.llm_generate import Generator, SampleConfig  # noqa: E402
@@ -103,17 +103,14 @@ def _conserved(tier):
 
 
 def _make_rt(gen, capacity_blocks, block=BLOCK, tier_mb=None, cache=True):
-    pool = KVBlockPool(capacity_blocks + 1, block)
-    rt = PagedKVRuntime(
-        init_kv_pool(gen.cfg, capacity_blocks + 1, block,
-                     dtype=gen.cache_dtype),
-        pool, gen.cfg.max_seq,
-        cache=PagedPrefixCache(pool) if cache else None)
+    rt = PagedKVRuntime.build(gen.cfg, 1, block=block,
+                              pool_blocks=capacity_blocks,
+                              dtype=gen.cache_dtype, prefix_cache=cache)
     if tier_mb and cache:
         # crossover OFF: on CPU-tiny shapes both EMAs measure dispatch
         # noise and the guard would (correctly) decline every restore
         rt.cache.host_tier = HostKVTier(
-            int(tier_mb * 1024 * 1024), pool,
+            int(tier_mb * 1024 * 1024), rt.pool,
             arrays_fn=lambda: rt.arrays, crossover=False)
     return rt
 
@@ -558,12 +555,11 @@ def test_chunked_prefill_byte_identity_and_stats(gen):
 
 
 def test_chunked_prefill_env_knob_arms_engine(gen, monkeypatch):
-    """TPUSTACK_PREFILL_CHUNK_TOKENS arms a default-constructed paged
-    engine; dense engines ignore it (paged-only by construction)."""
+    """TPUSTACK_PREFILL_CHUNK_TOKENS arms an engine that was given no
+    ``prefill_chunk`` of its own."""
     monkeypatch.setenv("TPUSTACK_PREFILL_CHUNK_TOKENS", "16")
     rt = _make_rt(gen, capacity_blocks=16, cache=False)
     assert ContinuousEngine(gen, slots=1, paged=rt)._chunk_tokens == 16
-    assert ContinuousEngine(gen, slots=1)._chunk_tokens == 0
     monkeypatch.setenv("TPUSTACK_PREFILL_CHUNK_TOKENS", "0")
     assert ContinuousEngine(gen, slots=1, paged=rt)._chunk_tokens == 0
 

@@ -1,7 +1,7 @@
 """Paged KV substrate (block pool + block tables) — the allocator, the
 refcounted block-id radix cache, the paged ContinuousEngine, and the HTTP
-server's capacity-true admission.  The ISSUE's acceptance bars: greedy
-outputs byte-identical paged-vs-dense (solo / engine / HTTP) and
+server's capacity-true admission.  The acceptance bars: greedy outputs
+byte-identical to the solo path (``generate_fused``; engine / HTTP) and
 cache-on-vs-off; a prefix hit moves ZERO KV bytes (copy-avoided counter);
 out-of-blocks admission answers 429 with a capacity-true Retry-After; and
 the pool's free-block count returns to its initial value after a burst
@@ -17,7 +17,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from tpustack.models.llama import LlamaConfig, init_kv_pool
+from tpustack.models.llama import LlamaConfig
 from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
 from tpustack.models.llm_generate import Generator, SampleConfig
 from tpustack.serving.kv_pool import (KVBlockPool, OutOfBlocks,
@@ -34,11 +34,9 @@ def gen():
 
 
 def make_runtime(gen, capacity_blocks=32, block=BLOCK, cache=True):
-    pool = KVBlockPool(capacity_blocks + 1, block)
-    return PagedKVRuntime(
-        init_kv_pool(gen.cfg, capacity_blocks + 1, block, jnp.float32),
-        pool, gen.cfg.max_seq,
-        cache=PagedPrefixCache(pool) if cache else None)
+    return PagedKVRuntime.build(gen.cfg, 2, block=block,
+                                pool_blocks=capacity_blocks,
+                                dtype=jnp.float32, prefix_cache=cache)
 
 
 # ------------------------------------------------------------ the allocator
@@ -134,6 +132,70 @@ def test_paged_cache_evict_blocked_while_referenced():
     assert evicted == [2]                 # the exported-counter hook fired
 
 
+# ------------------------------------------------- the one place a pool is made
+@pytest.mark.parametrize("max_seq,asked,slots,pool_blocks,want_block,want_cap", [
+    (64, 0, 2, 0, 8, 16),         # default: min(64, max(8, 64 // 8))
+    (4096, 0, 8, 0, 64, 512),     # the Deployment's: 8 slots x 4096 / 64
+    (256, 0, 3, 0, 32, 24),       # default scales with the context...
+    (32, 0, 1, 0, 8, 4),          # ...and never drops below 8
+    (64, 24, 4, 10, 1, 10),       # halved 12 -> 6 -> 3 -> 1: none divides
+    (64, 16, 2, 0, 16, 8),        # an asked block that divides is kept
+    (64, 1000, 2, 0, 64, 2),      # clamped to the context: one block a line
+    (96, 64, 2, 5, 32, 5),        # 96 % 64 != 0 -> 32; asked pool size kept
+])
+@pytest.mark.parametrize("cache", [False, True])
+def test_runtime_build_snaps_block_sizes_pool_and_reserves_block0(
+        max_seq, asked, slots, pool_blocks, want_block, want_cap, cache):
+    """``PagedKVRuntime.build`` owns the block-snapping rule, the default
+    pool of ``slots x max_seq / block`` blocks and the reserved block 0."""
+    cfg = LlamaConfig.tiny(max_seq=max_seq)
+    rt = PagedKVRuntime.build(cfg, slots, block=asked,
+                              pool_blocks=pool_blocks, dtype=jnp.float32,
+                              prefix_cache=cache)
+    assert rt.block == rt.pool.block == want_block
+    assert max_seq % rt.block == 0
+    assert rt.blocks_per_seq == max_seq // want_block
+    assert rt.pool.capacity_blocks == want_cap == rt.pool.n_free
+    # block 0 is extra, on the device and in the allocator, and never handed out
+    assert rt.pool.n_blocks == want_cap + 1
+    assert all(x.shape[0] == want_cap + 1
+               for layer in rt.arrays for x in layer.values())
+    assert len(rt.arrays) == cfg.n_layers
+    assert rt.arrays[0]["k"].dtype == jnp.float32
+    ids = rt.pool.alloc_tokens(want_cap * want_block)
+    assert sorted(ids) == list(range(1, want_cap + 1))
+    rt.pool.decref(ids)
+    assert (rt.cache is not None) == cache
+    assert rt.cache is None or rt.cache.host_tier is None
+    assert rt.stats()["prefix_cache"]["enabled"] == cache
+
+
+def test_runtime_build_host_tier_needs_a_prefix_cache():
+    cfg = LlamaConfig.tiny(max_seq=64)
+    rt = PagedKVRuntime.build(cfg, 2, prefix_cache=True, host_tier_mb=1)
+    assert rt.cache.host_tier is not None
+    assert PagedKVRuntime.build(cfg, 2, host_tier_mb=1).cache is None
+
+
+def test_default_engine_builds_its_own_pool(gen):
+    """An engine given no runtime holds a pool of ``slots x max_seq``
+    tokens, without a prefix cache, and serves through it."""
+    eng = ContinuousEngine(gen, slots=3, chunk=4)
+    rt = eng.paged
+    assert rt.pool.capacity_blocks * rt.block == 3 * gen.cfg.max_seq
+    assert rt.cache is None and rt.max_seq == gen.cfg.max_seq
+    assert rt.arrays[0]["k"].dtype == gen.cache_dtype
+    res, stats = _run(eng, [{"ids": [5, 6, 7], "max_new": 6}])
+    assert stats["decode_kernel"] == "gather"  # CPU: 'auto' picks gather
+    assert stats["kernel_gather_dispatches"] >= 1
+    assert res[0][0] == gen.generate_fused([5, 6, 7], max_new_tokens=6,
+                                           sample=GREEDY, chunk=4)[0]
+    assert rt.pool.n_used == 0  # retired: every block back
+    with pytest.raises(ValueError, match="max_seq"):
+        ContinuousEngine(gen, slots=2, paged=PagedKVRuntime.build(
+            LlamaConfig.tiny(max_seq=32), 2))
+
+
 # ------------------------------------------------------- engine-level parity
 def _run(engine, requests):
     results = {}
@@ -148,36 +210,40 @@ def _run(engine, requests):
 
 
 def test_engine_paged_matches_dense_and_solo(gen):
-    """The tentpole bar: greedy outputs byte-identical paged-vs-dense,
+    """The tentpole bar: greedy outputs byte-identical to the solo path,
+    on the pool an engine builds for itself and on one handed in,
     including slot reuse (more requests than slots) and mixed lengths."""
     prompts = [[5, 6, 7], [9, 10, 11, 12, 13, 14, 15, 16, 17], [20],
                [30 + i for i in range(12)], [40, 41]]
     reqs = [{"ids": p, "max_new": 8} for p in prompts]
     solo = [gen.generate_fused(p, max_new_tokens=8, sample=GREEDY,
                                stop_tokens=(2,), chunk=4)[0] for p in prompts]
-    dense, _ = _run(ContinuousEngine(gen, slots=2, chunk=4,
-                                     stop_tokens=(2,)), reqs)
+    own, _ = _run(ContinuousEngine(gen, slots=2, chunk=4,
+                                   stop_tokens=(2,)), reqs)
     rt = make_runtime(gen)
     free0 = rt.pool.n_free
     paged, _ = _run(ContinuousEngine(gen, slots=2, chunk=4, stop_tokens=(2,),
                                      paged=rt), reqs)
     for i, s in enumerate(solo):
-        assert dense[i][0] == s, f"dense row {i} diverged from solo"
+        assert own[i][0] == s, f"own-pool row {i} diverged from solo"
         assert paged[i][0] == s, f"paged row {i} diverged from solo"
     assert rt.pool.n_free == free0  # burst leak check (no cache inserts)
 
 
 def test_engine_paged_seeded_sampling_parity(gen):
-    """Per-slot PRNG streams are substrate-independent: a seeded sampled
-    request draws the same tokens paged and dense."""
+    """Per-slot PRNG streams do not depend on where the KV rests: a
+    seeded sampled request draws the same tokens over pools of different
+    block size and capacity, and its greedy peer the solo path's."""
     reqs = [{"ids": [5, 6, 7, 8], "max_new": 8, "seed": 1234,
              "sample": SampleConfig(temperature=1.2, top_k=8)},
             {"ids": [9, 10], "max_new": 6}]
-    dense, _ = _run(ContinuousEngine(gen, slots=2, chunk=4), reqs)
-    paged, _ = _run(ContinuousEngine(gen, slots=2, chunk=4,
-                                     paged=make_runtime(gen)), reqs)
-    assert paged[0][0] == dense[0][0]
-    assert paged[1][0] == dense[1][0]
+    own, _ = _run(ContinuousEngine(gen, slots=2, chunk=4), reqs)
+    paged, _ = _run(ContinuousEngine(
+        gen, slots=2, chunk=4,
+        paged=make_runtime(gen, capacity_blocks=12, block=16)), reqs)
+    assert paged[0][0] == own[0][0]
+    assert paged[1][0] == own[1][0] == gen.generate_fused(
+        [9, 10], max_new_tokens=6, sample=GREEDY, chunk=4)[0]
 
 
 def test_engine_paged_prefix_sharing_lifecycle(gen):
@@ -309,23 +375,25 @@ def _post_all(server, payloads, collect_status=False):
     return asyncio.new_event_loop().run_until_complete(scenario())
 
 
-def _server(gen, **kw):
+def _server(gen, max_batch=4, **kw):
     from tpustack.models.text_tokenizer import ByteTokenizer
     from tpustack.obs import Registry
     from tpustack.serving.llm_server import LLMServer
 
     reg = kw.pop("registry", None) or Registry()
     return LLMServer(generator=gen, tokenizer=ByteTokenizer(512),
-                     max_batch=4, registry=reg, **kw), reg
+                     max_batch=max_batch, registry=reg, **kw), reg
 
 
 def test_server_paged_vs_dense_and_cache_onoff_parity(gen):
-    """The HTTP bar: greedy completions byte-identical across paged
-    (cache on), paged (cache off), and the dense fallback."""
+    """The HTTP bar: greedy completions byte-identical across the engine
+    (cache on), the engine (cache off), and the solo route (one dense
+    line, ``generate_fused``)."""
     prompts = [{"prompt": "shared system preamble for paged tests! " + t,
                 "n_predict": 6, "temperature": 0}
                for t in ("q1", "q2", "q1")]
-    dense, _ = _server(gen, paged=None)
+    dense, _ = _server(gen, max_batch=1, prefix_cache=None)
+    assert dense.paged is None
     outs_dense, props_dense, _ = _post_all(dense, prompts)
     assert props_dense["paged_kv"] == {"enabled": False,
                                        "dense_fallback": True}
@@ -415,14 +483,24 @@ def test_server_burst_leak_check(gen):
     assert rt.pool.n_free == free0
 
 
+def test_server_refuses_a_host_prefix_cache_beside_the_engine(gen):
+    """A host ``PrefixCache`` serves the ``LLM_MAX_BATCH=1`` solo route
+    only: handed to a server that runs the engine it is an error at
+    construction (it used to switch engines silently)."""
+    from tpustack.serving.prefix_cache import PrefixCache
+
+    pc = PrefixCache(chunk_tokens=8, capacity_bytes=1 << 20)
+    with pytest.raises(ValueError, match="PrefixCache"):
+        _server(gen, prefix_cache=pc)
+    solo, _ = _server(gen, max_batch=1, prefix_cache=pc)
+    assert solo.paged is None and solo.prefix_cache is pc
+
+
 def test_build_paged_env_knobs(gen, monkeypatch):
     from tpustack.serving.llm_server import LLMServer
 
-    monkeypatch.setenv("TPUSTACK_PAGED_KV", "0")
-    assert LLMServer._build_paged(gen, 4) is None
-    monkeypatch.setenv("TPUSTACK_PAGED_KV", "1")
     assert LLMServer._build_paged(gen, 1) is None  # solo stays dense
-    monkeypatch.setenv("TPUSTACK_KV_BLOCK", "24")  # 64 % 24 != 0 → snap 12→6→3
+    monkeypatch.setenv("TPUSTACK_KV_BLOCK", "24")  # 64 % 24 != 0 → snaps down
     monkeypatch.setenv("TPUSTACK_KV_POOL_BLOCKS", "10")
     rt = LLMServer._build_paged(gen, 4)
     assert gen.cfg.max_seq % rt.block == 0

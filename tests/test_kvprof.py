@@ -30,7 +30,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from tpustack.models.llama import LlamaConfig, init_kv_pool
+from tpustack.models.llama import LlamaConfig
 from tpustack.models.llm_generate import Generator
 from tpustack.obs import Registry
 from tpustack.obs import accounting as obs_accounting
@@ -269,11 +269,9 @@ def _server(gen, **kw):
 
 
 def _make_runtime(gen, capacity_blocks=32, block=8, cache=True):
-    pool = KVBlockPool(capacity_blocks + 1, block)
-    return PagedKVRuntime(
-        init_kv_pool(gen.cfg, capacity_blocks + 1, block, jnp.float32),
-        pool, gen.cfg.max_seq,
-        cache=PagedPrefixCache(pool) if cache else None)
+    return PagedKVRuntime.build(gen.cfg, 4, block=block,
+                                pool_blocks=capacity_blocks,
+                                dtype=jnp.float32, prefix_cache=cache)
 
 
 def test_debug_kvcache_route_and_scrape_gauges(gen, monkeypatch):
@@ -378,18 +376,16 @@ import asyncio, json
 import jax.numpy as jnp
 from tpustack.obs import Registry
 from tpustack.obs import perfsig
-from tpustack.models.llama import LlamaConfig, init_kv_pool
+from tpustack.models.llama import LlamaConfig
 from tpustack.models.llm_generate import Generator
 from tpustack.models.text_tokenizer import ByteTokenizer
-from tpustack.serving.kv_pool import KVBlockPool, PagedKVRuntime, \\
-    PagedPrefixCache
+from tpustack.serving.kv_pool import PagedKVRuntime
 from tpustack.serving.llm_server import LLMServer
 
 gen = Generator(LlamaConfig.tiny(max_seq=64), dtype=jnp.float32, seed=3)
 watch = perfsig.compile_watch(gen)
-pool = KVBlockPool(33, 8)
-rt = PagedKVRuntime(init_kv_pool(gen.cfg, 33, 8, jnp.float32), pool,
-                    gen.cfg.max_seq, cache=PagedPrefixCache(pool))
+rt = PagedKVRuntime.build(gen.cfg, 4, block=8, pool_blocks=32,
+                          dtype=jnp.float32, prefix_cache=True)
 reg = Registry()
 server = LLMServer(generator=gen, tokenizer=ByteTokenizer(512),
                    model_name="t", max_batch=4, registry=reg, paged=rt)

@@ -134,14 +134,9 @@ def test_server_micro_batches_concurrent_completions(gen):
     server = LLMServer(generator=gen, tokenizer=tok, model_name="tiny-test",
                        max_batch=4)
     calls = {"batch": 0, "solo": 0}
-    real_cont, real_fused = gen._decode_scan_cont, gen.generate_fused
-    real_paged = gen._decode_scan_paged
+    real_paged, real_fused = gen._decode_scan_paged, gen.generate_fused
 
-    def spy_cont(*a, **kw):
-        calls["batch"] += 1
-        return real_cont(*a, **kw)
-
-    def spy_paged(*a, **kw):  # engine decode under the paged default
+    def spy_paged(*a, **kw):  # the engine's decode program
         calls["batch"] += 1
         return real_paged(*a, **kw)
 
@@ -149,8 +144,7 @@ def test_server_micro_batches_concurrent_completions(gen):
         calls["solo"] += 1
         return real_fused(*a, **kw)
 
-    gen._decode_scan_cont, gen.generate_fused = spy_cont, spy_fused
-    gen._decode_scan_paged = spy_paged
+    gen._decode_scan_paged, gen.generate_fused = spy_paged, spy_fused
     prompts = ["alpha", "bee", "gamma!"]
 
     async def scenario():
@@ -168,8 +162,7 @@ def test_server_micro_batches_concurrent_completions(gen):
     try:
         results = asyncio.new_event_loop().run_until_complete(scenario())
     finally:
-        gen._decode_scan_cont, gen.generate_fused = real_cont, real_fused
-        gen._decode_scan_paged = real_paged
+        gen._decode_scan_paged, gen.generate_fused = real_paged, real_fused
 
     assert calls["batch"] >= 1 and calls["solo"] == 0, calls
     for p, r in zip(prompts, results):
@@ -198,14 +191,9 @@ def test_server_batched_streaming_coalesces(gen):
     server = LLMServer(generator=gen, tokenizer=tok, model_name="tiny-test",
                        max_batch=4)
     calls = {"batch": 0, "solo": 0}
-    real_cont, real_solo = gen._decode_scan_cont, gen.generate
-    real_paged = gen._decode_scan_paged
+    real_paged, real_solo = gen._decode_scan_paged, gen.generate
 
-    def spy_cont(*a, **kw):
-        calls["batch"] += 1
-        return real_cont(*a, **kw)
-
-    def spy_paged(*a, **kw):  # engine decode under the paged default
+    def spy_paged(*a, **kw):  # the engine's decode program
         calls["batch"] += 1
         return real_paged(*a, **kw)
 
@@ -213,8 +201,7 @@ def test_server_batched_streaming_coalesces(gen):
         calls["solo"] += 1
         return real_solo(*a, **kw)
 
-    gen._decode_scan_cont, gen.generate = spy_cont, spy_solo
-    gen._decode_scan_paged = spy_paged
+    gen._decode_scan_paged, gen.generate = spy_paged, spy_solo
     prompts = ["stream one", "stream two!"]
 
     async def read_stream(client, prompt):
@@ -246,8 +233,7 @@ def test_server_batched_streaming_coalesces(gen):
     try:
         results = asyncio.new_event_loop().run_until_complete(scenario())
     finally:
-        gen._decode_scan_cont, gen.generate = real_cont, real_solo
-        gen._decode_scan_paged = real_paged
+        gen._decode_scan_paged, gen.generate = real_paged, real_solo
 
     assert calls["batch"] >= 1 and calls["solo"] == 0, calls
     for p, (text, final) in zip(prompts, results):

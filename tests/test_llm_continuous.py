@@ -334,7 +334,6 @@ def test_server_engine_failure_strands_nothing(gen):
     reg = Registry()
     server = LLMServer(generator=gen, tokenizer=ByteTokenizer(512),
                        model_name="tiny-test", max_batch=4, registry=reg)
-    real = gen._decode_scan_cont
     real_paged = gen._decode_scan_paged
     broken = {"on": True}
 
@@ -345,10 +344,7 @@ def test_server_engine_failure_strands_nothing(gen):
             return real_fn(*a, **kw)
         return wrapped
 
-    # the server routes decode through the paged program by default and
-    # the dense one under TPUSTACK_PAGED_KV=0 — break whichever runs
-    gen._decode_scan_cont = boom(real)
-    gen._decode_scan_paged = boom(real_paged)
+    gen._decode_scan_paged = boom(real_paged)  # the engine's decode
     try:
         async def scenario():
             client = TestClient(TestServer(server.build_app()))
@@ -377,14 +373,12 @@ def test_server_engine_failure_strands_nothing(gen):
         asyncio.new_event_loop().run_until_complete(scenario())
         # the self-heal path reset the running gauge after the failed run
         assert reg.get_sample_value("tpustack_llm_running_requests") == 0
-        # paged: the failed run's slots released their pool blocks — any
+        # the failed run's slots released their pool blocks — any
         # still-used block is held ONLY by the prefix cache (evictable),
         # never leaked by a stranded slot
-        if server.paged is not None:
-            assert (server.paged.pool.n_used
-                    == server.paged.cache.evictable_blocks())
+        assert (server.paged.pool.n_used
+                == server.paged.cache.evictable_blocks())
     finally:
-        gen._decode_scan_cont = real
         gen._decode_scan_paged = real_paged
 
 

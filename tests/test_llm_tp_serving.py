@@ -1,6 +1,6 @@
 """Tensor-parallel SERVING paths (ISSUE 10 tentpole): the continuous
-engine — dense slot caches, the paged block pool, int8 KV, and the
-speculative verify — run GSPMD-partitioned over a tp mesh with the KV
+engine — the block pool it builds itself and one handed in, int8 KV, and
+the speculative verify — run GSPMD-partitioned over a tp mesh with the KV
 substrate sharded on the head axis, and greedy outputs stay BYTE-IDENTICAL
 to the unsharded engine across all of it.  Plus: the pool tensors are
 provably head-axis-sharded (per-chip HBM = total/tp), the kv-pool leak
@@ -20,12 +20,11 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from tpustack.models.llama import LlamaConfig, init_kv_pool
+from tpustack.models.llama import LlamaConfig
 from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
 from tpustack.models.llm_generate import Generator, SampleConfig
 from tpustack.parallel import build_mesh
-from tpustack.serving.kv_pool import (KVBlockPool, PagedKVRuntime,
-                                      PagedPrefixCache)
+from tpustack.serving.kv_pool import PagedKVRuntime
 from tpustack.serving.speculative import SpecConfig
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -49,12 +48,10 @@ def _tp_gen(ref, tp, kv_quant=None, shard_kv=True):
 
 
 def _runtime(gen, capacity_blocks=32, cache=True):
-    pool = KVBlockPool(capacity_blocks + 1, BLOCK)
-    return PagedKVRuntime(
-        init_kv_pool(gen.cfg, capacity_blocks + 1, BLOCK, jnp.float32,
-                     mesh=gen.kv_mesh),
-        pool, gen.cfg.max_seq,
-        cache=PagedPrefixCache(pool) if cache else None)
+    return PagedKVRuntime.build(gen.cfg, 2, block=BLOCK,
+                                pool_blocks=capacity_blocks,
+                                dtype=jnp.float32, mesh=gen.kv_mesh,
+                                prefix_cache=cache)
 
 
 def _run(engine, requests):
@@ -73,8 +70,8 @@ def _run(engine, requests):
                                 pytest.param(8, marks=pytest.mark.slow)])
 def test_engine_tp_matches_unsharded_dense_and_paged(ref, tp):
     """THE acceptance bar: the continuous engine over a tp mesh emits the
-    unsharded engine's exact greedy bytes — dense slot caches AND the
-    paged block pool — including slot reuse, mixed lengths, and a seeded
+    unsharded engine's exact greedy bytes — on the pool it builds itself
+    AND on one handed in — including slot reuse, mixed lengths, and a seeded
     sampled row (per-slot PRNG streams are sharding-independent)."""
     tpg = _tp_gen(ref, tp)
     reqs = [{"ids": p, "max_new": 8} for p in PROMPTS]
@@ -82,14 +79,14 @@ def test_engine_tp_matches_unsharded_dense_and_paged(ref, tp):
                  "sample": SampleConfig(temperature=1.1, top_k=8)})
     base, _ = _run(ContinuousEngine(ref, slots=2, chunk=4,
                                     stop_tokens=(2,)), reqs)
-    dense, _ = _run(ContinuousEngine(tpg, slots=2, chunk=4,
-                                     stop_tokens=(2,)), reqs)
+    own, _ = _run(ContinuousEngine(tpg, slots=2, chunk=4,
+                                   stop_tokens=(2,)), reqs)
     rt = _runtime(tpg)
     free0 = rt.pool.n_free
     paged, _ = _run(ContinuousEngine(tpg, slots=2, chunk=4, stop_tokens=(2,),
                                      paged=rt), reqs)
     for i in range(len(reqs)):
-        assert dense[i][0] == base[i][0], f"tp dense row {i} diverged"
+        assert own[i][0] == base[i][0], f"tp own-pool row {i} diverged"
         assert paged[i][0] == base[i][0], f"tp paged row {i} diverged"
     # leak check under tp: everything still held is cache-resident (the
     # prefix trie's own refs); evicting it returns the pool to pristine
@@ -106,18 +103,18 @@ def test_engine_tp_int8_kv_matches_unsharded(ref):
     tpg = _tp_gen(ref, 2, kv_quant="int8")
     reqs = [{"ids": p, "max_new": 8} for p in PROMPTS[:3]]
     base, _ = _run(ContinuousEngine(solo, slots=2, chunk=4), reqs)
-    dense, _ = _run(ContinuousEngine(tpg, slots=2, chunk=4), reqs)
+    own, _ = _run(ContinuousEngine(tpg, slots=2, chunk=4), reqs)
     paged, _ = _run(ContinuousEngine(tpg, slots=2, chunk=4,
                                      paged=_runtime(tpg)), reqs)
     for i in range(len(reqs)):
-        assert dense[i][0] == base[i][0]
+        assert own[i][0] == base[i][0]
         assert paged[i][0] == base[i][0]
 
 
 def test_engine_tp_speculative_matches_unsharded(ref):
     """Speculative verify under tp: drafts scored by the mesh-partitioned
     one-pass verify accept exactly what the unsharded spec-off engine
-    would have produced — dense and paged."""
+    would have produced — own pool and handed-in pool."""
     # repetitive prompts so prompt-lookup actually drafts
     pat = [7, 11, 13, 5]
     prompts = [[pat[j % 4] + i for j in range(16)] for i in range(3)]
@@ -125,13 +122,13 @@ def test_engine_tp_speculative_matches_unsharded(ref):
     base, _ = _run(ContinuousEngine(ref, slots=2, chunk=4), reqs)
     tpg = _tp_gen(ref, 2)
     spec = lambda: SpecConfig(tokens=3)
-    dense, ds = _run(ContinuousEngine(tpg, slots=2, chunk=4, spec=spec()),
-                     reqs)
+    own, ds = _run(ContinuousEngine(tpg, slots=2, chunk=4, spec=spec()),
+                   reqs)
     rt = _runtime(tpg)
     paged, ps = _run(ContinuousEngine(tpg, slots=2, chunk=4, spec=spec(),
                                       paged=rt), reqs)
     for i in range(len(reqs)):
-        assert dense[i][0] == base[i][0], f"tp spec dense row {i} diverged"
+        assert own[i][0] == base[i][0], f"tp spec own-pool row {i} diverged"
         assert paged[i][0] == base[i][0], f"tp spec paged row {i} diverged"
     assert ds["spec_drafted_tokens"] > 0, "spec never drafted under tp"
     assert ps["spec_drafted_tokens"] > 0
@@ -388,7 +385,7 @@ def test_multihost_driver_single_process(monkeypatch, capsys, tmp_path):
 def test_bench_tp_tiny_smoke():
     """Shell ``tools/bench_llm.py --tp 2 --tiny`` — the CPU-runnable
     tensor-parallel sweep tier-1 keeps green: outputs identical tp on/off
-    in BOTH substrates and the per-chip weight bill strictly below the
+    on the block pool and the per-chip weight bill strictly below the
     unsharded total."""
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "bench_llm.py"),
@@ -402,7 +399,7 @@ def test_bench_tp_tiny_smoke():
     assert out["outputs_identical"] is True
     assert out["tp_ways"] == 2
     sweep = {c["mode"]: c for c in out["sweep"]}
-    assert set(sweep) == {"dense", "paged"}
+    assert set(sweep) == {"paged"}
     for cell in sweep.values():
         assert (cell["tp_on"]["weights_per_chip_bytes"]
                 < cell["tp_off"]["weights_per_chip_bytes"])

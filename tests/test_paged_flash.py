@@ -30,7 +30,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from tpustack.models.llama import LlamaConfig, init_kv_pool, pool_pages
+from tpustack.models.llama import LlamaConfig, pool_pages
 from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
 from tpustack.models.llm_generate import (Generator, SampleConfig,
                                           resolve_paged_flash)
@@ -43,7 +43,7 @@ from tpustack.ops.pallas.flash_attention import (PAGED_COMPUTE_TOKENS,
                                                  paged_flash_attention,
                                                  paged_pages_per_step,
                                                  paged_scale_rows)
-from tpustack.serving.kv_pool import KVBlockPool, PagedKVRuntime
+from tpustack.serving.kv_pool import PagedKVRuntime
 from tpustack.serving.speculative import SpecConfig
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -56,10 +56,9 @@ def gen():
 
 
 def make_runtime(gen, capacity_blocks=32, block=8):
-    pool = KVBlockPool(capacity_blocks + 1, block)
-    return PagedKVRuntime(
-        init_kv_pool(gen.cfg, capacity_blocks + 1, block, jnp.float32),
-        pool, gen.cfg.max_seq)
+    return PagedKVRuntime.build(gen.cfg, 2, block=block,
+                                pool_blocks=capacity_blocks,
+                                dtype=jnp.float32)
 
 
 # ------------------------------------------------------------ kernel units
@@ -586,15 +585,12 @@ def test_resolve_paged_flash_values(monkeypatch):
 _BISECT = r"""
 import json, sys
 import jax.numpy as jnp
-from tpustack.models.llama import LlamaConfig, init_kv_pool
+from tpustack.models.llama import LlamaConfig
 from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
 from tpustack.models.llm_generate import Generator, SampleConfig
-from tpustack.serving.kv_pool import KVBlockPool, PagedKVRuntime
 
 gen = Generator(LlamaConfig.tiny(max_seq=64), dtype=jnp.float32, seed=3)
-pool = KVBlockPool(33, 8)
-rt = PagedKVRuntime(init_kv_pool(gen.cfg, 33, 8, jnp.float32), pool, 64)
-eng = ContinuousEngine(gen, slots=2, chunk=4, paged=rt)  # knob-resolved
+eng = ContinuousEngine(gen, slots=2, chunk=4)  # own pool, knob-resolved
 res = {}
 reqs = [SlotRequest(ids=[3 + i, 7, 11, 13 + i], max_new=10,
                     sample=SampleConfig(greedy=True),

@@ -169,9 +169,9 @@ def test_compare_clean_within_wallclock_jitter():
 
 def test_compare_seeded_counter_regression_names_the_row():
     base = _rec({"engine.decode_weight_passes": 48,
-                 "recompiles._decode_scan_cont": 1})
+                 "recompiles._decode_scan_paged": 1})
     fresh = _rec({"engine.decode_weight_passes": 56,
-                  "recompiles._decode_scan_cont": 1})
+                  "recompiles._decode_scan_paged": 1})
     rows = perf_gate.compare(base, fresh, tolerance=0.35,
                              gate_wallclock=True)
     bad = [r for r in rows if r["gating"]

@@ -1,7 +1,8 @@
-"""Cross-request prefix KV cache (radix reuse) — the store itself, the
-Generator's extract/restore/suffix-prefill surgery, and end-to-end parity:
-greedy outputs must be IDENTICAL with the cache on vs off, across the solo
-path, the continuous engine, and the HTTP server.  The ISSUE's acceptance
+"""Cross-request prefix KV cache (radix reuse) — the solo route's host
+store itself, the Generator's extract/restore/suffix-prefill surgery, and
+end-to-end parity: greedy outputs must be IDENTICAL with the cache on vs
+off, across the solo path, the continuous engine and the HTTP server (both
+through the pool's block trie, ``PagedPrefixCache``).  The ISSUE's acceptance
 bars: cache-warm requests skip ≥50% of prefill tokens; the cache-off path
 is the unchanged pre-cache behavior; memory is bounded (LRU, byte cap)."""
 
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 from tpustack.models.llama import LlamaConfig
 from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
 from tpustack.models.llm_generate import Generator, SampleConfig
+from tpustack.serving.kv_pool import PagedKVRuntime
 from tpustack.serving.prefix_cache import PrefixCache
 
 GREEDY = SampleConfig(greedy=True)
@@ -163,18 +165,22 @@ def test_prefix_rejects_degenerate_cover(gen):
 
 
 # ------------------------------------------------------- continuous engine
-def _server_style_request(pc, ids, i, results, max_new=8):
-    """Wire a SlotRequest the way llm_server does: lookup before admission,
-    insert from the engine's extraction callback."""
-    m = pc.match(ids)
-    upto = pc.snap(len(ids))
-    spec = (m.length, upto) if upto > m.length else None
+def _pool(gen):
+    """A pool with a block trie at the old store's granularity (8)."""
+    return PagedKVRuntime.build(gen.cfg, 2, block=8, pool_blocks=32,
+                                dtype=gen.cache_dtype, prefix_cache=True)
+
+
+def _server_style_request(rt, ids, i, results, max_new=8):
+    """Wire a SlotRequest the way llm_server does: lookup before admission
+    (a hit's shared blocks ride ``prefix``), insert the prompt's full
+    blocks once the engine says prefill has landed."""
+    m = rt.cache.match(ids)
     return SlotRequest(
         ids=ids, max_new=max_new, sample=GREEDY,
-        prefix=(m.length, m.kv, m.key) if m.length else None,
-        kv_extract=spec,
-        on_prefill_kv=((lambda kv, ids=list(ids), s=m.length:
-                        pc.insert(ids, s, kv)) if spec else None),
+        prefix=(m.length, m.block_ids) if m.length else None,
+        on_prefill_blocks=(lambda bids, ids=list(ids):
+                           rt.cache.insert(ids, bids)),
         on_done=lambda t, s, i=i: results.__setitem__(i, (t, s)))
 
 
@@ -189,20 +195,22 @@ def test_engine_prefix_parity_and_stats(gen):
     ContinuousEngine(gen, slots=2, chunk=4).run(
         lambda: q.pop(0) if q else None)
 
-    pc = PrefixCache(chunk_tokens=8, capacity_bytes=1 << 22)
+    rt = _pool(gen)
     warm = {}
     for i, p in enumerate(prompts):
-        q2 = [_server_style_request(pc, p, i, warm)]
-        ContinuousEngine(gen, slots=2, chunk=4).run(
+        q2 = [_server_style_request(rt, p, i, warm)]
+        ContinuousEngine(gen, slots=2, chunk=4, paged=rt).run(
             lambda: q2.pop(0) if q2 else None)
 
     for i in range(4):
         assert warm[i][0] == cold[i][0], f"row {i} diverged"
+        assert warm[i][0] == gen.generate_fused(
+            prompts[i], max_new_tokens=8, sample=GREEDY, chunk=4)[0]
     assert warm[0][1]["cached_tokens"] == 0
     for i in (1, 2, 3):
         assert warm[i][1]["cached_tokens"] == 24
         assert warm[i][1]["prefill_tokens"] == 1
-    st = pc.stats()
+    st = rt.cache.stats()
     assert st["hits"] == 3 and st["misses"] == 1
     # acceptance bar: ≥50% of prefill tokens skipped on cache-warm requests
     skipped = sum(warm[i][1]["cached_tokens"] for i in (1, 2, 3))
@@ -216,21 +224,21 @@ def test_engine_prefix_hits_mixed_with_misses_in_one_wave(gen):
     shared = list(range(5, 5 + 24))
     hit_p = shared + [41]
     miss_p = [9, 10, 11]
-    pc = PrefixCache(chunk_tokens=8, capacity_bytes=1 << 22)
+    rt = _pool(gen)
     seed_res = {}
-    q0 = [_server_style_request(pc, shared + [40], 0, seed_res)]
-    ContinuousEngine(gen, slots=1, chunk=4).run(
+    q0 = [_server_style_request(rt, shared + [40], 0, seed_res)]
+    ContinuousEngine(gen, slots=1, chunk=4, paged=rt).run(
         lambda: q0.pop(0) if q0 else None)
-    assert pc.entries > 0
+    assert rt.cache.entries > 0
 
     solo_hit = gen.generate_fused(hit_p, max_new_tokens=8, sample=GREEDY,
                                   chunk=4)[0]
     solo_miss = gen.generate_fused(miss_p, max_new_tokens=8, sample=GREEDY,
                                    chunk=4)[0]
     res = {}
-    q = [_server_style_request(pc, hit_p, "hit", res),
-         _server_style_request(pc, miss_p, "miss", res)]
-    ContinuousEngine(gen, slots=2, chunk=4).run(
+    q = [_server_style_request(rt, hit_p, "hit", res),
+         _server_style_request(rt, miss_p, "miss", res)]
+    ContinuousEngine(gen, slots=2, chunk=4, paged=rt).run(
         lambda: q.pop(0) if q else None)
     assert res["hit"][0] == solo_hit
     assert res["miss"][0] == solo_miss
@@ -240,7 +248,9 @@ def test_engine_prefix_hits_mixed_with_misses_in_one_wave(gen):
 
 def test_engine_prefix_with_int8_kv_cache():
     """The store/restore path is layout-generic: int8 KV caches carry
-    their per-vector scales through extract → host → restore."""
+    their per-vector scales through extract → host → restore on the solo
+    route, and through shared pool blocks (scale planes included) on the
+    engine."""
     import dataclasses
 
     cfg = dataclasses.replace(LlamaConfig.tiny(max_seq=64), kv_quant="int8")
@@ -256,6 +266,13 @@ def test_engine_prefix_with_int8_kv_cache():
     warm, st = g.generate_fused(p2, max_new_tokens=6, sample=GREEDY, chunk=4,
                                 prefix=(16, store["kv"]))
     assert warm == cold and st["cached_tokens"] == 16
+    rt, res = _pool(g), {}
+    assert {"k", "v", "k_scale", "v_scale"} <= set(rt.arrays[0])
+    for i, p in enumerate((p1, p2)):
+        q = [_server_style_request(rt, p, i, res, max_new=6)]
+        ContinuousEngine(g, slots=1, chunk=4, paged=rt).run(
+            lambda: q.pop(0) if q else None)
+    assert res[1][0] == cold and res[1][1]["cached_tokens"] == 16
 
 
 # ------------------------------------------------------------- HTTP server
@@ -293,20 +310,24 @@ def test_server_cache_on_off_parity_props_and_metrics(gen):
     outs_off, props_off, _ = _post_all(off, prompts)
     assert props_off["prefix_cache"] == {"enabled": False}
 
-    pc = PrefixCache(chunk_tokens=8, capacity_bytes=1 << 22)
     on = LLMServer(generator=gen, tokenizer=ByteTokenizer(512), max_batch=4,
-                   registry=Registry(), prefix_cache=pc)
+                   registry=Registry(), paged=_pool(gen))
     outs_on, props_on, metrics = _post_all(on, prompts)
     assert outs_on == outs_off  # bit-identical greedy completions
     p = props_on["prefix_cache"]
-    assert p["enabled"] and p["chunk_tokens"] == 8
+    assert p["enabled"] and p["block_tokens"] == 8
     assert p["hits"] >= 2 and p["entries"] > 0 and p["hit_rate"] > 0
-    assert "capacity_mb" in p
-    # catalog metrics moved: lookups counted, residency gauges set
+    # catalog metrics moved: lookups counted
     assert 'tpustack_llm_prefix_cache_lookups_total{result="hit"} 2' in metrics
     assert ('tpustack_llm_prefix_cache_lookups_total{result="miss"} 1'
             in metrics)
-    assert "tpustack_llm_prefix_cache_bytes" in metrics
+    # the host store serves only the solo route: handing one to a server
+    # that runs the engine is an error, not a silent switch of engines
+    with pytest.raises(ValueError, match="PrefixCache"):
+        LLMServer(generator=gen, tokenizer=ByteTokenizer(512), max_batch=4,
+                  registry=Registry(),
+                  prefix_cache=PrefixCache(chunk_tokens=8,
+                                           capacity_bytes=1 << 22))
 
 
 def test_server_cache_prompt_opt_out(gen):
@@ -314,9 +335,10 @@ def test_server_cache_prompt_opt_out(gen):
     from tpustack.obs import Registry
     from tpustack.serving.llm_server import LLMServer
 
-    pc = PrefixCache(chunk_tokens=8, capacity_bytes=1 << 22)
+    rt = _pool(gen)
+    pc = rt.cache
     server = LLMServer(generator=gen, tokenizer=ByteTokenizer(512),
-                       max_batch=4, registry=Registry(), prefix_cache=pc)
+                       max_batch=4, registry=Registry(), paged=rt)
     from aiohttp.test_utils import TestClient, TestServer
 
     async def scenario():

@@ -28,13 +28,12 @@ import pytest
 
 import jax.numpy as jnp
 
-from tpustack.models.llama import LlamaConfig, init_kv_pool
+from tpustack.models.llama import LlamaConfig
 from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
 from tpustack.models.llm_generate import Generator, SampleConfig
 from tpustack.obs import Registry
 from tpustack.serving import qos as qos_mod
-from tpustack.serving.kv_pool import (KVBlockPool, PagedKVRuntime,
-                                      PagedPrefixCache)
+from tpustack.serving.kv_pool import PagedKVRuntime
 from tpustack.serving.qos import QosPolicy, TokenBucket
 from tpustack.serving.resilience import ResilienceManager
 from tpustack.serving.speculative import SpecConfig
@@ -53,11 +52,9 @@ def gen():
 
 
 def make_runtime(gen, capacity_blocks=32, block=8, cache=False):
-    pool = KVBlockPool(capacity_blocks + 1, block)
-    return PagedKVRuntime(
-        init_kv_pool(gen.cfg, capacity_blocks + 1, block, jnp.float32),
-        pool, gen.cfg.max_seq,
-        cache=PagedPrefixCache(pool) if cache else None)
+    return PagedKVRuntime.build(gen.cfg, 1, block=block,
+                                pool_blocks=capacity_blocks,
+                                dtype=jnp.float32, prefix_cache=cache)
 
 
 # ------------------------------------------------------------ token bucket

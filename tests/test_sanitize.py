@@ -226,7 +226,7 @@ def test_engine_declares_decode_budgets():
     eng = ContinuousEngine(gen, slots=2, chunk=4)
     assert eng._san is not None
     stats = eng._san.stats()
-    assert "_decode_scan_cont" in stats
+    assert "_decode_scan_paged" in stats
     eng._sanitize_wave()  # fresh engine: nothing compiled, no violation
 
 
@@ -287,11 +287,8 @@ def test_burst_cancel_leaves_pool_leak_free():
 
     cfg = LlamaConfig.tiny(max_seq=64)
     gen = Generator(cfg)
-    from tpustack.models.llama import init_kv_pool
-
-    pool = KVBlockPool(33, 8)
-    rt = PagedKVRuntime(init_kv_pool(cfg, 33, 8), pool, 64,
-                        PagedPrefixCache(pool))
+    rt = PagedKVRuntime.build(cfg, 2, block=8, pool_blocks=32,
+                              prefix_cache=True)
     eng = ContinuousEngine(gen, slots=2, chunk=4, paged=rt)
     cancelled = {"n": 0}
 

@@ -2,7 +2,7 @@
 verify step, the acceptance throttle, and the HTTP surface.
 
 The ISSUE's acceptance bars: greedy outputs byte-identical speculation on
-vs off across solo / engine / HTTP, paged AND dense, int8 KV, and with
+vs off across solo / engine / HTTP, own and handed-in pool, int8 KV, and with
 mid-stream cancellation in the mix; the plain path byte-for-byte
 unchanged at ``TPUSTACK_SPEC_TOKENS=0``; rejected draft KV never lands
 (paged block accounting stays capacity-true — the leak bar lives in
@@ -21,11 +21,10 @@ import sys
 import jax.numpy as jnp
 import pytest
 
-from tpustack.models.llama import LlamaConfig, init_kv_pool
+from tpustack.models.llama import LlamaConfig
 from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
 from tpustack.models.llm_generate import Generator, SampleConfig
-from tpustack.serving.kv_pool import (KVBlockPool, PagedKVRuntime,
-                                      PagedPrefixCache, eta_until_blocks)
+from tpustack.serving.kv_pool import PagedKVRuntime, eta_until_blocks
 from tpustack.serving.speculative import (DraftModelDrafter,
                                           PromptLookupDrafter, SpecConfig)
 
@@ -39,11 +38,9 @@ def gen():
 
 
 def make_runtime(gen, capacity_blocks=32, block=8, cache=True):
-    pool = KVBlockPool(capacity_blocks + 1, block)
-    return PagedKVRuntime(
-        init_kv_pool(gen.cfg, capacity_blocks + 1, block, jnp.float32),
-        pool, gen.cfg.max_seq,
-        cache=PagedPrefixCache(pool) if cache else None)
+    return PagedKVRuntime.build(gen.cfg, 2, block=block,
+                                pool_blocks=capacity_blocks,
+                                dtype=jnp.float32, prefix_cache=cache)
 
 
 def _run(engine, requests):
@@ -121,8 +118,9 @@ def test_draft_model_drafter_self_draft_is_greedy(gen):
 
 # ----------------------------------------------- engine greedy identity
 def test_engine_spec_matches_solo_dense_and_paged(gen):
-    """The tentpole bar: greedy outputs byte-identical speculation on vs
-    off, dense and paged, including slot reuse and mixed lengths.
+    """The tentpole bar: greedy outputs byte-identical to the solo path
+    with speculation on, over the engine's own pool and a handed-in one
+    with a prefix cache, including slot reuse and mixed lengths.
     Prompts are cyclic so the drafter genuinely proposes (and the tiny
     model's generated tail cycles, so drafts genuinely get accepted)."""
     prompts = [[5, 6, 7, 5, 6, 7, 5, 6], [9, 10, 9, 10, 9, 10], [20],
@@ -131,15 +129,15 @@ def test_engine_spec_matches_solo_dense_and_paged(gen):
     solo = [gen.generate_fused(p, max_new_tokens=16, sample=GREEDY,
                                stop_tokens=(2,), chunk=4)[0] for p in prompts]
     spec = lambda: SpecConfig(tokens=4)
-    dense, st = _run(ContinuousEngine(gen, slots=2, chunk=4,
-                                      stop_tokens=(2,), spec=spec()), reqs)
+    own, st = _run(ContinuousEngine(gen, slots=2, chunk=4,
+                                    stop_tokens=(2,), spec=spec()), reqs)
     rt = make_runtime(gen)
     free0 = rt.pool.n_free
     paged, stp = _run(ContinuousEngine(gen, slots=2, chunk=4,
                                        stop_tokens=(2,), paged=rt,
                                        spec=spec()), reqs)
     for i, s in enumerate(solo):
-        assert dense[i][0] == s, f"dense spec row {i} diverged from solo"
+        assert own[i][0] == s, f"own-pool spec row {i} diverged from solo"
         assert paged[i][0] == s, f"paged spec row {i} diverged from solo"
     # the sweep genuinely speculated, and the twins dispatched identically
     assert st["spec_dispatches"] > 0 and st["spec_accepted_tokens"] > 0
@@ -156,13 +154,13 @@ def test_engine_spec_int8_kv_parity():
     solo = [g.generate_fused(p, max_new_tokens=10, sample=GREEDY, chunk=4)[0]
             for p in prompts]
     reqs = [{"ids": p, "max_new": 10, "sample": GREEDY} for p in prompts]
-    dense, _ = _run(ContinuousEngine(g, slots=2, chunk=4,
-                                     spec=SpecConfig(tokens=4)), reqs)
+    own, _ = _run(ContinuousEngine(g, slots=2, chunk=4,
+                                   spec=SpecConfig(tokens=4)), reqs)
     paged, _ = _run(ContinuousEngine(g, slots=2, chunk=4,
                                      paged=make_runtime(g),
                                      spec=SpecConfig(tokens=4)), reqs)
     for i, s in enumerate(solo):
-        assert dense[i][0] == s and paged[i][0] == s
+        assert own[i][0] == s and paged[i][0] == s
 
 
 def test_engine_spec_stop_token_inside_accepted_run(gen):
@@ -258,7 +256,7 @@ def test_engine_spec_adversarial_drafter_throttles_to_plain(gen):
 def test_engine_spec_seeded_sampling_deterministic(gen):
     """Sampled rows under speculation: rejection sampling rides the
     per-slot PRNG chain, so a seeded request reproduces exactly (same
-    seed → same tokens, dense == paged) and mixes safely with greedy
+    seed → same tokens, own pool == handed-in pool) and mixes safely with greedy
     peers (who stay byte-exact)."""
     seeded = {"ids": [5, 6, 5, 6, 5, 6], "max_new": 8, "seed": 99,
               "sample": SampleConfig(temperature=1.2, top_k=8)}

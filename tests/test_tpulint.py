@@ -358,7 +358,7 @@ def test_tpl401_quiet_on_registry_reads_and_env_writes(tmp_path):
 
         from tpustack.utils import knobs
 
-        a = knobs.get_bool("TPUSTACK_PAGED_KV")
+        a = knobs.get_bool("TPUSTACK_PREFIX_CACHE")
         b = os.environ.get("SOME_OTHER_VAR", "")
         os.environ["TPUSTACK_FOO"] = "1"  # configuring a child process
     """, select=["TPL401"]) == []

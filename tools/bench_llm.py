@@ -166,18 +166,19 @@ def _paged_bench(args, gen, cfg, log, watch, t0) -> int:
     """``--paged``: the capacity-true-admission workload the paged KV pool
     exists for — a concurrency sweep over request context footprints
     (``--req-ctx``, default 1k/4k/8k clipped to ctx) with the SAME HBM
-    budget in both modes: the dense engine reserves ``--dense-slots`` full
-    ``max_seq`` cache lines (its admission cap), the paged engine carves
-    the identical token budget into blocks and admits by ``ceil((prompt +
-    max_new) / block)``.  Reports admitted concurrency, end-to-end
-    tokens/s, p50/p99 TTFT and peak pool utilization per footprint, and
-    asserts greedy outputs identical paged-vs-dense plus a free-block leak
-    check (pool returns to its initial free count after the burst)."""
-    from tpustack.models.llama import init_kv_pool
+    budget in both arms: the ``dense`` arm is an engine held to
+    ``--dense-slots`` slots (what reserving a full ``max_seq`` line per
+    request admits; it builds its own pool of that many lines), the
+    ``paged`` arm carves the identical token budget into blocks and
+    admits by ``ceil((prompt + max_new) / block)`` over as many slots as
+    that allows.  Reports admitted concurrency, end-to-end tokens/s,
+    p50/p99 TTFT and peak pool utilization per footprint, and asserts
+    greedy outputs identical across the arms plus a free-block leak check
+    (pool returns to its initial free count after the burst)."""
     from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
     from tpustack.models.llm_generate import SampleConfig
     from tpustack.obs.kvprof import KVProfiler
-    from tpustack.serving.kv_pool import KVBlockPool, PagedKVRuntime
+    from tpustack.serving.kv_pool import PagedKVRuntime
 
     sample = SampleConfig(greedy=True)
     ctx = cfg.max_seq
@@ -222,17 +223,16 @@ def _paged_bench(args, gen, cfg, log, watch, t0) -> int:
         def feed():
             if not queue:
                 return None
-            if engine.paged is not None:
-                ids, new = queue[0].ids, queue[0].max_new
-                need = engine.paged.need_blocks(len(ids), new)
-                if not engine.paged.ensure_free(need):
-                    return None  # capacity-true: wait for block release
+            ids, new = queue[0].ids, queue[0].max_new
+            need = engine.paged.need_blocks(len(ids), new)
+            if not engine.paged.ensure_free(need):
+                return None  # capacity-true: wait for block release
             if pool is not None:
                 peak["used"] = max(peak["used"], pool.n_used)
             return queue.pop(0)
 
         stats = engine.run(feed)
-        if engine.paged is not None:
+        if pool is not None:  # the paged arm's kernel split only
             kern["tag"] = stats.get("decode_kernel")
             kern["gather"] += stats.get("kernel_gather_dispatches", 0)
             kern["flash"] += stats.get("kernel_paged_flash_dispatches", 0)
@@ -273,10 +273,10 @@ def _paged_bench(args, gen, cfg, log, watch, t0) -> int:
                                              chunk=min(args.chunk, new))
         run_fleet(dense_eng(), warm)
         dense_res, dense = run_fleet(dense_eng(), reqs)
-        pool = KVBlockPool(capacity + 1, block)
-        rt = PagedKVRuntime(
-            init_kv_pool(cfg, capacity + 1, block, dtype=gen.cache_dtype),
-            pool, ctx)
+        rt = PagedKVRuntime.build(cfg, dense_slots, block=block,
+                                  pool_blocks=capacity,
+                                  dtype=gen.cache_dtype)
+        pool = rt.pool
         # KV working-set observatory riding the bench pool: forced-on
         # sampling, snapshot-only (no registry) — the artifact carries
         # block-lifetime/curve/calibration evidence; the pool counters in
@@ -387,13 +387,11 @@ def _host_tier_bench(args, gen, cfg, log, watch, t0) -> int:
     restore and the smoke would pin zeros."""
     import random
 
-    from tpustack.models.llama import init_kv_pool
     from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
     from tpustack.models.llm_generate import SampleConfig
     from tpustack.obs.kvprof import KVProfiler
     from tpustack.serving.kv_host_tier import HostKVTier
-    from tpustack.serving.kv_pool import (KVBlockPool, OutOfBlocks,
-                                          PagedKVRuntime, PagedPrefixCache)
+    from tpustack.serving.kv_pool import OutOfBlocks, PagedKVRuntime
 
     sample = SampleConfig(greedy=True)
     ctx, vocab = cfg.max_seq, cfg.vocab_size
@@ -453,13 +451,10 @@ def _host_tier_bench(args, gen, cfg, log, watch, t0) -> int:
         return prefix, kv_blocks, on_insert, host_restore
 
     def run_mode(tier_mb, order):
-        pool = KVBlockPool(pool_blocks + 1, block)
-        rt = PagedKVRuntime(
-            init_kv_pool(cfg, pool_blocks + 1, block,
-                         dtype=gen.cache_dtype),
-            pool, ctx, cache=None)
-        cache = PagedPrefixCache(pool)
-        rt.cache = cache
+        rt = PagedKVRuntime.build(cfg, 1, block=block,
+                                  pool_blocks=pool_blocks,
+                                  dtype=gen.cache_dtype, prefix_cache=True)
+        pool, cache = rt.pool, rt.cache
         tier = None
         if tier_mb:
             cache.host_tier = tier = HostKVTier(
@@ -571,10 +566,9 @@ def _chunked_prefill_bench(args, gen, cfg, log, watch, t0) -> int:
     engine; reports tokens/s and short-request TTFT both ways with the
     chunk-dispatch count pinned in the signature, greedy outputs
     asserted identical and a free-block leak check."""
-    from tpustack.models.llama import init_kv_pool
     from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
     from tpustack.models.llm_generate import SampleConfig
-    from tpustack.serving.kv_pool import KVBlockPool, PagedKVRuntime
+    from tpustack.serving.kv_pool import PagedKVRuntime
 
     sample = SampleConfig(greedy=True)
     ctx, vocab = cfg.max_seq, cfg.vocab_size
@@ -588,7 +582,6 @@ def _chunked_prefill_bench(args, gen, cfg, log, watch, t0) -> int:
     short_p = block // 2
     n_short = max(2, args.requests // 2)
     slots = 2
-    pool_blocks = slots * (ctx // block)
     dchunk = min(args.chunk, new)
 
     longs = [[(5 + j) % (vocab - 1) + 1 for j in range(long_p)]]
@@ -597,11 +590,9 @@ def _chunked_prefill_bench(args, gen, cfg, log, watch, t0) -> int:
     reqs = longs + shorts
 
     def run_mode(prefill_chunk):
-        pool = KVBlockPool(pool_blocks + 1, block)
-        rt = PagedKVRuntime(
-            init_kv_pool(cfg, pool_blocks + 1, block,
-                         dtype=gen.cache_dtype),
-            pool, ctx)
+        rt = PagedKVRuntime.build(cfg, slots, block=block,
+                                  dtype=gen.cache_dtype)
+        pool = rt.pool
         results = {}
         queue = [SlotRequest(ids=ids, max_new=new, sample=sample,
                              on_done=lambda t, s, i=i:
@@ -670,20 +661,19 @@ def _chunked_prefill_bench(args, gen, cfg, log, watch, t0) -> int:
 def _tp_bench(args, gen, cfg, log, watch, t0) -> int:
     """``--tp N``: the tensor-parallel serving sweep — the continuous
     engine (the served path) run UNSHARDED then over a (1, 1, N, 1) mesh
-    with the same weights, dense and paged, asserting greedy outputs
-    byte-identical tp-on vs tp-off.  Reports end-to-end + steady tokens/s,
+    with the same weights, asserting greedy outputs byte-identical tp-on
+    vs tp-off.  Reports end-to-end + steady tokens/s,
     TTFT/TPOT p50-p99, and the per-chip HBM bill (weights + KV largest
     single-device shard) in each mode — the latency/model-size trade the
     mesh exists for.  On real hardware tp=N needs N chips; short device
     counts emit an error record instead of crashing the extras run."""
     import jax
 
-    from tpustack.models.llama import init_kv_pool
     from tpustack.models.llm_continuous import ContinuousEngine, SlotRequest
     from tpustack.models.llm_generate import Generator, SampleConfig
     from tpustack.parallel import build_mesh
     from tpustack.parallel.sharding import tree_per_shard_bytes
-    from tpustack.serving.kv_pool import KVBlockPool, PagedKVRuntime
+    from tpustack.serving.kv_pool import PagedKVRuntime
 
     tp = args.tp
     if len(jax.devices()) < tp:
@@ -704,18 +694,9 @@ def _tp_bench(args, gen, cfg, log, watch, t0) -> int:
             + [(11 + i + j) % (vocab - 1) + 1 for j in range(p_len - 1)]
             for i in range(n_req)]
 
-    def make_rt(g):
-        block = max(1, min(args.kv_block, ctx))
-        while block > 1 and ctx % block:
-            block //= 2
-        cap = batch * (ctx // block)
-        pool = KVBlockPool(cap + 1, block)
-        return PagedKVRuntime(
-            init_kv_pool(cfg, cap + 1, block, dtype=g.cache_dtype,
-                         mesh=g.kv_mesh), pool, ctx)
-
-    def run_fleet(g, paged):
-        rt = make_rt(g) if paged else None
+    def run_fleet(g):
+        rt = PagedKVRuntime.build(cfg, batch, block=max(1, args.kv_block),
+                                  dtype=g.cache_dtype, mesh=g.kv_mesh)
         eng = ContinuousEngine(g, slots=batch, chunk=chunk, paged=rt)
         results = {}
         queue = [SlotRequest(ids=ids, max_new=new,
@@ -738,30 +719,25 @@ def _tp_bench(args, gen, cfg, log, watch, t0) -> int:
             "tpot_p50_ms": round(q(tpots, 0.50) * 1e3, 2),
             "tpot_p99_ms": round(q(tpots, 0.99) * 1e3, 2),
             "weights_per_chip_bytes": tree_per_shard_bytes(g.params),
-            "kv_per_chip_bytes": (rt.per_shard_bytes if rt is not None
-                                  else None),
+            "kv_per_chip_bytes": rt.per_shard_bytes,
         }
         return results, cell
 
-    sweep = []
-    identical = True
-    for mode, paged in (("dense", False), ("paged", True)):
-        run_fleet(gen, paged)       # warm (compile) — uncounted
-        run_fleet(tp_gen, paged)
-        res_off, off = run_fleet(gen, paged)
-        res_on, on = run_fleet(tp_gen, paged)
-        same = all(res_off[i][0] == res_on[i][0] for i in range(n_req))
-        identical = identical and same
-        sweep.append({"mode": mode, "batch": batch, "tp_off": off,
-                      "tp_on": on, "outputs_identical": same})
-        log(f"[bench_llm] tp sweep {mode} batch {batch}: tp=1 "
-            f"{off['tokens_per_s']} tok/s vs tp={tp} {on['tokens_per_s']} "
-            f"tok/s (per-chip weights {on['weights_per_chip_bytes'] / 1e9:.2f}"
-            f" GB vs {off['weights_per_chip_bytes'] / 1e9:.2f} GB, "
-            f"identical={same})")
+    run_fleet(gen)       # warm (compile) — uncounted
+    run_fleet(tp_gen)
+    res_off, off = run_fleet(gen)
+    res_on, on = run_fleet(tp_gen)
+    identical = all(res_off[i][0] == res_on[i][0] for i in range(n_req))
+    paged_cell = {"mode": "paged", "batch": batch, "tp_off": off,
+                  "tp_on": on, "outputs_identical": identical}
+    sweep = [paged_cell]
+    log(f"[bench_llm] tp sweep batch {batch}: tp=1 "
+        f"{off['tokens_per_s']} tok/s vs tp={tp} {on['tokens_per_s']} "
+        f"tok/s (per-chip weights {on['weights_per_chip_bytes'] / 1e9:.2f}"
+        f" GB vs {off['weights_per_chip_bytes'] / 1e9:.2f} GB, "
+        f"identical={identical})")
     if not identical:
         log("[bench_llm] WARNING: tp outputs diverged from unsharded")
-    paged_cell = sweep[1]
     from tpustack.obs import perfsig
 
     sig = perfsig.signature(watch=watch,
@@ -987,9 +963,10 @@ def main() -> int:
                    help="paged-mode CPU smoke shape: --preset tiny with "
                         "scaled footprints (the tier-1 suite shells this)")
     p.add_argument("--dense-slots", type=int, default=8,
-                   help="paged mode: the dense engine's slot count — both "
-                        "the dense admission cap AND the shared HBM budget "
-                        "(pool tokens = dense-slots x ctx)")
+                   help="paged mode: the slot count of the arm that admits "
+                        "a whole ctx line per request — its admission cap "
+                        "AND the shared HBM budget (pool tokens = "
+                        "dense-slots x ctx)")
     p.add_argument("--kv-block", type=int, default=64,
                    help="paged mode: block size in tokens "
                         "(TPUSTACK_KV_BLOCK analog; snapped to divide ctx)")

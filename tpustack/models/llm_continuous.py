@@ -12,28 +12,34 @@ batch generation.
 This engine is the TPU-native version of those semantics under XLA's
 static-shape rules:
 
-- **Fixed slot count** ``B`` (one compiled decode program per (B, chunk)),
-  persistent KV cache ``[B, max_seq]``.  Idle slots decode garbage at
-  position 0 — decode streams the weights once per step regardless of how
-  many slots are live, so an idle slot costs almost nothing.
-- **Per-slot contiguous cache lines**: row i decodes at its own frontier
-  ``cur[i]``, attends ``[0, cur[i]]`` with true RoPE positions.  No shared
-  prompt bucket: every row's budget is its own ``max_seq - len(prompt)``,
+- **Fixed slot count** ``B`` (one compiled decode program per (B, chunk))
+  over ONE KV store: the block pool (``tpustack.serving.kv_pool``; made
+  by ``PagedKVRuntime.build``).  A slot's cache line is a BLOCK TABLE —
+  ``max_seq // block`` ids into the pool — so admission capacity is free
+  blocks (``prompt + max_new`` of them, not a whole ``max_seq`` line), a
+  prefix hit is a refcount bump on shared blocks, and the pool arrays
+  persist across runs (cached blocks outlive busy periods).  Idle slots
+  decode garbage at position 0 against the reserved block 0 — decode
+  streams the weights once per step regardless of how many slots are
+  live, so an idle slot costs almost nothing.
+- **Per-slot frontiers**: row i decodes at its own frontier ``cur[i]``,
+  attends ``[0, cur[i]]`` with true RoPE positions.  No shared prompt
+  bucket: every row's budget is its own ``max_seq - len(prompt)``,
   unlike ``generate_batch``'s longest-peer bucket.
-- **Chunk-local K/V accumulation**: within a decode chunk the main cache is
+- **Chunk-local K/V accumulation**: within a decode chunk the pool is
   FROZEN — each step's K/V land in a small per-layer ``[B, chunk]`` buffer
-  at the uniform step index, attention merges {cache prefix} ∪ {buffer}
-  with an exact streaming-softmax split, and the buffer flushes into the
-  per-row cache lines once per chunk (``Generator._decode_scan_cont``).
-  The r4 one-hot write-back rewrote the whole cache every step (~2x KV
-  traffic for concurrent long-context decodes); write-back now amortises
-  by the chunk length, so concurrent deep decodes stay KV-read-bound.
+  at the uniform step index, attention merges {pool blocks} ∪ {buffer}
+  with an exact streaming-softmax split, and the buffer is written
+  through the block tables once per chunk
+  (``Generator._decode_scan_paged``), so write-back amortises by the
+  chunk length and concurrent deep decodes stay KV-read-bound.
 - **Overlapped one-dispatch admission at chunk boundaries**: a joining
-  wave's fresh row caches, prefill, KV-line splice, first-token sampling
-  and slot activation run as ONE fused device program
-  (``Generator._admit_fused``; prompts longer than PREFILL_CHUNK run the
+  wave's fresh row caches, prefill, write through the rows' block tables,
+  first-token sampling and slot activation run as ONE fused device
+  program (``Generator._admit_fused_paged``; a prefix hit's warm start is
+  ``_admit_prefix_paged``; prompts longer than PREFILL_CHUNK run the
   fused-scan chunked prefill — or a per-chunk host loop for non-multiple
-  buckets — plus the splice/sample/activate dispatches) — the host
+  buckets — plus the write/sample/activate dispatches) — the host
   never syncs on admission, so the depth-``depth`` pipelined chunk chain
   keeps flowing while prefill is still in flight.  The host picks up the
   first tokens (one tiny [n]-int32 fetch) at the next natural sync point,
@@ -45,33 +51,23 @@ static-shape rules:
   its own ``seed`` (or a fresh random one) and advanced once per generated
   token, so sampled output — like greedy — is a pure function of (request,
   seed): independent of admission timing and batch composition.  That is
-  what lets the server put seeded-sampled requests in slots instead of the
-  r4 solo carve-out.
+  what lets the server put seeded-sampled requests in slots.
 - **Retirement at fetch**: a row hitting EOS/budget is answered immediately
-  (``on_done``) and its slot parked (``active=0``, ``cur=0``) then reused.
+  (``on_done``), its blocks decref'd and its slot parked (``active=0``,
+  ``cur=0``) then reused.
 
 Safety of the fetch-lag overshoot (host retires up to ``depth`` chunks after
 the device computed them): ``cur`` clamps at ``max_seq - 1``, a parked slot
-freezes at position 0, overshoot steps are clipped out of the chunk-flush
-window (never written to the cache at all), and a reassigned slot's prefill
-+ contiguous decode overwrite every position its mask will ever attend.
+freezes at position 0, overshoot steps are clipped out of the chunk's
+write window (never written to the pool at all), and a reassigned slot's
+prefill + contiguous decode overwrite every position its mask will ever
+attend.  Freed blocks reassigned while chunks are in flight: dispatches
+execute in order on the device stream and the host frees a retiring
+slot's blocks BEFORE dispatching the new owner's admission, so a stale
+chunk's write lands first and is overwritten before any mask admits it.
 
-Measured (v5e, Qwen-7B int8+int8KV, ``tools/bench_llm.py --continuous`` —
-the numbers BASELINE.md quotes for batched serving, since this engine IS
-the served path):
-
-- 8x(128 prompt + 512 new), ctx 2048: **672-695 tok/s end-to-end,
-  753 tok/s steady aggregate decode** (128-new short generations:
-  444-543 e2e) — vs the static batcher's 630 decode-phase / ~371 e2e
-  same-session (the r4 engine measured 441 e2e: +9% admission tax then;
-  the r5 engine's one-dispatch admissions + chunk-local K/V + all-greedy
-  sampling gate turned that into a ~20% steady-state LEAD over the
-  static path).  Residual e2e spread sits in the remaining host
-  round-trips (admission, fetch); steady decode (the slope between the
-  first and last block fetches) is the figure free of them.
-- 2x(16384 prompt + 96 new), ctx 32768: **143.8 tok/s steady = 92% of
-  2x the solo-row rate** (78.1 tok/s) — the long-context write-back cliff
-  the r4 docstring predicted ("would roughly double KV traffic") is gone.
+What this engine measures on the chip is the benchmark's to say
+(``PERF.md``, ``PERF_LEDGER.jsonl``).
 """
 
 from __future__ import annotations
@@ -89,9 +85,12 @@ import numpy as np
 
 from tpustack import sanitize
 from tpustack.models.llama import init_kv_caches
-from tpustack.models.llm_generate import Generator, SampleConfig
+from tpustack.models.llm_generate import (Generator, SampleConfig,
+                                          resolve_paged_flash)
 from tpustack.obs.flight import PhaseClock
-from tpustack.utils import get_logger
+from tpustack.serving.kv_pool import (OutOfBlocks, PagedKVRuntime,
+                                      eta_until_blocks)
+from tpustack.utils import get_logger, knobs
 
 log = get_logger("models.llm_continuous")
 
@@ -111,31 +110,22 @@ class SlotRequest:
     its output exactly regardless of admission timing / batch peers (per-
     slot key chains); None draws a fresh random seed.
 
-    Prefix-KV-cache hooks (``tpustack.serving.prefix_cache``): ``prefix``
-    is an optional ``(n_cached, kv)`` hit — the cached KV restores into
-    the slot's cache line and admission prefills ONLY the uncached suffix;
-    ``kv_extract`` is an optional ``(start, end)`` token range the engine
-    slices out of the slot's cache after prefill and hands (as host numpy
-    arrays) to ``on_prefill_kv`` — the server's cache-insert hook.  All
-    three default to None: the no-cache path is byte-for-byte the
-    pre-prefix-cache engine.
+    Pool hooks (``tpustack.serving.kv_pool``): ``prefix`` is an optional
+    ``(n_cached, block_ids)`` hit — shared POOL blocks the lookup already
+    incref'd for this request; the engine installs them in the slot's
+    block table (no KV moves) and admission prefills ONLY the uncached
+    suffix.  ``kv_blocks`` optionally carries pre-allocated fresh blocks
+    (the server reserves at admission so the HTTP capacity check and the
+    engine can never disagree); None lets the engine allocate.
+    ``on_prefill_blocks(ids)`` fires once prefill has provably landed,
+    with the blocks covering the prompt's full blocks — the server's
+    zero-copy cache-insert hook.  All default to None: no prefix cache.
 
     ``span_ctx``: the request's trace context (``tpustack.obs.trace
     .SpanContext``).  Engine threads don't inherit the handler's
     contextvars, so the server passes the handle explicitly; when set
     (and the engine has a tracer) the request's prefill/wave spans parent
     under its HTTP root span.
-
-    Paged-KV hooks (engines constructed with a ``kv_pool.PagedKVRuntime``):
-    ``prefix`` becomes ``(n_cached, block_ids)`` — shared POOL blocks the
-    lookup already incref'd for this request (the engine installs them in
-    the slot's block table; no KV moves).  ``kv_blocks`` optionally carries
-    pre-allocated fresh blocks (the server reserves at admission so the
-    HTTP capacity check and the engine can never disagree); None lets the
-    engine allocate.  ``on_prefill_blocks(ids)`` fires once prefill has
-    provably landed, with the blocks covering the prompt's full blocks —
-    the server's zero-copy cache-insert hook.  ``kv_extract``/
-    ``on_prefill_kv`` are the DENSE hooks and are ignored under paging.
 
     ``speculative``: per-request opt-out (body ``"speculative": false``) —
     False means this row never drafts (it still rides batch-wide verify
@@ -158,8 +148,6 @@ class SlotRequest:
     cancelled: Callable[[], bool] = lambda: False
     seed: Optional[int] = None
     prefix: Optional[Tuple[int, list]] = None
-    kv_extract: Optional[Tuple[int, int]] = None
-    on_prefill_kv: Optional[Callable[[list], None]] = None
     span_ctx: Optional[object] = None
     kv_blocks: Optional[List[int]] = None
     on_prefill_blocks: Optional[Callable[[List[int]], None]] = None
@@ -167,7 +155,7 @@ class SlotRequest:
     # tenant cost accounting (tpustack.obs.accounting): the request's
     # tenant id, resolved once by the HTTP middleware and carried here
     # explicitly (engine threads don't inherit the contextvar — same
-    # contract as span_ctx), and the wall-clock the request's paged KV
+    # contract as span_ctx), and the wall-clock the request's KV
     # blocks were allocated at (the server's admission-is-allocation
     # point; None = the engine's own admission time) — the alloc→release
     # window the KV-block-seconds charge covers.  Both None on bench/CLI
@@ -232,13 +220,13 @@ class _Slot:
         self.dispatched = 0  # decode steps dispatched for this occupancy
         self.done = True
         self.pending = False  # admission dispatched, firsts not yet fetched
-        self.cached = 0  # prompt tokens restored from the prefix KV cache
+        self.cached = 0  # prompt tokens a prefix hit's shared blocks cover
         self.span = None  # active trace span: prefill until resolve, wave
         # from resolve to retire (None when the request carries no context)
-        self.blocks: List[int] = []  # paged: pool blocks this slot holds a
+        self.blocks: List[int] = []  # pool blocks this slot holds a
         # reference on (shared prefix ids first, then fresh) — decref'd
         # exactly once at retire
-        self.alloc = 0  # paged: tokens this slot's allocation covers
+        self.alloc = 0  # tokens this slot's allocation covers
         # speculation state (engines constructed with spec=SpecConfig):
         # rolling acceptance-rate EMA (optimistic start — the first verify
         # measures the real rate), waves since this slot last drafted (the
@@ -253,16 +241,13 @@ class _Slot:
 class _PendingWave:
     """One dispatched-but-unresolved admission group: the device is (or
     soon will be) holding the group's first tokens; ``resolve`` fetches
-    them and completes the host-side bookkeeping.  ``extracts``: per-row
-    prefix-cache KV slices dispatched right after the splice — fetched at
-    resolution (when prefill has provably landed) and handed to each
-    request's ``on_prefill_kv``."""
+    them and completes the host-side bookkeeping."""
 
-    __slots__ = ("rows", "firsts_dev", "t0", "extracts", "block_inserts",
-                 "bucket", "moe_dev")
+    __slots__ = ("rows", "firsts_dev", "t0", "block_inserts", "bucket",
+                 "moe_dev")
 
-    def __init__(self, rows, firsts_dev, t0, extracts=(), block_inserts=(),
-                 bucket=None, moe_dev=None):
+    def __init__(self, rows, firsts_dev, t0, block_inserts=(), bucket=None,
+                 moe_dev=None):
         self.rows = rows            # [(slot_idx, req, budget)]
         self.firsts_dev = firsts_dev
         # the admission's routed-expert counters (Generator._apply_counted;
@@ -270,14 +255,13 @@ class _PendingWave:
         self.moe_dev = moe_dev
         self.t0 = t0
         self.bucket = bucket        # padded tokens a row (a hit: its suffix)
-        self.extracts = list(extracts)  # [(req, device kv slices)]
-        # paged: [(req, prompt block ids)] — handed to on_prefill_blocks at
+        # [(req, prompt block ids)] — handed to on_prefill_blocks at
         # resolution (zero-copy cache insert; no device work at all)
         self.block_inserts = list(block_inserts)
 
 
 class ContinuousEngine:
-    """Drives ``Generator._decode_scan_cont`` over persistent slots.
+    """Drives ``Generator._decode_scan_paged`` over persistent slots.
 
     ``run(feed)`` decodes until every admitted request is answered and
     ``feed()`` returns None; it is synchronous and device-blocking — the
@@ -337,18 +321,17 @@ class ContinuousEngine:
         # drains) ask for the same history's draft — pay the drafter once
         # (matters for DraftModelDrafter, whose proposal is a model run)
         self._draft_memo: Dict[int, Tuple[Tuple[int, int, int], List[int]]] = {}
-        # paged KV substrate (tpustack.serving.kv_pool.PagedKVRuntime):
-        # slots hold BLOCK TABLES into one shared HBM pool instead of
-        # private [max_seq] cache lines — admission capacity is free
-        # blocks, prefix hits are refcount bumps, and the pool arrays
-        # persist across runs (cached blocks outlive busy periods).  None
-        # keeps the dense engine byte-for-byte.
-        self.paged = paged
-        if paged is not None:
-            if gen.cfg.max_seq != paged.max_seq:
-                raise ValueError(
-                    f"paged runtime max_seq {paged.max_seq} != engine "
-                    f"config {gen.cfg.max_seq}")
+        # the KV store (tpustack.serving.kv_pool.PagedKVRuntime): slots
+        # hold BLOCK TABLES into one shared HBM pool whose arrays persist
+        # across runs.  The server hands in the runtime it admits against;
+        # None (tests, benches) makes a pool of this engine's own: what
+        # ``slots`` whole cache lines would hold, no prefix cache.
+        self.paged = paged or PagedKVRuntime.build(
+            gen.cfg, slots, dtype=gen.cache_dtype, mesh=gen.kv_mesh)
+        if gen.cfg.max_seq != self.paged.max_seq:
+            raise ValueError(
+                f"paged runtime max_seq {self.paged.max_seq} != engine "
+                f"config {gen.cfg.max_seq}")
         # paged-flash (TPUSTACK_PAGED_FLASH): read pool blocks IN PLACE
         # via the scalar-prefetch Pallas kernel instead of gathering a
         # dense per-slot copy every chunk — the static `flash` flag on
@@ -358,17 +341,14 @@ class ContinuousEngine:
         # on for real TPU kinds, off on CPU/interpret and under a mesh);
         # False is byte-for-byte the gather engine.
         if paged_flash is None:
-            from tpustack.models.llm_generate import resolve_paged_flash
-
-            paged_flash = paged is not None and resolve_paged_flash(
-                mesh=gen.mesh)
-        self.paged_flash = bool(paged_flash) and paged is not None
+            paged_flash = resolve_paged_flash(mesh=gen.mesh)
+        self.paged_flash = bool(paged_flash)
         # per-run kernel-dispatch split (perfsig signature counters: the
         # gather path's copy count must read ZERO when the kernel is
         # active — the perf gate's paged-flash scenario pins it)
         self._gather_dispatches = 0
         self._flash_dispatches = 0
-        self._bt = None  # paged: host block tables [B, blocks_per_seq]
+        self._bt = None  # host block tables [B, blocks_per_seq]
         self._slots_view = None  # live slots during run() (release hints)
         # distributed tracing (tpustack.obs.trace.Tracer): per-request
         # prefill/wave spans parented to each SlotRequest's span_ctx.  None
@@ -398,8 +378,7 @@ class ContinuousEngine:
         # engine accounting-free (bench/CLI paths).
         self.ledger = ledger
         self._queue_depth_fn = queue_depth
-        # QoS preemption (tpustack.serving.qos, paged engines only):
-        # `preempt_hint()` answers "is an interactive request waiting for
+        # QoS preemption (tpustack.serving.qos): `preempt_hint()` answers "is an interactive request waiting for
         # a slot?" (the server's queue view; racy reads are fine — a
         # stale True costs one spurious park, a stale False one wave of
         # extra wait).  When it fires with every slot busy and a batch
@@ -415,19 +394,15 @@ class ContinuousEngine:
         self._on_preempt = on_preempt
         self._parked: List[SlotRequest] = []
         self._preempted = 0
-        # chunked prefill (TPUSTACK_PREFILL_CHUNK_TOKENS, paged only): a
-        # prompt whose uncached remainder exceeds the chunk size admits
+        # chunked prefill (TPUSTACK_PREFILL_CHUNK_TOKENS): a prompt whose uncached remainder exceeds the chunk size admits
         # ONE block-aligned chunk at a time, parking the remainder
         # exactly like QoS preemption does (retained block refs, warm
         # resume through the prefix path) so decode waves interleave
         # between chunks.  0 (the default) keeps admission byte-for-byte
         # the monolithic-prefill engine.
         if prefill_chunk is None:
-            from tpustack.utils import knobs
-
             prefill_chunk = knobs.get_int("TPUSTACK_PREFILL_CHUNK_TOKENS")
-        self._chunk_tokens = (max(0, int(prefill_chunk))
-                              if paged is not None else 0)
+        self._chunk_tokens = max(0, int(prefill_chunk))
         self._prefill_chunks = 0  # per-run chunk dispatches (stats)
         self._last_wave_t: Optional[float] = None
         # host phase timers (tpustack.obs.flight.PhaseClock): where the
@@ -474,8 +449,7 @@ class ContinuousEngine:
             # flash kernel); one engine uses exactly one flag value, so
             # the per-engine growth budget is unchanged — a flash engine
             # that silently retraced its kernel program still gates here
-            for name in ("_decode_scan_cont", "_decode_scan_paged",
-                         "_spec_verify_cont", "_spec_verify_paged"):
+            for name in ("_decode_scan_paged", "_spec_verify_paged"):
                 watch.watch(name, cls.__dict__.get(name),
                             budgets.pop(name, default_budget))
             for name, budget in budgets.items():  # caller-declared extras
@@ -484,21 +458,12 @@ class ContinuousEngine:
 
     # ------------------------------------------------------------ device state
     def _fresh_state(self):
-        c = self.gen.cfg
-        if self.paged is not None:
-            # the POOL is the persistent KV store (handed back in run()'s
-            # finally); only the per-slot scalars are fresh per run.  Block
-            # tables live host-side, snapshotted to device per dispatch.
-            self._bt = np.zeros((self.B, self.paged.blocks_per_seq),
-                                np.int32)
-            state = {"pool": self.paged.arrays}
-        else:
-            # kv_mesh: under LLM_TP the slot cache lines land head-axis-
-            # sharded over tp (None = the unsharded dense layout)
-            state = {"caches": init_kv_caches(c, self.B,
-                                              dtype=self.gen.cache_dtype,
-                                              mesh=self.gen.kv_mesh)}
-        state.update({
+        # the POOL is the persistent KV store (handed back in run()'s
+        # finally); only the per-slot scalars are fresh per run.  Block
+        # tables live host-side, snapshotted to device per dispatch.
+        self._bt = np.zeros((self.B, self.paged.blocks_per_seq), np.int32)
+        state = {
+            "pool": self.paged.arrays,
             "cur": jnp.zeros((self.B,), jnp.int32),
             "active": jnp.zeros((self.B,), jnp.int32),
             "first": jnp.zeros((self.B, 1), jnp.int32),
@@ -506,7 +471,7 @@ class ContinuousEngine:
             "topk": jnp.zeros((self.B,), jnp.int32),
             "greedy": jnp.ones((self.B,), jnp.bool_),
             "keys": jnp.zeros((self.B, 2), jnp.uint32),
-        })
+        }
         if self.gen.mesh is not None:
             # commit the per-slot state arrays to the mesh (replicated) so
             # the FIRST dispatch's pjit cache key matches the steady state
@@ -522,12 +487,12 @@ class ContinuousEngine:
                 state[k] = jax.device_put(state[k], rep)
         return state
 
-    # ------------------------------------------------------- paged plumbing
+    # -------------------------------------------------------- pool plumbing
     def _release_blocks(self, req: Optional[SlotRequest]) -> None:
         """Drop the pool references a not-yet-admitted request carries
         (prefix-hit refs from the lookup + any server-preallocated fresh
         blocks) — the failure path's counterpart of a retire decref."""
-        if self.paged is None or req is None:
+        if req is None:
             return
         ids = list(req.kv_blocks or [])
         if req.prefix and req.prefix[0] > 0:
@@ -543,8 +508,6 @@ class ContinuousEngine:
         provided; otherwise allocates here, evicting unreferenced cached
         blocks on pressure.  False (with the request error-retired by the
         caller) when the pool genuinely cannot cover the request."""
-        from tpustack.serving.kv_pool import OutOfBlocks
-
         rt = self.paged
         n_prompt = len(req.ids)
         s.alloc = n_prompt + budget
@@ -576,8 +539,6 @@ class ContinuousEngine:
         speculation), so Retry-After neither assumes one token per wave
         nor overestimates when speculation is landing multiple.  Tolerates
         racing the engine thread — this is a hint, not a barrier."""
-        from tpustack.serving.kv_pool import eta_until_blocks
-
         with self._marks_lock:
             marks = list(self._fetch_marks)
         wave_rate = None
@@ -697,13 +658,15 @@ class ContinuousEngine:
                         waves: List[Tuple[int, SlotRequest]], gen_ctr: int):
         """Dispatch admissions WITHOUT any host sync: per prompt-bucket
         group, ONE fused device program covering row caches + prefill +
-        cache splice + first-token sample + slot activation
-        (``_admit_fused``; prompts beyond PREFILL_CHUNK run the host-
-        driven chunked prefill plus the same splice/sample/activate
+        the write through the rows' block tables + first-token sample +
+        slot activation (``_admit_fused_paged``; a prefix hit's warm start
+        is ``_admit_prefix_paged``; prompts beyond PREFILL_CHUNK run the
+        host-driven chunked prefill plus the same write/sample/activate
         dispatches).  The chunk chain keeps flowing behind these — the
         host resolves the first tokens later (``_resolve``).  Mid-run
         singles take the same path with n=1."""
         g, c = self.gen, self.gen.cfg
+        rt = self.paged
         t0 = time.time()
         valid: List[Tuple[int, SlotRequest, int]] = []  # (slot, req, budget)
         for i, req in waves:
@@ -733,12 +696,11 @@ class ContinuousEngine:
                 self._release_blocks(req)
                 self._retire(state, slots, i, self._live(slots), park=False)
                 continue
-            if self.paged is not None and not self._alloc_slot_blocks(
-                    i, s, req, budget):
+            if not self._alloc_slot_blocks(i, s, req, budget):
                 s.req, s.done = None, True
-                log.warning("paged admission: out of KV blocks for a "
-                            "%d-token request (pool %s)", n_prompt + budget,
-                            self.paged.pool.stats())
+                log.warning("admission: out of KV blocks for a %d-token "
+                            "request (pool %s)", n_prompt + budget,
+                            rt.pool.stats())
                 if req.on_done is not None:
                     req.on_done(None, {"error": "out of KV blocks"})
                 continue
@@ -757,19 +719,16 @@ class ContinuousEngine:
         if self._on_progress is not None:
             self._on_progress("prefill")
 
-        # chunked prefill: a paged row whose uncached remainder exceeds
-        # the chunk size dispatches ONE block-aligned chunk and parks the
-        # rest (see _chunk_prefill_step) — it never reaches the grouped
+        # chunked prefill: a row whose uncached remainder exceeds the
+        # chunk size dispatches ONE block-aligned chunk and parks the rest
+        # (see _chunk_prefill_step) — it never reaches the grouped
         # admission below this wave
-        if self._chunk_tokens > 0 and self.paged is not None:
-            step = max(self.paged.block,
-                       (self._chunk_tokens // self.paged.block)
-                       * self.paged.block)
+        if self._chunk_tokens > 0:
+            step = max(rt.block, (self._chunk_tokens // rt.block) * rt.block)
             rest = []
             for row in valid:
                 plen = row[1].prefix[0] if row[1].prefix else 0
-                if (plen % self.paged.block == 0
-                        and len(row[1].ids) - plen > step):
+                if plen % rt.block == 0 and len(row[1].ids) - plen > step:
                     self._chunk_prefill_step(state, slots, row, t0)
                 else:
                     rest.append(row)
@@ -781,7 +740,7 @@ class ContinuousEngine:
         # peer's padded prefill (the engine admits ANY prompt that fits ctx
         # — long prompts included — so buckets can differ wildly in a wave).
         # Prefix-cache hits admit one at a time (n=1 groups): each carries
-        # its own restored prefix length, so there is no shared bucket.
+        # its own shared prefix length, so there is no shared bucket.
         groups: Dict[int, List[Tuple[int, SlotRequest, int]]] = {}
         prefix_rows: List[Tuple[int, SlotRequest, int]] = []
         for row in valid:
@@ -810,42 +769,21 @@ class ContinuousEngine:
                     jnp.asarray([r.sample.greedy for _, r, _ in rows],
                                 jnp.bool_))
 
-        def dispatch_extracts(rows):
-            # prefix-cache inserts: slice each row's prompt KV out of the
-            # just-spliced slot cache (device-side; fetched at _resolve,
-            # when the firsts fetch proves prefill landed).  Dispatch order
-            # makes this safe against the donated-cache hazard: the slices
-            # read state["caches"] BEFORE any later dispatch donates it.
-            if self.paged is not None:
-                return []
-            out = []
-            for i, r, _ in rows:
-                if r.kv_extract is None or r.on_prefill_kv is None:
-                    continue
-                lo, hi = r.kv_extract
-                if hi > lo:
-                    out.append((r, g._extract_kv(
-                        state["caches"], jnp.asarray(i, jnp.int32),
-                        jnp.asarray(lo, jnp.int32), hi - lo)))
-            return out
-
         def block_inserts(rows):
-            # the paged counterpart of dispatch_extracts: NO device work —
-            # the prompt's full blocks already hold its prefilled KV, so a
-            # cache insert is handing their ids to the server at resolve
-            # time (when the firsts fetch proves prefill landed)
-            if self.paged is None:
-                return []
+            # prefix-cache inserts need NO device work: the prompt's full
+            # blocks already hold its prefilled KV, so an insert is handing
+            # their ids to the server at resolve time (when the firsts
+            # fetch proves prefill landed)
             out = []
             for i, r, _ in rows:
                 if r.on_prefill_blocks is None:
                     continue
-                n_full = len(r.ids) // self.paged.block
+                n_full = len(r.ids) // rt.block
                 if n_full:
                     out.append((r, list(slots[i].blocks[:n_full])))
             return out
 
-        def paged_rowmeta(rows):
+        def rowmeta(rows):
             """(bt rows, per-row allocation limits) device arrays for the
             rows being admitted — snapshotted AFTER _alloc_slot_blocks
             installed their tables."""
@@ -853,80 +791,10 @@ class ContinuousEngine:
             return (jnp.asarray(self._bt[ids]),
                     jnp.asarray([slots[i].alloc for i in ids], jnp.int32))
 
-        for row in prefix_rows:
-            rows = [row]
-            i, req, budget = row
-            plen, pkv = req.prefix[0], req.prefix[1]
-            n_prompt = len(req.ids)
-            # suffix bucket: power-of-two padded, capped so the restored
-            # prefix + suffix writes stay inside the cache line
-            sbucket = min(g._bucket(n_prompt - plen), c.max_seq - plen)
-            tokens = np.zeros((1, sbucket), np.int32)
-            tokens[0, :n_prompt - plen] = req.ids[plen:]
-            lengths, slot_ids, seeds, temp_r, topk_r, greedy_r = (
-                row_arrays(rows))
-            if self.paged is not None:
-                # zero-copy warm start: the shared blocks are already in
-                # this slot's table (installed by _alloc_slot_blocks) and
-                # hold exactly what prefill wrote — no host KV, no
-                # restore; the fused program gathers the line, prefills
-                # the suffix, and scatters it back.  A host-tier hit
-                # first scatters its claimed payloads into the tail
-                # blocks of that prefix (one extra dispatch, no prefill
-                # FLOPs) — the gather below then reads restored bytes.
-                if req.host_restore:
-                    self._dispatch_restore(state, req)
-                bt_rows, limits = paged_rowmeta(rows)
-                moe = None
-                if sbucket * c.max_seq <= g.MASKED_PREFILL_MAX:
-                    (state["pool"], firsts, state["cur"], state["active"],
-                     state["first"], state["temp"], state["topk"],
-                     state["greedy"], state["keys"],
-                     moe) = g._admit_prefix_paged(
-                        g.params, jnp.asarray(tokens), state["pool"],
-                        bt_rows, jnp.asarray(plen, jnp.int32), lengths,
-                        limits, slot_ids, seeds, state["cur"],
-                        state["active"], state["first"], state["temp"],
-                        state["topk"], state["greedy"], state["keys"],
-                        temp_r, topk_r, greedy_r)
-                else:
-                    row_caches = g._gather_rows_paged(state["pool"], bt_rows)
-                    logits, row_caches = g._prefill_from(tokens, plen,
-                                                         lengths, row_caches)
-                    state["pool"] = g._insert_rows_paged(
-                        state["pool"], bt_rows, row_caches,
-                        jnp.asarray(plen, jnp.int32), sbucket, limits)
-                    firsts, row_keys = g._admit_sample_jit(
-                        logits, seeds, temp_r, topk_r, greedy_r)
-                    (state["cur"], state["active"], state["first"],
-                     state["temp"], state["topk"], state["greedy"],
-                     state["keys"]) = g._slot_activate(
-                        state["cur"], state["active"], state["first"],
-                        state["temp"], state["topk"], state["greedy"],
-                        state["keys"], slot_ids, lengths, firsts, temp_r,
-                        topk_r, greedy_r, row_keys)
-                self.paged.arrays = state["pool"]
-                slots[i].pending = True
-                self._pending.append(_PendingWave(
-                    rows, firsts, t0, block_inserts=block_inserts(rows),
-                    bucket=sbucket, moe_dev=moe))
-                continue
-            prefix_dev = g._prefix_to_device(
-                pkv, req.prefix[2] if len(req.prefix) > 2 else None)
-            if sbucket * c.max_seq <= g.MASKED_PREFILL_MAX:
-                # one dispatch: in-graph row caches + restore + masked
-                # suffix prefill (the common warm-hit shape)
-                logits, row_caches = g._prefill_prefix_fused(
-                    g.params, jnp.asarray(tokens),
-                    jnp.asarray(plen, jnp.int32), lengths, prefix_dev)
-            else:
-                row_caches = init_kv_caches(c, 1, dtype=g.cache_dtype,
-                                            mesh=g.kv_mesh)
-                row_caches = g._restore_kv_rows(row_caches, prefix_dev)
-                logits, row_caches = g._prefill_from(tokens, plen, lengths,
-                                                     row_caches)
-            state["caches"] = g._insert_cache_rows(
-                state["caches"], row_caches, slot_ids, 1, plen + sbucket)
+        def sample_activate(logits, slot_ids, lengths, seeds, temp_r,
+                            topk_r, greedy_r):
+            """The unfused tail of an admission whose prefill ran as
+            dispatches of its own: sample the firsts, activate the rows."""
             firsts, row_keys = g._admit_sample_jit(
                 logits, seeds, temp_r, topk_r, greedy_r)
             (state["cur"], state["active"], state["first"],
@@ -936,10 +804,61 @@ class ContinuousEngine:
                 state["temp"], state["topk"], state["greedy"],
                 state["keys"], slot_ids, lengths, firsts, temp_r,
                 topk_r, greedy_r, row_keys)
-            slots[i].pending = True
-            self._pending.append(_PendingWave(rows, firsts, t0,
-                                              dispatch_extracts(rows),
-                                              bucket=sbucket))
+            return firsts
+
+        def pend(rows, firsts, bucket, moe):
+            rt.arrays = state["pool"]
+            for i, _, _ in rows:
+                slots[i].pending = True
+            self._pending.append(_PendingWave(
+                rows, firsts, t0, block_inserts=block_inserts(rows),
+                bucket=bucket, moe_dev=moe))
+
+        for row in prefix_rows:
+            rows = [row]
+            i, req, budget = row
+            plen = req.prefix[0]
+            n_prompt = len(req.ids)
+            # suffix bucket: power-of-two padded, capped so the shared
+            # prefix + suffix writes stay inside the cache line
+            sbucket = min(g._bucket(n_prompt - plen), c.max_seq - plen)
+            tokens = np.zeros((1, sbucket), np.int32)
+            tokens[0, :n_prompt - plen] = req.ids[plen:]
+            lengths, slot_ids, seeds, temp_r, topk_r, greedy_r = (
+                row_arrays(rows))
+            # zero-copy warm start: the shared blocks are already in this
+            # slot's table (installed by _alloc_slot_blocks) and hold
+            # exactly what prefill wrote — no host KV, no restore; the
+            # fused program gathers the line, prefills the suffix, and
+            # scatters it back.  A host-tier hit first scatters its
+            # claimed payloads into the tail blocks of that prefix (one
+            # extra dispatch, no prefill FLOPs) — the gather below then
+            # reads restored bytes.
+            if req.host_restore:
+                self._dispatch_restore(state, req)
+            bt_rows, limits = rowmeta(rows)
+            moe = None
+            if sbucket * c.max_seq <= g.MASKED_PREFILL_MAX:
+                (state["pool"], firsts, state["cur"], state["active"],
+                 state["first"], state["temp"], state["topk"],
+                 state["greedy"], state["keys"],
+                 moe) = g._admit_prefix_paged(
+                    g.params, jnp.asarray(tokens), state["pool"],
+                    bt_rows, jnp.asarray(plen, jnp.int32), lengths,
+                    limits, slot_ids, seeds, state["cur"],
+                    state["active"], state["first"], state["temp"],
+                    state["topk"], state["greedy"], state["keys"],
+                    temp_r, topk_r, greedy_r)
+            else:
+                row_caches = g._gather_rows_paged(state["pool"], bt_rows)
+                logits, row_caches = g._prefill_from(tokens, plen,
+                                                     lengths, row_caches)
+                state["pool"] = g._insert_rows_paged(
+                    state["pool"], bt_rows, row_caches,
+                    jnp.asarray(plen, jnp.int32), sbucket, limits)
+                firsts = sample_activate(logits, slot_ids, lengths, seeds,
+                                         temp_r, topk_r, greedy_r)
+            pend(rows, firsts, sbucket, moe)
 
         for bucket, rows in sorted(groups.items()):
             n = len(rows)
@@ -948,80 +867,35 @@ class ContinuousEngine:
                 tokens[j, :len(r.ids)] = r.ids
             lengths, slot_ids, seeds, temp_r, topk_r, greedy_r = (
                 row_arrays(rows))
-            if self.paged is not None:
-                bt_rows, limits = paged_rowmeta(rows)
-                moe = None
-                if bucket > g.PREFILL_CHUNK:
-                    # chunked long-prompt admission: same prefill programs
-                    # as dense, only the splice goes through block tables
-                    row_caches = init_kv_caches(c, n, dtype=g.cache_dtype,
-                                                mesh=g.kv_mesh)
-                    logits, row_caches = g._prefill_long(tokens, lengths,
-                                                         row_caches)
-                    state["pool"] = g._insert_rows_paged(
-                        state["pool"], bt_rows, row_caches,
-                        jnp.zeros((), jnp.int32), bucket, limits)
-                    firsts, row_keys = g._admit_sample_jit(
-                        logits, seeds, temp_r, topk_r, greedy_r)
-                    (state["cur"], state["active"], state["first"],
-                     state["temp"], state["topk"], state["greedy"],
-                     state["keys"]) = g._slot_activate(
-                        state["cur"], state["active"], state["first"],
-                        state["temp"], state["topk"], state["greedy"],
-                        state["keys"], slot_ids, lengths, firsts, temp_r,
-                        topk_r, greedy_r, row_keys)
-                else:
-                    (state["pool"], firsts, state["cur"], state["active"],
-                     state["first"], state["temp"], state["topk"],
-                     state["greedy"], state["keys"],
-                     moe) = g._admit_fused_paged(
-                        g.params, jnp.asarray(tokens), state["pool"],
-                        bt_rows, lengths, limits, slot_ids, seeds,
-                        state["cur"], state["active"], state["first"],
-                        state["temp"], state["topk"], state["greedy"],
-                        state["keys"], temp_r, topk_r, greedy_r)
-                self.paged.arrays = state["pool"]
-                for i, _, _ in rows:
-                    slots[i].pending = True
-                self._pending.append(_PendingWave(
-                    rows, firsts, t0, block_inserts=block_inserts(rows),
-                    bucket=bucket, moe_dev=moe))
-                continue
+            bt_rows, limits = rowmeta(rows)
+            moe = None
             if bucket > g.PREFILL_CHUNK:
                 # chunked long-prompt admission: one fused scan dispatch
                 # for exact-multiple buckets (16k/32k), a per-chunk host
-                # loop otherwise (_prefill_long), then the same
-                # splice/sample/activate dispatches
+                # loop otherwise (_prefill_long), on row caches of its
+                # own, then the write through the block tables
                 row_caches = init_kv_caches(c, n, dtype=g.cache_dtype,
                                             mesh=g.kv_mesh)
                 logits, row_caches = g._prefill_long(tokens, lengths,
                                                      row_caches)
-                state["caches"] = g._insert_cache_rows(
-                    state["caches"], row_caches, slot_ids, n, bucket)
-                firsts, row_keys = g._admit_sample_jit(
-                    logits, seeds, temp_r, topk_r, greedy_r)
-                (state["cur"], state["active"], state["first"],
-                 state["temp"], state["topk"], state["greedy"],
-                 state["keys"]) = g._slot_activate(
+                state["pool"] = g._insert_rows_paged(
+                    state["pool"], bt_rows, row_caches,
+                    jnp.zeros((), jnp.int32), bucket, limits)
+                firsts = sample_activate(logits, slot_ids, lengths, seeds,
+                                         temp_r, topk_r, greedy_r)
+            else:
+                # the common case: prefill + write + sample + activation
+                # in ONE dispatch (each dispatch is a host round-trip)
+                (state["pool"], firsts, state["cur"], state["active"],
+                 state["first"], state["temp"], state["topk"],
+                 state["greedy"], state["keys"],
+                 moe) = g._admit_fused_paged(
+                    g.params, jnp.asarray(tokens), state["pool"],
+                    bt_rows, lengths, limits, slot_ids, seeds,
                     state["cur"], state["active"], state["first"],
                     state["temp"], state["topk"], state["greedy"],
-                    state["keys"], slot_ids, lengths, firsts, temp_r,
-                    topk_r, greedy_r, row_keys)
-            else:
-                # the common case: prefill + splice + sample + activation
-                # in ONE dispatch (each dispatch is a host round-trip)
-                (state["caches"], firsts, state["cur"], state["active"],
-                 state["first"], state["temp"], state["topk"],
-                 state["greedy"], state["keys"]) = g._admit_fused(
-                    g.params, jnp.asarray(tokens), state["caches"], lengths,
-                    slot_ids, seeds, state["cur"], state["active"],
-                    state["first"], state["temp"], state["topk"],
-                    state["greedy"], state["keys"], temp_r, topk_r, greedy_r)
-            for i, _, _ in rows:
-                slots[i].pending = True
-            self._pending.append(_PendingWave(rows, firsts, t0,
-                                              dispatch_extracts(rows),
-                                              bucket=bucket))
+                    state["keys"], temp_r, topk_r, greedy_r)
+            pend(rows, firsts, bucket, moe)
         return gen_ctr
 
     def _resolve(self, state, slots: List[_Slot], wave: _PendingWave):
@@ -1034,15 +908,13 @@ class ContinuousEngine:
             firsts, moe = jax.device_get((wave.firsts_dev, wave.moe_dev))
             firsts = [int(t) for t in firsts]
         t_first = time.time() - wave.t0
-        if self.paged is not None and self.paged.cache is not None:
-            tier = getattr(self.paged.cache, "host_tier", None)
-            if tier is not None:
-                # feed the restore-vs-recompute crossover: this wave
-                # prefilled its rows' uncached tokens in t_first wall
-                n_new = sum(max(0, len(r.ids) - slots[i].cached)
-                            for i, r, _ in wave.rows)
-                tier.note_prefill(self.paged.pool.blocks_for(n_new),
-                                  t_first)
+        tier = getattr(self.paged.cache, "host_tier", None)
+        if tier is not None:
+            # feed the restore-vs-recompute crossover: this wave
+            # prefilled its rows' uncached tokens in t_first wall
+            n_new = sum(max(0, len(r.ids) - slots[i].cached)
+                        for i, r, _ in wave.rows)
+            tier.note_prefill(self.paged.pool.blocks_for(n_new), t_first)
         if self.flight is not None:
             self.flight.record(
                 "prefill", rows=len(wave.rows),
@@ -1070,21 +942,8 @@ class ContinuousEngine:
             try:
                 req.on_prefill_blocks(ids)
             except Exception:
-                log.exception("on_prefill_blocks failed (paged prefix-cache "
+                log.exception("on_prefill_blocks failed (prefix-cache "
                               "insert skipped)")
-        for req, dev in wave.extracts:
-            # prefill has landed (the firsts fetch above synced on it), so
-            # this fetch costs only the transfer; a failing server-side
-            # insert must not kill the engine run for every in-flight peer
-            try:
-                req.on_prefill_kv(  # intended sync point: the firsts
-                    # fetch above already proved prefill landed, so this
-                    # fetch costs only the transfer
-                    [{k: np.asarray(v)  # tpulint: disable=TPL101
-                      for k, v in layer.items()} for layer in dev])
-            except Exception:
-                log.exception("on_prefill_kv failed (prefix-cache insert "
-                              "skipped)")
         live = self._live(slots)
         for (i, req, budget), first in zip(wave.rows, firsts):
             s = slots[i]
@@ -1156,7 +1015,7 @@ class ContinuousEngine:
             s.span.set_attribute("generated_tokens", len(out))
             s.span.end()
             s.span = None
-        if self.paged is not None and s.blocks:
+        if s.blocks:
             if self.ledger is not None and req is not None \
                     and req.tenant is not None:
                 # KV-block-seconds, alloc→release: blocks held x wall
@@ -1176,8 +1035,7 @@ class ContinuousEngine:
             # waiter observing the pool sees its capacity already released
             self.paged.pool.decref(s.blocks, outcome="retired")
             s.blocks, s.alloc = [], 0
-            if self._bt is not None:
-                self._bt[i, :] = 0
+            self._bt[i, :] = 0
         self._retired_tokens += len(out)  # incl. the admission-sampled first
         if park:
             # coalesced: applied in ONE _slot_update before the next dispatch
@@ -1211,12 +1069,12 @@ class ContinuousEngine:
     def _maybe_preempt(self, slots: List[_Slot]) -> None:
         """Park one batch slot at the wave boundary when an interactive
         request is waiting and every slot is busy — the freed slot is fed
-        (interactive-first) by the next ``admit_free``.  Paged engines
-        only: the park keeps the slot's pool block refs, which is what
-        makes resumption free of prefill work.  At most one park per
-        boundary (no thrash), and none while a park is already pending."""
-        if (self.paged is None or self._preempt_hint is None
-                or self._to_park or self._pending):
+        (interactive-first) by the next ``admit_free``.  The park keeps
+        the slot's pool block refs, which is what makes resumption free of
+        prefill work.  At most one park per boundary (no thrash), and none
+        while a park is already pending."""
+        if (self._preempt_hint is None or self._to_park
+                or self._pending):
             return
         for s in slots:
             if s.req is None:
@@ -1269,8 +1127,7 @@ class ContinuousEngine:
             s.span.add_event("preempted", tokens_so_far=len(prior))
             s.span.end()
             s.span = None
-        if self._bt is not None:
-            self._bt[i, :] = 0
+        self._bt[i, :] = 0
         self._to_park.append(i)
         # prior tokens were generated and delivered during this occupancy;
         # the resumed occupancy's retire counts only its own
@@ -1441,9 +1298,9 @@ class ContinuousEngine:
         except BaseException:
             # a failed run (injected device error, shutdown) must not leak
             # open spans — their trace would sit in the live table until
-            # eviction instead of being captured as the error it is — nor,
-            # under paging, the slots' pool references (the pool outlives
-            # this run; leaked refs would shrink capacity forever)
+            # eviction instead of being captured as the error it is — nor
+            # the slots' pool references (the pool outlives this run;
+            # leaked refs would shrink capacity forever)
             if self.flight is not None:
                 # post-mortem first: the ring around the failure IS the
                 # artifact the fatal-engine-error runbook starts from
@@ -1452,7 +1309,7 @@ class ContinuousEngine:
                 if s.span is not None:
                     s.span.end(status="error")
                     s.span = None
-                if self.paged is not None and s.blocks:
+                if s.blocks:
                     try:
                         self.paged.pool.decref(s.blocks)
                     except Exception:
@@ -1470,10 +1327,9 @@ class ContinuousEngine:
             self._parked = []
             raise
         finally:
-            if self.paged is not None:
-                # hand the (donation-rotated) pool buffers back — cached
-                # prefix blocks must survive into the next busy period
-                self.paged.arrays = state["pool"]
+            # hand the (donation-rotated) pool buffers back — cached
+            # prefix blocks must survive into the next busy period
+            self.paged.arrays = state["pool"]
             self._slots_view = None
 
         self._sanitize_wave()  # drain-time recompile + conservation sweep
@@ -1502,24 +1358,19 @@ class ContinuousEngine:
             "decode_weight_passes": passes,
             "tokens_per_weight_pass": decoded / passes if passes else 0.0,
             "preempted": self._preempted,
-        })
-        if self.paged is not None:
             # which decode-attention body served this run, plus the exact
             # dispatch split — `kernel_gather_dispatches` at ZERO is the
             # "the gather copy never ran" signature counter the paged-
-            # flash perf-gate scenario pins (dense engines omit all three:
-            # their signature keys must not change under the flag)
-            stats.update({
-                "decode_kernel": ("paged_flash" if self.paged_flash
-                                  else "gather"),
-                "kernel_gather_dispatches": self._gather_dispatches,
-                "kernel_paged_flash_dispatches": self._flash_dispatches,
-            })
-            if self._chunk_tokens > 0:
-                # only when chunked prefill is armed — the key must be
-                # ABSENT with the knob off so perfsig signature keys do
-                # not change under the bisection contract
-                stats["prefill_chunks"] = self._prefill_chunks
+            # flash perf-gate scenario pins
+            "decode_kernel": "paged_flash" if self.paged_flash else "gather",
+            "kernel_gather_dispatches": self._gather_dispatches,
+            "kernel_paged_flash_dispatches": self._flash_dispatches,
+        })
+        if self._chunk_tokens > 0:
+            # only when chunked prefill is armed — the key must be
+            # ABSENT with the knob off so perfsig signature keys do
+            # not change under the bisection contract
+            stats["prefill_chunks"] = self._prefill_chunks
         if self.spec is not None:
             stats.update({
                 "spec_drafted_tokens": self._spec_drafted,
@@ -1540,32 +1391,23 @@ class ContinuousEngine:
                     dispatch_ok(s) for s in slots):
                 snapshot = [(i, s.gen_id, s.dispatched)
                             for i, s in enumerate(slots) if dispatch_ok(s)]
-                moe = None
-                if self.paged is not None:
-                    (toks, last, state["cur"], state["pool"],
-                     state["keys"], moe) = g._decode_scan_paged(
-                        g.params, state["first"], state["cur"],
-                        state["active"], state["pool"],
-                        jnp.asarray(self._bt), state["keys"],
-                        state["temp"], state["topk"], state["greedy"],
-                        self.chunk, flash=self.paged_flash)
-                    # keep the runtime's arrays reference CURRENT (donation
-                    # rotated the buffers): the host-tier spill path reads
-                    # blocks through it between dispatches, and cached prefix
-                    # blocks are immutable post-prefill — so the freshest
-                    # buffer generation always holds their right bytes
-                    self.paged.arrays = state["pool"]
-                    if self.paged_flash:
-                        self._flash_dispatches += 1
-                    else:
-                        self._gather_dispatches += 1
+                (toks, last, state["cur"], state["pool"],
+                 state["keys"], moe) = g._decode_scan_paged(
+                    g.params, state["first"], state["cur"],
+                    state["active"], state["pool"],
+                    jnp.asarray(self._bt), state["keys"],
+                    state["temp"], state["topk"], state["greedy"],
+                    self.chunk, flash=self.paged_flash)
+                # keep the runtime's arrays reference CURRENT (donation
+                # rotated the buffers): the host-tier spill path reads
+                # blocks through it between dispatches, and cached prefix
+                # blocks are immutable post-prefill — so the freshest
+                # buffer generation always holds their right bytes
+                self.paged.arrays = state["pool"]
+                if self.paged_flash:
+                    self._flash_dispatches += 1
                 else:
-                    (toks, last, state["cur"], state["caches"],
-                     state["keys"]) = g._decode_scan_cont(
-                        g.params, state["first"], state["cur"],
-                        state["active"], state["caches"], state["keys"],
-                        state["temp"], state["topk"], state["greedy"],
-                        self.chunk)
+                    self._gather_dispatches += 1
                 state["first"] = last
                 self._plain_steps += self.chunk
                 for i, _, _ in snapshot:
@@ -1574,16 +1416,15 @@ class ContinuousEngine:
 
     def _sanitize_wave(self) -> None:
         """Wave-boundary sanitizer checks (no-op unless TPUSTACK_SANITIZE):
-        recompile budgets on the decode/verify entry points and, under
-        paging, pool conservation — the engine's quiesce cadence, so a
+        recompile budgets on the decode/verify entry points and pool
+        conservation — the engine's quiesce cadence, so a
         violation surfaces within one wave of the bug instead of at
         drain."""
         if self._san is None:
             return
         self._san.check(where="wave boundary")
-        if self.paged is not None:
-            sanitize.check_kv_conservation(self.paged.pool,
-                                           where="wave boundary")
+        sanitize.check_kv_conservation(self.paged.pool,
+                                       where="wave boundary")
 
     @staticmethod
     def _tenant_occupancy(slots) -> Dict[str, int]:
@@ -1661,12 +1502,11 @@ class ContinuousEngine:
                 pass  # server thread by design: a torn queue-depth read
                 # costs this record one advisory field, and logging per
                 # wave would spam the engine's hot loop
-        if self.paged is not None:
-            free, used, frag = self.paged.pool.flight_snapshot()
-            rec["kv_free"] = free
-            rec["kv_used"] = used
-            rec["kv_fragmentation"] = round(frag, 4)
-            rec["kernel"] = "paged_flash" if self.paged_flash else "gather"
+        free, used, frag = self.paged.pool.flight_snapshot()
+        rec["kv_free"] = free
+        rec["kv_used"] = used
+        rec["kv_fragmentation"] = round(frag, 4)
+        rec["kernel"] = "paged_flash" if self.paged_flash else "gather"
         # per-wave tenant occupancy ({tenant: slots served}): the split
         # key for the chip-seconds attribution — recorded IN the flight
         # record and charged FROM it, so /debug/flight and the tenant
@@ -1910,27 +1750,18 @@ class ContinuousEngine:
             draft[i, :len(toks)] = toks
             dlen[i] = len(toks)
             rows.append((i, slots[i].gen_id))
-        moe = None
-        if self.paged is not None:
-            (toks_dev, n_acc, last, state["cur"], state["pool"],
-             state["keys"], moe) = g._spec_verify_paged(
-                g.params, state["first"], jnp.asarray(draft),
-                jnp.asarray(dlen), state["cur"], state["active"],
-                state["pool"], jnp.asarray(self._bt), state["keys"],
-                state["temp"], state["topk"], state["greedy"], K,
-                flash=self.paged_flash)
-            self.paged.arrays = state["pool"]  # see _fill_chain
-            if self.paged_flash:
-                self._flash_dispatches += 1
-            else:
-                self._gather_dispatches += 1
+        (toks_dev, n_acc, last, state["cur"], state["pool"],
+         state["keys"], moe) = g._spec_verify_paged(
+            g.params, state["first"], jnp.asarray(draft),
+            jnp.asarray(dlen), state["cur"], state["active"],
+            state["pool"], jnp.asarray(self._bt), state["keys"],
+            state["temp"], state["topk"], state["greedy"], K,
+            flash=self.paged_flash)
+        self.paged.arrays = state["pool"]  # see _fill_chain
+        if self.paged_flash:
+            self._flash_dispatches += 1
         else:
-            (toks_dev, n_acc, last, state["cur"], state["caches"],
-             state["keys"]) = g._spec_verify_cont(
-                g.params, state["first"], jnp.asarray(draft),
-                jnp.asarray(dlen), state["cur"], state["active"],
-                state["caches"], state["keys"], state["temp"],
-                state["topk"], state["greedy"], K)
+            self._gather_dispatches += 1
         state["first"] = last
         self._spec_dispatches += 1
         return toks_dev, n_acc, dlen.tolist(), rows, moe

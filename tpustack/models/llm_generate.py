@@ -763,13 +763,14 @@ class Generator:
     #
     # A third decode layout for the CONTINUOUS batcher
     # (tpustack.models.llm_continuous): B persistent slots, each with its own
-    # CONTIGUOUS cache line — row i decodes at its own frontier cur[i],
-    # attends [0, cur[i]] and takes RoPE position cur[i], exactly the solo
-    # decoder's layout per row.  Slots join (B=1 prefill inserted via
-    # _insert_cache_rows) and retire at chunk boundaries without touching
-    # their peers; parked slots idle at position 0 (active=0 freezes cur)
-    # until reassigned.  Per-row K/V land in a small chunk-local buffer
-    # (the main cache stays FROZEN within a chunk; one flush per chunk), so
+    # logical cache line (a block table into the KV pool, below) — row i
+    # decodes at its own frontier cur[i], attends [0, cur[i]] and takes RoPE
+    # position cur[i], exactly the solo decoder's layout per row.  Slots
+    # join (admission writes their prefill through their block tables) and
+    # retire at chunk boundaries without touching their peers; parked slots
+    # idle at position 0 (active=0 freezes cur) until reassigned.  Per-row
+    # K/V land in a small chunk-local buffer (the pool stays FROZEN within
+    # a chunk; one write through the block tables per chunk), so
     # a row's attention math depends only on its own prompt/seed — greedy
     # rows are token-identical to the solo path in practice (the chunk-
     # boundary softmax split changes fp summation ORDER only, never the
@@ -781,12 +782,10 @@ class Generator:
                           temperature, top_k, greedy, n_steps: int):
         """Traced body of one continuous-slot decode chunk: the ``n_steps``
         scan over a FROZEN cache view, K/V landing in chunk-local buffers.
-        Shared verbatim by the dense program (``_decode_scan_cont``, which
-        flushes the buffers into each slot's cache line) and the paged one
-        (``_decode_scan_paged``, which gathers the view from the block
-        pool and scatters the buffers back through the block tables) — one
-        source of truth is what makes paged-vs-dense greedy outputs
-        byte-identical.  Returns ``(toks [B, T], last, cur_end, bufs,
+        ``_decode_scan_paged`` presents the view (the pool read in place,
+        or gathered through the block tables) and scatters the buffers
+        back through the block tables — one body for both reads is what
+        makes their greedy outputs byte-identical.  Returns ``(toks [B, T], last, cur_end, bufs,
         keys, moe)`` (``moe``: ``_apply_counted``'s counters summed over
         the steps, None for a model without routed experts)."""
         from tpustack.models.llama import init_chunk_bufs
@@ -819,41 +818,16 @@ class Generator:
         cur_end = jnp.minimum(cur0 + n_steps * active, S - 1)
         return toks.T, last, cur_end, bufs, keys, moe
 
-    @functools.partial(jax.jit, static_argnums=(0, 10), donate_argnums=(5,))
-    def _decode_scan_cont(self, params, first_tok, cur, active, caches, keys,
-                          temperature, top_k, greedy, n_steps: int):
-        """``n_steps`` continuous-slot decode iterations in ONE dispatch.
-
-        ``cur [B]``: per-slot frontier at chunk START (``cur0``) — advances
-        only where ``active``, clamped at max_seq-1.  ``keys [B, 2]``:
-        per-slot PRNG streams (see ``_sample_from_logits_perrow``).
-
-        The main KV cache is read-only for the whole chunk: step t writes
-        its K/V at the UNIFORM index t of per-layer chunk buffers
-        (``init_chunk_bufs``, scan-internal) and attention merges
-        {cache [0, cur0[i])} ∪ {buffer [0, t]} with an exact streaming-
-        softmax split (LlamaAttention chunk mode).  After the scan the
-        buffers flush into each row's cache line at [cur0[i], cur_end[i])
-        in ONE gather+select pass — per-step cache write-back traffic
-        (which would ~double KV bytes for concurrent long-context decodes)
-        amortises by the chunk length.  Overshoot steps past max_seq-1 are
-        clipped out of the flush window entirely, so a retiring row's
-        speculative garbage is never written to the cache at all."""
-        cur0 = cur
-        toks, last, cur_end, bufs, keys, _ = self._decode_cont_body(
-            params, first_tok, cur, active, caches, keys, temperature,
-            top_k, greedy, n_steps)
-        caches = self._flush_chunk_bufs(caches, bufs, cur0, cur_end, n_steps)
-        return toks, last, cur_end, caches, keys
-
     @jax.named_scope("kv_write")
     def _flush_chunk_bufs(self, caches, bufs, cur0, cur_end, n_steps: int):
         """Traced flush of chunk-local K/V buffers into per-row cache lines
         at ``[cur0, cur_end)``: one linear pass per cache tensor — gather
         each row's chunk K/V at (position - cur0) and select it inside the
-        window.  Shared by the plain decode scan and the speculative verify
-        (where ``cur_end`` stops at the accepted frontier, so rejected
-        draft K/V is never written at all)."""
+        window.  No program calls it any more: it stays as the plain
+        reference the pool's page writer (``_pool_scatter_body``) is held
+        to (``tests/test_pool_layout.py``, and on the chip the hardware
+        tier's ``test_pool_writers_spell_the_dense_cache_on_chip``) —
+        its only use."""
         S = self.cfg.max_seq
         B = cur0.shape[0]
         ar = jnp.arange(S)[None, :]
@@ -877,15 +851,15 @@ class Generator:
 
     # --------------------------------------------------------- paged KV pool
     #
-    # Device half of the paged KV substrate (tpustack.serving.kv_pool):
+    # Device half of the engine's KV store (tpustack.serving.kv_pool):
     # every layer's K/V lives in pool tensors [n_blocks, block, kvh*hd]
     # (int8 scales [n_blocks, kvh*block]: llama.init_kv_pool — the layout
     # the paged kernel and the page writes below take as it rests) and a
     # slot's logical cache line is a BLOCK TABLE (bt [B, max_seq // block],
     # int32 pool indices; the reserved block 0 backs idle entries).  The
     # compute view is a gather through the table — elementwise equal to
-    # what the dense cache line would hold, so the attention bodies above
-    # run unchanged and greedy outputs are byte-identical paged-vs-dense.
+    # what a contiguous cache line would hold, so the attention bodies above
+    # run unchanged and greedy outputs are byte-identical to the solo path.
     # Writes land ONLY the freshly produced K/V (an admission's prefill
     # rows, a chunk's buffers) through the table, a whole page at a time
     # (the pages a run touches are read, the run's valid tokens laid over
@@ -900,8 +874,7 @@ class Generator:
     # host only frees a retiring slot's blocks BEFORE dispatching the new
     # owner's admission — so a stale in-flight chunk's flush into those
     # blocks lands first and is overwritten by the new owner's prefill/
-    # decode before any mask can admit it, the same ordering argument the
-    # dense engine makes for reassigned slot lines.
+    # decode before any mask can admit it.
 
     @jax.named_scope("kv_read")
     def _pool_gather_body(self, pool, bt):
@@ -1010,8 +983,7 @@ class Generator:
         ``caches`` are full-line row caches (``[R, max_seq, ...]``) whose
         data at those positions is what prefill just produced; ``limits
         [R]`` clips each row's write at its allocation (padded-bucket
-        garbage beyond it is dropped, where the dense splice wrote it into
-        the slot's private line)."""
+        garbage beyond it is dropped)."""
 
         def sl(x):
             idx = (jnp.zeros((), jnp.int32), start) + (
@@ -1043,11 +1015,24 @@ class Generator:
     def _decode_scan_paged(self, params, first_tok, cur, active, pool, bt,
                            keys, temperature, top_k, greedy, n_steps: int,
                            flash: bool = False):
-        """Paged twin of ``_decode_scan_cont``: present the frozen chunk
-        view of the pool, run the IDENTICAL scan body, scatter the chunk
-        buffers back through the block tables at ``[cur0, cur_end)``.
-        Only the new tokens' K/V move pool-ward — shared prefix blocks are
-        read, never rewritten.
+        """``n_steps`` continuous-slot decode iterations in ONE dispatch:
+        present the frozen chunk view of the pool, run the scan body
+        (``_decode_cont_body``), scatter the chunk buffers back through
+        the block tables at ``[cur0, cur_end)``.  Only the new tokens'
+        K/V move pool-ward — shared prefix blocks are read, never
+        rewritten.
+
+        ``cur [B]``: per-slot frontier at chunk START (``cur0``) — advances
+        only where ``active``, clamped at max_seq-1.  ``keys [B, 2]``:
+        per-slot PRNG streams (see ``_sample_from_logits_perrow``).  The
+        pool is read-only for the whole chunk: step t writes its K/V at
+        the UNIFORM index t of per-layer chunk buffers
+        (``init_chunk_bufs``, scan-internal) and attention merges
+        {pool [0, cur0[i])} ∪ {buffer [0, t]} with an exact streaming-
+        softmax split (LlamaAttention chunk mode), so per-step write-back
+        traffic amortises by the chunk length.  Overshoot steps past
+        max_seq-1 are clipped out of the write window entirely, so a
+        retiring row's speculative garbage is never written at all.
 
         ``flash`` (static; the engine passes its knob-resolved
         ``TPUSTACK_PAGED_FLASH`` verdict) picks HOW the frozen view is
@@ -1107,8 +1092,8 @@ class Generator:
     def _spec_verify_parts(self, params, first_tok, draft, draft_len, cur,
                            active, caches, keys, temperature, top_k, greedy,
                            n_draft: int):
-        """Traced body of one verify step, shared by the dense and paged
-        programs.  ``first_tok [B,1]``: last accepted token (KV not yet
+        """Traced body of one verify step (``_spec_verify_paged``'s, for
+        either read of the pool).  ``first_tok [B,1]``: last accepted token (KV not yet
         written); ``draft [B,K]`` host-proposed continuations with per-row
         valid counts ``draft_len [B]`` (zero-draft rows run exactly one
         plain decode step's worth of work inside the same dispatch).
@@ -1204,29 +1189,14 @@ class Generator:
         cur_end = jnp.minimum(cur0 + (n_acc + 1) * active, S_max - 1)
         return toks, n_acc, bonus[:, None], cur_end, bufs, keys, moe
 
-    @functools.partial(jax.jit, static_argnums=(0, 12), donate_argnums=(7,))
-    def _spec_verify_cont(self, params, first_tok, draft, draft_len, cur,
-                          active, caches, keys, temperature, top_k, greedy,
-                          n_draft: int):
-        """Dense speculative verify: one K+1-position forward pass over the
-        frozen slot caches, then the shared chunk flush clipped at each
-        row's ACCEPTED frontier — rejected draft K/V is never written."""
-        cur0 = cur
-        toks, n_acc, last, cur_end, bufs, keys, _ = self._spec_verify_parts(
-            params, first_tok, draft, draft_len, cur, active, caches, keys,
-            temperature, top_k, greedy, n_draft)
-        caches = self._flush_chunk_bufs(caches, bufs, cur0, cur_end,
-                                        n_draft + 1)
-        return toks, n_acc, last, cur_end, caches, keys
-
     @functools.partial(jax.jit, static_argnums=(0, 13),
                        static_argnames=("flash",), donate_argnums=(7,))
     def _spec_verify_paged(self, params, first_tok, draft, draft_len, cur,
                            active, pool, bt, keys, temperature, top_k,
                            greedy, n_draft: int, flash: bool = False):
-        """Paged twin of ``_spec_verify_cont``: present the frozen view of
-        the block pool, run the IDENTICAL verify body, scatter ONLY the
-        accepted positions back through the block tables — so shared
+        """Speculative verify: one K+1-position forward pass over the
+        frozen view of the block pool (``_spec_verify_parts``), then
+        scatter ONLY the accepted positions back through the block tables — so shared
         prefix blocks are read but never rewritten, and block accounting
         stays capacity-true (no rejected-draft KV ever lands).
 
@@ -1256,10 +1226,15 @@ class Generator:
     def _admit_fused_paged(self, params, tokens, pool, bt_rows, lengths,
                            limits, slot_ids, seeds, cur, active, first, temp,
                            topk, greedy, keys, temp_r, topk_r, greedy_r):
-        """Paged twin of ``_admit_fused``: ONE dispatch covering fresh
-        in-graph row caches → batched prefill (identical trace, identical
-        logits) → paged splice through the rows' block tables →
-        first-token sample → slot activation.  Last of what it returns is
+        """ONE-dispatch admission for a same-bucket wave (bucket ≤
+        PREFILL_CHUNK): fresh in-graph row caches → batched prefill →
+        write through the rows' block tables → per-request first-token
+        sample + key-chain init → slot-state activation.  The
+        multi-dispatch path (``_prefill_long``/``_insert_rows_paged``/
+        ``_admit_sample_jit``/``_slot_activate``) remains for chunked
+        long-prompt admissions; this fused program exists because each
+        dispatch costs a host round-trip, and an admission's ~6 of them
+        weigh on short-generation end-to-end.  Last of what it returns is
         ``_apply_counted``'s ``moe``: it leaves with the first tokens."""
         n, bucket = tokens.shape
         row_caches = init_kv_caches(self.cfg, n, dtype=self.cache_dtype)
@@ -1282,8 +1257,8 @@ class Generator:
                             greedy_r):
         """ONE-dispatch paged warm start: gather the hit row's line (the
         shared prefix blocks hold exactly what prefill wrote — zero-copy
-        restore) → masked suffix prefill (same traced body as the dense
-        fused warm start) → scatter the suffix span back through the block
+        restore) → masked suffix prefill (the solo route's warm-start
+        body) → scatter the suffix span back through the block
         table → sample + activate.  ``moe`` last, as in
         ``_admit_fused_paged``."""
         caches = self._pool_gather_body(pool, bt_rows)
@@ -1335,31 +1310,11 @@ class Generator:
         return self._insert_span_body(pool, bt_rows, caches, base,
                                       tokens.shape[1], limits)
 
-    @staticmethod
-    @jax.named_scope("kv_write")
-    def _splice_rows(slot_caches, row_caches, slot_ids, n: int, bucket: int):
-        """Traced body: copy positions ``[0, bucket)`` of an n-row prefill
-        cache into the slot rows ``slot_ids[j]`` (all layers, K/V and int8
-        scales alike).  Shared by ``_insert_cache_rows`` (the chunked
-        long-prompt admission path) and ``_admit_fused`` — one source of
-        truth for the scatter."""
-
-        def ins(dst, src):
-            src = jax.lax.slice_in_dim(src, 0, bucket, axis=1)
-            for j in range(n):
-                row = jax.lax.slice_in_dim(src, j, j + 1, axis=0)
-                idx = ((slot_ids[j],)
-                       + (jnp.zeros((), jnp.int32),) * (dst.ndim - 1))
-                dst = jax.lax.dynamic_update_slice(dst, row.astype(dst.dtype),
-                                                   idx)
-            return dst
-
-        return jax.tree.map(ins, slot_caches, row_caches)
-
     @jax.named_scope("sample")
     def _first_sample(self, logits, seeds, temperature, top_k, greedy):
         """Traced body: per-request key-chain init from seeds + first-token
-        sample.  Shared by ``_admit_sample_jit`` and ``_admit_fused``."""
+        sample.  Shared by ``_admit_sample_jit`` and the fused
+        admissions."""
         base = jax.vmap(jax.random.PRNGKey)(seeds)          # [n, 2]
         first_keys, next_keys = _advance_keys(base)
         firsts = self._sample_from_logits_perrow(
@@ -1371,7 +1326,7 @@ class Generator:
                        slot_ids, n_cur, n_first, n_temp, n_topk, n_greedy,
                        n_keys):
         """Traced body: scatter n admitted rows into the B-slot state
-        arrays.  Shared by ``_slot_activate`` and ``_admit_fused``."""
+        arrays.  Shared by ``_slot_activate`` and the fused admissions."""
         return (cur.at[slot_ids].set(n_cur),
                 active.at[slot_ids].set(1),
                 first.at[slot_ids].set(n_first[:, None]),
@@ -1379,42 +1334,6 @@ class Generator:
                 topk.at[slot_ids].set(n_topk),
                 greedy.at[slot_ids].set(n_greedy),
                 keys.at[slot_ids].set(n_keys))
-
-    @functools.partial(jax.jit, static_argnums=(0, 4, 5), donate_argnums=(1,))
-    def _insert_cache_rows(self, slot_caches, row_caches, slot_ids,
-                           n: int, bucket: int):
-        """One dispatch per (chunked-admission) wave — see _splice_rows."""
-        return self._splice_rows(slot_caches, row_caches, slot_ids, n, bucket)
-
-    @functools.partial(jax.jit, static_argnums=(0,),
-                       donate_argnums=(3, 7, 8, 9, 10, 11, 12, 13))
-    def _admit_fused(self, params, tokens, slot_caches, lengths, slot_ids,
-                     seeds, cur, active, first, temp, topk, greedy, keys,
-                     temp_r, topk_r, greedy_r):
-        """ONE-dispatch admission for a same-bucket wave (bucket ≤
-        PREFILL_CHUNK): fresh row caches created in-graph → batched
-        prefill → splice into the slot cache rows → per-request
-        first-token sample + key-chain init → slot-state activation.
-        The multi-dispatch path (``_prefill``/``_insert_cache_rows``/
-        ``_admit_sample_jit``/``_slot_activate``) remains for chunked
-        long-prompt admissions; this fused program exists because each
-        dispatch costs a host round-trip, and an admission's ~6 of them
-        weigh on short-generation end-to-end.
-
-        Returns ``(slot_caches, firsts [n], state arrays...)``."""
-        n, bucket = tokens.shape
-        row_caches = init_kv_caches(self.cfg, n, dtype=self.cache_dtype)
-        positions = jnp.broadcast_to(jnp.arange(bucket), (n, bucket))
-        logits, row_caches = self.model.apply(
-            {"params": params}, tokens, positions, row_caches, 0, None,
-            lengths - 1)
-        slot_caches = self._splice_rows(slot_caches, row_caches, slot_ids,
-                                        n, bucket)
-        firsts, next_keys = self._first_sample(logits[:, 0], seeds, temp_r,
-                                               topk_r, greedy_r)
-        return (slot_caches, firsts) + self._activate_rows(
-            cur, active, first, temp, topk, greedy, keys, slot_ids,
-            lengths, firsts, temp_r, topk_r, greedy_r, next_keys)
 
     @functools.partial(jax.jit, static_argnums=(0,))
     def _admit_sample_jit(self, logits, seeds, temperature, top_k, greedy):
@@ -1433,7 +1352,7 @@ class Generator:
                        n_keys):
         """Scatter n admitted rows into the B-slot state arrays in ONE
         dispatch (chunked long-prompt admissions; the common path fuses
-        this into ``_admit_fused``).  Entirely device-valued, so admission
+        this into ``_admit_fused_paged``).  Entirely device-valued, so admission
         never syncs the host — the decode chain keeps flowing while
         prefill+activation are still in flight.  See _activate_rows."""
         return self._activate_rows(cur, active, first, temp, topk, greedy,

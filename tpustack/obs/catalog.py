@@ -105,9 +105,9 @@ CATALOG: Tuple[MetricSpec, ...] = (
                "prefix cache.", unit="blocks"),
     MetricSpec("tpustack_llm_kv_copy_avoided_tokens_total", "counter",
                "Prompt-KV tokens served by block POINTER sharing instead "
-               "of the dense path's copies: prefix hits (restore host→HBM "
+               "of a host store's copies: prefix hits (restore host→HBM "
                "avoided) plus cache inserts (extract HBM→host avoided).  "
-               "Zero with the cache cold or under the dense fallback.",
+               "Zero with the cache cold.",
                unit="total"),
     MetricSpec("tpustack_llm_kv_block_fragmentation_ratio", "gauge",
                "Reserved-but-unfillable token slack in used blocks "

@@ -62,8 +62,7 @@ SCHEMA_VERSION = 1
 #: decode programs the bench's non-engine paths run.  A forced watch on an
 #: entry a scenario never compiles reports 0 — and a committed 0 is
 #: signature too (that path STARTING to compile is the regression)
-ENTRY_POINTS = ("_decode_scan_cont", "_decode_scan_paged",
-                "_spec_verify_cont", "_spec_verify_paged",
+ENTRY_POINTS = ("_decode_scan_paged", "_spec_verify_paged",
                 "_decode_scan", "_decode_scan_batch")
 
 
@@ -203,7 +202,7 @@ def signature(*, engine: Optional[Mapping] = None,
               extra: Optional[Dict[str, int]] = None) -> Dict[str, int]:
     """Assemble one flat signature dict from whichever sources the bench
     scenario has.  Keys are dotted (``engine.generated_tokens``,
-    ``recompiles._decode_scan_cont``), values are plain ints — the gate
+    ``recompiles._decode_scan_paged``), values are plain ints — the gate
     compares with ``==`` and nothing else.  Pool/allocator counters go
     through ``extra`` (the paged bench keys them per footprint)."""
     sig: Dict[str, int] = {}

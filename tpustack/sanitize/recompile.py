@@ -3,7 +3,7 @@
 An XLA recompile on the serving path is a multi-second (CPU) to
 multi-minute (TPU) stall that looks exactly like a hung dispatch from the
 outside — the watchdog may even restart the pod for it.  The engine's
-entry points are all shape-static by design (``_decode_scan_cont`` and
+entry points are all shape-static by design (``_decode_scan_paged`` and
 friends trace once per (B, chunk, dtype) configuration), so in steady
 state their trace caches must stop growing.  This module makes that a
 checked contract:

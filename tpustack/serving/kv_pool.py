@@ -1,9 +1,8 @@
 """Paged KV substrate — block pool, block-table bookkeeping, paged radix cache.
 
-ROADMAP item 2: the dense per-slot KV (each engine slot owns a private
-``[max_seq]`` cache line) and the host-resident prefix cache
-(``tpustack.serving.prefix_cache``: extract → host numpy → restore) are
-replaced by ONE HBM-resident pool of fixed-size KV *blocks*:
+The engine's one KV store (``ContinuousEngine`` has no other; a pool is
+made in one place, ``PagedKVRuntime.build``): ONE HBM-resident pool of
+fixed-size KV *blocks*:
 
 - Every layer's K/V lives in pool tensors ``[n_blocks, block_tokens, ...]``
   (``tpustack.models.llama.init_kv_pool``).  A sequence's logical cache
@@ -13,7 +12,7 @@ replaced by ONE HBM-resident pool of fixed-size KV *blocks*:
 - **Admission is capacity-true**: a request needs
   ``ceil((prompt + max_new) / block)`` blocks, not a whole ``max_seq``
   line, so concurrency at ctx 4k–8k rises to what HBM actually holds
-  instead of the dense ``HBM / max_seq`` slot cap.
+  instead of an ``HBM / max_seq`` slot cap.
 - **Prefix reuse is zero-copy**: a finished prefill's *full* blocks are
   recorded in a radix trie keyed by token ids (``PagedPrefixCache``).  A
   later request sharing the prefix points its block table at the SAME
@@ -674,8 +673,7 @@ class PagedKVRuntime:
     """Everything the serving stack shares about one paged KV pool: the
     host allocator, the persistent DEVICE pool arrays (handed to each
     ``ContinuousEngine`` run and handed back — cached blocks must survive
-    across busy periods, unlike the dense engine's per-run caches), and
-    the optional paged prefix cache.
+    across busy periods), and the optional paged prefix cache.
 
     ``arrays`` is the per-layer list of pool tensors from
     ``tpustack.models.llama.init_kv_pool``; the engine donates them to
@@ -709,6 +707,59 @@ class PagedKVRuntime:
         self.per_shard_bytes = tree_per_shard_bytes(arrays)
         self.kv_shards = max(1, round(self.pool_bytes
                                       / max(1, self.per_shard_bytes)))
+
+    @staticmethod
+    def build(cfg, slots: int, *, block: int = 0, pool_blocks: int = 0,
+              dtype=None, mesh=None, prefix_cache: bool = False,
+              host_tier_mb: float = 0.0) -> "PagedKVRuntime":
+        """THE place a pool is made: allocator, device arrays and (asked
+        for) the block trie and its host tier, for an engine of ``slots``
+        slots serving ``cfg``.
+
+        ``block`` (tokens; 0 = ``min(64, max(8, max_seq // 8))``) snaps
+        down by halving until it divides the context.  ``pool_blocks``
+        (allocatable; 0 = ``slots x max_seq / block``, what ``slots``
+        private cache lines would hold — the concurrency win comes from
+        admission charging each request its ACTUAL ``prompt + max_new``
+        instead of a whole line) excludes the reserved block 0, which is
+        added here.  ``dtype`` is the cache dtype of a float pool (None =
+        ``init_kv_pool``'s own); ``mesh`` lands the pool tensors
+        head-axis-sharded over its ``tp`` axis, so each chip holds
+        ``pool_bytes / tp`` — what ``per_shard_bytes`` reports back."""
+        from tpustack.models.llama import init_kv_pool
+
+        max_seq = cfg.max_seq
+        if block <= 0:
+            block = min(64, max(8, max_seq // 8))
+        block = min(block, max_seq)
+        while block > 1 and max_seq % block:
+            block //= 2
+        if pool_blocks <= 0:
+            pool_blocks = slots * (max_seq // block)
+        pool = KVBlockPool(pool_blocks + 1, block)
+        cache = PagedPrefixCache(pool) if prefix_cache else None
+        kw = {} if dtype is None else {"dtype": dtype}
+        rt = PagedKVRuntime(
+            init_kv_pool(cfg, pool_blocks + 1, block, mesh=mesh, **kw),
+            pool, max_seq, cache)
+        if cache is not None and host_tier_mb > 0:
+            from tpustack.serving.kv_host_tier import HostKVTier
+
+            # arrays_fn, not arrays: decode dispatches donate the pool
+            # buffers, so the tier must re-read the runtime's CURRENT
+            # reference at every spill
+            cache.host_tier = HostKVTier(
+                int(host_tier_mb * 1024 * 1024), pool,
+                arrays_fn=lambda: rt.arrays)
+            log.info("host KV tier on: %.0f MB arena behind the %d-block "
+                     "pool", host_tier_mb, pool_blocks)
+        log.info("KV pool: %d blocks x %d tokens (ctx %d, %d slots), "
+                 "%.2f GB total / %.2f GB per chip (%d shard%s), prefix "
+                 "cache %s", pool_blocks, block, max_seq, slots,
+                 rt.pool_bytes / 1e9, rt.per_shard_bytes / 1e9,
+                 rt.kv_shards, "s" if rt.kv_shards != 1 else "",
+                 "on" if cache is not None else "off")
+        return rt
 
     # ------------------------------------------------------ admission math
     def need_tokens(self, n_prompt: int, max_new: int) -> int:

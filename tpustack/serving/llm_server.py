@@ -34,19 +34,16 @@ reference's Q4_K_M GGUF but ~2x decode from halved HBM traffic),
 ``LLM_MAX_BATCH`` (continuous-batching slot count — llama.cpp
 ``--parallel`` analog; requests join/leave the running batch at chunk
 boundaries; ``LLM_BATCH_WINDOW_MS`` is a legacy no-op),
-``TPUSTACK_PAGED_KV`` (paged KV substrate, ON by default for batched
-serving: slots hold block tables into one HBM-resident pool instead of
-private ``max_seq`` cache lines, admission is "enough free blocks for
-prompt + max_new" instead of "free slot", prefix reuse is zero-copy
-refcounted block sharing, and out-of-blocks requests get 429 with a
-Retry-After computed from projected block release; ``0`` falls back to
-the dense per-slot engine for bisection;
+the engine's KV pool (slots hold block tables into one HBM-resident
+pool, admission is "enough free blocks for prompt + max_new", prefix
+reuse is zero-copy refcounted block sharing, and out-of-blocks requests
+get 429 with a Retry-After computed from projected block release):
 ``TPUSTACK_KV_BLOCK`` is the block size in tokens (default
 ``min(64, max(8, ctx / 8))``, snapped to divide ctx);
 ``TPUSTACK_KV_POOL_BLOCKS`` is the allocatable pool size in blocks
-(default ``LLM_MAX_BATCH x ctx / block`` — dense HBM parity; raise it
-and ``LLM_MAX_BATCH`` together to serve more concurrent requests from
-the same HBM when typical contexts run short of ctx)),
+(default ``LLM_MAX_BATCH x ctx / block``; raise it and ``LLM_MAX_BATCH``
+together to serve more concurrent requests from the same HBM when
+typical contexts run short of ctx),
 ``TPUSTACK_SPEC_TOKENS`` (speculative decoding on the continuous engine,
 ON by default at 4 draft tokens per verify step: a host-side n-gram
 prompt-lookup drafter proposes continuations out of each request's own
@@ -65,12 +62,12 @@ MODEL of that preset (``tiny``|``llama2_7b``|``qwen25_7b``; weights from
 same verify program),
 ``TPUSTACK_PREFIX_CACHE`` (cross-request prefix KV cache — radix reuse of
 finished prefill KV so chat requests sharing a system prompt skip its
-prefill entirely; on by default, ``0`` disables.  Under paged KV the
-store is the refcounted block trie (``tpustack.serving.kv_pool``) and a
-hit is pointer sharing; under the dense fallback it is the host-resident
-radix store, where ``TPUSTACK_PREFIX_CACHE_MB`` caps resident host
-bytes, default 512, and ``TPUSTACK_PREFIX_CACHE_CHUNK`` is the snap
-granularity in tokens, default 256; per-request opt-out via
+prefill entirely; on by default, ``0`` disables.  The engine's store is
+the pool's refcounted block trie (``tpustack.serving.kv_pool``) and a
+hit is pointer sharing; the ``LLM_MAX_BATCH=1`` solo route keeps the
+host-resident radix store, where ``TPUSTACK_PREFIX_CACHE_MB`` caps
+resident host bytes, default 512, and ``TPUSTACK_PREFIX_CACHE_CHUNK`` is
+the snap granularity in tokens, default 256; per-request opt-out via
 ``"cache_prompt": false`` in the body — llama.cpp's field name),
 ``MODEL_DIR`` (HF safetensors), ``LLM_TOKENIZER_DIR``, ``PORT`` (8080),
 plus the shared resilience contract (``tpustack.serving.resilience``):
@@ -242,15 +239,15 @@ class _PendingCompletion:
     other)."""
 
     __slots__ = ("ids", "n_predict", "sample", "future", "cancel",
-                 "stream_put", "seed", "prefix", "kv_extract", "on_prefill_kv",
-                 "phase", "span_ctx", "queue_span", "kv_blocks",
-                 "on_prefill_blocks", "speculative", "tenant", "t_enqueue",
-                 "t_handed", "t_kv_alloc", "priority", "host_restore")
+                 "stream_put", "seed", "prefix", "phase", "span_ctx",
+                 "queue_span", "kv_blocks", "on_prefill_blocks",
+                 "speculative", "tenant", "t_enqueue", "t_handed",
+                 "t_kv_alloc", "priority", "host_restore")
 
     def __init__(self, ids, n_predict, sample, future, stream_put=None,
-                 seed=None, prefix=None, kv_extract=None, on_prefill_kv=None,
-                 kv_blocks=None, on_prefill_blocks=None, speculative=True,
-                 t_kv_alloc=None, host_restore=None):
+                 seed=None, prefix=None, kv_blocks=None,
+                 on_prefill_blocks=None, speculative=True, t_kv_alloc=None,
+                 host_restore=None):
         self.ids = ids
         self.n_predict = n_predict
         self.sample = sample
@@ -261,18 +258,14 @@ class _PendingCompletion:
         # deadline reporting: "queued" until feed() hands the request to an
         # engine slot, "decode" after — the phase a 504 names
         self.phase = "queued"
-        # prefix-KV-cache hooks (see tpustack.serving.prefix_cache): a hit
-        # restores `prefix` into the slot's cache line; `kv_extract` +
-        # `on_prefill_kv` hand the prefilled KV back for insertion
-        self.prefix = prefix
-        self.kv_extract = kv_extract
-        self.on_prefill_kv = on_prefill_kv
-        # paged-KV hooks: blocks pre-allocated at HTTP admission (the
-        # capacity check IS the allocation, so admission and the engine can
-        # never disagree) and the zero-copy cache-insert callback.  While
+        # pool hooks: a prefix hit's shared blocks (`prefix`), the fresh
+        # blocks pre-allocated at HTTP admission (the capacity check IS
+        # the allocation, so admission and the engine can never
+        # disagree) and the zero-copy cache-insert callback.  While
         # phase == "queued" the SERVER owns the references (released if the
         # request dies in the queue); feed() handing it to a slot transfers
         # ownership to the engine.
+        self.prefix = prefix
         self.kv_blocks = kv_blocks
         self.on_prefill_blocks = on_prefill_blocks
         # host-tier warm start: (restore block ids, claimed payloads) —
@@ -310,9 +303,10 @@ class LLMServer:
     Concurrent completions decode in persistent slots
     (``tpustack.models.llm_continuous.ContinuousEngine``): a request
     arriving mid-generation joins the running batch at the next
-    ``LLM_CHUNK``-token boundary (its prefill + KV splice happen while the
-    chain keeps flowing) and a finished row is answered and its slot freed
-    immediately — llama.cpp's slot semantics (reference server
+    ``LLM_CHUNK``-token boundary (its prefill, written through the slot's
+    block table into the KV pool, happens while the chain keeps flowing)
+    and a finished row is answered and its slot freed immediately —
+    llama.cpp's slot semantics (reference server
     ``--parallel``; deployment.yaml:67-84), not a collect-window batch.
     Decode streams the weights once per step regardless of how many slots
     are live, so aggregate tokens/s scales ~linearly with occupancy, and
@@ -322,9 +316,10 @@ class LLMServer:
     EVERY request batches (llama.cpp parity): seeded non-greedy requests
     ride per-slot PRNG streams, so their output depends only on (prompt,
     seed) — never on admission timing or batch peers — and long prompts
-    admit like any other (each slot owns a full ``max_seq`` cache line;
-    admission prefills are bucket-grouped so a short prompt never pays a
-    long peer's padding, and they overlap the running decode chain).  The
+    admit like any other (a slot's block table spans ``max_seq``;
+    admission charges ``prompt + max_new`` pool blocks; admission prefills
+    are bucket-grouped so a short prompt never pays a long peer's
+    padding, and they overlap the running decode chain).  The
     one long-prompt cost that remains is physical: a K-token admission
     prefill occupies the chip for its duration, so in-flight peers see
     that as added latency — exactly llama.cpp's behavior on one GPU.  The
@@ -333,8 +328,6 @@ class LLMServer:
 
     #: sentinel: "build the prefix cache from the environment"
     _PREFIX_FROM_ENV = object()
-    #: sentinel: "build the paged KV runtime from the environment"
-    _PAGED_FROM_ENV = object()
     #: sentinel: "build the speculative-decoding config from the environment"
     _SPEC_FROM_ENV = object()
 
@@ -342,7 +335,7 @@ class LLMServer:
                  max_batch: Optional[int] = None,
                  batch_window_ms: Optional[float] = None,
                  registry=None, prefix_cache=_PREFIX_FROM_ENV, tracer=None,
-                 paged=_PAGED_FROM_ENV, spec=_SPEC_FROM_ENV):
+                 paged=None, spec=_SPEC_FROM_ENV):
         # metrics registry: tests pass a fresh Registry for isolation; the
         # default is the process-wide one /metrics exposes
         self._registry = registry
@@ -371,21 +364,27 @@ class LLMServer:
         self._lock = asyncio.Lock()
         self.max_batch = (knobs.get_int("LLM_MAX_BATCH")
                           if max_batch is None else max_batch)
-        # paged KV substrate (tpustack.serving.kv_pool) — the default
-        # serving engine: one HBM block pool + per-slot block tables,
-        # capacity-true admission, refcounted zero-copy prefix sharing.
-        # Tests pass an explicit PagedKVRuntime or None; an explicit DENSE
-        # PrefixCache instance forces the dense fallback (the two stores
-        # don't mix).  TPUSTACK_PAGED_KV=0 is the bisection flag.
-        explicit_dense_cache = (
-            prefix_cache is not LLMServer._PREFIX_FROM_ENV
-            and prefix_cache is not None)
-        if paged is LLMServer._PAGED_FROM_ENV:
-            paged = (None if explicit_dense_cache
-                     else self._build_paged(self.gen, self.max_batch))
-            if paged is not None and prefix_cache is None:
-                paged.cache = None  # caller asked for NO prefix cache:
-                # keep the paged engine, drop the block trie
+        # the engine's KV store (tpustack.serving.kv_pool): one HBM block
+        # pool + per-slot block tables, capacity-true admission,
+        # refcounted zero-copy prefix sharing.  Tests pass a runtime; None
+        # builds one from the environment.  ``paged is None`` afterwards
+        # is the same fact as ``max_batch == 1``: the solo route runs no
+        # engine and keeps the host-resident PrefixCache instead.
+        if self.max_batch > 1:
+            if (prefix_cache is not LLMServer._PREFIX_FROM_ENV
+                    and prefix_cache is not None):
+                raise ValueError(
+                    "a host PrefixCache serves only the LLM_MAX_BATCH=1 "
+                    "solo route; the engine's prefix cache is the pool's "
+                    "block trie (TPUSTACK_PREFIX_CACHE)")
+            if paged is None:
+                paged = self._build_paged(self.gen, self.max_batch)
+                if prefix_cache is None:
+                    paged.cache = None  # caller asked for NO prefix cache:
+                    # keep the pool, drop the block trie
+            prefix_cache = None  # the block trie replaces the host store
+        else:
+            paged = None
         self.paged = paged
         # paged-flash verdict resolved ONCE at boot: a typo'd
         # TPUSTACK_PAGED_FLASH fails startup like every other knob typo,
@@ -395,9 +394,7 @@ class LLMServer:
 
         self.paged_flash = (resolve_paged_flash(mesh=self.gen.mesh)
                             if paged is not None else False)
-        if self.paged is not None:
-            prefix_cache = None  # the block trie replaces the host store
-        # cross-request prefix KV cache, DENSE fallback form
+        # cross-request prefix KV cache of the solo route
         # (tpustack.serving.prefix_cache): tests pass an instance (tiny
         # chunk) or None (hard off); serving builds from
         # TPUSTACK_PREFIX_CACHE{,_MB,_CHUNK}, default ON — lookup/insert
@@ -409,19 +406,19 @@ class LLMServer:
             prefix_cache._on_evict = (
                 lambda n: self.metrics[
                     "tpustack_llm_prefix_cache_evictions_total"].inc(n))
-        if (self.paged is not None and self.paged.cache is not None
-                and self.paged.cache.on_evict is None):
-            # same exported counter as the dense store, paged substrate
-            self.paged.cache.on_evict = (
-                lambda n: self.metrics[
-                    "tpustack_llm_prefix_cache_evictions_total"].inc(n))
-        if self.paged is not None and self.paged.cache is not None:
+        trie = paged.cache if paged is not None else None
+        if trie is not None:
+            if trie.on_evict is None:
+                # same exported counter as the solo route's host store
+                trie.on_evict = (
+                    lambda n: self.metrics[
+                        "tpustack_llm_prefix_cache_evictions_total"].inc(n))
             # warm-eviction visibility rides the unconditional last-hit
             # stamping (kv_pool) — counted whether or not the profiler is on
-            self.paged.cache.on_evict_warm = (
+            trie.on_evict_warm = (
                 lambda n: self.metrics[
                     "tpustack_llm_prefix_evicted_warm_total"].inc(n))
-            tier = getattr(self.paged.cache, "host_tier", None)
+            tier = getattr(trie, "host_tier", None)
             if tier is not None and tier.metrics is None:
                 # _build_paged is static (and tests hand-build runtimes):
                 # the spill/restore/expire counters attach here, once the
@@ -571,8 +568,8 @@ class LLMServer:
 
     # --------------------------------------------------- mesh accounting
     def _kv_per_chip_bytes(self) -> int:
-        """Serving-KV bytes ONE chip holds: the paged pool's largest
-        single-device shard, or (dense fallback) the slot caches'
+        """Serving-KV bytes ONE chip holds: the pool's largest
+        single-device shard, or (solo route) the one cache line's
         arithmetic equivalent — total cache bytes over the tp ways when
         the kv-head axis shards, whole otherwise."""
         if self.paged is not None:
@@ -653,59 +650,21 @@ class LLMServer:
 
     @staticmethod
     def _build_paged(gen, max_batch: int):
-        """Paged KV runtime from the environment (default ON for batched
-        serving; ``LLM_MAX_BATCH=1`` solo deployments keep the dense
-        engine).  Block size snaps down to divide the context; the pool
-        defaults to dense HBM parity (``max_batch x ctx`` tokens) — the
-        concurrency win comes from admission charging each request its
-        ACTUAL ``prompt + max_new`` instead of a whole ctx line."""
-        if not knobs.get_bool("TPUSTACK_PAGED_KV"):
-            return None
+        """The engine's KV pool, sized from the environment
+        (``TPUSTACK_KV_BLOCK``, ``TPUSTACK_KV_POOL_BLOCKS``,
+        ``TPUSTACK_PREFIX_CACHE``, ``TPUSTACK_KV_HOST_TIER_MB``); None for
+        an ``LLM_MAX_BATCH=1`` solo deployment, which runs no engine."""
         if max_batch < 2:
             return None
-        from tpustack.models.llama import init_kv_pool
-        from tpustack.serving.kv_pool import (KVBlockPool, PagedKVRuntime,
-                                              PagedPrefixCache)
+        from tpustack.serving.kv_pool import PagedKVRuntime
 
-        max_seq = gen.cfg.max_seq
-        block = knobs.get_int("TPUSTACK_KV_BLOCK")
-        if block <= 0:
-            block = min(64, max(8, max_seq // 8))
-        block = min(block, max_seq)
-        while block > 1 and max_seq % block:
-            block //= 2
-        n_blocks = knobs.get_int("TPUSTACK_KV_POOL_BLOCKS")
-        if n_blocks <= 0:
-            n_blocks = max_batch * (max_seq // block)
-        pool = KVBlockPool(n_blocks + 1, block)  # +1: reserved block 0
-        cache = None
-        if knobs.get_bool("TPUSTACK_PREFIX_CACHE"):
-            cache = PagedPrefixCache(pool)
-        # kv_mesh: under LLM_TP the pool tensors land head-axis-sharded
-        # over the tp axis, so each chip holds pool_bytes / tp — the
-        # accounting the runtime's per_shard_bytes reports back
-        arrays = init_kv_pool(gen.cfg, n_blocks + 1, block,
-                              dtype=gen.cache_dtype, mesh=gen.kv_mesh)
-        rt = PagedKVRuntime(arrays, pool, max_seq, cache)
-        tier_mb = knobs.get_float("TPUSTACK_KV_HOST_TIER_MB")
-        if cache is not None and tier_mb > 0:
-            from tpustack.serving.kv_host_tier import HostKVTier
-
-            # arrays_fn, not arrays: decode dispatches donate the pool
-            # buffers, so the tier must re-read the runtime's CURRENT
-            # reference at every spill
-            cache.host_tier = HostKVTier(
-                int(tier_mb * 1024 * 1024), pool,
-                arrays_fn=lambda: rt.arrays)
-            log.info("host KV tier on: %.0f MB arena behind the %d-block "
-                     "pool", tier_mb, n_blocks)
-        log.info("paged KV pool: %d blocks x %d tokens (ctx %d, %d-slot "
-                 "dense parity), %.2f GB total / %.2f GB per chip "
-                 "(%d shard%s), prefix cache %s", n_blocks, block, max_seq,
-                 max_batch, rt.pool_bytes / 1e9, rt.per_shard_bytes / 1e9,
-                 rt.kv_shards, "s" if rt.kv_shards != 1 else "",
-                 "on" if cache is not None else "off")
-        return rt
+        return PagedKVRuntime.build(
+            gen.cfg, max_batch,
+            block=knobs.get_int("TPUSTACK_KV_BLOCK"),
+            pool_blocks=knobs.get_int("TPUSTACK_KV_POOL_BLOCKS"),
+            dtype=gen.cache_dtype, mesh=gen.kv_mesh,
+            prefix_cache=knobs.get_bool("TPUSTACK_PREFIX_CACHE"),
+            host_tier_mb=knobs.get_float("TPUSTACK_KV_HOST_TIER_MB"))
 
     @staticmethod
     def _build_spec(gen):
@@ -913,7 +872,7 @@ class LLMServer:
         blocks + prefix-hit refs).  No-op once feed() handed the request
         to a slot — from then on the engine owns the references and
         releases them at retire (or in its failure path)."""
-        if self.paged is None or r.phase != "queued":
+        if r.phase != "queued":
             return
         ids = list(r.kv_blocks or [])
         if r.prefix:
@@ -1047,11 +1006,11 @@ class LLMServer:
         self._wake.set()
 
     def _request_hooks(self, ids, n_predict: int, cache_prompt: bool) -> dict:
-        """Per-request KV-cache wiring, mode-routed: paged admission (the
-        allocation-is-admission path; may raise :class:`OutOfKVBlocks` or
-        ValueError) or the dense prefix-cache lookup.  Returns
-        _PendingCompletion/SlotRequest kwargs."""
-        if self.paged is not None and self._batchable():
+        """Per-request KV-cache wiring: the engine's admission (allocation
+        IS admission; may raise :class:`OutOfKVBlocks` or ValueError) as
+        _PendingCompletion kwargs, or the solo route's host prefix-cache
+        lookup as ``generate``/``generate_fused`` kwargs."""
+        if self.paged is not None:
             prefix, kv_blocks, on_insert, host_restore = self._paged_admit(
                 ids, n_predict, cache_prompt)
             return {"prefix": prefix, "kv_blocks": kv_blocks,
@@ -1098,9 +1057,8 @@ class LLMServer:
 
         def on_done(tokens, row_stats):
             self.metrics["tpustack_llm_running_requests"].dec()
-            if self.paged is not None:
-                # the engine freed the slot's blocks before calling us
-                self._paged_gauges()
+            self._paged_gauges()  # the engine freed the slot's blocks
+            # before calling us
             if tokens is None:  # admission-time validation failure
                 self.metrics["tpustack_llm_requests_rejected_total"].labels(
                     reason="admission").inc()
@@ -1117,9 +1075,8 @@ class LLMServer:
         return SlotRequest(ids=r.ids, max_new=r.n_predict, sample=r.sample,
                            on_tokens=on_tokens, on_done=on_done,
                            cancelled=r.cancel.is_set, seed=r.seed,
-                           prefix=r.prefix, kv_extract=r.kv_extract,
-                           on_prefill_kv=r.on_prefill_kv,
-                           span_ctx=r.span_ctx, kv_blocks=r.kv_blocks,
+                           prefix=r.prefix, span_ctx=r.span_ctx,
+                           kv_blocks=r.kv_blocks,
                            on_prefill_blocks=r.on_prefill_blocks,
                            speculative=r.speculative, tenant=r.tenant,
                            t_kv_alloc=r.t_kv_alloc, priority=r.priority,
@@ -1303,9 +1260,8 @@ class LLMServer:
         block must belong to the prefix cache at refcount exactly 1.
         Anything else is a leaked slot reference: capacity gone until
         restart."""
-        if (not sanitize.enabled() or self.paged is None
-                or self._queue or self.resilience._inflight
-                or self._solo_waiting):
+        if (not sanitize.enabled() or self._queue
+                or self.resilience._inflight or self._solo_waiting):
             return
         sanitize.check_kv_quiesce(self.paged, where="llm engine drain")
 

@@ -5,7 +5,7 @@ BENCH_r05) — every plain decode step streams the full weight + KV working
 set to emit one token per slot, so the only way materially faster at low
 batch is to amortise that read over several tokens per step.  The
 continuous engine does that with a verify step
-(``llm_generate._spec_verify_cont``/``_paged``): score the last accepted
+(``llm_generate._spec_verify_paged``): score the last accepted
 token plus up to ``SpecConfig.tokens`` host-proposed draft tokens in ONE
 forward pass and keep the longest prefix the model agrees with (greedy:
 argmax-identical; sampled: rejection-sampled, distribution-preserving).

@@ -129,22 +129,20 @@ _declare("TPUSTACK_PAGED_FLASH", str, "auto",
          "chunk.  'auto' = on for real TPU backends, off on CPU/"
          "interpret and under a tp mesh; 0 bisects to the gather path "
          "(greedy outputs identical).")
-_declare("TPUSTACK_PAGED_KV", bool, True,
-         "Paged KV substrate for batched serving (block pool + block "
-         "tables); 0 falls back to the dense per-slot engine (bisection).")
 _declare("TPUSTACK_KV_BLOCK", int, 0,
          "KV block size in tokens; 0 = min(64, max(8, ctx/8)) snapped to "
          "divide ctx.")
 _declare("TPUSTACK_KV_POOL_BLOCKS", int, 0,
-         "Allocatable pool size in blocks; 0 = LLM_MAX_BATCH x ctx / block "
-         "(dense HBM parity).")
+         "Allocatable pool size in blocks; 0 = LLM_MAX_BATCH x ctx / "
+         "block.")
 _declare("TPUSTACK_PREFIX_CACHE", bool, True,
-         "Cross-request prefix KV cache (refcounted block trie under "
-         "paging, host radix store under the dense fallback).")
+         "Cross-request prefix KV cache (the engine's refcounted block "
+         "trie; the host radix store on the LLM_MAX_BATCH=1 solo route).")
 _declare("TPUSTACK_PREFIX_CACHE_MB", float, 512.0,
-         "Resident host-byte cap for the DENSE prefix cache store.")
+         "Resident host-byte cap for the solo route's host prefix cache.")
 _declare("TPUSTACK_PREFIX_CACHE_CHUNK", int, 256,
-         "Snap granularity in tokens for the dense prefix cache.")
+         "Snap granularity in tokens for the solo route's host prefix "
+         "cache.")
 _declare("TPUSTACK_KV_HOST_TIER_MB", float, 0.0,
          "Host-RAM second tier for the paged prefix cache: evicted "
          "refcount-0 prefix blocks spill device->host into an LRU arena "
