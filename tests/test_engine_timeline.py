@@ -274,6 +274,31 @@ def test_ctx_tokens_is_prompt_plus_generated_of_the_rows(gen, engine):
         (3 + 1) + (5 + 1), (3 + 5) + (5 + 5)]
 
 
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+def test_records_say_how_long_a_dispatch_ran_and_what_an_admission_waited_behind(
+        gen, engine):
+    """PR 31's two fields, by name: ``cut`` on every ``wave`` record, beside
+    ``weight_passes`` = the steps that ran; ``behind_steps`` on every
+    ``prefill`` record.  A ``verify`` record carries no ``cut``."""
+    recs, stats = _run(gen, [REPETITIVE, REPETITIVE[:7], [5, 6, 7]],
+                       max_new=11, min_steps=1, **ENGINES[engine](gen))
+    waves = [r for r in recs if r["kind"] == "wave"]
+    assert waves and all(
+        r["cut"] in ("full", "row_end", "seating") for r in waves)
+    assert all(1 <= r["weight_passes"] <= 4 for r in waves)
+    assert all((r["cut"] == "full") == (r["weight_passes"] == 4)
+               for r in waves)
+    assert all("cut" not in r and r["weight_passes"] == 1
+               for r in recs if r["kind"] == "verify")
+    assert sum(r["weight_passes"] for r in recs
+               if r["kind"] in ("wave", "verify")) == \
+        stats["decode_weight_passes"]
+    pre = [r for r in recs if r["kind"] == "prefill"]
+    assert pre and all(isinstance(r["behind_steps"], int)
+                       and 0 <= r["behind_steps"] <= 2 * 4 for r in pre)
+    assert pre[0]["behind_steps"] == 0  # an idle engine's first admission
+
+
 def test_ctx_tokens_under_speculation_counts_what_was_delivered(gen):
     delivered = {"n": 0}
     rec = FlightRecorder("eng", capacity=256)
@@ -332,7 +357,7 @@ def paged_programs(gen):
     traced = {
         "_decode_scan_paged": Generator._decode_scan_paged.trace(
             g, P, i32(B, 1), i32(B), i32(B), pool, i32(B, nb), keys,
-            f32(B), i32(B), flags, 4, flash=False),
+            f32(B), i32(B), flags, 4, i32(), flash=False),
         "_spec_verify_paged": Generator._spec_verify_paged.trace(
             g, P, i32(B, 1), i32(B, K), i32(B), i32(B), i32(B), pool,
             i32(B, nb), keys, f32(B), i32(B), flags, K, flash=True),
@@ -493,7 +518,7 @@ def moe_programs(moe_gen):
     traced = {
         "_decode_scan_paged": Generator._decode_scan_paged.trace(
             g, g.params, i32(B, 1), i32(B), i32(B), pool, i32(B, nb), keys,
-            f32(B), i32(B), flags, 4, flash=True),
+            f32(B), i32(B), flags, 4, i32(), flash=True),
         "_admit_fused_paged": Generator._admit_fused_paged.trace(
             g, g.params, i32(n, bucket), pool, i32(n, nb), i32(n), i32(n),
             i32(n), sds((n,), jnp.uint32), *slot_state, *row),

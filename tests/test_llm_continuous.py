@@ -404,3 +404,213 @@ def test_resolve_guard_fails_safe(gen):
     assert slots[0].pending is False  # fails SAFE: cleared, not wedged
     assert slots[0].req is current    # the occupant was not touched
     assert slots[0].out == []         # stale wave's token was dropped
+
+
+# ------------------------------------------- a dispatch's length follows the lanes
+#
+# Counts, never rates: ``min_steps`` pins ``m`` (what ``_Pace`` measures on
+# a chip), the feed is scripted, and everything read is a flight record or
+# a token list.
+
+#: two callers' requests, ``(prompt, max_new)``, served one after another
+CALLERS = [[([5, 6, 7], 7), ([8, 9], 12), ([3, 4, 5, 6], 5)],
+           [([9, 10, 11, 12], 20), ([7, 7], 9)]]
+CAPACITY = 8
+
+
+def _closed_loop(gen, callers=CALLERS, *, min_steps, sample=GREEDY, seed=None,
+                 late=0, **engine_kw):
+    """The benchmark's chat cells, scripted: as many callers as slots, a
+    caller's next request queued by the ``on_done`` of its last (``late``:
+    that many ``feed()`` polls after it).  Returns ``(tokens by (caller,
+    request), run stats, flight records, the number of wave records there
+    were when each request retired)``."""
+    from tpustack.obs.flight import FlightRecorder
+
+    rec = FlightRecorder("eng", capacity=4096)
+    eng = ContinuousEngine(gen, slots=len(callers), chunk=CAPACITY,
+                           flight=rec, min_steps=min_steps, **engine_kw)
+    queue, outs, ends = [], {}, {}
+
+    def submit(c, k):
+        ids, n = callers[c][k]
+
+        def on_done(toks, st):
+            outs[c, k] = toks
+            ends[c, k] = sum(r["kind"] == "wave" for r in rec.recent())
+            if k + 1 < len(callers[c]):
+                submit(c, k + 1)
+
+        queue.append([late if k else 0, SlotRequest(
+            ids=list(ids), max_new=n, sample=sample, seed=seed,
+            on_done=on_done)])
+
+    def feed():
+        if not queue:
+            return None
+        if queue[0][0] > 0:
+            queue[0][0] -= 1
+            return None
+        return queue.pop(0)[1]
+
+    for c in range(len(callers)):
+        submit(c, 0)
+    stats = eng.run(feed)
+    return outs, stats, rec.recent(), ends
+
+
+def _kind(recs, kind):
+    return [r for r in recs if r["kind"] == kind]
+
+
+@pytest.mark.parametrize("m", [1, 2, 3])
+def test_cutting_dispatches_changes_no_token(gen, m):
+    """(a) Greedy rows equal the full-capacity engine's and the solo
+    path's however their steps are cut into dispatches; a seeded sampled
+    row's stream is per step, so it is equal across the cuttings too."""
+    full, _, _, _ = _closed_loop(gen, min_steps=CAPACITY)
+    cut, _, recs, _ = _closed_loop(gen, min_steps=m)
+    assert {r["cut"] for r in _kind(recs, "wave")} == {"full", "row_end",
+                                                      "seating"}
+    assert cut == full
+    for c, reqs in enumerate(CALLERS):
+        for k, (ids, n) in enumerate(reqs):
+            solo = gen.generate_fused(ids, max_new_tokens=n, sample=GREEDY,
+                                      chunk=4)[0]
+            assert cut[c, k] == solo, (c, k)
+    sampled = SampleConfig(temperature=1.2, top_k=8)
+    a, _, _, _ = _closed_loop(gen, min_steps=CAPACITY, sample=sampled,
+                              seed=1234)
+    b, _, _, _ = _closed_loop(gen, min_steps=m, sample=sampled, seed=1234)
+    assert a == b and a != full
+
+
+def test_a_row_retires_at_the_fetch_of_a_dispatch_that_ends_on_its_last_step(
+        gen):
+    """(b) A budget that runs out mid-capacity cuts the dispatch there:
+    the row's last tokens and its seat are there at that fetch."""
+    outs, _, recs, ends = _closed_loop(gen, min_steps=2)
+    waves = _kind(recs, "wave")
+    # caller 0's first request: 7 tokens, the first from its prefill, so 6
+    # steps of a capacity of 8 — the first dispatch of the run
+    last = waves[ends[0, 0]]
+    assert (last["cut"], last["weight_passes"]) == ("row_end", 6)
+    # no token of it discarded: both rows rode all 6 steps
+    assert last["tokens"] == 2 * 6 and len(outs[0, 0]) == 7
+    # the full-capacity engine ran the 8 and threw two lane-steps away
+    _, _, recs_full, ends_full = _closed_loop(gen, min_steps=CAPACITY)
+    old = _kind(recs_full, "wave")[ends_full[0, 0]]
+    assert (old["cut"], old["weight_passes"], old["tokens"]) == ("full", 8,
+                                                                 6 + 8)
+    # a row with fewer steps left than m ends inside a dispatch of m
+    _, _, recs3, ends3 = _closed_loop(
+        gen, [[([5, 6, 7], 3), ([5, 6], 4)], [([9, 10, 11], 30)]],
+        min_steps=3)
+    short = _kind(recs3, "wave")[ends3[0, 0]]
+    assert (short["cut"], short["weight_passes"], short["tokens"]) == (
+        "row_end", 3, 2 + 3)
+
+
+@pytest.mark.parametrize("late", [0, 1])
+@pytest.mark.parametrize("m", [1, 2])
+def test_a_request_fed_after_an_end_is_dispatched_behind_few_steps(gen, m,
+                                                                   late):
+    """(c) ``behind_steps``: the decode steps queued on the device ahead
+    of an admission.  Behind an end the dispatches run ``m`` steps, so the
+    caller's next request — there at the next boundary, or one later —
+    waits behind at most 2 m of them; the full-capacity engine queues a
+    whole chunk ahead of it."""
+    _, _, recs, _ = _closed_loop(gen, min_steps=m, late=late)
+    pre = _kind(recs, "prefill")
+    assert all("behind_steps" in r for r in pre)
+    mid_run = [r["behind_steps"] for r in pre[1:]]
+    assert mid_run and all(b <= 2 * m for b in mid_run), mid_run
+    assert any(b > 0 for b in mid_run)  # admitted with decode in flight
+    _, _, recs_full, _ = _closed_loop(gen, min_steps=CAPACITY, late=late)
+    assert max(r["behind_steps"]
+               for r in _kind(recs_full, "prefill")) == CAPACITY
+
+
+def test_weight_passes_are_the_steps_that_ran(gen):
+    """(d) Every wave record's ``weight_passes`` is the steps its dispatch
+    ran; they add up to the run's; and the scripted closed loop delivers
+    its 48 decoded tokens in 27 weight passes where full-capacity
+    dispatches take 48."""
+    decoded = sum(n - 1 for reqs in CALLERS for _, n in reqs)
+    got = {}
+    for m in (CAPACITY, 2):
+        _, stats, recs, _ = _closed_loop(gen, min_steps=m)
+        waves = _kind(recs, "wave")
+        assert sum(r["weight_passes"] for r in waves) == \
+            stats["decode_weight_passes"]
+        assert sum(r["tokens"] for r in waves) == decoded
+        assert all(r["stride"] == r["weight_passes"] for r in waves)
+        assert all(1 <= r["weight_passes"] <= CAPACITY for r in waves)
+        got[m] = stats["decode_weight_passes"]
+        assert stats["tokens_per_weight_pass"] == pytest.approx(
+            decoded / got[m])
+    assert decoded == 48 and got == {CAPACITY: 48, 2: 27}
+
+
+def test_one_decode_program_serves_every_length(gen):
+    """(e) The steps a dispatch runs are an operand, not a shape: one
+    trace of ``_decode_scan_paged`` whatever lengths the engine asks for
+    (the sanitizer's recompile budget, here 1, is checked every wave)."""
+    capacity = 7  # a capacity no other test of this module compiles
+    from tpustack.obs.flight import FlightRecorder
+
+    rec = FlightRecorder("eng", capacity=1024)
+    eng = ContinuousEngine(gen, slots=2, chunk=capacity, flight=rec,
+                           min_steps=1,
+                           compile_budgets={"_decode_scan_paged": 1})
+    queue = [SlotRequest(ids=[5, 6, 7], max_new=n, sample=GREEDY)
+             for n in (4, 9, 6, 17, 3)]
+    eng.run(lambda: queue.pop(0) if queue else None)
+    lengths = {r["weight_passes"] for r in _kind(rec.recent(), "wave")}
+    assert len(lengths) >= 4, lengths
+    assert eng._san.compiles("_decode_scan_paged") == 1
+
+
+def test_pace_is_the_capacity_until_both_clocks_are_measured():
+    """(f) ``m`` = 1.5 x the dearest of the last 8 waves' host seconds over
+    the device's seconds a step: floor 1, ceiling the capacity, and the
+    capacity while either clock is unread."""
+    from tpustack.models.llm_continuous import _Pace
+
+    pace = _Pace(16)
+    assert pace.min_steps() == 16  # nothing measured
+    pace.note_host(0.020)
+    assert pace.min_steps() == 16  # one clock is not both
+    pace.note_step(0.010)
+    assert pace.min_steps() == 3   # 1.5 x 20 ms of host / 10 ms a step
+    pace.note_host(0.200)
+    assert pace.min_steps() == 16  # a host slower than the device: capacity
+    for _ in range(_Pace.WAVES):
+        pace.note_host(0.001)
+    assert pace.min_steps() == 1   # floor 1; the dear wave aged out
+    assert _Pace(16, fixed=99).min_steps() == 16  # a pinned m is clipped
+
+
+@pytest.mark.parametrize("m", [None, CAPACITY])
+def test_unmeasured_or_at_capacity_the_dispatches_are_the_old_ones(gen, m):
+    """(f) An engine with no recorder never measures, and one whose ``m``
+    is the capacity has nothing to cut: every dispatch runs the capacity,
+    the parent's sequence."""
+    engine_kw = {} if m is None else {"min_steps": m}
+    eng = ContinuousEngine(gen, slots=2, chunk=CAPACITY, **engine_kw)
+    lengths = []
+    real = gen._decode_scan_paged
+
+    def spy(*a, **kw):
+        lengths.append(int(a[-1]))
+        return real(*a, **kw)
+
+    gen._decode_scan_paged = spy
+    try:
+        queue = [SlotRequest(ids=list(ids), max_new=n, sample=GREEDY)
+                 for reqs in CALLERS for ids, n in reqs]
+        stats = eng.run(lambda: queue.pop(0) if queue else None)
+    finally:
+        gen._decode_scan_paged = real
+    assert lengths and set(lengths) == {CAPACITY}
+    assert stats["decode_weight_passes"] == CAPACITY * len(lengths)

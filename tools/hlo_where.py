@@ -6,8 +6,8 @@ and outside the decode scan, for a DESCRIBED (not attached) TPU v5e.
     python tools/hlo_where.py admit --preset k_exaone_236b_ep8 --layers 2
     python tools/hlo_where.py decode --layers 0 --dump /root/scratch/d.txt
 
-Compiles ``Generator._decode_scan_paged`` (``decode``: the 16-step chunk,
-``flash=True``, every slot live) or ``_admit_fused_paged`` (``admit``: one
+Compiles ``Generator._decode_scan_paged`` (``decode``: a capacity of 16
+steps, the steps run an operand, ``flash=True``, every slot live) or ``_admit_fused_paged`` (``admit``: one
 row of the 512 bucket) of a served configuration on ``ShapeDtypeStruct``s
 (``tpustack/utils/hlo_text.py``) and reads the optimised HLO: every
 instruction that is not inside a fusion, with the bytes its result takes

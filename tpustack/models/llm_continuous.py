@@ -55,6 +55,15 @@ static-shape rules:
 - **Retirement at fetch**: a row hitting EOS/budget is answered immediately
   (``on_done``), its blocks decref'd and its slot parked (``active=0``,
   ``cur=0``) then reused.
+- **A dispatch's length follows the lanes**: ``chunk`` is the CAPACITY of
+  a decode dispatch; how many steps one runs is a run-time operand of the
+  one compiled program, chosen per dispatch (``_dispatch_len``) from what
+  the host holds.  It ends where the first of its rows ends (budgets are
+  known at admission), so that row's last tokens and its seat are there
+  at that fetch; while a seat is being refilled it runs the fewest steps
+  the host keeps up with (``_Pace``: measured, not set), so an arriving
+  request is dispatched behind a few steps of other rows' decode, not
+  behind one or two whole chunks; otherwise it runs the capacity.
 
 Safety of the fetch-lag overshoot (host retires up to ``depth`` chunks after
 the device computed them): ``cur`` clamps at ``max_seq - 1``, a parked slot
@@ -96,6 +105,10 @@ log = get_logger("models.llm_continuous")
 
 #: what ``with self._phase(...)`` enters on an engine with no flight recorder
 _NO_PHASE = contextlib.nullcontext()
+
+#: the phases in which the engine thread waits for the device; the rest of a
+#: wave's ``host_s`` is the host's own work
+_WAIT_PHASES = ("fetch_wait", "resolve_wait", "verify_wait")
 
 
 @dataclasses.dataclass
@@ -244,12 +257,15 @@ class _PendingWave:
     them and completes the host-side bookkeeping."""
 
     __slots__ = ("rows", "firsts_dev", "t0", "block_inserts", "bucket",
-                 "moe_dev")
+                 "moe_dev", "behind_steps")
 
     def __init__(self, rows, firsts_dev, t0, block_inserts=(), bucket=None,
-                 moe_dev=None):
+                 moe_dev=None, behind_steps=0):
         self.rows = rows            # [(slot_idx, req, budget)]
         self.firsts_dev = firsts_dev
+        # decode steps queued on the device ahead of this admission:
+        # dispatched, not yet fetched, when it was dispatched
+        self.behind_steps = behind_steps
         # the admission's routed-expert counters (Generator._apply_counted;
         # None for a model without such a layer): fetched with the firsts
         self.moe_dev = moe_dev
@@ -258,6 +274,61 @@ class _PendingWave:
         # [(req, prompt block ids)] — handed to on_prefill_blocks at
         # resolution (zero-copy cache insert; no device work at all)
         self.block_inserts = list(block_inserts)
+
+
+class _Dispatch:
+    """One decode dispatch in flight: its device outputs, the rows it
+    carries, and what the engine chose for it."""
+
+    __slots__ = ("out", "rows", "steps", "cut", "timed")
+
+    def __init__(self, out, rows, steps, cut, timed):
+        self.out = out        # (toks_dev [B, chunk], moe counters or None)
+        self.rows = rows      # [(slot_idx, gen_id, offset)] at dispatch
+        self.steps = steps    # decode steps it runs (<= the capacity)
+        self.cut = cut        # why that many: full | row_end | seating
+        # its device time can be read between two fetches: queued straight
+        # behind another decode dispatch, no admission between the two
+        self.timed = timed
+
+
+class _Pace:
+    """``m``: the fewest steps a decode dispatch may run with the host
+    still keeping up.  While the device runs one dispatch the host has to
+    consume the one before it and queue the next, so a dispatch must keep
+    the device busy for as long as a wave costs the host.  Both sides are
+    the engine's own measurements: the host's non-wait seconds a wave
+    (``PhaseClock``; the dearest of the last ``WAVES``, because a long
+    wave's bookkeeping falls due while the short one behind it runs)
+    against the device's seconds a decode step (between two fetches that
+    both had to wait, ``_Dispatch.timed``), times ``MARGIN``.  Until both
+    are measured — and on an engine with no phase clock — it is the
+    capacity: every dispatch as long as it can be."""
+
+    MARGIN = 1.5
+    WAVES = 8
+
+    def __init__(self, capacity: int, fixed: Optional[int] = None):
+        self.capacity = capacity
+        self._fixed = (None if fixed is None
+                       else max(1, min(capacity, int(fixed))))
+        self._host_s: deque = deque(maxlen=self.WAVES)
+        self._step_s: Optional[float] = None
+
+    def note_host(self, seconds: float) -> None:
+        self._host_s.append(seconds)
+
+    def note_step(self, seconds: float) -> None:
+        self._step_s = (seconds if self._step_s is None
+                        else 0.75 * self._step_s + 0.25 * seconds)
+
+    def min_steps(self) -> int:
+        if self._fixed is not None:
+            return self._fixed
+        if not self._host_s or not self._step_s:
+            return self.capacity
+        need = self.MARGIN * max(self._host_s) / self._step_s
+        return max(1, min(self.capacity, int(np.ceil(need))))
 
 
 class ContinuousEngine:
@@ -278,10 +349,16 @@ class ContinuousEngine:
                  ledger=None,
                  preempt_hint: Optional[Callable[[], bool]] = None,
                  on_preempt: Optional[Callable[[str], None]] = None,
-                 prefill_chunk: Optional[int] = None):
+                 prefill_chunk: Optional[int] = None,
+                 min_steps: Optional[int] = None):
         self.gen = gen
         self.B = slots
+        # the CAPACITY of a decode dispatch (the compiled program's chunk
+        # buffers, the coarsest streaming cadence); what one runs is
+        # ``_dispatch_len``'s choice.  ``min_steps`` pins ``_Pace``'s
+        # measurement for a test.
         self.chunk = chunk
+        self._pace = _Pace(chunk, fixed=min_steps)
         self.stop_tokens = stop_tokens
         self.depth = depth
         # what the flight records say of the model's layer kinds: its
@@ -416,6 +493,15 @@ class ContinuousEngine:
                        else lambda name: _NO_PHASE)
         self._to_park: List[int] = []  # retirements awaiting a fused park
         self._pending: List[_PendingWave] = []
+        # what _dispatch_len and the flight records read (per run, set in
+        # run()): seats freed and not taken again, and the steps their
+        # window still has; decode steps dispatched and not yet fetched; an
+        # admission dispatched since the last decode dispatch; when the
+        # last fetch that had to wait returned
+        self._seats_open = self._seat_left = 0
+        self._in_flight = 0
+        self._admit_queued = False
+        self._fetch_t: Optional[float] = None
         self._retired_tokens = 0
         # fetch-boundary rate marks: appended by the engine thread once per
         # wave, read by the SERVER thread computing projected block release
@@ -718,6 +804,8 @@ class ContinuousEngine:
                            "budget": budget})
         if self._on_progress is not None:
             self._on_progress("prefill")
+        self._admit_queued = True  # the next decode dispatch runs behind it
+        self._seats_open = max(0, self._seats_open - len(valid))
 
         # chunked prefill: a row whose uncached remainder exceeds the
         # chunk size dispatches ONE block-aligned chunk and parks the rest
@@ -812,7 +900,7 @@ class ContinuousEngine:
                 slots[i].pending = True
             self._pending.append(_PendingWave(
                 rows, firsts, t0, block_inserts=block_inserts(rows),
-                bucket=bucket, moe_dev=moe))
+                bucket=bucket, moe_dev=moe, behind_steps=self._in_flight))
 
         for row in prefix_rows:
             rows = [row]
@@ -933,6 +1021,7 @@ class ContinuousEngine:
                          for _, r, _ in wave.rows],
                 bucket=wave.bucket,
                 prompt_lens=[len(r.ids) for _, r, _ in wave.rows],
+                behind_steps=wave.behind_steps,
                 **self._moe_fields(moe, passes=1))
         for req, ids in wave.block_inserts:
             # prefill has landed (the firsts fetch above synced on it): the
@@ -1011,6 +1100,11 @@ class ContinuousEngine:
         s = slots[i]
         req, out = s.req, s.out
         s.req, s.done, s.pending = None, True, False
+        # a seat is free: until it is taken, or for a capacity's worth of
+        # steps, the dispatches stay short for whoever takes it
+        # (_dispatch_len)
+        self._seats_open += 1
+        self._seat_left = self.chunk
         if s.span is not None:
             s.span.set_attribute("generated_tokens", len(out))
             s.span.end()
@@ -1232,7 +1326,7 @@ class ContinuousEngine:
         state = self._fresh_state()
         slots = [_Slot() for _ in range(self.B)]
         self._slots_view = slots  # projected_block_release_s reads this
-        chain: deque = deque()  # (toks_dev, [(slot_idx, gen_id, offset)])
+        chain: deque = deque()  # _Dispatch, oldest first
         gen_ctr = 0
         t_start = time.time()
         admitted = 0
@@ -1246,6 +1340,8 @@ class ContinuousEngine:
         self._spec_drafted = self._spec_accepted = 0
         self._spec_dispatches = self._plain_steps = 0
         self._wave_ctr = 0
+        self._seats_open = self._seat_left = self._in_flight = 0
+        self._admit_queued, self._fetch_t = False, None
         self._last_wave_t = None  # per-run: wave_s must not span idle gaps
         if self._clock is not None:
             self._clock.reset()  # likewise host_s
@@ -1381,23 +1477,61 @@ class ContinuousEngine:
             })
         return stats
 
+    def _dispatch_len(self, slots, rows, dispatch_ok) -> Tuple[int, str]:
+        """How many steps the next decode dispatch runs, and why (the
+        wave record's ``cut``) — from what the host already holds:
+
+        - ``row_end``: it ends where the first of the rows it carries
+          ends (``budget`` is known at admission), so that row's last
+          tokens reach its caller and its seat is free at this dispatch's
+          fetch, with no dead tail — but never below ``m``, the fewest
+          steps the host keeps up with (``_Pace``): a row with fewer left
+          ends inside a dispatch of ``m``;
+        - ``seating``: ``m`` steps while a seat is being refilled — a row
+          whose last step is already queued (its lane rides this dispatch
+          dead), a seat freed and not yet taken again (for a capacity's
+          worth of steps: then nobody is coming), or a request queued
+          beside an empty lane — so that what is queued on the device
+          ahead of the next admission is at most ``2 m`` steps;
+        - ``full``: the capacity otherwise — every lane seated and no end
+          inside the chunk, or lanes empty with nothing queued and no
+          recent end (an under-full engine takes no boundary for nobody,
+          and a burst arriving together is still admitted together)."""
+        cap, m = self.chunk, self._pace.min_steps()
+        carried = [slots[i] for i, _, _ in rows]
+        target = max(m, min(s.budget - 1 - s.dispatched for s in carried))
+        seating = len(carried) < self.B and (
+            any(s.req is not None and not dispatch_ok(s) for s in slots)
+            or self._seats_open > 0
+            or bool(self._queue_depth_fn is not None
+                    and self._queue_depth_fn()))
+        steps = min(cap, target, m if seating else cap)
+        if self._seats_open > 0:
+            self._seat_left -= steps
+            if self._seat_left <= 0:
+                self._seats_open = 0  # nobody came: full dispatches again
+        cut = ("full" if steps == cap
+               else "row_end" if steps == target else "seating")
+        return steps, cut
+
     def _fill_chain(self, state, slots, chain, dispatch_ok):
-        """Keep up to ``depth`` plain decode chunks in flight (the
+        """Keep up to ``depth`` plain decode dispatches in flight (the
         pipelined dispatch half of the wave loop, shared by the plain and
-        speculative run loops)."""
+        speculative run loops), each as long as ``_dispatch_len`` says."""
         g = self.gen
         with self._phase("dispatch"):
             while len(chain) < self.depth and any(
                     dispatch_ok(s) for s in slots):
                 snapshot = [(i, s.gen_id, s.dispatched)
                             for i, s in enumerate(slots) if dispatch_ok(s)]
+                steps, cut = self._dispatch_len(slots, snapshot, dispatch_ok)
                 (toks, last, state["cur"], state["pool"],
                  state["keys"], moe) = g._decode_scan_paged(
                     g.params, state["first"], state["cur"],
                     state["active"], state["pool"],
                     jnp.asarray(self._bt), state["keys"],
                     state["temp"], state["topk"], state["greedy"],
-                    self.chunk, flash=self.paged_flash)
+                    self.chunk, np.int32(steps), flash=self.paged_flash)
                 # keep the runtime's arrays reference CURRENT (donation
                 # rotated the buffers): the host-tier spill path reads
                 # blocks through it between dispatches, and cached prefix
@@ -1409,10 +1543,14 @@ class ContinuousEngine:
                 else:
                     self._gather_dispatches += 1
                 state["first"] = last
-                self._plain_steps += self.chunk
+                self._plain_steps += steps
+                self._in_flight += steps
                 for i, _, _ in snapshot:
-                    slots[i].dispatched += self.chunk
-                chain.append(((toks, moe), snapshot))
+                    slots[i].dispatched += steps
+                chain.append(_Dispatch(
+                    (toks, moe), snapshot, steps, cut,
+                    timed=bool(chain) and not self._admit_queued))
+                self._admit_queued = False
 
     def _sanitize_wave(self) -> None:
         """Wave-boundary sanitizer checks (no-op unless TPUSTACK_SANITIZE):
@@ -1455,7 +1593,8 @@ class ContinuousEngine:
                      tenants: Optional[Dict[str, int]] = None,
                      priorities: Optional[Dict[str, int]] = None,
                      ctx_tokens: int = 0, ctx_window: int = 0,
-                     moe: Optional[Dict[str, int]] = None) -> None:
+                     moe: Optional[Dict[str, int]] = None,
+                     cut: Optional[str] = None) -> None:
         """Append one flight record for a fetched wave (plain chunk or
         speculative verify).  Host-side values only — the fetch that
         produced ``tokens`` already synced, so this is a dict build and a
@@ -1468,7 +1607,9 @@ class ContinuousEngine:
         ``ctx_window``: the same with each row's context cut at the
         model's attention window (what a window layer had to read; only a
         model with such layers gets the field).  ``moe``: the wave's
-        routed-expert counters (``_moe_fields``).
+        routed-expert counters (``_moe_fields``).  ``weight_passes``:
+        the steps the dispatch ran (a verify: 1); ``cut``: why a plain
+        dispatch ran that many (``_dispatch_len``).
         ``host_s``: the engine thread's seconds by phase since the
         previous wave/verify record, ``other`` being what no phase
         covered — they add up to ``wave_s``."""
@@ -1476,6 +1617,10 @@ class ContinuousEngine:
             return
         now = time.time()
         host_s = self._clock.take()
+        if cut is not None:
+            # what this wave cost the host, for the next dispatches' length
+            self._pace.note_host(sum(
+                v for k, v in host_s.items() if k not in _WAIT_PHASES))
         rec = {
             "wave": self._wave_ctr,
             "occupancy": (occupancy if occupancy is not None else
@@ -1491,6 +1636,8 @@ class ContinuousEngine:
             "host_s": host_s,
             "ctx_tokens": int(ctx_tokens),
         }
+        if cut is not None:
+            rec["cut"] = cut
         if self._window is not None:
             rec["ctx_tokens_window"] = int(ctx_window)
         rec.update(moe or {})
@@ -1535,10 +1682,12 @@ class ContinuousEngine:
         if self.ledger is not None:
             self.ledger.charge_flight_wave("llm", rec)
 
-    def _consume_block(self, state, slots, block, snapshot, moe=None):
-        """Host bookkeeping for one fetched plain chunk block (the consume
-        half of the wave loop, shared by both run loops).  ``moe``: the
-        chunk's fetched routed-expert counters, if the model has any."""
+    def _consume_block(self, state, slots, block, d: _Dispatch, moe=None):
+        """Host bookkeeping for one fetched plain decode dispatch ``d``
+        (the consume half of the wave loop, shared by both run loops):
+        ``block`` its fetched tokens, of which the first ``d.steps``
+        columns ran.  ``moe``: its fetched routed-expert counters, if the
+        model has any."""
         if self._on_progress is not None:
             self._on_progress("wave")
         self._sanitize_wave()
@@ -1552,7 +1701,7 @@ class ContinuousEngine:
         tenants = self._tenant_occupancy(slots)  # pre-retire, like live
         priorities = self._priority_occupancy(slots)
         wave_tokens = ctx_tokens = ctx_window = 0
-        for i, gid, offset in snapshot:
+        for i, gid, offset in d.rows:
             s = slots[i]
             if s.req is None or s.gen_id != gid or s.done:
                 continue  # lane is garbage for a retired/reassigned slot
@@ -1563,11 +1712,11 @@ class ContinuousEngine:
             ctx = len(s.req.ids) + len(s.out)
             ctx_tokens += ctx
             ctx_window += min(ctx, self._window or 0)
-            # chunks are consumed in dispatch order and never overlap:
-            # this block carries exactly decode steps [offset, offset+chunk)
+            # dispatches are consumed in order and never overlap: this
+            # block carries exactly decode steps [offset, offset + d.steps)
             assert len(s.out) - 1 == offset, (len(s.out), offset)
             accepted = []
-            for t in (int(x) for x in block[i]):
+            for t in (int(x) for x in block[i, :d.steps]):
                 s.out.append(t)
                 accepted.append(t)
                 if t in self.stop_tokens or len(s.out) >= s.budget:
@@ -1582,11 +1731,12 @@ class ContinuousEngine:
                 s.req.on_tokens(accepted)
             if s.done:
                 self._retire(state, slots, i, live)
-        self._flight_wave(slots, "wave", wave_tokens, self.chunk,
-                          stride=self.chunk, occupancy=live,
+        self._flight_wave(slots, "wave", wave_tokens, d.steps,
+                          stride=d.steps, occupancy=live,
                           tenants=tenants, priorities=priorities,
                           ctx_tokens=ctx_tokens, ctx_window=ctx_window,
-                          moe=self._moe_fields(moe, passes=self.chunk))
+                          moe=self._moe_fields(moe, passes=d.steps),
+                          cut=d.cut)
 
     def _moe_fields(self, moe, passes: int) -> Dict[str, int]:
         """Flight-record fields of one dispatch's routed-expert work:
@@ -1600,15 +1750,26 @@ class ContinuousEngine:
                 "moe_pairs": pairs, "moe_experts_touched": touched,
                 "moe_max_expert_tokens": fullest}
 
-    def _fetch_consume(self, state, slots, block, snapshot):
-        """THE wave-boundary fetch: one sync per consumed chunk, with
-        `depth` more chunks already dispatched behind it — the wait timed
-        apart from the bookkeeping that follows it."""
+    def _fetch_consume(self, state, slots, d: _Dispatch):
+        """THE wave-boundary fetch: one sync per consumed dispatch, with
+        `depth` more already dispatched behind it — the wait timed apart
+        from the bookkeeping that follows it."""
         with self._phase("fetch_wait"):
-            # the chunk's tokens and, with them, its routed-expert counters
-            block, moe = jax.device_get(block)  # tpulint: disable=TPL101
+            t0 = time.perf_counter()
+            # the dispatch's tokens and, with them, its routed-expert
+            # counters
+            block, moe = jax.device_get(d.out)  # tpulint: disable=TPL101
+            now = time.perf_counter()
+        self._in_flight -= d.steps
+        # a fetch that had to wait returns when the device is done: two of
+        # them in a row, with this dispatch queued straight behind the
+        # last, are this dispatch's device time apart
+        waited = now - t0 > 1e-3
+        if waited and d.timed and self._fetch_t is not None:
+            self._pace.note_step((now - self._fetch_t) / d.steps)
+        self._fetch_t = now if waited else None
         with self._phase("consume"):
-            self._consume_block(state, slots, block, snapshot, moe)
+            self._consume_block(state, slots, block, d, moe)
 
     def _retire_exhausted(self, state, slots, dispatch_ok):
         """Retire every row that is done or has nothing left to dispatch
@@ -1644,8 +1805,8 @@ class ContinuousEngine:
                 self._resolve_pending(state, slots)
                 self._retire_exhausted(state, slots, dispatch_ok)
                 continue
-            block, snapshot = chain.popleft()
-            pending_here = {i for i, _, _ in snapshot if slots[i].pending}
+            d = chain.popleft()
+            pending_here = {i for i, _, _ in d.rows if slots[i].pending}
             if pending_here or self._pending:
                 # this block may carry decode steps for rows whose first
                 # token the host hasn't picked up yet — resolve exactly
@@ -1656,7 +1817,7 @@ class ContinuousEngine:
                 # already-computed tokens are never stalled behind them
                 self._resolve_pending(state, slots,
                                       needed_slots=pending_here)
-            self._fetch_consume(state, slots, block, snapshot)
+            self._fetch_consume(state, slots, d)
 
     # ------------------------------------------------- speculative decoding
     def _slot_draft_budget(self, s: _Slot) -> int:
@@ -1903,11 +2064,11 @@ class ContinuousEngine:
                 self._resolve_pending(state, slots)
                 self._retire_exhausted(state, slots, dispatch_ok)
                 continue
-            block, snapshot = chain.popleft()
-            pending_here = {i for i, _, _ in snapshot if slots[i].pending}
+            d = chain.popleft()
+            pending_here = {i for i, _, _ in d.rows if slots[i].pending}
             if pending_here or self._pending:
                 self._resolve_pending(state, slots,
                                       needed_slots=pending_here)
             # the spec loop's plain-chunk fallback shares the one-sync-
             # per-wave contract of _run_loop above
-            self._fetch_consume(state, slots, block, snapshot)
+            self._fetch_consume(state, slots, d)

@@ -779,15 +779,21 @@ class Generator:
     # timing or batch composition.
 
     def _decode_cont_body(self, params, first_tok, cur, active, caches, keys,
-                          temperature, top_k, greedy, n_steps: int):
-        """Traced body of one continuous-slot decode chunk: the ``n_steps``
-        scan over a FROZEN cache view, K/V landing in chunk-local buffers.
+                          temperature, top_k, greedy, n_steps: int, steps):
+        """Traced body of one continuous-slot decode dispatch: ``steps``
+        steps (a traced scalar, ``1 <= steps <= n_steps``) over a FROZEN
+        cache view, K/V landing in chunk-local buffers of ``n_steps``
+        positions — a loop whose trip count the engine chooses a dispatch,
+        so one compiled program serves every length.
         ``_decode_scan_paged`` presents the view (the pool read in place,
         or gathered through the block tables) and scatters the buffers
         back through the block tables — one body for both reads is what
-        makes their greedy outputs byte-identical.  Returns ``(toks [B, T], last, cur_end, bufs,
-        keys, moe)`` (``moe``: ``_apply_counted``'s counters summed over
-        the steps, None for a model without routed experts)."""
+        makes their greedy outputs byte-identical.  Returns ``(toks [B,
+        n_steps], last, cur_end, bufs, keys, moe)``: columns of ``toks``
+        and buffer positions from ``steps`` on are zeros no step wrote;
+        the PRNG chains advanced once a step that ran; ``moe``:
+        ``_apply_counted``'s counters summed over those steps, None for a
+        model without routed experts."""
         from tpustack.models.llama import init_chunk_bufs
 
         S = self.cfg.max_seq
@@ -798,8 +804,8 @@ class Generator:
         moe0 = (None if self.cfg.moe is None
                 else jnp.zeros((3,), jnp.int32))
 
-        def step(carry, t):
-            tok, bufs, keys, moe = carry
+        def step(t, carry):
+            tok, bufs, keys, moe, toks = carry
             cur_t = jnp.minimum(cur0 + t * active, S - 1)
             merged = [dict(c, **bf) for c, bf in zip(caches, bufs)]
             logits, merged, counts = self._apply_counted(
@@ -811,11 +817,13 @@ class Generator:
             nxt = self._sample_from_logits_perrow(
                 logits[:, -1].astype(jnp.float32), step_keys, temperature,
                 top_k, greedy)
-            return (nxt[:, None], bufs, keys, moe), nxt
+            return nxt[:, None], bufs, keys, moe, toks.at[t].set(nxt)
 
-        (last, bufs, keys, moe), toks = jax.lax.scan(
-            step, (first_tok, bufs0, keys, moe0), jnp.arange(n_steps))
-        cur_end = jnp.minimum(cur0 + n_steps * active, S - 1)
+        steps = jnp.asarray(steps, jnp.int32)
+        last, bufs, keys, moe, toks = jax.lax.fori_loop(
+            0, steps, step,
+            (first_tok, bufs0, keys, moe0, jnp.zeros((n_steps, B), jnp.int32)))
+        cur_end = jnp.minimum(cur0 + steps * active, S - 1)
         return toks.T, last, cur_end, bufs, keys, moe
 
     @jax.named_scope("kv_write")
@@ -1014,20 +1022,29 @@ class Generator:
                        static_argnames=("flash",), donate_argnums=(5,))
     def _decode_scan_paged(self, params, first_tok, cur, active, pool, bt,
                            keys, temperature, top_k, greedy, n_steps: int,
-                           flash: bool = False):
-        """``n_steps`` continuous-slot decode iterations in ONE dispatch:
-        present the frozen chunk view of the pool, run the scan body
+                           steps, flash: bool = False):
+        """``steps`` continuous-slot decode iterations in ONE dispatch:
+        present the frozen chunk view of the pool, run the loop body
         (``_decode_cont_body``), scatter the chunk buffers back through
         the block tables at ``[cur0, cur_end)``.  Only the new tokens'
         K/V move pool-ward — shared prefix blocks are read, never
         rewritten.
+
+        ``n_steps`` (static) is a dispatch's CAPACITY — the chunk buffers,
+        the ``[B, n_steps]`` token block, the scatter's window; ``steps``
+        (a traced int32 scalar, ``1 <= steps <= n_steps``) is how many of
+        them this dispatch runs, the engine's choice per dispatch
+        (``ContinuousEngine._dispatch_len``): ONE compiled program for every
+        length.  ``cur_end``, the write window, the PRNG chains, ``moe``
+        and ``last`` all follow ``steps``; token columns from ``steps`` on
+        are zeros.
 
         ``cur [B]``: per-slot frontier at chunk START (``cur0``) — advances
         only where ``active``, clamped at max_seq-1.  ``keys [B, 2]``:
         per-slot PRNG streams (see ``_sample_from_logits_perrow``).  The
         pool is read-only for the whole chunk: step t writes its K/V at
         the UNIFORM index t of per-layer chunk buffers
-        (``init_chunk_bufs``, scan-internal) and attention merges
+        (``init_chunk_bufs``, loop-internal) and attention merges
         {pool [0, cur0[i])} ∪ {buffer [0, t]} with an exact streaming-
         softmax split (LlamaAttention chunk mode), so per-step write-back
         traffic amortises by the chunk length.  Overshoot steps past
@@ -1051,7 +1068,7 @@ class Generator:
                 else self._pool_gather_body(pool, bt))
         toks, last, cur_end, bufs, keys, moe = self._decode_cont_body(
             params, first_tok, cur, active, view,
-            keys, temperature, top_k, greedy, n_steps)
+            keys, temperature, top_k, greedy, n_steps, steps)
         valid = (cur[:, None] + jnp.arange(n_steps)[None, :]
                  < cur_end[:, None])
         pool = self._pool_scatter_body(

@@ -9,7 +9,7 @@ to the hardware are we") before adding a replica.
 Two halves, one data structure:
 
 - :class:`FlightRecorder` — a dependency-free, lock-cheap ring buffer
-  (``TPUSTACK_FLIGHT_RECORDS``, default 512) that each serving engine
+  (``TPUSTACK_FLIGHT_RECORDS``, default 4096) that each serving engine
   feeds ONE structured host-side record per dispatch: the LLM continuous
   engine per wave (slot occupancy, tokens emitted, spec drafted/accepted,
   stride, kv-pool free/used/fragmentation, queue depth, wave wall time,

@@ -467,11 +467,12 @@ class LLMServer:
         # decode tokens per fused scan dispatch: larger chunks amortise the
         # per-dispatch tail (chunk 64 measured ~6% over 32 at 7B int8)
         self.chunk = max(1, knobs.get_int("LLM_CHUNK"))
-        # the continuous engine's chunk is ALSO the admission + SSE cadence,
-        # so it defaults latency-first to min(LLM_CHUNK, 16); the measured
-        # throughput cost of 16 vs 32 is ~4% steady aggregate (708 vs 736
-        # tok/s, 7B int8 batch 8) — LLM_ENGINE_CHUNK overrides for
-        # throughput-first deployments that accept the coarser cadence
+        # the continuous engine's chunk is the CAPACITY of a decode
+        # dispatch — the most steps one runs, so the coarsest admission +
+        # SSE cadence; it defaults to min(LLM_CHUNK, 16).  How many steps a
+        # dispatch does run the engine chooses itself, from its lanes and
+        # its own clocks (ContinuousEngine._dispatch_len), so this is no
+        # latency knob: LLM_ENGINE_CHUNK overrides the capacity.
         # 0/empty means "no override" (the LLM_BATCH_WINDOW_MS convention),
         # not a 1-token cadence
         override = knobs.get_int("LLM_ENGINE_CHUNK")

@@ -171,8 +171,8 @@ def serving_program(program: str, cfg, chip, *, rows: int, pool_blocks: int,
                     block: int = 64, steps: int = 16, bucket: int = 512,
                     dtype=None):
     """``(jitted, args, kwargs)`` of a paged serving program of ``cfg`` on
-    shapes placed on ``chip``: ``decode`` (``rows`` slots, ``steps``
-    steps, ``flash=True``) or ``admit`` (``rows`` rows of ``bucket``).
+    shapes placed on ``chip``: ``decode`` (``rows`` slots, a capacity of
+    ``steps`` steps, ``flash=True``) or ``admit`` (``rows`` rows of ``bucket``).
     The engine's own jitted methods, so the pool is donated as it is when
     served."""
     import jax
@@ -199,7 +199,7 @@ def serving_program(program: str, cfg, chip, *, rows: int, pool_blocks: int,
         fn, kwargs = Generator._decode_scan_paged, {"flash": True}
         args = (gen, params, sds((B, 1), i32), slot(i32), slot(i32), pool,
                 sds((B, nb), i32), sds((B, 2), jnp.uint32), slot(f32),
-                slot(i32), slot(jnp.bool_), steps)
+                slot(i32), slot(jnp.bool_), steps, sds((), i32))
     elif program == "admit":
         n, slots = rows, max(rows, 8)
         row = lambda dt: sds((n,), dt)

@@ -116,8 +116,10 @@ _declare("LLM_MAX_BATCH", int, 8,
 _declare("LLM_CHUNK", int, 32,
          "Decode tokens per fused dispatch on the solo path.")
 _declare("LLM_ENGINE_CHUNK", int, 0,
-         "Override for the continuous engine's chunk (admission + SSE "
-         "cadence); 0 = default min(LLM_CHUNK, 16).")
+         "Override for the continuous engine's chunk: the most steps a "
+         "decode dispatch runs (the coarsest admission + SSE cadence; the "
+         "engine runs fewer where its lanes say so); 0 = default "
+         "min(LLM_CHUNK, 16).")
 _declare("LLM_BATCH_WINDOW_MS", float, 0.0,
          "Legacy pre-continuous batching window; accepted, unused.")
 
@@ -351,9 +353,11 @@ _declare("TPUSTACK_TRACE_BUFFER", int, 128,
 _declare("TPUSTACK_TRACE_SLOW_S", float, 5.0,
          "Traces at or above this duration are always kept (survive the "
          "ring buffer's churn).")
-_declare("TPUSTACK_FLIGHT_RECORDS", int, 512,
+_declare("TPUSTACK_FLIGHT_RECORDS", int, 4096,
          "Flight-recorder ring capacity: per-dispatch engine records "
-         "retained for /debug/flight and post-mortem dumps.")
+         "retained for /debug/flight and post-mortem dumps (an LLM decode "
+         "dispatch is 1 to LLM_ENGINE_CHUNK steps: up to a hundred records "
+         "a second).")
 _declare("TPUSTACK_FLIGHT_DUMP_DIR", str, "/tmp/tpustack-flight",
          "Directory for flight-recorder JSON dumps (watchdog fire, SIGTERM "
          "drain, fatal engine error, sanitizer violation); empty disables "
