@@ -152,8 +152,12 @@ def test_prefill_records_carry_the_admission_timeline(gen, engine):
         assert len(r["queue_s"]) == len(r["admit_s"]) == n
         assert len(r["prompt_lens"]) == n
         assert r["prefill_s"] >= 0
-        # one padded bucket for the group, no smaller than its longest row
+        # one padded bucket for the group, no smaller than its longest row:
+        # ``bucket`` is the positions computed a row — ``chunks`` chunks of
+        # the compiled ``program_bucket`` where the admission walks it (PR
+        # 34: above Generator.ADMIT_CHUNK), all of it in one shot here
         assert r["bucket"] >= max(r["prompt_lens"])
+        assert r["chunks"] == 1 and r["program_bucket"] == r["bucket"]
         assert r["prompt_tokens"] == sum(r["prompt_lens"])
         # every request waited at least the delay feed() imposed on it
         assert all(q >= delay * 0.99 for q in r["queue_s"]), r
@@ -373,6 +377,13 @@ def paged_programs(gen):
         "_prefill_chunk": Generator._prefill_chunk.trace(
             g, P, i32(n, bucket), i32(), i32(n), caches),
     }
+    # the same admission program of a bucket above the generator's chunk
+    # (PR 34): it walks the bucket, 4 chunks of 8 here
+    walk = Generator(cfg, params=P, dtype=jnp.float32)
+    walk.ADMIT_CHUNK = 8
+    traced["_admit_fused_paged/walk"] = Generator._admit_fused_paged.trace(
+        walk, P, i32(n, 32), pool, i32(n, nb), i32(n), i32(n), i32(n),
+        sds((n,), jnp.uint32), *slot_state, *row)
     return {name: _lowered_text(t) for name, t in traced.items()}
 
 
@@ -395,6 +406,7 @@ def test_decode_program_names_every_scope(paged_programs, scope):
 
 
 @pytest.mark.parametrize("program", ["_admit_fused_paged",
+                                     "_admit_fused_paged/walk",
                                      "_admit_prefix_paged",
                                      "_spec_verify_paged"])
 def test_admission_and_verify_programs_name_the_scopes(paged_programs,
@@ -403,6 +415,12 @@ def test_admission_and_verify_programs_name_the_scopes(paged_programs,
     want = set(SCOPES) - {"kv_read"}
     if program == "_admit_prefix_paged":
         want.add("kv_read")  # the warm start gathers the hit row's line
+    if program == "_admit_fused_paged/walk":
+        # a chunk reads the row line it attends (an int8 one dequantised),
+        # through the k-streaming kernel, under the program's own name
+        want.add("kv_read")
+        assert "flash_kstream" in text and "stablehlo.while" in text
+        assert re.search(r"module @jit__admit_fused_paged\b", text)
     missing = [s for s in sorted(want) if not _has_scope(text, s)]
     assert not missing, missing
     if program == "_spec_verify_paged":

@@ -297,14 +297,16 @@ def test_engine_paged_prefix_sharing_lifecycle(gen):
 
 
 def test_engine_paged_long_prompt_and_big_suffix_paths():
-    """The two paged admission fallbacks tiny shapes never reach with the
-    production thresholds: (a) chunked long-prompt prefill + paged splice
-    (bucket > PREFILL_CHUNK), (b) big-suffix prefix hit via row gather +
-    the traced-offset chunk loop (past MASKED_PREFILL_MAX).  Shrinking the
-    instance thresholds forces both; outputs must still match the solo
-    path bit-for-bit."""
+    """The two paged admission paths tiny shapes never reach with the
+    production thresholds: (a) a cold admission that walks its bucket in
+    chunks inside the fused program (bucket > ADMIT_CHUNK; PR 34 — the
+    separate long-prompt dispatches above PREFILL_CHUNK went into it),
+    (b) big-suffix prefix hit via row gather + the traced-offset chunk
+    loop (past MASKED_PREFILL_MAX).  Shrinking the instance thresholds
+    forces both; outputs must still match the solo path bit-for-bit."""
     g = Generator(LlamaConfig.tiny(max_seq=64), dtype=jnp.float32, seed=3)
-    g.PREFILL_CHUNK = 16      # 40-token prompt → bucket 64 → long path
+    # a 40-token prompt → bucket 64 → four chunks of capacity, three run
+    g.PREFILL_CHUNK = g.ADMIT_CHUNK = 16
     g.MASKED_PREFILL_MAX = 1  # every suffix prefill → gather + chunk loop
     rt = make_runtime(g)
     shared = list(range(5, 5 + 24))

@@ -334,13 +334,14 @@ def test_chunked_prefill_matches_single_shot():
     g = Generator(LlamaConfig.tiny(max_seq=64), dtype=jnp.float32, seed=3)
     prompt = list(range(5, 45))  # bucket 64
     ref, _ = g.generate(prompt, max_new_tokens=6, sample=GREEDY, seed=0)
-    g.PREFILL_CHUNK = 16  # bucket 64 % 16 == 0 → the fused SCAN path
+    g.PREFILL_CHUNK = 16  # bucket 64 % 16 == 0 → four whole chunks
     out, _ = g.generate(prompt, max_new_tokens=6, sample=GREEDY, seed=0)
     assert out == ref
     # r5: a bucket that is NOT a chunk multiple (max_seq-capped buckets)
-    # takes the per-chunk host loop with a shorter tail segment — it must
-    # produce the same tokens as both the scan path and single-shot
-    g.PREFILL_CHUNK = 24  # 64 % 24 != 0 → loop fallback, tail of 16
+    # is padded to whole chunks inside the program, tokens and cache lines
+    # alike (PR 34; a per-chunk host loop before) — it must produce the
+    # same tokens as the whole-chunk walk and the single shot
+    g.PREFILL_CHUNK = 24  # 64 % 24 != 0 → padded to 72, three chunks
     out_loop, _ = g.generate(prompt, max_new_tokens=6, sample=GREEDY, seed=0)
     assert out_loop == ref
 

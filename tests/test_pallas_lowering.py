@@ -15,6 +15,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from tpustack.ops.pallas.flash_attention import (flash_attention,
@@ -191,6 +192,68 @@ def test_no_decode_step_stages_the_pool(one_v5e_chip, preset, kv):
               * (1 if kv == "int8" else 2))
     found = pool_relayouts(text, n_blocks, block, tensor // 4, in_scan=True)
     assert not found, "\n".join(i.line[:200] for i in found[:6])
+
+
+# ------------------------------------- an admission's work follows its rows
+@pytest.mark.parametrize("preset", ["k_exaone_236b_ep8", "qwen25_7b"])
+def test_admission_above_a_chunk_walks_to_a_traced_bound(one_v5e_chip,
+                                                         preset):
+    """The admission program of a bucket above ``Generator.ADMIT_CHUNK`` (PR
+    34), compiled for a described v5e — 4 rows of the 4,096 bucket, two
+    layers at true widths: it holds ONE ``while`` (the walk over the
+    bucket's chunks), whose trip count XLA does not know (the bound is an
+    operand, the longest row's last chunk: no ``known_trip_count``, no
+    constant in the condition), the k-streaming kernel inside it and the
+    panel kernel nowhere, and no temporary of rows x bucket positions by
+    the feed-forward width (1.2 GB at 28 layers' worth of reuse on the tree
+    before): a chunk's ``[rows x chunk, ffn]`` is the largest."""
+    from tpustack.models.llm_generate import Generator
+    from tpustack.utils.hlo_text import (SERVED_SLOTS, compile_program,
+                                         parse, serving_config,
+                                         serving_program)
+
+    cfg = serving_config(preset, 2)
+    rows, bucket, block = 4, 4096, 64
+    assert bucket > Generator.ADMIT_CHUNK
+    n_blocks = SERVED_SLOTS[preset] * (cfg.max_seq // block) + 1
+    text = compile_program(*serving_program(
+        "admit", cfg, one_v5e_chip, rows=rows, pool_blocks=n_blocks,
+        block=block, bucket=bucket)).as_text()
+    instrs, _, _, _ = parse(text)
+    whiles = [i for i in instrs if i.opcode == "while"]
+    assert len(whiles) == 1, [i.line[:120] for i in whiles]
+    assert "known_trip_count" not in whiles[0].line
+    cond = re.search(r"condition=%?([\w.\-]+)", whiles[0].line).group(1)
+    held = [i for i in instrs if i.comp == cond]
+    assert held and not [i for i in held if i.opcode == "constant"], [
+        i.line[:120] for i in held]
+    assert "flash_kstream" in text and "flash_panel" not in text
+    wide = max(cfg.ffn_dim, cfg.dim)
+    chunk_rows = rows * Generator.ADMIT_CHUNK
+    big = [i for i in instrs for dt, dims, _ in i.shapes
+           if dims and dims[-1] >= wide and dt != "s8"
+           and int(np.prod(dims[:-1])) > chunk_rows]
+    assert not big, "\n".join(i.line[:160] for i in big[:4])
+    assert any(dims and dims[-1] == cfg.ffn_dim
+               and int(np.prod(dims[:-1])) == chunk_rows
+               for i in instrs for _, dims, _ in i.shapes)
+
+
+def test_admission_of_one_chunk_is_the_single_shot(one_v5e_chip):
+    """A bucket of at most ``ADMIT_CHUNK`` keeps the single-shot body: no
+    ``while``, no k-streaming call.  (That its compiled text is the parent's
+    but for source lines, both served presets at every layer, is shown by
+    hand from ``tools/hlo_where.py admit --layers 0 --dump``: CHANGES.md.)"""
+    from tpustack.models.llm_generate import Generator
+    from tpustack.utils.hlo_text import (compile_program, parse,
+                                         serving_config, serving_program)
+
+    cfg = serving_config("qwen25_7b", 2)
+    text = compile_program(*serving_program(
+        "admit", cfg, one_v5e_chip, rows=1, pool_blocks=513, block=64,
+        bucket=Generator.ADMIT_CHUNK)).as_text()
+    assert not [i for i in parse(text)[0] if i.opcode == "while"]
+    assert "flash_kstream" not in text
 
 
 # moe_gmm at K-EXAONE's share (16 held experts of [6144, 2048]): (tokens a

@@ -5,10 +5,14 @@ and outside the decode scan, for a DESCRIBED (not attached) TPU v5e.
     python tools/hlo_where.py decode --layers 2
     python tools/hlo_where.py admit --preset k_exaone_236b_ep8 --layers 2
     python tools/hlo_where.py decode --layers 0 --dump /root/scratch/d.txt
+    python tools/hlo_where.py admit --bucket 4096 --rows 4 --layers 2
 
 Compiles ``Generator._decode_scan_paged`` (``decode``: a capacity of 16
-steps, the steps run an operand, ``flash=True``, every slot live) or ``_admit_fused_paged`` (``admit``: one
-row of the 512 bucket) of a served configuration on ``ShapeDtypeStruct``s
+steps, the steps run an operand, ``flash=True``, every slot live) or
+``_admit_fused_paged`` (``admit``: ``--rows`` rows, default one, of the
+``--bucket`` bucket, default 512; a bucket above ``Generator.ADMIT_CHUNK``
+walks its chunks in a ``while``, and what that body holds counts as inside
+the scan) of a served configuration on ``ShapeDtypeStruct``s
 (``tpustack/utils/hlo_text.py``) and reads the optimised HLO: every
 instruction that is not inside a fusion, with the bytes its result takes
 UNDER ITS TILED LAYOUT (an ``f32[512,64,4]{2,1,0:T(8,128)}`` is 16.8 MB,
@@ -43,21 +47,26 @@ def main(argv=None) -> int:
                     choices=sorted(SERVED_SLOTS))
     ap.add_argument("--layers", type=int, default=2,
                     help="layers compiled (0: all the preset has)")
+    ap.add_argument("--bucket", type=int, default=512,
+                    help="admit: the prompt bucket compiled")
+    ap.add_argument("--rows", type=int, default=1,
+                    help="admit: rows admitted together")
     ap.add_argument("--dump", help="write the optimised HLO text here")
     a = ap.parse_args(argv)
 
     cfg = serving_config(a.preset, a.layers)
     slots, block = SERVED_SLOTS[a.preset], 64
-    rows = slots if a.program == "decode" else 1
+    rows = slots if a.program == "decode" else a.rows
     n_blocks = slots * (cfg.max_seq // block) + 1
     compiled = compile_program(*serving_program(
         a.program, cfg, describe_v5e(), rows=rows, pool_blocks=n_blocks,
-        block=block))
+        block=block, bucket=a.bucket))
     text = compiled.as_text()
     if a.dump:
         with open(a.dump, "w") as f:
             f.write(text)
-    print(f"{a.program} of {a.preset}: {cfg.n_layers} layers, {rows} rows, "
+    print(f"{a.program} of {a.preset}: {cfg.n_layers} layers, {rows} rows"
+          + (f" of bucket {a.bucket}, " if a.program == "admit" else ", ") +
           f"pool {n_blocks} x {block} tokens, int8 KV; compiled for a "
           "described v5e (bytes are results under their tiled layouts)")
     mem = compiled.memory_analysis()

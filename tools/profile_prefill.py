@@ -2,7 +2,7 @@
 """Profile chunked prefill on the real chip: timing + xprof per-op table.
 
 Round-4 companion to ``tools/bench_llm.py`` (VERDICT r3 #5: "give prefill
-the decode treatment").  Runs the 7B serving config's ``_prefill_long`` at a
+the decode treatment").  Runs the 7B serving config's ``_prefill_walk`` at a
 dispatch-amortised size, times it device-honestly (block_until_ready), and
 captures an xplane trace for ``tools/xprof_summary.py``.
 
@@ -69,6 +69,8 @@ def main() -> int:
         lambda t: jnp.zeros(t.shape, t.dtype if t.dtype == jnp.int8 else dtype),
         tmpl)
     gen = Generator(cfg, params=params, dtype=dtype)
+    if args.preset == "tiny":
+        gen.PREFILL_CHUNK = 32  # two chunks of the 64-token smoke
     log(f"[profile_prefill] init {time.time() - t0:.1f}s")
 
     P = args.prompt_tokens
@@ -79,7 +81,8 @@ def main() -> int:
         # returns a small device array; the benchmark loop's np.asarray on
         # the PREVIOUS dispatch is the blocking fetch
         caches = init_kv_caches(cfg, 1, dtype=gen.cache_dtype)
-        logits, caches = gen._prefill_long(tokens, length, caches)
+        logits, caches = gen._prefill_walk(gen.params, jnp.asarray(tokens),
+                                            length, caches)
         return logits.sum()
 
     t0 = time.time()

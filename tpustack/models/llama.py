@@ -653,8 +653,10 @@ def _shard_kv(caches, cfg: "LlamaConfig", mesh, pool: bool = False):
 
 
 def init_kv_caches(cfg: LlamaConfig, batch: int, dtype=jnp.bfloat16,
-                   mesh=None):
-    shape = (batch, cfg.max_seq, cfg.n_kv_heads, cfg.head_dim)
+                   mesh=None, seq: Optional[int] = None):
+    """Dense per-row cache lines of ``seq`` positions (default: the
+    context, ``cfg.max_seq``)."""
+    shape = (batch, seq or cfg.max_seq, cfg.n_kv_heads, cfg.head_dim)
     if cfg.kv_quant == "int8":
         sshape = shape[:-1]  # one scale per cached K/V vector
         caches = [{"k": jnp.zeros(shape, jnp.int8),

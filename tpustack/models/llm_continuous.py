@@ -37,9 +37,9 @@ static-shape rules:
   wave's fresh row caches, prefill, write through the rows' block tables,
   first-token sampling and slot activation run as ONE fused device
   program (``Generator._admit_fused_paged``; a prefix hit's warm start is
-  ``_admit_prefix_paged``; prompts longer than PREFILL_CHUNK run the
-  fused-scan chunked prefill — or a per-chunk host loop for non-multiple
-  buckets — plus the write/sample/activate dispatches) — the host
+  ``_admit_prefix_paged``; a bucket above ``Generator.ADMIT_CHUNK`` is
+  walked in chunks inside that one program, as far as its longest row
+  reaches, so a long prompt's work follows its length) — the host
   never syncs on admission, so the depth-``depth`` pipelined chunk chain
   keeps flowing while prefill is still in flight.  The host picks up the
   first tokens (one tiny [n]-int32 fetch) at the next natural sync point,
@@ -93,7 +93,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpustack import sanitize
-from tpustack.models.llama import init_kv_caches
 from tpustack.models.llm_generate import (Generator, SampleConfig,
                                           resolve_paged_flash)
 from tpustack.obs.flight import PhaseClock
@@ -257,12 +256,16 @@ class _PendingWave:
     them and completes the host-side bookkeeping."""
 
     __slots__ = ("rows", "firsts_dev", "t0", "block_inserts", "bucket",
-                 "moe_dev", "behind_steps")
+                 "moe_dev", "chunks_dev", "behind_steps")
 
     def __init__(self, rows, firsts_dev, t0, block_inserts=(), bucket=None,
-                 moe_dev=None, behind_steps=0):
+                 moe_dev=None, chunks_dev=None, behind_steps=0):
         self.rows = rows            # [(slot_idx, req, budget)]
         self.firsts_dev = firsts_dev
+        # chunks the admission program's walk of its bucket ran
+        # (Generator._prefill_walk_body; None: a single shot): fetched with
+        # the firsts
+        self.chunks_dev = chunks_dev
         # decode steps queued on the device ahead of this admission:
         # dispatched, not yet fetched, when it was dispatched
         self.behind_steps = behind_steps
@@ -270,7 +273,8 @@ class _PendingWave:
         # None for a model without such a layer): fetched with the firsts
         self.moe_dev = moe_dev
         self.t0 = t0
-        self.bucket = bucket        # padded tokens a row (a hit: its suffix)
+        self.bucket = bucket        # the program's tokens a row (a hit: its
+        # suffix), of which a walk computes ``chunks`` chunks
         # [(req, prompt block ids)] — handed to on_prefill_blocks at
         # resolution (zero-copy cache insert; no device work at all)
         self.block_inserts = list(block_inserts)
@@ -746,9 +750,9 @@ class ContinuousEngine:
         group, ONE fused device program covering row caches + prefill +
         the write through the rows' block tables + first-token sample +
         slot activation (``_admit_fused_paged``; a prefix hit's warm start
-        is ``_admit_prefix_paged``; prompts beyond PREFILL_CHUNK run the
-        host-driven chunked prefill plus the same write/sample/activate
-        dispatches).  The chunk chain keeps flowing behind these — the
+        is ``_admit_prefix_paged``; a big-suffix hit runs the host-driven
+        chunk loop plus write/sample/activate dispatches of its own).  The
+        chunk chain keeps flowing behind these — the
         host resolves the first tokens later (``_resolve``).  Mid-run
         singles take the same path with n=1."""
         g, c = self.gen, self.gen.cfg
@@ -879,28 +883,14 @@ class ContinuousEngine:
             return (jnp.asarray(self._bt[ids]),
                     jnp.asarray([slots[i].alloc for i in ids], jnp.int32))
 
-        def sample_activate(logits, slot_ids, lengths, seeds, temp_r,
-                            topk_r, greedy_r):
-            """The unfused tail of an admission whose prefill ran as
-            dispatches of its own: sample the firsts, activate the rows."""
-            firsts, row_keys = g._admit_sample_jit(
-                logits, seeds, temp_r, topk_r, greedy_r)
-            (state["cur"], state["active"], state["first"],
-             state["temp"], state["topk"], state["greedy"],
-             state["keys"]) = g._slot_activate(
-                state["cur"], state["active"], state["first"],
-                state["temp"], state["topk"], state["greedy"],
-                state["keys"], slot_ids, lengths, firsts, temp_r,
-                topk_r, greedy_r, row_keys)
-            return firsts
-
-        def pend(rows, firsts, bucket, moe):
+        def pend(rows, firsts, bucket, moe, chunks=None):
             rt.arrays = state["pool"]
             for i, _, _ in rows:
                 slots[i].pending = True
             self._pending.append(_PendingWave(
                 rows, firsts, t0, block_inserts=block_inserts(rows),
-                bucket=bucket, moe_dev=moe, behind_steps=self._in_flight))
+                bucket=bucket, moe_dev=moe, chunks_dev=chunks,
+                behind_steps=self._in_flight))
 
         for row in prefix_rows:
             rows = [row]
@@ -944,8 +934,16 @@ class ContinuousEngine:
                 state["pool"] = g._insert_rows_paged(
                     state["pool"], bt_rows, row_caches,
                     jnp.asarray(plen, jnp.int32), sbucket, limits)
-                firsts = sample_activate(logits, slot_ids, lengths, seeds,
-                                         temp_r, topk_r, greedy_r)
+                # the unfused tail: sample the firsts, activate the row
+                firsts, row_keys = g._admit_sample_jit(
+                    logits, seeds, temp_r, topk_r, greedy_r)
+                (state["cur"], state["active"], state["first"],
+                 state["temp"], state["topk"], state["greedy"],
+                 state["keys"]) = g._slot_activate(
+                    state["cur"], state["active"], state["first"],
+                    state["temp"], state["topk"], state["greedy"],
+                    state["keys"], slot_ids, lengths, firsts, temp_r,
+                    topk_r, greedy_r, row_keys)
             pend(rows, firsts, sbucket, moe)
 
         for bucket, rows in sorted(groups.items()):
@@ -956,34 +954,19 @@ class ContinuousEngine:
             lengths, slot_ids, seeds, temp_r, topk_r, greedy_r = (
                 row_arrays(rows))
             bt_rows, limits = rowmeta(rows)
-            moe = None
-            if bucket > g.PREFILL_CHUNK:
-                # chunked long-prompt admission: one fused scan dispatch
-                # for exact-multiple buckets (16k/32k), a per-chunk host
-                # loop otherwise (_prefill_long), on row caches of its
-                # own, then the write through the block tables
-                row_caches = init_kv_caches(c, n, dtype=g.cache_dtype,
-                                            mesh=g.kv_mesh)
-                logits, row_caches = g._prefill_long(tokens, lengths,
-                                                     row_caches)
-                state["pool"] = g._insert_rows_paged(
-                    state["pool"], bt_rows, row_caches,
-                    jnp.zeros((), jnp.int32), bucket, limits)
-                firsts = sample_activate(logits, slot_ids, lengths, seeds,
-                                         temp_r, topk_r, greedy_r)
-            else:
-                # the common case: prefill + write + sample + activation
-                # in ONE dispatch (each dispatch is a host round-trip)
-                (state["pool"], firsts, state["cur"], state["active"],
-                 state["first"], state["temp"], state["topk"],
-                 state["greedy"], state["keys"],
-                 moe) = g._admit_fused_paged(
-                    g.params, jnp.asarray(tokens), state["pool"],
-                    bt_rows, lengths, limits, slot_ids, seeds,
-                    state["cur"], state["active"], state["first"],
-                    state["temp"], state["topk"], state["greedy"],
-                    state["keys"], temp_r, topk_r, greedy_r)
-            pend(rows, firsts, bucket, moe)
+            # prefill + write + sample + activation in ONE dispatch (each
+            # dispatch is a host round-trip); a bucket above ADMIT_CHUNK is
+            # walked in chunks inside it, as far as its longest row reaches
+            (state["pool"], firsts, state["cur"], state["active"],
+             state["first"], state["temp"], state["topk"],
+             state["greedy"], state["keys"], moe,
+             chunks) = g._admit_fused_paged(
+                g.params, jnp.asarray(tokens), state["pool"],
+                bt_rows, lengths, limits, slot_ids, seeds,
+                state["cur"], state["active"], state["first"],
+                state["temp"], state["topk"], state["greedy"],
+                state["keys"], temp_r, topk_r, greedy_r)
+            pend(rows, firsts, bucket, moe, chunks)
         return gen_ctr
 
     def _resolve(self, state, slots: List[_Slot], wave: _PendingWave):
@@ -993,8 +976,10 @@ class ContinuousEngine:
         ``prefill_s`` is wall time from dispatch to resolution — with
         overlap this is the request's true time-to-first-token."""
         with self._phase("resolve_wait"):
-            firsts, moe = jax.device_get((wave.firsts_dev, wave.moe_dev))
+            firsts, moe, chunks = jax.device_get(
+                (wave.firsts_dev, wave.moe_dev, wave.chunks_dev))
             firsts = [int(t) for t in firsts]
+            chunks = None if chunks is None else int(chunks)
         t_first = time.time() - wave.t0
         tier = getattr(self.paged.cache, "host_tier", None)
         if tier is not None:
@@ -1019,7 +1004,12 @@ class ContinuousEngine:
                 admit_s=[None if r.t_handed is None
                          else round(wave.t0 - r.t_handed, 6)
                          for _, r, _ in wave.rows],
-                bucket=wave.bucket,
+                # positions computed a row: the chunks a walk of the
+                # bucket ran, or the program's whole bucket in one shot
+                bucket=(wave.bucket if chunks is None
+                        else chunks * self.gen.ADMIT_CHUNK),
+                chunks=chunks or 1,
+                program_bucket=wave.bucket,
                 prompt_lens=[len(r.ids) for _, r, _ in wave.rows],
                 behind_steps=wave.behind_steps,
                 **self._moe_fields(moe, passes=1))

@@ -315,74 +315,108 @@ class Generator:
             length - 1)
         return logits[:, 0], caches
 
-    #: chunk size for long prompts — one 8k chunk's activations (~1.3 GB of
-    #: gate/up transients at 7B) bound prefill memory however long the
-    #: prompt; a single-shot 32k-bucket program would need ~23 GB
+    #: chunk size of the solo and static-batch routes' long prompts — one 8k
+    #: chunk's activations (~1.3 GB of gate/up transients at 7B) bound
+    #: prefill memory however long the prompt; a single-shot 32k-bucket
+    #: program would need ~23 GB
     PREFILL_CHUNK = 8192
 
+    #: chunk a cold admission's program walks its bucket in
+    #: (``_admit_fused_paged``): a bucket above it runs chunk-major and
+    #: stops at its longest row's last chunk, so its work follows the
+    #: prompt, not the power-of-two bucket.  A constant read off the chip,
+    #: not a knob — the smallest chunk at which (a) ONE row's matmuls stay
+    #: compute-bound with margin: int8 weights give 2·C flop a weight byte
+    #: against the v5e's ridge of 197 TFLOP/s ÷ 819 GB/s = 240, so C = 512
+    #: stands at 4.3 ridges, and (b) the k-streaming kernel keeps a whole q
+    #: block (``flash_attention``: K/V are re-streamed once a q block).
+    #: Read on a v5e at 7B (PERF.md §6, PR 34): a row's 512 tokens cost
+    #: 44.5 ms in chunks of 512 and of 1,024 alike, 51.4 ms in chunks of
+    #: 256 (2.1 ridges: the margin is gone); padding left is under one
+    #: chunk a group, so the smallest chunk that holds the rate wins.
+    ADMIT_CHUNK = 512
+
     def _prefill_chunk_body(self, params, tokens, offset, length, caches):
-        """Traced body of one long-prompt chunk: rows at global positions
-        offset + i attend the whole cache prefix (flash, traced offset).
-        Returns logits at ``length - 1`` clipped into this chunk (garbage
-        except on the chunk holding the row's last real token).  Single
-        source of truth for the host-loop (``_prefill_chunk``) and fused
-        (``_prefill_long_scan``) drivers."""
+        """Traced body of one prefill chunk: rows at global positions
+        offset + i attend the whole cache prefix (flash, traced offset), in
+        the cache's own type — an int8 line is attended as quantised, as
+        every warm start and every decode step attends it.  Returns
+        ``(logits, caches, moe)``: logits at ``length - 1`` clipped into
+        this chunk (garbage except on the chunk holding the row's last real
+        token), ``moe`` as ``_apply_counted`` gives it.  Single source of
+        truth for the host-loop (``_prefill_chunk``) and in-program
+        (``_prefill_walk_body``) drivers."""
         b, s = tokens.shape
         positions = offset + jnp.broadcast_to(jnp.arange(s), (b, s))
         local_last = jnp.clip(length - 1 - offset, 0, s - 1)
-        logits, caches = self.model.apply(
-            {"params": params}, tokens, positions, caches, offset, None,
-            local_last)
-        return logits[:, 0], caches
+        logits, caches, moe = self._apply_counted(
+            params, tokens, positions, caches, offset, None, local_last)
+        return logits[:, 0], caches, moe
 
     @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(5,))
     def _prefill_chunk(self, params, tokens, offset, length, caches):
-        """One dispatch per chunk (the non-multiple-bucket fallback driver);
+        """One dispatch per chunk (the big-suffix loop of ``_prefill_from``);
         every chunk reuses ONE compiled program — see _prefill_chunk_body."""
         return self._prefill_chunk_body(params, tokens, offset, length,
-                                        caches)
+                                        caches)[:2]
 
-    @functools.partial(jax.jit, static_argnums=(0, 5), donate_argnums=(4,))
-    def _prefill_long_scan(self, params, tokens, length, caches,
-                           n_chunks: int):
-        """Whole chunked prefill in ONE dispatch: ``lax.scan`` over
-        ``n_chunks`` PREFILL_CHUNK-sized segments (bucket must be an exact
-        multiple — 16k/32k buckets are).  The host loop this replaces paid
-        one dispatch round-trip per chunk (the xprof'd "inter-chunk
-        dispatch IDLE") and made every long-prompt engine admission a
-        multi-round-trip affair.
-        Memory matches the loop: scan keeps ONE chunk's activations live.
-        Per-row logits are selected from the chunk containing the row's
-        last real token, exactly like the loop did."""
-        C = self.PREFILL_CHUNK
-        b = tokens.shape[0]
+    def _prefill_walk_body(self, params, tokens, length, caches, chunk: int):
+        """Traced: a whole chunk-major prefill inside its caller's program.
+        ``tokens [B, n · chunk]`` run as ``[B, chunk]`` segments at offset
+        ``i · chunk`` through ONE traced ``_prefill_chunk_body`` — a
+        ``lax.fori_loop`` whose bound is an operand: ``ceil(max(length) /
+        chunk)``, the longest row's last chunk.  Chunks past it hold only
+        padding and are never computed (their cache positions stay as
+        ``caches`` came: no row reads them before decode writes them), so
+        one compiled program per shape does work that follows the prompts.
+        One chunk's activations are live at a time.  Returns ``(logits [B,
+        V], caches, moe, chunks)``: each row's logits from the chunk that
+        holds its last real token, the routed-expert counters summed over
+        the chunks that ran (None without such a layer), and the trip
+        count as an int32 scalar."""
+        b, width = tokens.shape
+        assert width % chunk == 0, (width, chunk)
+        chunks = jnp.minimum(-(-jnp.max(length) // chunk),
+                             width // chunk).astype(jnp.int32)
+        moe0 = (None if self.cfg.moe is None
+                else jnp.zeros((3,), jnp.int32))
 
-        def body(carry, i):
-            out, caches = carry
-            seg = jax.lax.dynamic_slice_in_dim(tokens, i * C, C, axis=1)
-            offset = i * C
-            logits, caches = self._prefill_chunk_body(
+        def body(i, carry):
+            out, caches, moe = carry
+            offset = i * chunk
+            seg = jax.lax.dynamic_slice_in_dim(tokens, offset, chunk, axis=1)
+            logits, caches, counts = self._prefill_chunk_body(
                 params, seg, offset, length, caches)
-            hit = (length - 1 >= offset) & (length - 1 < offset + C)
-            out = jnp.where(hit[:, None], logits, out)
-            return (out, caches), None
+            if counts is not None:
+                moe = moe + counts
+            hit = (length - 1 >= offset) & (length - 1 < offset + chunk)
+            return jnp.where(hit[:, None], logits, out), caches, moe
 
-        init = jnp.zeros((b, self.cfg.vocab_size), jnp.float32)
-        (out, caches), _ = jax.lax.scan(
-            body, (init, caches), jnp.arange(n_chunks, dtype=jnp.int32))
-        return out, caches
+        out0 = jnp.zeros((b, self.cfg.vocab_size), jnp.float32)
+        out, caches, moe = jax.lax.fori_loop(0, chunks, body,
+                                             (out0, caches, moe0))
+        return out, caches, moe, chunks
 
-    def _prefill_long(self, tokens: np.ndarray, length, caches):
-        """Chunked prefill driver: ``tokens [B, bucket]``.  Exact-multiple
-        buckets (the power-of-two ladder: 16k, 32k, ...) run as ONE fused
-        scan dispatch; a bucket capped at a non-multiple ``max_seq`` falls
-        back to the per-chunk host loop with its shorter tail segment."""
-        b, bucket = tokens.shape
-        if bucket % self.PREFILL_CHUNK == 0:
-            return self._prefill_long_scan(
-                self.params, jnp.asarray(tokens), length, caches,
-                bucket // self.PREFILL_CHUNK)
-        return self._prefill_from(tokens, 0, length, caches)
+    @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(4,))
+    def _prefill_walk(self, params, tokens, length, caches):
+        """Whole chunked prefill of the solo and static-batch routes in ONE
+        dispatch: ``_prefill_walk_body`` over PREFILL_CHUNK-sized segments
+        of caller-held ``caches`` (a host loop paid a dispatch round-trip
+        per chunk).  ``tokens [B, bucket]``; a bucket capped at a ``max_seq``
+        that is no multiple of the chunk is padded to whole chunks in here,
+        tokens and cache lines alike, as ``_admit_fused_paged`` pads its
+        own."""
+        C = self.PREFILL_CHUNK
+        pad = -tokens.shape[1] % C
+        seq = lambda t, n: ((0, 0), (0, n)) + ((0, 0),) * (t.ndim - 2)
+        if pad:
+            tokens = jnp.pad(tokens, seq(tokens, pad))
+            caches = jax.tree.map(lambda t: jnp.pad(t, seq(t, pad)), caches)
+        logits, caches = self._prefill_walk_body(params, tokens, length,
+                                                 caches, C)[:2]
+        if pad:
+            caches = jax.tree.map(lambda t: t[:, :t.shape[1] - pad], caches)
+        return logits, caches
 
     #: score-matrix budget (elements) under which a suffix prefill runs as
     #: ONE explicit-mask XLA attention dispatch over the full cache instead
@@ -429,18 +463,16 @@ class Generator:
 
     def _prefill_from(self, tokens: np.ndarray, base: int, length, caches):
         """Prefill ``tokens [B, bucket]`` starting at cache position
-        ``base``, attending the already-populated cache ``[0, base)`` —
-        chunked like ``_prefill_long`` (each chunk reuses the one compiled
-        ``_prefill_chunk`` program; ``base`` is a traced offset, so a new
-        prefix length never recompiles).  ``base=0`` is the long-prompt
-        fallback loop; ``base>0`` is the prefix-cache suffix path: a
-        restored cross-request KV prefix sits in ``[0, base)`` and only the
-        uncached suffix pays prefill FLOPs.  ``length`` stays the TRUE
-        per-row prompt length (global), so logits land at ``length - 1``."""
+        ``base``, attending the already-populated cache ``[0, base)``: the
+        prefix-cache suffix path — a restored cross-request KV prefix sits
+        in ``[0, base)`` and only the uncached suffix pays prefill FLOPs —
+        and the parked chunking's steps (the first at ``base`` 0).  A
+        small suffix runs as one masked dispatch; a big one in
+        PREFILL_CHUNK segments on the host, each reusing the one compiled
+        ``_prefill_chunk`` program (``base`` is a traced offset, so a new
+        prefix length never recompiles).  ``length`` stays the TRUE per-row
+        prompt length (global), so logits land at ``length - 1``."""
         b, bucket = tokens.shape
-        # base == 0 is the cold long-prompt fallback — byte-for-byte the
-        # pre-prefix-cache flash chunk loop; only warm suffixes take the
-        # masked fast path
         if base > 0 and bucket * self.cfg.max_seq <= self.MASKED_PREFILL_MAX:
             return self._prefill_masked(self.params, jnp.asarray(tokens),
                                         jnp.asarray(base, jnp.int32), length,
@@ -1006,8 +1038,9 @@ class Generator:
     @functools.partial(jax.jit, static_argnums=(0, 5), donate_argnums=(1,))
     def _insert_rows_paged(self, pool, bt_rows, row_caches, start,
                            bucket: int, limits):
-        """One-dispatch paged splice (the chunked long-prompt and
-        big-suffix admission paths) — see _insert_span_body."""
+        """One-dispatch paged splice (the big-suffix prefix-hit admission
+        and the parked chunk steps, whose prefill ran as dispatches of
+        their own) — see _insert_span_body."""
         return self._insert_span_body(pool, bt_rows, row_caches, start,
                                       bucket, limits)
 
@@ -1243,28 +1276,42 @@ class Generator:
     def _admit_fused_paged(self, params, tokens, pool, bt_rows, lengths,
                            limits, slot_ids, seeds, cur, active, first, temp,
                            topk, greedy, keys, temp_r, topk_r, greedy_r):
-        """ONE-dispatch admission for a same-bucket wave (bucket ≤
-        PREFILL_CHUNK): fresh in-graph row caches → batched prefill →
-        write through the rows' block tables → per-request first-token
-        sample + key-chain init → slot-state activation.  The
-        multi-dispatch path (``_prefill_long``/``_insert_rows_paged``/
-        ``_admit_sample_jit``/``_slot_activate``) remains for chunked
-        long-prompt admissions; this fused program exists because each
-        dispatch costs a host round-trip, and an admission's ~6 of them
-        weigh on short-generation end-to-end.  Last of what it returns is
-        ``_apply_counted``'s ``moe``: it leaves with the first tokens."""
+        """ONE-dispatch admission for a same-bucket wave: fresh in-graph
+        row caches → batched prefill → write through the rows' block
+        tables → per-request first-token sample + key-chain init →
+        slot-state activation.  One program per (rows, bucket); each
+        dispatch costs a host round-trip, and an unfused admission's ~6 of
+        them weigh on short-generation end-to-end.  A bucket of at most
+        ADMIT_CHUNK prefills in one shot; a larger one walks its bucket in
+        chunks and stops at its longest row's last one
+        (``_prefill_walk_body``), on row lines as long as the bucket.
+        Returns the pool, the first tokens, the slot state, then
+        ``_apply_counted``'s ``moe`` and the chunks the walk ran (None for
+        the single shot): they leave with the first tokens."""
         n, bucket = tokens.shape
-        row_caches = init_kv_caches(self.cfg, n, dtype=self.cache_dtype)
-        positions = jnp.broadcast_to(jnp.arange(bucket), (n, bucket))
-        logits, row_caches, moe = self._apply_counted(
-            params, tokens, positions, row_caches, 0, None, lengths - 1)
+        C = self.ADMIT_CHUNK
+        if bucket <= C:
+            chunks = None
+            row_caches = init_kv_caches(self.cfg, n, dtype=self.cache_dtype)
+            positions = jnp.broadcast_to(jnp.arange(bucket), (n, bucket))
+            logits, row_caches, moe = self._apply_counted(
+                params, tokens, positions, row_caches, 0, None, lengths - 1)
+        else:
+            line = -(-bucket // C) * C      # a max_seq-capped bucket: padded
+            row_caches = init_kv_caches(self.cfg, n, dtype=self.cache_dtype,
+                                        seq=line)
+            logits, row_caches, moe, chunks = self._prefill_walk_body(
+                params, jnp.pad(tokens, ((0, 0), (0, line - bucket))),
+                lengths, row_caches, C)
+            logits = logits[:, None]    # the single shot's [n, 1, V]
         pool = self._insert_span_body(pool, bt_rows, row_caches, 0, bucket,
                                       limits)
         firsts, next_keys = self._first_sample(logits[:, 0], seeds, temp_r,
                                                topk_r, greedy_r)
         return (pool, firsts) + self._activate_rows(
             cur, active, first, temp, topk, greedy, keys, slot_ids,
-            lengths, firsts, temp_r, topk_r, greedy_r, next_keys) + (moe,)
+            lengths, firsts, temp_r, topk_r, greedy_r, next_keys) + (
+                moe, chunks)
 
     @functools.partial(jax.jit, static_argnums=(0,),
                        donate_argnums=(3, 10, 11, 12, 13, 14, 15, 16))
@@ -1448,7 +1495,8 @@ class Generator:
                                 mesh=self.kv_mesh)
         lengths = jnp.asarray(lens, jnp.int32)
         if bucket > self.PREFILL_CHUNK:
-            logits, caches = self._prefill_long(tokens, lengths, caches)
+            logits, caches = self._prefill_walk(
+                self.params, jnp.asarray(tokens), lengths, caches)
         else:
             logits, caches = self._prefill(self.params, jnp.asarray(tokens),
                                            lengths, caches)
@@ -1617,7 +1665,8 @@ class Generator:
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :n_prompt] = prompt_tokens
             if bucket > self.PREFILL_CHUNK:
-                logits, caches = self._prefill_long(tokens, length, caches)
+                logits, caches = self._prefill_walk(
+                    self.params, jnp.asarray(tokens), length, caches)
             else:
                 logits, caches = self._prefill(self.params,
                                                jnp.asarray(tokens),
