@@ -314,3 +314,35 @@ def test_generate_fused_edge_cases(gen):
     ref, _ = gen.generate([1, 2, 3], max_new_tokens=11,
                           sample=SampleConfig(greedy=True), seed=1)
     assert out == ref
+
+
+@pytest.mark.parametrize("preset,kinds", [("tiny", 1), ("tiny_moe", 3)])
+def test_each_layer_kind_is_traced_once_a_program(preset, kinds,
+                                                  monkeypatch):
+    """Applied, ``LlamaModel`` runs a layer through its kind's jitted,
+    inlined block: a lowered serving program traces the block once a
+    ``LayerSpec`` (tiny: 2 layers of 1 kind; tiny_moe: 4 layers of 3),
+    however many layers call it."""
+    from tpustack.models import llama
+    from tpustack.models.llama import init_kv_caches
+
+    traced = []
+    real = llama.LlamaBlock.apply
+
+    def counted(block, *a, **kw):
+        traced.append(block.spec)
+        return real(block, *a, **kw)
+
+    cfg = getattr(LlamaConfig, preset)(max_seq=96)
+    m = LlamaModel(cfg, dtype=jnp.float32)
+    params = jax.eval_shape(lambda: m.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    caches = jax.eval_shape(lambda: init_kv_caches(cfg, 2, jnp.float32))
+    llama._layer_program.cache_clear()
+    monkeypatch.setattr(llama.LlamaBlock, "apply", counted)
+    jax.jit(lambda p, t, c: m.apply({"params": p}, t, None, c, 0, None,
+                                    mutable=["moe_stats"])).lower(
+        params, jax.ShapeDtypeStruct((2, 32), jnp.int32), caches)
+    assert len(cfg.layer_specs) > kinds == len(set(cfg.layer_specs))
+    assert sorted(map(repr, traced)) == sorted(map(repr,
+                                                   set(cfg.layer_specs)))

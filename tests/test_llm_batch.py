@@ -135,16 +135,20 @@ def test_server_micro_batches_concurrent_completions(gen):
                        max_batch=4)
     calls = {"batch": 0, "solo": 0}
     real_paged, real_fused = gen._decode_scan_paged, gen.generate_fused
+    real_ride = gen._ride_scan_paged
 
-    def spy_paged(*a, **kw):  # the engine's decode program
-        calls["batch"] += 1
-        return real_paged(*a, **kw)
+    def spy(real):  # the engine's decode programs, a ride on one or not
+        def spied(*a, **kw):
+            calls["batch"] += 1
+            return real(*a, **kw)
+        return spied
 
     def spy_fused(*a, **kw):
         calls["solo"] += 1
         return real_fused(*a, **kw)
 
-    gen._decode_scan_paged, gen.generate_fused = spy_paged, spy_fused
+    gen._decode_scan_paged, gen.generate_fused = spy(real_paged), spy_fused
+    gen._ride_scan_paged = spy(real_ride)
     prompts = ["alpha", "bee", "gamma!"]
 
     async def scenario():
@@ -163,6 +167,7 @@ def test_server_micro_batches_concurrent_completions(gen):
         results = asyncio.new_event_loop().run_until_complete(scenario())
     finally:
         gen._decode_scan_paged, gen.generate_fused = real_paged, real_fused
+        gen._ride_scan_paged = real_ride
 
     assert calls["batch"] >= 1 and calls["solo"] == 0, calls
     for p, r in zip(prompts, results):

@@ -335,6 +335,7 @@ def test_server_engine_failure_strands_nothing(gen):
     server = LLMServer(generator=gen, tokenizer=ByteTokenizer(512),
                        model_name="tiny-test", max_batch=4, registry=reg)
     real_paged = gen._decode_scan_paged
+    real_ride = gen._ride_scan_paged
     broken = {"on": True}
 
     def boom(real_fn):
@@ -344,7 +345,9 @@ def test_server_engine_failure_strands_nothing(gen):
             return real_fn(*a, **kw)
         return wrapped
 
-    gen._decode_scan_paged = boom(real_paged)  # the engine's decode
+    # the engine's decode, with and without a ride on it
+    gen._decode_scan_paged = boom(real_paged)
+    gen._ride_scan_paged = boom(real_ride)
     try:
         async def scenario():
             client = TestClient(TestServer(server.build_app()))
@@ -380,6 +383,7 @@ def test_server_engine_failure_strands_nothing(gen):
                 == server.paged.cache.evictable_blocks())
     finally:
         gen._decode_scan_paged = real_paged
+        gen._ride_scan_paged = real_ride
 
 
 def test_resolve_guard_fails_safe(gen):
@@ -534,8 +538,9 @@ def test_a_request_fed_after_an_end_is_dispatched_behind_few_steps(gen, m,
 def test_weight_passes_are_the_steps_that_ran(gen):
     """(d) Every wave record's ``weight_passes`` is the steps its dispatch
     ran; they add up to the run's; and the scripted closed loop delivers
-    its 48 decoded tokens in 27 weight passes where full-capacity
-    dispatches take 48."""
+    its 48 decoded tokens in 30 weight passes where full-capacity
+    dispatches take 49.  (Three of its requests ride: a ride's dispatch is
+    weight passes too, one a segment, and its row joins at its end.)"""
     decoded = sum(n - 1 for reqs in CALLERS for _, n in reqs)
     got = {}
     for m in (CAPACITY, 2):
@@ -549,7 +554,7 @@ def test_weight_passes_are_the_steps_that_ran(gen):
         got[m] = stats["decode_weight_passes"]
         assert stats["tokens_per_weight_pass"] == pytest.approx(
             decoded / got[m])
-    assert decoded == 48 and got == {CAPACITY: 48, 2: 27}
+    assert decoded == 48 and got == {CAPACITY: 49, 2: 30}
 
 
 def test_one_decode_program_serves_every_length(gen):
@@ -595,22 +600,28 @@ def test_pace_is_the_capacity_until_both_clocks_are_measured():
 def test_unmeasured_or_at_capacity_the_dispatches_are_the_old_ones(gen, m):
     """(f) An engine with no recorder never measures, and one whose ``m``
     is the capacity has nothing to cut: every dispatch runs the capacity,
-    the parent's sequence."""
+    the parent's sequence — but for one that carries a ride's segments,
+    which runs those (its row joins at its end)."""
     engine_kw = {} if m is None else {"min_steps": m}
     eng = ContinuousEngine(gen, slots=2, chunk=CAPACITY, **engine_kw)
-    lengths = []
-    real = gen._decode_scan_paged
+    lengths = []  # (steps, ride segments) a dispatch
+    real, real_ride = gen._decode_scan_paged, gen._ride_scan_paged
 
     def spy(*a, **kw):
-        lengths.append(int(a[-1]))
+        lengths.append((int(a[-1]), 0))
         return real(*a, **kw)
 
-    gen._decode_scan_paged = spy
+    def spy_ride(*a, **kw):  # a ride's operands come after the steps
+        lengths.append((int(a[-2]), int(a[-1]["seg_n"])))
+        return real_ride(*a, **kw)
+
+    gen._decode_scan_paged, gen._ride_scan_paged = spy, spy_ride
     try:
         queue = [SlotRequest(ids=list(ids), max_new=n, sample=GREEDY)
                  for reqs in CALLERS for ids, n in reqs]
         stats = eng.run(lambda: queue.pop(0) if queue else None)
     finally:
-        gen._decode_scan_paged = real
-    assert lengths and set(lengths) == {CAPACITY}
-    assert stats["decode_weight_passes"] == CAPACITY * len(lengths)
+        gen._decode_scan_paged, gen._ride_scan_paged = real, real_ride
+    assert any(n for _, n in lengths)
+    assert all(steps == (n or CAPACITY) for steps, n in lengths), lengths
+    assert stats["decode_weight_passes"] == sum(s for s, _ in lengths)

@@ -24,6 +24,7 @@ quantisation or layer offload — and is designed around XLA:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -203,6 +204,10 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 
 KVCache = Dict[str, jax.Array]
 
+#: the cache keys of a ride's line (``LlamaAttention._attend_ride``): the
+#: riding row's dense line beside a decode step's buffers
+RIDE_KEYS = ("rk", "rv", "rk_scale", "rv_scale")
+
 
 def _per_head_shard(fn, mesh, cfg: LlamaConfig, n_scalars: int = 0):
     """``fn(q, k, v, *scalars) -> out`` (BSHD) made safe to trace under the
@@ -278,209 +283,17 @@ class LlamaAttention(nn.Module):
                 k = rope(k, positions, c.rope_theta)
 
         if kv_cache is not None and "ck" in kv_cache:
-            # CONTINUOUS-slot decode chunk (s == 1): every slot sits at its
-            # OWN contiguous position cur0[i] + t.  The main cache is FROZEN
-            # for the whole chunk — this step's K/V go into the small
-            # chunk-local buffer ck/cv at the UNIFORM index t (a cheap
-            # dynamic_update_slice), and attention is the exact streaming-
-            # softmax merge of {main cache [0, cur0[i])} ∪ {chunk buffer
-            # [0, t]}.  The engine flushes the buffer into the cache once
-            # per chunk (per-row offsets).  This replaces the per-step
-            # one-hot write-back (a full cache read+write pass per step:
-            # fine at 4k, ~2x KV traffic for concurrent 32k decodes) with
-            # one flush pass per chunk — write-back amortises by the chunk
-            # length.  lax.scatter remains off the table (serialises on
-            # TPU; 7x decode slowdown, measured).
-            #
-            # s > 1 is the SPECULATIVE VERIFY segment (llm_generate
-            # ._spec_verify_*): s draft+carry tokens land at buffer indices
-            # [t, t+s) in ONE weight pass, and query row j attends the same
-            # {main cache [0, cur0[i])} set plus buffer [0, t+j] — the
-            # in-segment causal generalisation of the single-token mask,
-            # which it collapses to exactly at s == 1.
-            cur0, t = cache_index      # [B] slot frontiers, scalar chunk step
-            # the frozen main-cache view is either a dense per-slot line
-            # (k/v keys) or the paged-flash IN-PLACE pool view (pk/pv, an
-            # int8 pool's scale rows pk_rows/pv_rows, + block table bt,
-            # TPUSTACK_PAGED_FLASH): same key set, same
-            # masking semantics, different storage — see the partial
-            # branch below
-            paged_flash = "pk" in kv_cache
-            quantized = "k_scale" in kv_cache or "pk_rows" in kv_cache
-            cbuf_len = kv_cache["ck"].shape[1]
-            with jax.named_scope("kv_write"):
-                if quantized:
-                    # quantise at write — the buffer holds the SAME int8
-                    # values the main cache will, so flushing is a copy, not
-                    # a requant
-                    k_q, k_s = _quantize_kv(k)
-                    v_q, v_s = _quantize_kv(v)
-                    new_cache = dict(
-                        kv_cache,
-                        ck=jax.lax.dynamic_update_slice(
-                            kv_cache["ck"], k_q, (0, t, 0, 0)),
-                        cv=jax.lax.dynamic_update_slice(
-                            kv_cache["cv"], v_q, (0, t, 0, 0)),
-                        ck_scale=jax.lax.dynamic_update_slice(
-                            kv_cache["ck_scale"], k_s, (0, t, 0)),
-                        cv_scale=jax.lax.dynamic_update_slice(
-                            kv_cache["cv_scale"], v_s, (0, t, 0)))
-                else:
-                    new_cache = dict(
-                        kv_cache,
-                        ck=jax.lax.dynamic_update_slice(
-                            kv_cache["ck"], k.astype(kv_cache["ck"].dtype),
-                            (0, t, 0, 0)),
-                        cv=jax.lax.dynamic_update_slice(
-                            kv_cache["cv"], v.astype(kv_cache["cv"].dtype),
-                            (0, t, 0, 0)))
-            from tpustack.ops.attention import (dot_product_attention_partial,
-                                                merge_attention_partials)
-
-            with jax.named_scope("attn_core"):
-                if s == 1:
-                    buf_mask = jnp.broadcast_to(
-                        jnp.arange(cbuf_len)[None, None, :] <= t,
-                        (b, 1, cbuf_len))
-                else:
-                    # verify segment: per-query in-segment causal (see above)
-                    buf_mask = jnp.broadcast_to(
-                        jnp.arange(cbuf_len)[None, None, :]
-                        <= (t + jnp.arange(s))[None, :, None],
-                        (b, s, cbuf_len))
-                if window is not None:
-                    # buffer index u holds position cur0 + u
-                    buf_mask = buf_mask & (
-                        (cur0[:, None] + jnp.arange(cbuf_len)[None, :])
-                        [:, None, :] > positions[:, :, None] - window)
-                if paged_flash:
-                    # read the KV pool blocks IN PLACE through the slot block
-                    # tables (scalar-prefetch Pallas kernel, per-row `cur0`
-                    # masking + int8 dequant in-kernel) — no dense
-                    # [B, max_seq] gather copy; every query row of a multi-
-                    # query verify attends the same [0, cur0) pool prefix, so
-                    # ONE kernel pass covers the whole segment and the in-
-                    # segment causal half stays in the buffer partial below
-                    from tpustack.ops.pallas.flash_attention import (
-                        paged_attention_partial)
-
-                    part_main = paged_attention_partial(
-                        q, kv_cache["pk"], kv_cache["pv"], kv_cache["bt"],
-                        cur0, scale_rows=(
-                            (kv_cache["pk_rows"], kv_cache["pv_rows"])
-                            if quantized else None),
-                        **({} if window is None else {
-                            "window": window, "q_pos": positions[:, 0]}))
-                else:
-                    main_pos = jnp.arange(kv_cache["k"].shape[1])
-                    main_mask = (main_pos[None, None, :]
-                                 < cur0[:, None, None])      # [B, 1, S]
-                    if window is not None:
-                        main_mask = main_mask & (
-                            main_pos[None, None, :]
-                            > positions[:, :, None] - window)
-                    part_main = dot_product_attention_partial(
-                        q, kv_cache["k"], kv_cache["v"], mask=main_mask,
-                        k_scale=kv_cache.get("k_scale"),
-                        v_scale=kv_cache.get("v_scale"))
-                part_buf = dot_product_attention_partial(
-                    q, new_cache["ck"], new_cache["cv"], mask=buf_mask,
-                    k_scale=new_cache.get("ck_scale"),
-                    v_scale=new_cache.get("cv_scale"))
-                out = merge_attention_partials(part_main, part_buf, self.dtype)
-                out = out.reshape(b, s, c.n_heads * hd)
+            if RIDE_KEYS[0] in kv_cache:
+                out, new_cache = self._attend_ride(q, k, v, positions,
+                                                   kv_cache, cache_index)
+            else:
+                out, new_cache = self._attend_chunk(q, k, v, positions,
+                                                    kv_cache, *cache_index)
             with jax.named_scope("attn_out"):
                 return dense(c.dim, "o_proj", False)(out), new_cache
         if kv_cache is not None:
-            quantized = "k_scale" in kv_cache
-            with jax.named_scope("kv_write"):
-                if quantized:
-                    # int8 cache: quantise this call's K/V vectors as they are
-                    # written; reads below keep int8 as the attention matmul
-                    # operand and apply the scales outside the d-contraction
-                    k_q, k_s = _quantize_kv(k)
-                    v_q, v_s = _quantize_kv(v)
-                    k_all = jax.lax.dynamic_update_slice(
-                        kv_cache["k"], k_q, (0, cache_index, 0, 0))
-                    v_all = jax.lax.dynamic_update_slice(
-                        kv_cache["v"], v_q, (0, cache_index, 0, 0))
-                    ks_all = jax.lax.dynamic_update_slice(
-                        kv_cache["k_scale"], k_s, (0, cache_index, 0))
-                    vs_all = jax.lax.dynamic_update_slice(
-                        kv_cache["v_scale"], v_s, (0, cache_index, 0))
-                    new_cache = {"k": k_all, "k_scale": ks_all,
-                                 "v": v_all, "v_scale": vs_all}
-                else:
-                    # static-shape cache update at cache_index (decode: s==1)
-                    k_all = jax.lax.dynamic_update_slice(
-                        kv_cache["k"], k.astype(kv_cache["k"].dtype),
-                        (0, cache_index, 0, 0))
-                    v_all = jax.lax.dynamic_update_slice(
-                        kv_cache["v"], v.astype(kv_cache["v"].dtype),
-                        (0, cache_index, 0, 0))
-                    ks_all = vs_all = None
-                    new_cache = {"k": k_all, "v": v_all}
-            from_zero = isinstance(cache_index, int) and cache_index == 0
-            if s > 1 and from_zero and attn_mask is None:
-                # Prefill from position 0: attend IN-BUCKET, not over the
-                # whole cache — scores are [P, P] instead of [P, max_seq]
-                # (ctx/P× less attention work at serving shapes) and causal-
-                # only, so the Pallas flash kernel applies to long prompts.
-                # Padded tail positions only feed garbage to other padded
-                # rows (causal) and to cache slots that decode masks/
-                # overwrites; the engine reads logits at length-1 < P.
-                # Chunked prefill (cache_index > 0 / traced, or an explicit
-                # mask) must see the earlier cache, so it takes a full-cache
-                # path below.
-                with jax.named_scope("attn_core"):
-                    attend, sharded = _per_head_shard(
-                        lambda q, k, v: dot_product_attention(
-                            q, k, v, causal=True, impl="auto",
-                            window=window),
-                        self.tp_mesh, c)
-                    out = (attend(q, k, v) if sharded else
-                           dot_product_attention(q, k, v, causal=True,
-                                                 window=window))
-            elif s > 1 and attn_mask is None:
-                # Chunked long-context prefill: this chunk's rows sit at
-                # global positions cache_index + i and attend the whole
-                # cache prefix causally via the k-streaming flash kernel
-                # (traced offset/length — one compiled program serves every
-                # chunk; GQA K/V stay unexpanded inside the kernel).  XLA
-                # would need [s, max_seq] scores per head here.
-                from tpustack.ops.pallas.flash_attention import flash_attention
-
-                with jax.named_scope("kv_read"):
-                    if quantized:
-                        # the kernel has no scale inputs: dequantise for this
-                        # (per-chunk, compile-once) path — the decode step
-                        # below is where the int8 bandwidth saving matters
-                        k_in = (k_all.astype(self.dtype) *
-                                ks_all[..., None].astype(self.dtype))
-                        v_in = (v_all.astype(self.dtype) *
-                                vs_all[..., None].astype(self.dtype))
-                    else:
-                        k_in, v_in = k_all, v_all
-                # (a tp that does not divide the heads leaves the kernel
-                # unwrapped: Mosaic then refuses the partitioned program)
-                with jax.named_scope("attn_core"):
-                    attend, _ = _per_head_shard(
-                        lambda q, k, v, off: flash_attention(
-                            q, k, v, causal=True, q_offset=off,
-                            kv_len=off + s, window=window),
-                        self.tp_mesh, c, n_scalars=1)
-                    out = attend(q, k_in, v_in,
-                                 jnp.asarray(cache_index, jnp.int32))
-            else:
-                if window is not None:
-                    band = (jnp.arange(k_all.shape[1])[None, None, None, :]
-                            > positions[:, None, :, None] - window)
-                    attn_mask = (band if attn_mask is None
-                                 else attn_mask & band)
-                with jax.named_scope("attn_core"):
-                    out = dot_product_attention(
-                        q, k_all, v_all, mask=attn_mask, k_scale=ks_all,
-                        v_scale=vs_all)
+            out, new_cache = self._attend_line(q, k, v, positions, kv_cache,
+                                               cache_index, attn_mask)
         elif (self.ring_mesh is not None and attn_mask is None
                 and "sp" in self.ring_mesh.axis_names
                 and self.ring_mesh.shape["sp"] > 1
@@ -517,6 +330,258 @@ class LlamaAttention(nn.Module):
         out = out.reshape(b, s, c.n_heads * hd)
         with jax.named_scope("attn_out"):
             return dense(c.dim, "o_proj", False)(out), new_cache
+
+    def _attend_ride(self, q, k, v, positions, kv_cache, cache_index):
+        """A decode step that carries a segment of one row's prompt (the
+        engine's ride, ``llm_generate._ride_scan_paged``): rows ``[0, B)``
+        of ``q``/``k``/``v`` (``[B + S, 1, ...]``, out of one projection)
+        are the B decode tokens and take the chunk-mode path; rows ``[B,
+        B + S)`` are the segment's S tokens, a ``[1, S]`` run at positions
+        ``roff + i`` that is written into the riding row's own cache line
+        (``RIDE_KEYS``, a dense line as ``init_kv_caches`` lays one) at
+        ``roff`` and attends it causally — in the line's own type, an int8
+        line as quantised, as a warm start does.  ``cache_index`` is
+        ``(cur0, t, roff)``.  Returns the ``[B + S, 1, heads · hd]``
+        attention output and the new buffers and line."""
+        cur0, t, roff = cache_index
+        nb = cur0.shape[0]
+        seg = lambda a: jnp.swapaxes(a[nb:], 0, 1)     # [S, 1, ..] → [1, S, ..]
+        line = {key[1:]: kv_cache[key] for key in RIDE_KEYS if key in kv_cache}
+        pos = seg(positions)
+        mask = (jnp.arange(line["k"].shape[1])[None, None, None, :]
+                <= pos[:, None, :, None])
+        out_s, line = self._attend_line(seg(q), seg(k), seg(v), pos, line,
+                                        roff, mask)
+        out_d, new_cache = self._attend_chunk(
+            q[:nb], k[:nb], v[:nb], positions[:nb],
+            {key: val for key, val in kv_cache.items()
+             if key not in RIDE_KEYS}, cur0, t)
+        out = jnp.concatenate([out_d, jnp.swapaxes(
+            out_s.reshape(1, -1, out_d.shape[-1]), 0, 1)], axis=0)
+        new_cache.update({"r" + key: val for key, val in line.items()})
+        return out, new_cache
+
+    def _attend_chunk(self, q, k, v, positions, kv_cache, cur0, t):
+        """Chunk-mode attention (the continuous decode step and the
+        speculative verify segment): returns the ``[b, s, heads · hd]``
+        output and the cache with this step's K/V in its buffers."""
+        c = self.cfg
+        hd = c.head_dim
+        window = self.spec.window
+        b, s = q.shape[:2]
+        # CONTINUOUS-slot decode chunk (s == 1): every slot sits at its
+        # OWN contiguous position cur0[i] + t.  The main cache is FROZEN
+        # for the whole chunk — this step's K/V go into the small
+        # chunk-local buffer ck/cv at the UNIFORM index t (a cheap
+        # dynamic_update_slice), and attention is the exact streaming-
+        # softmax merge of {main cache [0, cur0[i])} ∪ {chunk buffer
+        # [0, t]}.  The engine flushes the buffer into the cache once
+        # per chunk (per-row offsets).  This replaces the per-step
+        # one-hot write-back (a full cache read+write pass per step:
+        # fine at 4k, ~2x KV traffic for concurrent 32k decodes) with
+        # one flush pass per chunk — write-back amortises by the chunk
+        # length.  lax.scatter remains off the table (serialises on
+        # TPU; 7x decode slowdown, measured).
+        #
+        # s > 1 is the SPECULATIVE VERIFY segment (llm_generate
+        # ._spec_verify_*): s draft+carry tokens land at buffer indices
+        # [t, t+s) in ONE weight pass, and query row j attends the same
+        # {main cache [0, cur0[i])} set plus buffer [0, t+j] — the
+        # in-segment causal generalisation of the single-token mask,
+        # which it collapses to exactly at s == 1.
+        #
+        # The frozen main-cache view is either a dense per-slot line
+        # (k/v keys) or the paged-flash IN-PLACE pool view (pk/pv, an
+        # int8 pool's scale rows pk_rows/pv_rows, + block table bt,
+        # TPUSTACK_PAGED_FLASH): same key set, same
+        # masking semantics, different storage — see the partial
+        # branch below
+        paged_flash = "pk" in kv_cache
+        quantized = "k_scale" in kv_cache or "pk_rows" in kv_cache
+        cbuf_len = kv_cache["ck"].shape[1]
+        with jax.named_scope("kv_write"):
+            if quantized:
+                # quantise at write — the buffer holds the SAME int8
+                # values the main cache will, so flushing is a copy, not
+                # a requant
+                k_q, k_s = _quantize_kv(k)
+                v_q, v_s = _quantize_kv(v)
+                new_cache = dict(
+                    kv_cache,
+                    ck=jax.lax.dynamic_update_slice(
+                        kv_cache["ck"], k_q, (0, t, 0, 0)),
+                    cv=jax.lax.dynamic_update_slice(
+                        kv_cache["cv"], v_q, (0, t, 0, 0)),
+                    ck_scale=jax.lax.dynamic_update_slice(
+                        kv_cache["ck_scale"], k_s, (0, t, 0)),
+                    cv_scale=jax.lax.dynamic_update_slice(
+                        kv_cache["cv_scale"], v_s, (0, t, 0)))
+            else:
+                new_cache = dict(
+                    kv_cache,
+                    ck=jax.lax.dynamic_update_slice(
+                        kv_cache["ck"], k.astype(kv_cache["ck"].dtype),
+                        (0, t, 0, 0)),
+                    cv=jax.lax.dynamic_update_slice(
+                        kv_cache["cv"], v.astype(kv_cache["cv"].dtype),
+                        (0, t, 0, 0)))
+        from tpustack.ops.attention import (dot_product_attention_partial,
+                                            merge_attention_partials)
+
+        with jax.named_scope("attn_core"):
+            if s == 1:
+                buf_mask = jnp.broadcast_to(
+                    jnp.arange(cbuf_len)[None, None, :] <= t,
+                    (b, 1, cbuf_len))
+            else:
+                # verify segment: per-query in-segment causal (see above)
+                buf_mask = jnp.broadcast_to(
+                    jnp.arange(cbuf_len)[None, None, :]
+                    <= (t + jnp.arange(s))[None, :, None],
+                    (b, s, cbuf_len))
+            if window is not None:
+                # buffer index u holds position cur0 + u
+                buf_mask = buf_mask & (
+                    (cur0[:, None] + jnp.arange(cbuf_len)[None, :])
+                    [:, None, :] > positions[:, :, None] - window)
+            if paged_flash:
+                # read the KV pool blocks IN PLACE through the slot block
+                # tables (scalar-prefetch Pallas kernel, per-row `cur0`
+                # masking + int8 dequant in-kernel) — no dense
+                # [B, max_seq] gather copy; every query row of a multi-
+                # query verify attends the same [0, cur0) pool prefix, so
+                # ONE kernel pass covers the whole segment and the in-
+                # segment causal half stays in the buffer partial below
+                from tpustack.ops.pallas.flash_attention import (
+                    paged_attention_partial)
+
+                part_main = paged_attention_partial(
+                    q, kv_cache["pk"], kv_cache["pv"], kv_cache["bt"],
+                    cur0, scale_rows=(
+                        (kv_cache["pk_rows"], kv_cache["pv_rows"])
+                        if quantized else None),
+                    **({} if window is None else {
+                        "window": window, "q_pos": positions[:, 0]}))
+            else:
+                main_pos = jnp.arange(kv_cache["k"].shape[1])
+                main_mask = (main_pos[None, None, :]
+                             < cur0[:, None, None])      # [B, 1, S]
+                if window is not None:
+                    main_mask = main_mask & (
+                        main_pos[None, None, :]
+                        > positions[:, :, None] - window)
+                part_main = dot_product_attention_partial(
+                    q, kv_cache["k"], kv_cache["v"], mask=main_mask,
+                    k_scale=kv_cache.get("k_scale"),
+                    v_scale=kv_cache.get("v_scale"))
+            part_buf = dot_product_attention_partial(
+                q, new_cache["ck"], new_cache["cv"], mask=buf_mask,
+                k_scale=new_cache.get("ck_scale"),
+                v_scale=new_cache.get("cv_scale"))
+            out = merge_attention_partials(part_main, part_buf, self.dtype)
+            out = out.reshape(b, s, c.n_heads * hd)
+        return out, new_cache
+
+    def _attend_line(self, q, k, v, positions, kv_cache, cache_index,
+                     attn_mask):
+        """Attention over a dense cache line (``init_kv_caches``' layout),
+        this call's K/V written into it at ``cache_index`` first: a prefill
+        from position 0 in-bucket, a chunk at a traced offset through the
+        k-streaming kernel, else under ``attn_mask`` over the whole line.
+        Returns the ``[b, s, heads, hd]`` output and the new line."""
+        c = self.cfg
+        window = self.spec.window
+        s = q.shape[1]
+        quantized = "k_scale" in kv_cache
+        with jax.named_scope("kv_write"):
+            if quantized:
+                # int8 cache: quantise this call's K/V vectors as they are
+                # written; reads below keep int8 as the attention matmul
+                # operand and apply the scales outside the d-contraction
+                k_q, k_s = _quantize_kv(k)
+                v_q, v_s = _quantize_kv(v)
+                k_all = jax.lax.dynamic_update_slice(
+                    kv_cache["k"], k_q, (0, cache_index, 0, 0))
+                v_all = jax.lax.dynamic_update_slice(
+                    kv_cache["v"], v_q, (0, cache_index, 0, 0))
+                ks_all = jax.lax.dynamic_update_slice(
+                    kv_cache["k_scale"], k_s, (0, cache_index, 0))
+                vs_all = jax.lax.dynamic_update_slice(
+                    kv_cache["v_scale"], v_s, (0, cache_index, 0))
+                new_cache = {"k": k_all, "k_scale": ks_all,
+                             "v": v_all, "v_scale": vs_all}
+            else:
+                # static-shape cache update at cache_index (decode: s==1)
+                k_all = jax.lax.dynamic_update_slice(
+                    kv_cache["k"], k.astype(kv_cache["k"].dtype),
+                    (0, cache_index, 0, 0))
+                v_all = jax.lax.dynamic_update_slice(
+                    kv_cache["v"], v.astype(kv_cache["v"].dtype),
+                    (0, cache_index, 0, 0))
+                ks_all = vs_all = None
+                new_cache = {"k": k_all, "v": v_all}
+        from_zero = isinstance(cache_index, int) and cache_index == 0
+        if s > 1 and from_zero and attn_mask is None:
+            # Prefill from position 0: attend IN-BUCKET, not over the
+            # whole cache — scores are [P, P] instead of [P, max_seq]
+            # (ctx/P× less attention work at serving shapes) and causal-
+            # only, so the Pallas flash kernel applies to long prompts.
+            # Padded tail positions only feed garbage to other padded
+            # rows (causal) and to cache slots that decode masks/
+            # overwrites; the engine reads logits at length-1 < P.
+            # Chunked prefill (cache_index > 0 / traced, or an explicit
+            # mask) must see the earlier cache, so it takes a full-cache
+            # path below.
+            with jax.named_scope("attn_core"):
+                attend, sharded = _per_head_shard(
+                    lambda q, k, v: dot_product_attention(
+                        q, k, v, causal=True, impl="auto",
+                        window=window),
+                    self.tp_mesh, c)
+                out = (attend(q, k, v) if sharded else
+                       dot_product_attention(q, k, v, causal=True,
+                                             window=window))
+        elif s > 1 and attn_mask is None:
+            # Chunked long-context prefill: this chunk's rows sit at
+            # global positions cache_index + i and attend the whole
+            # cache prefix causally via the k-streaming flash kernel
+            # (traced offset/length — one compiled program serves every
+            # chunk; GQA K/V stay unexpanded inside the kernel).  XLA
+            # would need [s, max_seq] scores per head here.
+            from tpustack.ops.pallas.flash_attention import flash_attention
+
+            with jax.named_scope("kv_read"):
+                if quantized:
+                    # the kernel has no scale inputs: dequantise for this
+                    # (per-chunk, compile-once) path — the decode step
+                    # below is where the int8 bandwidth saving matters
+                    k_in = (k_all.astype(self.dtype) *
+                            ks_all[..., None].astype(self.dtype))
+                    v_in = (v_all.astype(self.dtype) *
+                            vs_all[..., None].astype(self.dtype))
+                else:
+                    k_in, v_in = k_all, v_all
+            # (a tp that does not divide the heads leaves the kernel
+            # unwrapped: Mosaic then refuses the partitioned program)
+            with jax.named_scope("attn_core"):
+                attend, _ = _per_head_shard(
+                    lambda q, k, v, off: flash_attention(
+                        q, k, v, causal=True, q_offset=off,
+                        kv_len=off + s, window=window),
+                    self.tp_mesh, c, n_scalars=1)
+                out = attend(q, k_in, v_in,
+                             jnp.asarray(cache_index, jnp.int32))
+        else:
+            if window is not None:
+                band = (jnp.arange(k_all.shape[1])[None, None, None, :]
+                        > positions[:, None, :, None] - window)
+                attn_mask = (band if attn_mask is None
+                             else attn_mask & band)
+            with jax.named_scope("attn_core"):
+                out = dot_product_attention(
+                    q, k_all, v_all, mask=attn_mask, k_scale=ks_all,
+                    v_scale=vs_all)
+        return out, new_cache
 
 
 class LlamaMLP(nn.Module):
@@ -557,6 +622,12 @@ class LlamaBlock(nn.Module):
             ffn = MoEFeedForward(c, self.dtype, name="mlp")
         else:
             ffn = LlamaMLP(c, self.dtype, name="mlp")
+        if self.spec.ffn == "experts" and kv_cache is not None and (
+                RIDE_KEYS[0] in kv_cache):
+            # a ride's decode rows and segment go through one router call;
+            # their counters are kept apart (``MoEFeedForward``'s ``split``)
+            experts, split = ffn, cache_index[0].shape[0]
+            ffn = lambda h: experts(h, split=split)
         norm = lambda name: RMSNorm(c.rms_eps, self.dtype, name=name)
         if c.norm_placement == "pre":
             h, new_cache = attn(norm("input_layernorm")(x), positions,
@@ -588,11 +659,19 @@ class LlamaModel(nn.Module):
 
     @nn.compact
     def __call__(self, tokens, positions=None, kv_caches=None, cache_index=0,
-                 attn_mask=None, logits_at=None):
+                 attn_mask=None, logits_at=None, head_rows=None):
         """``logits_at``: optional ``[B]`` positions — compute logits ONLY at
         those sequence positions.  Long-context prefill must use this: full
         ``[B, S, vocab]`` f32 logits at 16k × Qwen's 152k vocab are ~10 GB,
-        more than the lm_head needs to produce one next token."""
+        more than the lm_head needs to produce one next token.
+        ``head_rows``: optional row indices — the head runs on those rows of
+        the batch only (a ride's decode rows and its segment's last
+        token).
+
+        Applied (not initialised), each layer runs through its kind's
+        ``_layer_program``: a block is traced once a program for every
+        layer of one ``LayerSpec`` and inlined where each layer calls it,
+        with that layer's own parameters."""
         c = self.cfg
         b, s = tokens.shape
         if positions is None:
@@ -613,15 +692,21 @@ class LlamaModel(nn.Module):
         new_caches = [] if kv_caches is not None else None
         for i, spec in enumerate(c.layer_specs):
             cache_i = kv_caches[i] if kv_caches is not None else None
-            x, nc = LlamaBlock(c, self.dtype, self.ring_mesh, self.tp_mesh,
-                               spec, name=f"layers_{i}")(
-                x, positions, cache_i, cache_index, attn_mask)
+            if self.is_initializing():
+                x, nc = LlamaBlock(c, self.dtype, self.ring_mesh,
+                                   self.tp_mesh, spec, name=f"layers_{i}")(
+                    x, positions, cache_i, cache_index, attn_mask)
+            else:
+                x, nc = self._apply_layer(i, spec, x, positions, cache_i,
+                                          cache_index, attn_mask)
             if new_caches is not None:
                 new_caches.append(nc)
         x = RMSNorm(c.rms_eps, self.dtype, name="norm")(x)
         if logits_at is not None:
             x = jnp.take_along_axis(
                 x, logits_at[:, None, None].astype(jnp.int32), axis=1)  # [B,1,D]
+        if head_rows is not None:
+            x = jnp.take(x, head_rows, axis=0)
         from tpustack.ops.quant import make_dense
 
         with jax.named_scope("lm_head"):
@@ -636,6 +721,52 @@ class LlamaModel(nn.Module):
                                     out_dtype=jnp.float32)(
                     x if c.quant else x.astype(jnp.float32))
         return logits, new_caches
+
+    def _apply_layer(self, i, spec, x, positions, cache, cache_index,
+                     attn_mask):
+        """Layer ``i`` through its kind's ``_layer_program``: array leaves
+        of the cache, ``cache_index`` and ``attn_mask`` are its operands,
+        any other leaf (a Python-int ``cache_index``) part of its key.
+        Counters the block sows land where the block would have put them."""
+        name = f"layers_{i}"
+        mutable = tuple(col for col in ("moe_stats",)
+                        if self.is_mutable_collection(col))
+        leaves, tree = jax.tree.flatten((cache, cache_index, attn_mask))
+        arrays = [v for v in leaves if isinstance(v, (jax.Array, np.ndarray))]
+        fixed = tuple((j, v) for j, v in enumerate(leaves)
+                      if not isinstance(v, (jax.Array, np.ndarray)))
+        program = _layer_program(self.cfg, spec, self.dtype, self.ring_mesh,
+                                 self.tp_mesh, mutable)
+        (x, new_cache), sown = program(self.variables["params"][name], x,
+                                       positions, arrays, tree, fixed)
+        for col, val in sown.items():
+            if val:
+                self.put_variable(col, name, val)
+        return x, new_cache
+
+
+@functools.lru_cache(maxsize=None)
+def _layer_program(cfg: LlamaConfig, spec: LayerSpec, dtype, ring_mesh,
+                   tp_mesh, mutable: Tuple[str, ...]):
+    """``LlamaBlock`` of one kind as ``jax.jit(..., inline=True)``: traced
+    once for every layer of that kind that a program calls with the same
+    shapes, and inlined into the caller, so the compiled program is what
+    tracing each layer by itself gives.  ``(params, x, positions, arrays,
+    tree, fixed) -> ((x, cache), sown)``: ``tree`` and ``fixed`` rebuild
+    ``(cache, cache_index, attn_mask)`` from ``arrays``."""
+    block = LlamaBlock(cfg, dtype, ring_mesh, tp_mesh, spec)
+
+    def layer_block(params, x, positions, arrays, tree, fixed):
+        leaves = list(arrays)
+        for j, v in fixed:
+            leaves.insert(j, v)
+        cache, cache_index, attn_mask = jax.tree.unflatten(tree, leaves)
+        args = (x, positions, cache, cache_index, attn_mask)
+        if not mutable:
+            return block.apply({"params": params}, *args), {}
+        return block.apply({"params": params}, *args, mutable=list(mutable))
+
+    return jax.jit(layer_block, static_argnums=(4, 5), inline=True)
 
 
 def _shard_kv(caches, cfg: "LlamaConfig", mesh, pool: bool = False):

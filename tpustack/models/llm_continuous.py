@@ -219,7 +219,7 @@ class SlotRequest:
 
 class _Slot:
     __slots__ = ("req", "out", "budget", "gen_id", "t0", "prefill_s",
-                 "dispatched", "done", "pending", "cached", "span",
+                 "dispatched", "done", "pending", "riding", "cached", "span",
                  "blocks", "alloc", "spec_ema", "spec_idle", "stride_ema")
 
     def __init__(self):
@@ -232,6 +232,8 @@ class _Slot:
         self.dispatched = 0  # decode steps dispatched for this occupancy
         self.done = True
         self.pending = False  # admission dispatched, firsts not yet fetched
+        self.riding = False  # its prompt rides the decode dispatches
+        # (_Ride): parked on the device until the last segment's dispatch
         self.cached = 0  # prompt tokens a prefix hit's shared blocks cover
         self.span = None  # active trace span: prefill until resolve, wave
         # from resolve to retire (None when the request carries no context)
@@ -256,12 +258,15 @@ class _PendingWave:
     them and completes the host-side bookkeeping."""
 
     __slots__ = ("rows", "firsts_dev", "t0", "block_inserts", "bucket",
-                 "moe_dev", "chunks_dev", "behind_steps")
+                 "moe_dev", "chunks_dev", "behind_steps", "ride_segments")
 
     def __init__(self, rows, firsts_dev, t0, block_inserts=(), bucket=None,
-                 moe_dev=None, chunks_dev=None, behind_steps=0):
+                 moe_dev=None, chunks_dev=None, behind_steps=0,
+                 ride_segments=None):
         self.rows = rows            # [(slot_idx, req, budget)]
         self.firsts_dev = firsts_dev
+        # the segments a ride's prompt took (None: an admission program)
+        self.ride_segments = ride_segments
         # chunks the admission program's walk of its bucket ran
         # (Generator._prefill_walk_body; None: a single shot): fetched with
         # the firsts
@@ -270,7 +275,8 @@ class _PendingWave:
         # dispatched, not yet fetched, when it was dispatched
         self.behind_steps = behind_steps
         # the admission's routed-expert counters (Generator._apply_counted;
-        # None for a model without such a layer): fetched with the firsts
+        # None for a model without such a layer; a ride: one a dispatch it
+        # rode): fetched with the firsts
         self.moe_dev = moe_dev
         self.t0 = t0
         self.bucket = bucket        # the program's tokens a row (a hit: its
@@ -284,9 +290,9 @@ class _Dispatch:
     """One decode dispatch in flight: its device outputs, the rows it
     carries, and what the engine chose for it."""
 
-    __slots__ = ("out", "rows", "steps", "cut", "timed")
+    __slots__ = ("out", "rows", "steps", "cut", "timed", "ride_tokens")
 
-    def __init__(self, out, rows, steps, cut, timed):
+    def __init__(self, out, rows, steps, cut, timed, ride_tokens=0):
         self.out = out        # (toks_dev [B, chunk], moe counters or None)
         self.rows = rows      # [(slot_idx, gen_id, offset)] at dispatch
         self.steps = steps    # decode steps it runs (<= the capacity)
@@ -294,6 +300,29 @@ class _Dispatch:
         # its device time can be read between two fetches: queued straight
         # behind another decode dispatch, no admission between the two
         self.timed = timed
+        self.ride_tokens = ride_tokens  # prompt tokens a ride's segments
+        # carried through its steps
+
+
+class _Ride:
+    """A lone admission riding the decode dispatches
+    (``Generator._ride_scan_paged``): its prompt goes through them one
+    segment — ``Generator.RIDE_SEGMENT`` tokens — a step, in the weight
+    passes the decode rows take anyway, instead of stopping them for a
+    single-shot admission program."""
+
+    __slots__ = ("row", "operands", "length", "segments", "sent", "t0",
+                 "behind_steps", "moe")
+
+    def __init__(self, row, operands, length, segments, t0, behind_steps):
+        self.row = row              # (slot_idx, req, budget)
+        self.operands = operands    # the program's ``ride`` that stays put
+        self.length = length        # prompt tokens
+        self.segments = segments    # segments the prompt takes
+        self.sent = 0               # segments dispatched so far
+        self.t0 = t0                # when it was admitted (prefill_s's start)
+        self.behind_steps = behind_steps
+        self.moe = []               # each dispatch's segment counters
 
 
 class _Pace:
@@ -497,6 +526,15 @@ class ContinuousEngine:
                        else lambda name: _NO_PHASE)
         self._to_park: List[int] = []  # retirements awaiting a fused park
         self._pending: List[_PendingWave] = []
+        # a lone admission whose prompt fits one ride line (the admission
+        # program's chunk, or the context if shorter) rides the decode
+        # dispatches instead of stopping them (_Ride), a segment of whole
+        # pool blocks a step; one at a time
+        self._ride: Optional[_Ride] = None
+        blk = self.paged.block
+        line = min(gen.ADMIT_CHUNK, gen.cfg.max_seq) // blk * blk
+        self._ride_seg = max(blk, min(gen.RIDE_SEGMENT, line) // blk * blk)
+        self._ride_len = line // self._ride_seg * self._ride_seg
         # what _dispatch_len and the flight records read (per run, set in
         # run()): seats freed and not taken again, and the steps their
         # window still has; decode steps dispatched and not yet fetched; an
@@ -539,7 +577,8 @@ class ContinuousEngine:
             # flash kernel); one engine uses exactly one flag value, so
             # the per-engine growth budget is unchanged — a flash engine
             # that silently retraced its kernel program still gates here
-            for name in ("_decode_scan_paged", "_spec_verify_paged"):
+            for name in ("_decode_scan_paged", "_ride_scan_paged",
+                         "_spec_verify_paged"):
                 watch.watch(name, cls.__dict__.get(name),
                             budgets.pop(name, default_budget))
             for name, budget in budgets.items():  # caller-declared extras
@@ -861,20 +900,6 @@ class ContinuousEngine:
                     jnp.asarray([r.sample.greedy for _, r, _ in rows],
                                 jnp.bool_))
 
-        def block_inserts(rows):
-            # prefix-cache inserts need NO device work: the prompt's full
-            # blocks already hold its prefilled KV, so an insert is handing
-            # their ids to the server at resolve time (when the firsts
-            # fetch proves prefill landed)
-            out = []
-            for i, r, _ in rows:
-                if r.on_prefill_blocks is None:
-                    continue
-                n_full = len(r.ids) // rt.block
-                if n_full:
-                    out.append((r, list(slots[i].blocks[:n_full])))
-            return out
-
         def rowmeta(rows):
             """(bt rows, per-row allocation limits) device arrays for the
             rows being admitted — snapshotted AFTER _alloc_slot_blocks
@@ -888,9 +913,31 @@ class ContinuousEngine:
             for i, _, _ in rows:
                 slots[i].pending = True
             self._pending.append(_PendingWave(
-                rows, firsts, t0, block_inserts=block_inserts(rows),
+                rows, firsts, t0,
+                block_inserts=self._block_inserts(slots, rows),
                 bucket=bucket, moe_dev=moe, chunks_dev=chunks,
                 behind_steps=self._in_flight))
+
+        # a lone cold admission while rows decode rides their dispatches
+        # (_Ride, _fill_chain) instead of stopping them for the 1-row
+        # admission program
+        i, req, _ = row = valid[0]
+        if (len(valid) == 1 and self._ride is None
+                and not (req.prefix and req.prefix[0] > 0)
+                and len(req.ids) <= self._ride_len
+                and any(self._wants_steps(s) for j, s in enumerate(slots)
+                        if j != i)):
+            _, _, seeds, temp_r, topk_r, greedy_r = row_arrays(valid)
+            seg = self._ride_seg
+            tokens = np.zeros((self._ride_len // seg, seg), np.int32)
+            tokens.reshape(-1)[:len(req.ids)] = req.ids
+            self._ride = _Ride(
+                row, self._ride_operands(tokens, i, len(req.ids), seeds,
+                                         temp_r, topk_r, greedy_r),
+                len(req.ids), -(-len(req.ids) // seg), t0,
+                self._in_flight)
+            slots[i].riding = True
+            return gen_ctr
 
         for row in prefix_rows:
             rows = [row]
@@ -969,6 +1016,29 @@ class ContinuousEngine:
             pend(rows, firsts, bucket, moe, chunks)
         return gen_ctr
 
+    def _block_inserts(self, slots, rows):
+        """Prefix-cache inserts of admitted rows: they need NO device work
+        — the prompt's full blocks already hold its prefilled KV, so an
+        insert is handing their ids to the server at resolve time (when
+        the firsts fetch proves prefill landed)."""
+        out = []
+        for i, r, _ in rows:
+            if r.on_prefill_blocks is None:
+                continue
+            n_full = len(r.ids) // self.paged.block
+            if n_full:
+                out.append((r, list(slots[i].blocks[:n_full])))
+        return out
+
+    @staticmethod
+    def _ride_operands(tokens, slot, length, seeds, temp, topk, greedy):
+        """The part of ``Generator._ride_scan_paged``'s ``ride`` that stays
+        the same over a ride's dispatches, on the device."""
+        return {"tokens": jnp.asarray(tokens),
+                "slot": jnp.asarray(slot, jnp.int32),
+                "length": jnp.asarray(length, jnp.int32),
+                "seed": seeds, "temp": temp, "topk": topk, "greedy": greedy}
+
     def _resolve(self, state, slots: List[_Slot], wave: _PendingWave):
         """Host-side completion of a dispatched admission: fetch the n
         first tokens (ready, or blocks until prefill lands), report them,
@@ -980,6 +1050,9 @@ class ContinuousEngine:
                 (wave.firsts_dev, wave.moe_dev, wave.chunks_dev))
             firsts = [int(t) for t in firsts]
             chunks = None if chunks is None else int(chunks)
+            if isinstance(moe, list):  # a ride's, one a dispatch
+                moe = None if moe[0] is None else np.sum(moe, axis=0)
+            ride = wave.ride_segments
         t_first = time.time() - wave.t0
         tier = getattr(self.paged.cache, "host_tier", None)
         if tier is not None:
@@ -989,6 +1062,13 @@ class ContinuousEngine:
                         for i, r, _ in wave.rows)
             tier.note_prefill(self.paged.pool.blocks_for(n_new), t_first)
         if self.flight is not None:
+            if ride is not None:
+                # a ride computed its segments' positions; its program's
+                # line is what a single shot would have computed
+                computed, chunks = ride * self._ride_seg, ride
+            else:
+                computed = (wave.bucket if chunks is None
+                            else chunks * self.gen.ADMIT_CHUNK)
             self.flight.record(
                 "prefill", rows=len(wave.rows),
                 prompt_tokens=sum(len(r.ids) for _, r, _ in wave.rows),
@@ -1005,13 +1085,14 @@ class ContinuousEngine:
                          else round(wave.t0 - r.t_handed, 6)
                          for _, r, _ in wave.rows],
                 # positions computed a row: the chunks a walk of the
-                # bucket ran, or the program's whole bucket in one shot
-                bucket=(wave.bucket if chunks is None
-                        else chunks * self.gen.ADMIT_CHUNK),
+                # bucket ran, the program's whole bucket in one shot, or
+                # the segments a ride carried
+                bucket=computed,
                 chunks=chunks or 1,
                 program_bucket=wave.bucket,
                 prompt_lens=[len(r.ids) for _, r, _ in wave.rows],
                 behind_steps=wave.behind_steps,
+                **({} if ride is None else {"ride": 1}),
                 **self._moe_fields(moe, passes=1))
         for req, ids in wave.block_inserts:
             # prefill has landed (the firsts fetch above synced on it): the
@@ -1089,7 +1170,7 @@ class ContinuousEngine:
                 park: bool = True):
         s = slots[i]
         req, out = s.req, s.out
-        s.req, s.done, s.pending = None, True, False
+        s.req, s.done, s.pending, s.riding = None, True, False, False
         # a seat is free: until it is taken, or for a capacity's worth of
         # steps, the dispatches stay short for whoever takes it
         # (_dispatch_len)
@@ -1167,7 +1248,7 @@ class ContinuousEngine:
             return
         victim, best = None, -1
         for i, s in enumerate(slots):
-            if s.req is None or s.pending or s.done:
+            if s.req is None or s.pending or s.done or s.riding:
                 continue
             if s.req.priority != "batch":
                 continue
@@ -1307,6 +1388,14 @@ class ContinuousEngine:
     def _live(slots: List[_Slot]) -> int:
         return sum(1 for s in slots if s.req is not None)
 
+    @staticmethod
+    def _wants_steps(s: _Slot) -> bool:
+        """The next decode dispatch carries this row: it still wants tokens
+        the chain hasn't covered (budget counts the prefill-sampled first
+        token; dispatched does not), and its prompt is not still riding."""
+        return (s.req is not None and not s.done and not s.riding
+                and 1 + s.dispatched < s.budget)
+
     # --------------------------------------------------------------------- run
     def run(self, feed: Callable[[], Optional[SlotRequest]]) -> Dict:
         """Decode loop: admit (dispatch-only) → keep ``depth`` chunks in
@@ -1322,6 +1411,7 @@ class ContinuousEngine:
         admitted = 0
         self._to_park = []
         self._pending = []
+        self._ride = None
         self._parked = []
         self._preempted = 0
         self._resumed = 0
@@ -1369,11 +1459,7 @@ class ContinuousEngine:
                     gen_ctr = self._admit_dispatch(state, slots, wave,
                                                    gen_ctr)
 
-        def dispatch_ok(s: _Slot) -> bool:
-            # this row still wants tokens the chain hasn't covered (budget
-            # counts the prefill-sampled first token; dispatched does not)
-            return (s.req is not None and not s.done
-                    and 1 + s.dispatched < s.budget)
+        dispatch_ok = self._wants_steps
 
         try:
             if self.spec is not None:
@@ -1482,20 +1568,29 @@ class ContinuousEngine:
           dead), a seat freed and not yet taken again (for a capacity's
           worth of steps: then nobody is coming), or a request queued
           beside an empty lane — so that what is queued on the device
-          ahead of the next admission is at most ``2 m`` steps;
+          ahead of the next admission is at most ``2 m`` steps — or, while
+          a ride is on, its segments left, fewer than ``m`` or more: the
+          rider joins at the end of the dispatch that carries its last
+          segment, so its prompt goes through in as few steps as it has
+          segments and no step carries a dead one;
         - ``full``: the capacity otherwise — every lane seated and no end
           inside the chunk, or lanes empty with nothing queued and no
           recent end (an under-full engine takes no boundary for nobody,
           and a burst arriving together is still admitted together)."""
         cap, m = self.chunk, self._pace.min_steps()
         carried = [slots[i] for i, _, _ in rows]
+        ride = self._ride
+        left = 0 if ride is None else ride.segments - ride.sent
+        if not carried:
+            # a ride with no row decoding beside it: its segments at once
+            return min(cap, left), "seating"
         target = max(m, min(s.budget - 1 - s.dispatched for s in carried))
         seating = len(carried) < self.B and (
             any(s.req is not None and not dispatch_ok(s) for s in slots)
             or self._seats_open > 0
             or bool(self._queue_depth_fn is not None
                     and self._queue_depth_fn()))
-        steps = min(cap, target, m if seating else cap)
+        steps = min(cap, target, left or (m if seating else cap))
         if self._seats_open > 0:
             self._seat_left -= steps
             if self._seat_left <= 0:
@@ -1507,21 +1602,36 @@ class ContinuousEngine:
     def _fill_chain(self, state, slots, chain, dispatch_ok):
         """Keep up to ``depth`` plain decode dispatches in flight (the
         pipelined dispatch half of the wave loop, shared by the plain and
-        speculative run loops), each as long as ``_dispatch_len`` says."""
+        speculative run loops), each as long as ``_dispatch_len`` says.
+        While a ride has segments left they go through
+        ``_ride_scan_paged`` a step each (``_ride_dispatch``)."""
         g = self.gen
         with self._phase("dispatch"):
-            while len(chain) < self.depth and any(
-                    dispatch_ok(s) for s in slots):
+            while len(chain) < self.depth and (
+                    self._ride_left(state, slots)
+                    or any(dispatch_ok(s) for s in slots)):
                 snapshot = [(i, s.gen_id, s.dispatched)
                             for i, s in enumerate(slots) if dispatch_ok(s)]
                 steps, cut = self._dispatch_len(slots, snapshot, dispatch_ok)
-                (toks, last, state["cur"], state["pool"],
-                 state["keys"], moe) = g._decode_scan_paged(
-                    g.params, state["first"], state["cur"],
-                    state["active"], state["pool"],
-                    jnp.asarray(self._bt), state["keys"],
-                    state["temp"], state["topk"], state["greedy"],
-                    self.chunk, np.int32(steps), flash=self.paged_flash)
+                ride, ride_tokens, segs = self._ride_dispatch(slots, steps)
+                args = (g.params, state["first"], state["cur"],
+                        state["active"], state["pool"],
+                        jnp.asarray(self._bt), state["keys"],
+                        state["temp"], state["topk"], state["greedy"],
+                        self.chunk, np.int32(steps))
+                if ride is None:
+                    (toks, last, state["cur"], state["pool"],
+                     state["keys"], moe) = g._decode_scan_paged(
+                        *args, flash=self.paged_flash)
+                    state["first"] = last
+                else:
+                    (toks, firsts, state["pool"], moe, ride_moe,
+                     state["cur"], state["active"], state["first"],
+                     state["temp"], state["topk"], state["greedy"],
+                     state["keys"]) = g._ride_scan_paged(
+                        *args, ride, flash=self.paged_flash)
+                    if self._ride is not None:
+                        self._ride_sent(slots, segs, firsts, ride_moe)
                 # keep the runtime's arrays reference CURRENT (donation
                 # rotated the buffers): the host-tier spill path reads
                 # blocks through it between dispatches, and cached prefix
@@ -1532,15 +1642,83 @@ class ContinuousEngine:
                     self._flash_dispatches += 1
                 else:
                     self._gather_dispatches += 1
-                state["first"] = last
                 self._plain_steps += steps
                 self._in_flight += steps
                 for i, _, _ in snapshot:
                     slots[i].dispatched += steps
                 chain.append(_Dispatch(
                     (toks, moe), snapshot, steps, cut,
-                    timed=bool(chain) and not self._admit_queued))
+                    timed=(bool(chain) and not self._admit_queued
+                           and not ride_tokens),
+                    ride_tokens=ride_tokens))
                 self._admit_queued = False
+
+    def _ride_left(self, state, slots) -> bool:
+        """A ride has segments still to dispatch.  A request cancelled
+        while its prompt rides leaves here, before another segment is
+        spent on it: no dispatch has activated its row, so its blocks go
+        back as any retirement's do."""
+        ride = self._ride
+        if ride is None:
+            return False
+        i, req, _ = ride.row
+        if req.cancelled():
+            self._ride = None
+            self._retire(state, slots, i, self._live(slots), park=False)
+            return False
+        return True
+
+    def _ride_dispatch(self, slots, steps: int):
+        """``_ride_scan_paged``'s ``ride`` for the next dispatch, the prompt
+        tokens its segments carry and how many segments it runs; ``(None,
+        0, 0)``: a plain decode dispatch.  Without a ride, an engine's
+        first decode dispatch that carries a prompt short enough to ride
+        runs the ride program with no segment, once a program shape: so
+        the one program a ride needs is compiled by a server's first
+        requests, not by its first ride."""
+        ride = self._ride
+        if ride is None:
+            key = (self.B, self.chunk, self.paged_flash, self._ride_len,
+                   self._ride_seg, self.paged.pool.n_blocks,
+                   self.paged.block)
+            if (self.B < 2 or key in self.gen.rides_compiled or not any(
+                    s.req is not None and len(s.req.ids) <= self._ride_len
+                    for s in slots)):
+                return None, 0, 0
+            self.gen.rides_compiled.add(key)
+            one = jnp.ones((1,), jnp.int32)
+            return dict(self._ride_operands(
+                np.zeros((self._ride_len // self._ride_seg, self._ride_seg),
+                         np.int32), 0, 1,
+                jnp.zeros((1,), jnp.uint32), one.astype(jnp.float32), one,
+                jnp.ones((1,), jnp.bool_)),
+                seg_off=np.int32(0), seg_n=np.int32(0),
+                finish=np.bool_(False)), 0, 0
+        seg = self._ride_seg
+        n = min(steps, ride.segments - ride.sent)
+        carried = min(ride.length, (ride.sent + n) * seg) - ride.sent * seg
+        return dict(ride.operands, seg_off=np.int32(ride.sent * seg),
+                    seg_n=np.int32(n),
+                    finish=np.bool_(ride.sent + n == ride.segments)), carried, n
+
+    def _ride_sent(self, slots, n: int, firsts, ride_moe) -> None:
+        """Book a ride dispatch of ``n`` segments.  The one that carries the
+        last segment activates the row at its end: the row is then an
+        admission like any other, pending until its first token is fetched
+        (``_resolve``), and the ride is over."""
+        ride = self._ride
+        ride.moe.append(ride_moe)
+        ride.sent += n
+        if ride.sent < ride.segments:
+            return
+        i = ride.row[0]
+        slots[i].riding, slots[i].pending = False, True
+        self._pending.append(_PendingWave(
+            [ride.row], firsts, ride.t0,
+            block_inserts=self._block_inserts(slots, [ride.row]),
+            bucket=self._ride_len, moe_dev=ride.moe,
+            behind_steps=ride.behind_steps, ride_segments=ride.segments))
+        self._ride = None
 
     def _sanitize_wave(self) -> None:
         """Wave-boundary sanitizer checks (no-op unless TPUSTACK_SANITIZE):
@@ -1584,7 +1762,8 @@ class ContinuousEngine:
                      priorities: Optional[Dict[str, int]] = None,
                      ctx_tokens: int = 0, ctx_window: int = 0,
                      moe: Optional[Dict[str, int]] = None,
-                     cut: Optional[str] = None) -> None:
+                     cut: Optional[str] = None,
+                     ride_tokens: int = 0) -> None:
         """Append one flight record for a fetched wave (plain chunk or
         speculative verify).  Host-side values only — the fetch that
         produced ``tokens`` already synced, so this is a dict build and a
@@ -1599,7 +1778,8 @@ class ContinuousEngine:
         model with such layers gets the field).  ``moe``: the wave's
         routed-expert counters (``_moe_fields``).  ``weight_passes``:
         the steps the dispatch ran (a verify: 1); ``cut``: why a plain
-        dispatch ran that many (``_dispatch_len``).
+        dispatch ran that many (``_dispatch_len``); ``ride_tokens``: the
+        prompt tokens a ride's segments carried through its steps.
         ``host_s``: the engine thread's seconds by phase since the
         previous wave/verify record, ``other`` being what no phase
         covered — they add up to ``wave_s``."""
@@ -1628,6 +1808,8 @@ class ContinuousEngine:
         }
         if cut is not None:
             rec["cut"] = cut
+        if ride_tokens:
+            rec["ride_tokens"] = int(ride_tokens)
         if self._window is not None:
             rec["ctx_tokens_window"] = int(ctx_window)
         rec.update(moe or {})
@@ -1726,7 +1908,7 @@ class ContinuousEngine:
                           tenants=tenants, priorities=priorities,
                           ctx_tokens=ctx_tokens, ctx_window=ctx_window,
                           moe=self._moe_fields(moe, passes=d.steps),
-                          cut=d.cut)
+                          cut=d.cut, ride_tokens=d.ride_tokens)
 
     def _moe_fields(self, moe, passes: int) -> Dict[str, int]:
         """Flight-record fields of one dispatch's routed-expert work:
@@ -1766,7 +1948,8 @@ class ContinuousEngine:
         (the chain-empty branch of both run loops)."""
         with self._phase("consume"):
             for i, s in enumerate(slots):
-                if s.req is not None and (s.done or not dispatch_ok(s)):
+                if s.req is not None and not s.riding and (
+                        s.done or not dispatch_ok(s)):
                     self._retire(state, slots, i, self._live(slots))
 
     def _run_loop(self, state, slots, chain, admit_free, dispatch_ok):

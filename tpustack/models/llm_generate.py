@@ -186,6 +186,10 @@ class Generator:
 
         self._prefix_dev: "Any" = _collections.OrderedDict()
         self.prefix_dev_cap = 4
+        #: the ``_ride_scan_paged`` shapes an engine has run on this
+        #: generator (``ContinuousEngine._ride_dispatch`` runs each once
+        #: before any request rides it)
+        self.rides_compiled: set = set()
 
     #: bytes of float random weights materialised per init program before
     #: they are quantised (see _random_quantized_params)
@@ -335,6 +339,17 @@ class Generator:
     #: 256 (2.1 ridges: the margin is gone); padding left is under one
     #: chunk a group, so the smallest chunk that holds the rate wins.
     ADMIT_CHUNK = 512
+
+    #: prompt tokens a decode step carries of a riding admission
+    #: (``_ride_scan_paged``; the engine rounds it to whole pool blocks).
+    #: A constant, not a knob: a step's int8 weight pass does 2·(B + S)
+    #: flop a weight byte against the v5e's ridge of 240 (above), so some
+    #: 120 tokens go through a pass the decode rows stream anyway; two
+    #: 64-token blocks sit at the ridge beside 8 rows.  Fewer tokens a
+    #: step only add steps to a rider's first token: read on a v5e at 7B
+    #: (PERF.md §6), 64-token segments took a 350-token prompt six steps,
+    #: 115 ms to its first token against the single shot's 75.
+    RIDE_SEGMENT = 128
 
     def _prefill_chunk_body(self, params, tokens, offset, length, caches):
         """Traced body of one prefill chunk: rows at global positions
@@ -811,7 +826,8 @@ class Generator:
     # timing or batch composition.
 
     def _decode_cont_body(self, params, first_tok, cur, active, caches, keys,
-                          temperature, top_k, greedy, n_steps: int, steps):
+                          temperature, top_k, greedy, n_steps: int, steps,
+                          ride=None):
         """Traced body of one continuous-slot decode dispatch: ``steps``
         steps (a traced scalar, ``1 <= steps <= n_steps``) over a FROZEN
         cache view, K/V landing in chunk-local buffers of ``n_steps``
@@ -825,8 +841,18 @@ class Generator:
         and buffer positions from ``steps`` on are zeros no step wrote;
         the PRNG chains advanced once a step that ran; ``moe``:
         ``_apply_counted``'s counters summed over those steps, None for a
-        model without routed experts."""
-        from tpustack.models.llama import init_chunk_bufs
+        model without routed experts.
+
+        ``ride`` (``_ride_scan_paged``): each step also carries the next
+        segment of one row's prompt through the same pass — ``ride`` holds
+        the prompt and where the dispatch's segments lie, and ``line``, the
+        riding row's dense cache line its segments are written into and
+        attend.  Steps past ``seg_n`` recompute the dispatch's last
+        segment (the same values to the same positions) and count for
+        nothing.  The return then ends with ``(line, logits [1, V] at the
+        prompt's last position if a segment held it, the segments'
+        routed-expert counters or None)``."""
+        from tpustack.models.llama import RIDE_KEYS, init_chunk_bufs
 
         S = self.cfg.max_seq
         B = first_tok.shape[0]
@@ -837,11 +863,40 @@ class Generator:
                 else jnp.zeros((3,), jnp.int32))
 
         def step(t, carry):
-            tok, bufs, keys, moe, toks = carry
+            tok, bufs, keys, moe, toks = carry[:5]
             cur_t = jnp.minimum(cur0 + t * active, S - 1)
             merged = [dict(c, **bf) for c, bf in zip(caches, bufs)]
-            logits, merged, counts = self._apply_counted(
-                params, tok, cur_t[:, None], merged, (cur0, t), None)
+            if ride is None:
+                logits, merged, counts = self._apply_counted(
+                    params, tok, cur_t[:, None], merged, (cur0, t), None)
+            else:
+                line, got, rmoe = carry[5]
+                seg = ride["tokens"].shape[1]
+                roff = ride["seg_off"] + seg * jnp.minimum(
+                    t, jnp.maximum(ride["seg_n"] - 1, 0))
+                run = roff + jnp.arange(seg)
+                last_here = jnp.clip(ride["length"] - 1 - roff, 0, seg - 1)
+                logits, merged, counts = self._apply_counted(
+                    params,
+                    jnp.concatenate([tok, jax.lax.dynamic_index_in_dim(
+                        ride["tokens"], roff // seg, keepdims=False
+                    )[:, None]]),
+                    jnp.concatenate([cur_t, run])[:, None],
+                    [dict(m, **{r: ln[r[1:]] for r in RIDE_KEYS
+                                if r[1:] in ln})
+                     for m, ln in zip(merged, line)],
+                    (cur0, t, roff), None, None,
+                    jnp.append(jnp.arange(B), B + last_here))
+                live = t < ride["seg_n"]
+                if counts is not None:
+                    rmoe = rmoe + jnp.where(live, counts[1], 0)
+                    counts = counts[0]
+                hit = live & (ride["length"] - 1 >= roff) & (
+                    ride["length"] - 1 < roff + seg)
+                got = jnp.where(hit, logits[B:, 0], got)
+                line = [{k: d["r" + k] for k in ln}
+                        for d, ln in zip(merged, line)]
+                logits = logits[:B]
             if counts is not None:
                 moe = moe + counts
             bufs = [{k: d[k] for k in bf} for d, bf in zip(merged, bufs)]
@@ -849,14 +904,20 @@ class Generator:
             nxt = self._sample_from_logits_perrow(
                 logits[:, -1].astype(jnp.float32), step_keys, temperature,
                 top_k, greedy)
-            return nxt[:, None], bufs, keys, moe, toks.at[t].set(nxt)
+            out = (nxt[:, None], bufs, keys, moe, toks.at[t].set(nxt))
+            return out if ride is None else out + ((line, got, rmoe),)
 
         steps = jnp.asarray(steps, jnp.int32)
-        last, bufs, keys, moe, toks = jax.lax.fori_loop(
-            0, steps, step,
-            (first_tok, bufs0, keys, moe0, jnp.zeros((n_steps, B), jnp.int32)))
+        carry = (first_tok, bufs0, keys, moe0,
+                 jnp.zeros((n_steps, B), jnp.int32))
+        if ride is not None:
+            carry += ((ride["line"],
+                       jnp.zeros((1, self.cfg.vocab_size), jnp.float32),
+                       moe0),)
+        out = jax.lax.fori_loop(0, steps, step, carry)
+        last, bufs, keys, moe, toks = out[:5]
         cur_end = jnp.minimum(cur0 + steps * active, S - 1)
-        return toks.T, last, cur_end, bufs, keys, moe
+        return (toks.T, last, cur_end, bufs, keys, moe) + tuple(out[5:])
 
     @jax.named_scope("kv_write")
     def _flush_chunk_bufs(self, caches, bufs, cur0, cur_end, n_steps: int):
@@ -1109,6 +1170,69 @@ class Generator:
             {"k": "ck", "v": "cv", "k_scale": "ck_scale",
              "v_scale": "cv_scale"}, cur, valid)
         return toks, last, cur_end, pool, keys, moe
+
+    @functools.partial(jax.jit, static_argnums=(0, 11),
+                       static_argnames=("flash",), donate_argnums=(5,))
+    def _ride_scan_paged(self, params, first_tok, cur, active, pool, bt,
+                         keys, temperature, top_k, greedy, n_steps: int,
+                         steps, ride, flash: bool = False):
+        """``_decode_scan_paged``'s dispatch with a lone admission riding
+        it: each decode step also carries the next segment — whole pool
+        blocks of tokens (``RIDE_SEGMENT``) — of one row's prompt, through
+        the same weight pass (``_decode_cont_body`` with ``ride``;
+        ``LlamaAttention._attend_ride``).  ONE program for every prompt
+        that fits ``ride["tokens"]`` and every segment: all of ``ride`` is
+        operands — ``tokens [L / S, S]`` the prompt padded to ``L``, a
+        segment of ``S`` tokens a row, ``slot``, ``length`` (the
+        prompt's), ``seg_off`` (where this dispatch's first segment
+        starts, a multiple of ``S``),
+        ``seg_n`` (the segments it runs, at most ``steps``; 0 runs the
+        decode steps alone), ``finish`` (whether the prompt's last segment
+        is among them), and the row's ``seed``, ``temp``, ``topk``,
+        ``greedy`` (``[1]`` each).
+
+        The riding row is parked while it rides (its lane decodes nothing
+        and writes nothing).  Its line is gathered from its pages at the
+        start (the segments earlier dispatches wrote), the dispatch's
+        segments are written back to them a whole page at a time at the
+        end, and with ``finish`` its first token is sampled from the
+        logits at the prompt's last position and the row activated, as
+        ``_admit_fused_paged`` activates one: it decodes from the next
+        dispatch on.  Returns ``(toks, firsts [1], pool, moe, ride_moe,
+        cur, active, first, temp, topk, greedy, keys)``: ``moe`` the decode
+        rows' routed-expert counters, ``ride_moe`` the segments' (None
+        without such a layer)."""
+        blk = pool[0]["k"].shape[1]
+        L, seg = ride["tokens"].size, ride["tokens"].shape[1]
+        assert seg % blk == 0, (seg, blk)
+        view = (self._pool_views(pool, bt) if flash
+                else self._pool_gather_body(pool, bt))
+        bt_r = jax.lax.dynamic_slice_in_dim(bt, ride["slot"], 1)[:, :L // blk]
+        line = self._pool_gather_body(pool, bt_r)
+        (toks, last, cur_end, bufs, keys, moe, (line, got, ride_moe)
+         ) = self._decode_cont_body(
+            params, first_tok, cur, active, view, keys, temperature, top_k,
+            greedy, n_steps, steps, ride=dict(ride, line=line))
+        valid = (cur[:, None] + jnp.arange(n_steps)[None, :]
+                 < cur_end[:, None])
+        pool = self._pool_scatter_body(
+            pool, bt, bufs,
+            {"k": "ck", "v": "cv", "k_scale": "ck_scale",
+             "v_scale": "cv_scale"}, cur, valid)
+        at = jnp.arange(L)[None, :]
+        end = jnp.minimum(ride["seg_off"] + ride["seg_n"] * seg,
+                          ride["length"])
+        pool = self._pool_scatter_body(pool, bt_r, line, {}, 0,
+                                       (at >= ride["seg_off"]) & (at < end))
+        firsts, next_keys = self._first_sample(
+            got, ride["seed"], ride["temp"], ride["topk"], ride["greedy"])
+        held = (cur_end, active, last, temperature, top_k, greedy, keys)
+        joined = self._activate_rows(
+            *held, ride["slot"][None], ride["length"][None], firsts,
+            ride["temp"], ride["topk"], ride["greedy"], next_keys)
+        state = tuple(jnp.where(ride["finish"], j, h)
+                      for j, h in zip(joined, held))
+        return (toks, firsts, pool, moe, ride_moe) + state
 
     # --------------------------------------------------- speculative verify
     #

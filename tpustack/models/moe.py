@@ -22,8 +22,9 @@ for every pair landing here.  The same body runs a decode step and a
 512-token admission; only ``tm``, from the static token count, differs.
 
 Counters leave through the ``moe_stats`` collection (``sow``): per call
-``[pairs, experts_touched, max_expert_tokens]``; whoever applies the model
-with ``mutable=["moe_stats"]`` sums them (``llm_generate``).
+``[pairs, experts_touched, max_expert_tokens]`` (a call with ``split``: one
+such row for each of its two parts); whoever applies the model with
+``mutable=["moe_stats"]`` sums them (``llm_generate``).
 """
 
 from __future__ import annotations
@@ -119,7 +120,11 @@ class MoEFeedForward(nn.Module):
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, split: Optional[int] = None
+                 ) -> jax.Array:
+        """``split``: the first ``split`` tokens are counted apart from the
+        rest (a ride's decode rows and its segment, one router call):
+        the counters are then ``[2, 3]``, one row for each part."""
         from tpustack.models.llama import LlamaMLP
 
         c, m = self.cfg, self.cfg.moe
@@ -160,7 +165,16 @@ class MoEFeedForward(nn.Module):
                                        picked * gates[..., None], 0.0),
                              axis=1)
             out = routed + shared.reshape(t, d).astype(jnp.float32)
-        self.sow("moe_stats", "counts", jnp.stack([
-            jnp.sum(plan.counts), jnp.sum(plan.counts > 0),
-            jnp.max(plan.counts)]).astype(jnp.int32))
+        stats = lambda n: jnp.stack([jnp.sum(n), jnp.sum(n > 0),
+                                     jnp.max(n)]).astype(jnp.int32)
+        if split is None:
+            self.sow("moe_stats", "counts", stats(plan.counts))
+        else:
+            local = (chosen - first)[:split]
+            head = jnp.sum(((local >= 0) & (local < held)).reshape(-1)[:, None]
+                           & (local.reshape(-1)[:, None]
+                              == jnp.arange(held)[None, :]), axis=0,
+                           dtype=jnp.int32)
+            self.sow("moe_stats", "counts", jnp.stack(
+                [stats(head), stats(plan.counts - head)]))
         return out.astype(self.dtype).reshape(b, s, d)
