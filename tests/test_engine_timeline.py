@@ -30,7 +30,8 @@ pytestmark = pytest.mark.filterwarnings("ignore")
 GREEDY = SampleConfig(greedy=True)
 #: every phase the engine may charge; a record's host_s has no other key
 PHASES = {"admit", "dispatch", "fetch_wait", "resolve", "resolve_wait",
-          "consume", "park", "draft", "verify", "verify_wait", "other"}
+          "consume", "stream", "record", "gc", "park", "draft", "verify",
+          "verify_wait", "other"}
 SCOPES = ("attn_qkv", "attn_core", "attn_out", "kv_write", "kv_read", "mlp",
           "lm_head", "sample", "norm", "embed")
 
@@ -427,6 +428,166 @@ def test_admission_and_verify_programs_name_the_scopes(paged_programs,
         assert "paged_attention" in text  # flash=True: the kernel, by name
 
 
+# ------------------------------------ (a2) where consume and collections go
+@pytest.mark.parametrize("engine", sorted(ENGINES))
+def test_consume_splits_into_stream_and_record(gen, engine):
+    recs, _ = _run(gen, [REPETITIVE, REPETITIVE[:7], [5, 6, 7]],
+                   max_new=16, **ENGINES[engine](gen))
+    waves = [r for r in recs if r["kind"] in ("wave", "verify")]
+    assert len(waves) >= 2
+    for r in waves:
+        # every wave hands its tokens on and is recorded: both phases are
+        # in every interval, beside what is left of consume
+        assert {"stream", "record"} <= set(r["host_s"]), r["host_s"]
+        if r["wave_s"] is not None:
+            assert sum(r["host_s"].values()) == pytest.approx(
+                r["wave_s"], rel=0.02, abs=5e-5), r
+
+
+@pytest.mark.parametrize("where", ["admit", "wait"])
+def test_a_collection_on_the_engine_thread_is_charged_to_gc(
+        gen, where, monkeypatch):
+    """A full collection forced inside the admission phase, or inside the
+    fetch of a dispatch (a wait), lands in ``host_s.gc``, and the phases
+    still add up to ``wave_s``."""
+    import gc
+
+    pauses = []
+
+    def collect():
+        t0 = time.perf_counter()
+        gc.collect()
+        pauses.append(time.perf_counter() - t0)
+
+    rec = FlightRecorder("eng", capacity=1024)
+    eng = ContinuousEngine(gen, slots=2, chunk=4, flight=rec,
+                           paged=_paged(gen))
+    q = [SlotRequest(ids=list(p), max_new=16, sample=GREEDY)
+         for p in (REPETITIVE, [5, 6, 7], [8, 9, 10, 11])]
+    handed = []
+
+    def feed():
+        if not q:
+            return None
+        handed.append(1)
+        if where == "admit" and len(handed) == 3:  # waves are running
+            collect()
+        return q.pop(0)
+
+    if where == "wait":
+        real, calls = jax.device_get, []
+
+        def device_get(x):
+            calls.append(1)
+            if len(calls) == 6:
+                collect()
+            return real(x)
+
+        monkeypatch.setattr(jax, "device_get", device_get)
+    eng.run(feed)
+    assert len(pauses) == 1
+    waves = [r for r in rec.recent()
+             if r["kind"] == "wave" and r["wave_s"] is not None]
+    hit = [r for r in waves if r["host_s"].get("gc", 0.0)
+           >= 0.9 * pauses[0]]
+    assert hit, (pauses, [r["host_s"] for r in waves])
+    for r in waves:
+        assert sum(r["host_s"].values()) == pytest.approx(
+            r["wave_s"], rel=0.02, abs=5e-5), r
+
+
+def test_the_gc_hook_lives_while_the_engine_runs(gen):
+    import gc
+
+    from tpustack.obs import flight
+
+    seen = []
+    rec = FlightRecorder("eng", capacity=64)
+    eng = ContinuousEngine(gen, slots=2, chunk=4, flight=rec)
+    q = [SlotRequest(ids=[5, 6, 7], max_new=5, sample=GREEDY)]
+
+    def feed():
+        seen.append(flight._on_gc in gc.callbacks)
+        return q.pop(0) if q else None
+
+    eng.run(feed)
+    assert seen and all(seen)
+    assert flight._on_gc not in gc.callbacks
+
+    def broken():
+        raise RuntimeError("feed failed")
+
+    with pytest.raises(RuntimeError):
+        eng.run(broken)
+    assert flight._on_gc not in gc.callbacks  # a failed run removes it too
+
+
+def test_a_collection_elsewhere_is_not_charged_to_the_engine_clock():
+    import gc
+    import threading
+
+    from tpustack.obs import flight
+
+    clock = PhaseClock()
+    flight.gc_attach(clock)
+    was_on = gc.isenabled()
+    gc.disable()  # no collection of this thread's own but the forced one
+    try:
+        with clock.phase("fetch_wait"):
+            t = threading.Thread(target=gc.collect)
+            t.start()
+            t.join(timeout=60)
+        assert not t.is_alive()
+        elsewhere = clock.take()
+        with clock.phase("fetch_wait"):
+            gc.collect()
+        here = clock.take()
+    finally:
+        if was_on:
+            gc.enable()
+        flight.gc_detach()
+    assert "gc" not in elsewhere and elsewhere["fetch_wait"] > 0
+    # the pause inside a wait is the gc phase's, not the wait's
+    assert here["gc"] > here["fetch_wait"]
+    assert flight._on_gc not in gc.callbacks
+
+
+def test_fetch_marks_keep_the_first_and_the_latest(gen):
+    """Two marks, not one a wave: the rate and the Retry-After estimate
+    read what they read from the whole list."""
+    from tpustack.models.llm_continuous import _Slot
+
+    eng = ContinuousEngine(gen, slots=2, chunk=2, min_steps=2,
+                           flight=FlightRecorder("eng", capacity=1024))
+    every, sizes = [], []
+    mark = eng._mark_fetch
+
+    def tracked(slots):
+        mark(slots)
+        with eng._marks_lock:
+            every.append(eng._fetch_marks[-1])
+            sizes.append(len(eng._fetch_marks))
+
+    eng._mark_fetch = tracked
+    q = [SlotRequest(ids=list(p), max_new=24, sample=GREEDY)
+         for p in ([5, 6, 7], [8, 9], [10, 11, 12, 13], [14])]
+    stats = eng.run(lambda: q.pop(0) if q else None)
+    assert len(every) >= 10 and max(sizes) == 2
+    with eng._marks_lock:
+        assert eng._fetch_marks == [every[0], every[-1]]
+    (t0, c0, _), (t1, c1, _) = every[0], every[-1]
+    assert stats["steady_tokens_per_s"] == pytest.approx(
+        (c1 - c0) / (t1 - t0))
+    s = _Slot()
+    s.req = SlotRequest(ids=[1], max_new=100, sample=GREEDY)
+    s.budget, s.out, s.blocks, s.stride_ema = 100, [0], [1, 2, 3], 2.0
+    eng._slots_view = [s]
+    two = eng.projected_block_release_s(3)
+    with eng._marks_lock:
+        eng._fetch_marks = list(every)
+    assert eng.projected_block_release_s(3) == pytest.approx(two)
+
+
 # --------------------------------------------- (c) the profiler's host plane
 def test_a_capture_holds_the_engine_phases_on_a_host_line(gen, tmp_path):
     from jax.profiler import ProfileData
@@ -448,6 +609,40 @@ def test_a_capture_holds_the_engine_phases_on_a_host_line(gen, tmp_path):
     assert names.get("engine/fetch_wait", 0) >= 3, names
     assert names.get("engine/admit", 0) >= 1, names
     assert {n.split("/", 1)[1] for n in names} <= PHASES
+
+
+def test_a_capture_holds_collections_on_the_engine_line(gen, tmp_path):
+    import gc
+
+    from jax.profiler import ProfileData
+
+    _run(gen, [[5, 6, 7]], max_new=5)  # compile outside the capture
+    q = [SlotRequest(ids=[5, 6, 7], max_new=9, sample=GREEDY)]
+
+    def feed():
+        if not q:
+            return None
+        gc.collect()  # inside engine/admit
+        return q.pop(0)
+
+    eng = ContinuousEngine(gen, slots=2, chunk=4,
+                           flight=FlightRecorder("eng", capacity=64))
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run(feed)
+    path = sorted(glob.glob(os.path.join(
+        str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    lines = [[e for e in line.events]
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:") for line in plane.lines]
+    engine = [evs for evs in lines
+              if any(e.name.startswith("engine/") for e in evs)]
+    assert len(engine) == 1
+    admits = [e for e in engine[0] if e.name == "engine/admit"]
+    full = [e for e in engine[0] if e.name == "host/gc2"]
+    assert full, sorted({e.name for e in engine[0]})
+    # the forced collection is nested in the admission that ran it
+    assert any(a.start_ns <= g.start_ns and g.end_ns <= a.end_ns
+               for g in full for a in admits)
 
 
 # ------------------------------- (d) layer kinds: routed experts and windows

@@ -95,7 +95,7 @@ import numpy as np
 from tpustack import sanitize
 from tpustack.models.llm_generate import (Generator, SampleConfig,
                                           resolve_paged_flash)
-from tpustack.obs.flight import PhaseClock
+from tpustack.obs.flight import PhaseClock, gc_attach, gc_detach
 from tpustack.serving.kv_pool import (OutOfBlocks, PagedKVRuntime,
                                       eta_until_blocks)
 from tpustack.utils import get_logger, knobs
@@ -521,6 +521,8 @@ class ContinuousEngine:
         # events on the profiler's host plane.  On wherever there is a
         # flight recorder to read them (a server always has one): a
         # perf_counter pair and an annotation object per phase, ~ten a wave.
+        # While run() runs, the engine thread's garbage collections are a
+        # ``gc`` phase too (tpustack.obs.flight.gc_attach).
         self._clock = PhaseClock() if flight is not None else None
         self._phase = (self._clock.phase if flight is not None
                        else lambda name: _NO_PHASE)
@@ -545,10 +547,11 @@ class ContinuousEngine:
         self._admit_queued = False
         self._fetch_t: Optional[float] = None
         self._retired_tokens = 0
-        # fetch-boundary rate marks: appended by the engine thread once per
-        # wave, read by the SERVER thread computing projected block release
-        # for 429 Retry-After — the only engine state a foreign thread
-        # reads, so it gets a real lock (one uncontended acquire per wave)
+        # fetch-boundary rate marks, the run's first and its latest: set by
+        # the engine thread once per wave, read by the SERVER thread
+        # computing projected block release for 429 Retry-After — the only
+        # engine state a foreign thread reads, so it gets a real lock (one
+        # uncontended acquire per wave)
         self._marks_lock = threading.Lock()
         self._fetch_marks: List[Tuple[float, int, int]] = []  # guarded-by: _marks_lock
         sanitize.install_guards(self)
@@ -1425,9 +1428,10 @@ class ContinuousEngine:
         self._last_wave_t = None  # per-run: wave_s must not span idle gaps
         if self._clock is not None:
             self._clock.reset()  # likewise host_s
-        # (wall time, tokens consumed so far, waves fetched so far) at each
-        # block fetch: the steady-state decode rate is the slope between
-        # the first and last marks — what the bench reports alongside
+            gc_attach(self._clock)
+        # (wall time, tokens consumed so far, waves fetched so far) at the
+        # first and the latest block fetch: the steady-state decode rate is
+        # the slope between them — what the bench reports alongside
         # end-to-end tokens/s; the wave count feeds the per-slot
         # stride-aware projected-block-release estimate
         with self._marks_lock:
@@ -1503,6 +1507,8 @@ class ContinuousEngine:
             # prefix blocks must survive into the next busy period
             self.paged.arrays = state["pool"]
             self._slots_view = None
+            if self._clock is not None:
+                gc_detach()
 
         self._sanitize_wave()  # drain-time recompile + conservation sweep
         dt = time.time() - t_start
@@ -1854,32 +1860,60 @@ class ContinuousEngine:
         if self.ledger is not None:
             self.ledger.charge_flight_wave("llm", rec)
 
+    def _mark_fetch(self, slots) -> None:
+        """A wave was fetched: count it, and move the latest rate mark (the
+        run's first stays)."""
+        self._wave_ctr += 1
+        mark = (time.time(), self._retired_tokens + sum(
+            len(s.out) for s in slots if s.req is not None), self._wave_ctr)
+        with self._marks_lock:
+            marks = self._fetch_marks
+            if len(marks) < 2:
+                marks.append(mark)
+            else:
+                marks[1] = mark
+
+    def _deliver(self, slots, emitted, spec_events=()):
+        """A fetched wave's tokens to their streams (the ``stream`` phase),
+        then onto the engine's own records (``record``): the sanitizer's
+        wave-boundary check, the rows' span events, and the tenant and
+        priority split, taken before the wave's rows retire — a request's
+        last wave still carries its tenant.  ``emitted``: ``(slot, tokens)``
+        of the rows that got tokens; ``spec_events``: ``(slot, drafted,
+        accepted)`` of the rows that drafted.  Returns the two splits."""
+        with self._phase("stream"):
+            for s, accepted in emitted:
+                if s.req.on_tokens is not None:
+                    s.req.on_tokens(accepted)
+        with self._phase("record"):
+            self._sanitize_wave()
+            for s, k_i, m in spec_events:
+                s.span.add_event("spec", drafted=k_i, accepted=m)
+            for s, accepted in emitted:
+                if s.span is not None:
+                    s.span.add_event("wave", tokens=len(accepted))
+            return (self._tenant_occupancy(slots),
+                    self._priority_occupancy(slots))
+
     def _consume_block(self, state, slots, block, d: _Dispatch, moe=None):
         """Host bookkeeping for one fetched plain decode dispatch ``d``
         (the consume half of the wave loop, shared by both run loops):
         ``block`` its fetched tokens, of which the first ``d.steps``
         columns ran.  ``moe``: its fetched routed-expert counters, if the
-        model has any."""
+        model has any.  Rows retire after the wave is delivered."""
         if self._on_progress is not None:
             self._on_progress("wave")
-        self._sanitize_wave()
-        self._wave_ctr += 1
-        with self._marks_lock:
-            self._fetch_marks.append((
-                time.time(), self._retired_tokens + sum(
-                    len(s.out) for s in slots if s.req is not None),
-                self._wave_ctr))
+        self._mark_fetch(slots)
         live = self._live(slots)
-        tenants = self._tenant_occupancy(slots)  # pre-retire, like live
-        priorities = self._priority_occupancy(slots)
         wave_tokens = ctx_tokens = ctx_window = 0
+        emitted, ended = [], []
         for i, gid, offset in d.rows:
             s = slots[i]
             if s.req is None or s.gen_id != gid or s.done:
                 continue  # lane is garbage for a retired/reassigned slot
             if s.req.cancelled():
                 s.done = True
-                self._retire(state, slots, i, live)
+                ended.append(i)
                 continue
             ctx = len(s.req.ids) + len(s.out)
             ctx_tokens += ctx
@@ -1897,18 +1931,20 @@ class ContinuousEngine:
             wave_tokens += len(accepted)
             s.spec_idle += 1  # plain wave: the slot did not draft
             s.stride_ema = 0.75 * s.stride_ema + 0.25 * max(1, len(accepted))
-            if accepted and s.span is not None:
-                s.span.add_event("wave", tokens=len(accepted))
-            if accepted and s.req.on_tokens is not None:
-                s.req.on_tokens(accepted)
+            if accepted:
+                emitted.append((s, accepted))
             if s.done:
-                self._retire(state, slots, i, live)
-        self._flight_wave(slots, "wave", wave_tokens, d.steps,
-                          stride=d.steps, occupancy=live,
-                          tenants=tenants, priorities=priorities,
-                          ctx_tokens=ctx_tokens, ctx_window=ctx_window,
-                          moe=self._moe_fields(moe, passes=d.steps),
-                          cut=d.cut, ride_tokens=d.ride_tokens)
+                ended.append(i)
+        tenants, priorities = self._deliver(slots, emitted)
+        for i in ended:
+            self._retire(state, slots, i, live)
+        with self._phase("record"):
+            self._flight_wave(slots, "wave", wave_tokens, d.steps,
+                              stride=d.steps, occupancy=live,
+                              tenants=tenants, priorities=priorities,
+                              ctx_tokens=ctx_tokens, ctx_window=ctx_window,
+                              moe=self._moe_fields(moe, passes=d.steps),
+                              cut=d.cut, ride_tokens=d.ride_tokens)
 
     def _moe_fields(self, moe, passes: int) -> Dict[str, int]:
         """Flight-record fields of one dispatch's routed-expert work:
@@ -2109,26 +2145,19 @@ class ContinuousEngine:
         spec = self.spec
         if self._on_progress is not None:
             self._on_progress("wave")
-        self._sanitize_wave()
-        self._wave_ctr += 1
-        with self._marks_lock:
-            self._fetch_marks.append((
-                time.time(), self._retired_tokens + sum(
-                    len(s.out) for s in slots if s.req is not None),
-                self._wave_ctr))
+        self._mark_fetch(slots)
         alpha = spec.ema_alpha
         live = self._live(slots)
-        tenants = self._tenant_occupancy(slots)  # pre-retire, like live
-        priorities = self._priority_occupancy(slots)
         wave_tokens = wave_drafted = wave_accepted = 0
         ctx_tokens = ctx_window = 0
+        emitted, ended, spec_events = [], [], []
         for i, gid in rows:
             s = slots[i]
             if s.req is None or s.gen_id != gid or s.done:
                 continue
             if s.req.cancelled():
                 s.done = True
-                self._retire(state, slots, i, live)
+                ended.append(i)
                 continue
             ctx = len(s.req.ids) + len(s.out)
             ctx_tokens += ctx
@@ -2143,7 +2172,7 @@ class ContinuousEngine:
                 wave_drafted += k_i
                 wave_accepted += m
                 if s.span is not None:
-                    s.span.add_event("spec", drafted=k_i, accepted=m)
+                    spec_events.append((s, k_i, m))
                 if self.on_spec is not None:
                     try:
                         self.on_spec(k_i, m)
@@ -2165,20 +2194,22 @@ class ContinuousEngine:
             s.dispatched = len(s.out) - 1
             s.stride_ema = (0.75 * s.stride_ema
                             + 0.25 * max(1, len(accepted)))
-            if accepted and s.span is not None:
-                s.span.add_event("wave", tokens=len(accepted))
-            if accepted and s.req.on_tokens is not None:
-                s.req.on_tokens(accepted)
+            if accepted:
+                emitted.append((s, accepted))
             if s.done:
-                self._retire(state, slots, i, live)
+                ended.append(i)
+        tenants, priorities = self._deliver(slots, emitted, spec_events)
+        for i in ended:
+            self._retire(state, slots, i, live)
         # one verify dispatch = ONE weight pass for all its 1..k+1 strides
-        self._flight_wave(slots, "verify", wave_tokens, 1,
-                          stride=wave_tokens / max(1, len(rows)),
-                          drafted=wave_drafted, accepted=wave_accepted,
-                          occupancy=live, tenants=tenants,
-                          priorities=priorities, ctx_tokens=ctx_tokens,
-                          ctx_window=ctx_window,
-                          moe=self._moe_fields(moe, passes=1))
+        with self._phase("record"):
+            self._flight_wave(slots, "verify", wave_tokens, 1,
+                              stride=wave_tokens / max(1, len(rows)),
+                              drafted=wave_drafted, accepted=wave_accepted,
+                              occupancy=live, tenants=tenants,
+                              priorities=priorities, ctx_tokens=ctx_tokens,
+                              ctx_window=ctx_window,
+                              moe=self._moe_fields(moe, passes=1))
 
     def _run_loop_spec(self, state, slots, chain, admit_free, dispatch_ok):
         """Variable-stride wave loop (``spec`` configured): whenever the

@@ -42,6 +42,7 @@ unwritable dir logs and returns None instead of taking the server down.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -53,8 +54,8 @@ from typing import Callable, Dict, List, Optional, Tuple
 from tpustack.utils import knobs
 
 __all__ = [
-    "FlightRecorder", "PhaseClock", "register", "recorders", "dump_all",
-    "snapshot_all",
+    "FlightRecorder", "PhaseClock", "gc_attach", "gc_detach", "register",
+    "recorders", "dump_all", "snapshot_all",
     "device_peaks_info", "llm_wave_arith", "llm_utilization",
     "sd_utilization",
 ]
@@ -284,6 +285,12 @@ class PhaseClock:
     — an open phase is charged up to now — with whatever no phase covered
     as ``other``, and starts the next interval.  One ``perf_counter`` pair
     per phase; no lock (one thread owns a clock).
+
+    A garbage collection on the owning thread (:func:`gc_attach`) pushes a
+    ``gc`` phase from inside the collector, which may start at any
+    allocation of a tracked object: between reading the time and storing
+    it, ``_push``, ``_pop`` and ``take`` allocate none, so a pause is never
+    charged twice.
     """
 
     __slots__ = ("_acc", "_stack", "_mark", "_annotate")
@@ -313,9 +320,10 @@ class PhaseClock:
             top[1] = now
 
     def _push(self, name: str) -> None:
-        now = time.perf_counter()
+        entry = [name, 0.0]  # allocated before the clock is read
+        now = entry[1] = time.perf_counter()
         self._charge(now)  # the parent pauses
-        self._stack.append([name, now])
+        self._stack.append(entry)
 
     def _pop(self) -> None:
         now = time.perf_counter()
@@ -325,14 +333,15 @@ class PhaseClock:
             self._stack[-1][1] = now  # the parent resumes
 
     def take(self) -> Dict[str, float]:
+        fresh: Dict[str, float] = {}
         now = time.perf_counter()
         self._charge(now)
-        out = {k: round(v, 6) for k, v in self._acc.items()}
-        other = (now - self._mark) - sum(self._acc.values())
+        acc, self._acc = self._acc, fresh
+        mark, self._mark = self._mark, now
+        out = {k: round(v, 6) for k, v in acc.items()}
+        other = (now - mark) - sum(acc.values())
         if other > 0:
             out["other"] = round(other, 6)
-        self._acc = {}
-        self._mark = now
         return out
 
 
@@ -351,6 +360,64 @@ class _Phase:
         self._clock._pop()
         self._ann.__exit__(*exc)
         return False
+
+
+# ------------------------------------------------ garbage collection pauses
+# ``gc.callbacks`` is the process's, so is the table below of which thread's
+# collections are charged to which engine's clock
+#: the host plane's event of a collection of generation ``n``
+_GC_NAMES = tuple(f"host/gc{n}" for n in range(3))
+_GC_LOCK = threading.Lock()
+#: thread ident -> the PhaseClock of the engine running on that thread
+_GC_CLOCKS: Dict[int, PhaseClock] = {}
+#: thread ident -> (annotation, clock charged) of the collection it is in
+_GC_OPEN: Dict[int, Tuple] = {}
+#: ``jax.profiler.TraceAnnotation``, bound when the hook is installed
+_GC_ANNOTATE: List = [None]
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    """The ``gc.callbacks`` hook: a collection is a ``host/gc<generation>``
+    event on the collecting thread's line of the profiler's host plane and,
+    on an engine thread, a ``gc`` phase of its clock — which pauses the
+    phase it interrupted, a wait included."""
+    tid = threading.get_ident()
+    if phase == "start":
+        ann = _GC_ANNOTATE[0](_GC_NAMES[info["generation"]])
+        ann.__enter__()
+        clock = _GC_CLOCKS.get(tid)
+        if clock is not None:
+            clock._push("gc")
+        _GC_OPEN[tid] = (ann, clock)
+        return
+    opened = _GC_OPEN.pop(tid, None)
+    if opened is None:  # the hook came in during this collection
+        return
+    ann, clock = opened
+    if clock is not None:
+        clock._pop()
+    ann.__exit__(None, None, None)
+
+
+def gc_attach(clock: PhaseClock) -> None:
+    """Charge this thread's garbage collections to ``clock`` (a ``gc``
+    phase) until :func:`gc_detach`; the process's one ``gc.callbacks``
+    hook is installed while any clock is attached, and puts every
+    thread's collections on the profiler's host plane."""
+    with _GC_LOCK:
+        _GC_CLOCKS[threading.get_ident()] = clock
+        _GC_ANNOTATE[0] = clock._annotate
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+
+def gc_detach() -> None:
+    """Undo this thread's :func:`gc_attach`; the hook goes with the last
+    clock attached."""
+    with _GC_LOCK:
+        _GC_CLOCKS.pop(threading.get_ident(), None)
+        if not _GC_CLOCKS and _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
 
 
 def _log():
